@@ -145,14 +145,15 @@ def run(args):
 
     workdir = tempfile.mkdtemp(prefix="mx_fleet_bench_")
     if not args.quick:
-        cache = os.path.join(workdir, "compile_cache")
-        os.makedirs(cache, exist_ok=True)
-        os.environ["MXNET_COMPILE_CACHE_DIR"] = cache
+        # replicas inherit one shared persistent cache (every spawn after
+        # the first is warm), at a fixed path
+        from incubator_mxnet_tpu.deploy import default_compile_cache_to_checkout
+        default_compile_cache_to_checkout()
     seconds = args.duration
     out = {"meta": {"bench": "fleet_bench", "quick": bool(args.quick),
                     "stub": bool(args.quick), "duration_s": seconds,
                     "replicas": 2, "pump_threads": args.threads,
-                    "host_cores": os.cpu_count(), "platform": "cpu",
+                    "host_cores": os.cpu_count(),
                     "model": None if args.quick else CFG}}
     try:
         out["meta"]["host_loadavg_1m"] = round(os.getloadavg()[0], 2)
@@ -163,7 +164,7 @@ def run(args):
             "host has fewer cores than replicas: fleet_vs_single_speedup "
             "measures core contention, not added capacity — compare only "
             "against rounds on the same core count")
-    out["backend_ok"] = True    # CPU IS the intended backend here
+    out["backend_ok"] = True
 
     # -- single-replica capacity baseline -------------------------------
     single = serve.Fleet(_spec("v1", 0, args.quick), replicas=1,
@@ -258,6 +259,10 @@ def run(args):
             out["swap"]["first_errors"] = swap_errs[:3]
     finally:
         fleet.close()
+    # stamped only now: the router process stays off jax while replica
+    # processes hold the devices, and every fleet is closed by here
+    import jax
+    out["meta"]["platform"] = jax.devices()[0].platform
     return out
 
 

@@ -221,7 +221,7 @@ def bench_device_interleave(layers, dim, n_elem, reps):
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     devs = jax.devices()
     mesh = Mesh(np.array(devs), ("dp",))
@@ -281,7 +281,7 @@ def main():
     reps = 5 if args.quick else 9
     out = {"meta": {"bench": "overlap_bench", "quick": bool(args.quick),
                     "devices": 8, "host_cores": os.cpu_count(),
-                    "platform": "cpu"}}
+                    "platform": jax.devices()[0].platform}}
 
     if args.quick:
         out["overlap"] = bench_overlap(

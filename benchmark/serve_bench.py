@@ -73,6 +73,13 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import numpy as np
 
 
+def _platform():
+    """The platform the numbers were taken on, as jax reports it — never
+    a literal, so a CPU number cannot carry a device's name."""
+    import jax
+    return jax.devices()[0].platform
+
+
 def _percentiles(lat_ms):
     lat = sorted(lat_ms)
     from incubator_mxnet_tpu.serve.metrics import percentile
@@ -995,15 +1002,24 @@ def bench_autoreg_open_loop(model, workload, rates, duration_s, seed=11,
     return rows
 
 
+_CACHE_CONFIG = ("jax_compilation_cache_dir",
+                 "jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes")
+
+
 def bench_compile_cache_skip(quick):
-    """Warm-replica start: with MXNET_COMPILE_CACHE_DIR set, build an
-    engine (cold — compiles AND serializes both programs), then drop
+    """Warm-replica start: with the persistent compilation cache on, build
+    an engine (cold — compiles AND serializes both programs), then drop
     jax's in-memory caches (what a fresh replica process starts without)
     and build it again — the second warmup deserializes from the
     persistent cache instead of recompiling. Reports both warmup times;
-    the acceptance is warm << cold."""
+    the acceptance is warm << cold. The experiment needs an EMPTY cache,
+    so it points jax at a private directory for its duration and then
+    restores exactly the configuration it found (an externally placed
+    JAX_COMPILATION_CACHE_DIR included)."""
     import tempfile
     import jax
+    from jax.experimental.compilation_cache import compilation_cache as _cc
     from incubator_mxnet_tpu import serve
     from incubator_mxnet_tpu import deploy
 
@@ -1012,12 +1028,18 @@ def bench_compile_cache_skip(quick):
            serve.DecoderConfig(vocab=256, embed=64, layers=3, heads=4,
                                head_dim=16, max_len=80))
     out = {}
+    # arm the process's own configuration first, so the CachedDecoder
+    # constructors below find nothing left to re-point
+    deploy.maybe_enable_compile_cache()
+    found = {k: getattr(jax.config, k) for k in _CACHE_CONFIG}
     with tempfile.TemporaryDirectory(prefix="mx_compile_cache_") as d:
-        saved = os.environ.get("MXNET_COMPILE_CACHE_DIR")
-        saved_armed = deploy._COMPILE_CACHE_ARMED[0]
-        os.environ["MXNET_COMPILE_CACHE_DIR"] = d
-        deploy._COMPILE_CACHE_ARMED[0] = False
         try:
+            jax.config.update("jax_compilation_cache_dir", d)
+            jax.config.update(
+                "jax_persistent_cache_min_compile_time_secs", 0)
+            jax.config.update(
+                "jax_persistent_cache_min_entry_size_bytes", -1)
+            _cc.reset_cache()
             model = serve.CachedDecoder(cfg, seed=5)
             eng = serve.ContinuousEngine(model, max_slots=4).start()
             eng.close()
@@ -1034,17 +1056,9 @@ def bench_compile_cache_skip(quick):
                 out["serve_compile_cache_warm_speedup"] = round(
                     eng.warmup_s / eng2.warmup_s, 2)
         finally:
-            if saved is None:
-                os.environ.pop("MXNET_COMPILE_CACHE_DIR", None)
-            else:
-                os.environ["MXNET_COMPILE_CACHE_DIR"] = saved
-            deploy._COMPILE_CACHE_ARMED[0] = saved_armed
-            # point jax away from the about-to-vanish temp dir (a write
-            # into a deleted dir would warn on every later compile)
-            try:
-                jax.config.update("jax_compilation_cache_dir", saved)
-            except Exception:
-                pass
+            for k, v in found.items():
+                jax.config.update(k, v)
+            _cc.reset_cache()
     return out
 
 
@@ -1374,7 +1388,7 @@ def main():
                         "concurrency": args.concurrency,
                         "duration_s": duration,
                         "host_cores": os.cpu_count(),
-                        "platform": "cpu"}}
+                        "platform": _platform()}}
         (model, workload, block, window, n_prefix,
          shared_len) = _build_shared_prefix(args.quick)
         out["meta"]["model"] = model.config.as_dict()
@@ -1426,7 +1440,7 @@ def main():
                         "duration_s": duration,
                         "draft_tokens": draft,
                         "host_cores": os.cpu_count(),
-                        "platform": "cpu"}}
+                        "platform": _platform()}}
         model, workload, max_prompt, t_max = _build_autoreg(args.quick)
         slots = args.max_slots or min(32, args.concurrency)
         out["meta"]["max_slots"] = slots
@@ -1481,7 +1495,7 @@ def main():
                         "concurrency": args.concurrency,
                         "duration_s": duration,
                         "host_cores": os.cpu_count(),
-                        "platform": "cpu"}}
+                        "platform": _platform()}}
         out.update(bench_sanitize_ab(args.quick, args.concurrency,
                                      duration, max_slots=args.max_slots))
         print(f"sanitizer overhead (all modes vs off): "
@@ -1511,7 +1525,7 @@ def main():
                         "concurrency": args.concurrency,
                         "duration_s": duration,
                         "host_cores": os.cpu_count(),
-                        "platform": "cpu",
+                        "platform": _platform(),
                         "batch_timeout_ms": args.batch_timeout_ms}}
         model, workload, max_prompt, t_max = _build_autoreg(args.quick)
         # slot count defaults to the client concurrency (capped): the
@@ -1599,7 +1613,7 @@ def main():
                         "buckets": buckets,
                         "batch_timeout_ms": args.batch_timeout_ms,
                         "host_cores": os.cpu_count(),
-                        "platform": "cpu"}}
+                        "platform": _platform()}}
         if args.trace_ab:
             out["meta"]["mode"] = "trace_ab"
             ab = bench_trace_ab(model, sample, args.concurrency,

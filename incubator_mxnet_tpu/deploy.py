@@ -35,7 +35,30 @@ _register_env("MXNET_COMPILE_CACHE_DIR", str, None,
               "process (replica, restart) DESERIALIZES instead of "
               "recompiling — replica warmup becomes O(load), not "
               "O(compile). Armed at the first ExportedModel load or "
-              "serve.CachedDecoder build; share the dir across replicas")
+              "serve.CachedDecoder build; share the dir across replicas. "
+              "Ignored when JAX_COMPILATION_CACHE_DIR is set: jax already "
+              "uses that directory and no other is set in code")
+
+# where the repo's own runners (chip_smoke.py, bench.py, fleet_bench,
+# crashtest) keep the cache when no variable places it: a FIXED path inside
+# the checkout (the path is part of a cache key's provenance — a directory
+# that moves between runs never hits)
+CHECKOUT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def default_compile_cache_to_checkout():
+    """For a runner of this checkout, before it (or a child it spawns)
+    arms the cache: when neither variable places it, export
+    `MXNET_COMPILE_CACHE_DIR` as the fixed in-checkout path, so this
+    process's and every child's `maybe_enable_compile_cache()` find the
+    same directory. Never a temp-named one — it would not hit again.
+    Touches the environment only (a router parent stays off jax)."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.environ.setdefault("MXNET_COMPILE_CACHE_DIR",
+                              CHECKOUT_COMPILE_CACHE_DIR)
+
 
 # armed-once latch: jax.config.update is process-global, and re-applying
 # it per model load would spam config churn
@@ -43,37 +66,38 @@ _COMPILE_CACHE_ARMED = [False]
 
 
 def maybe_enable_compile_cache():
-    """Wire `MXNET_COMPILE_CACHE_DIR` onto jax's persistent compilation
-    cache (idempotent; no-op when the env is unset). Must run BEFORE the
-    first compile of the programs it should cover — ExportedModel and
-    serve.CachedDecoder call it in their constructors. The min-time /
-    min-size thresholds are zeroed so even small serving programs (bucket
-    MLPs, decode steps) persist: replica warmup is the target, and a
-    second replica should skip EVERY compile, not just the slow ones.
-    Returns True when the cache is armed."""
+    """Arm jax's persistent compilation cache (idempotent). ONE rule for
+    where it lives:
+
+      1. `JAX_COMPILATION_CACHE_DIR` set — jax already uses it; no other
+         directory is set in code (`MXNET_COMPILE_CACHE_DIR` is ignored);
+      2. else `MXNET_COMPILE_CACHE_DIR` (the repo's own runners default it
+         to `<checkout>/.jax_cache`: `default_compile_cache_to_checkout`);
+      3. else no cache (returns False).
+
+    Must run BEFORE the first compile of the programs it should cover —
+    ExportedModel and serve.CachedDecoder call it in their constructors.
+    The min-time / min-size thresholds are zeroed so even small serving
+    programs (bucket MLPs, decode steps) persist: replica warmup is the
+    target, and a second replica should skip EVERY compile, not just the
+    slow ones. Returns True when the cache is armed."""
     if _COMPILE_CACHE_ARMED[0]:
         return True
-    d = os.environ.get("MXNET_COMPILE_CACHE_DIR")
-    if not d:
+    placed = bool(os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+    d = None if placed else os.environ.get("MXNET_COMPILE_CACHE_DIR")
+    if not placed and not d:
         return False
     import jax
-    jax.config.update("jax_compilation_cache_dir", d)
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except AttributeError:        # older jax: threshold knob absent
-            pass
+    if d:
+        jax.config.update("jax_compilation_cache_dir", d)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # jax initializes its cache backend lazily at the FIRST compile and
     # then never re-reads the dir config: a process that compiled
     # anything before arming would silently keep running cache-less.
-    # Reset forces re-initialization against the new dir.
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
-        _cc.reset_cache()
-    except Exception:
-        pass
+    # Reset forces re-initialization against the configured dir.
+    from jax.experimental.compilation_cache import compilation_cache as _cc
+    _cc.reset_cache()
     _COMPILE_CACHE_ARMED[0] = True
     return True
 

@@ -59,7 +59,7 @@ class FusedInferStep:
     The compiled step maps ``x -> (logits, x_next)`` where ``x_next`` is the
     donated input perturbed by a scalar derived from the logits. The data
     dependence means step N+1 cannot begin before step N produced its
-    output and no step can be elided by a transport layer, while the host
+    output and no step can be elided, while the host
     never blocks between dispatches — per-dispatch latency overlaps with
     device compute exactly like the fused training chain.
 
@@ -184,8 +184,8 @@ class FusedTrainStep:
     """One XLA program per call; with ``steps_per_call=K`` the program runs K
     full train steps via ``lax.scan`` (weights/optimizer-state/BN-stats carry
     on device) — the standard TPU host-loop-elimination pattern: per-dispatch
-    transport latency amortizes K-fold, which is what bounds small-batch
-    throughput on remote-attached chips. Inputs then take a leading (K, ...)
+    host latency amortizes K-fold, which is what bounds small-batch
+    throughput. Inputs then take a leading (K, ...)
     axis. The learning rate is resolved once per call (per-step schedules
     advance by optimizer update count as usual; within one call the lr is a
     trace constant, like the reference's update_on_kvstore batching)."""

@@ -218,6 +218,7 @@ def parse_module(text):
         mname = hm.group(1)
     module = HloModule(mname)
     current = None
+    shapes = {}             # instruction name -> result shape, per computation
     for raw in text.splitlines():
         line = raw.rstrip()
         stripped = line.strip()
@@ -236,6 +237,7 @@ def parse_module(text):
             if comp.is_entry:
                 module.entry_name = comp.name
             current = comp
+            shapes = {}
             continue
         if current is None:
             continue
@@ -246,16 +248,20 @@ def parse_module(text):
         operand_text, attr_tail = _split_operands(body)
         shape = parse_shape(shape_text)
         operands, opshapes = [], []
-        # operand entries look like `f32[4,8]{1,0} %name` or `%name`;
-        # constants may inline literals — those carry no %name and are
-        # skipped (their bytes are trace constants, not HBM traffic)
+        # operand entries look like `f32[4,8]{1,0} %name` or, as XLA
+        # prints them today, `%name` alone — then the shape is the result
+        # shape of the instruction of that name, defined earlier in the
+        # same computation. Constants may inline literals — those carry no
+        # %name and are skipped (their bytes are trace constants, not HBM
+        # traffic)
         for part in _split_top_level(operand_text):
             nm = _OPERAND_NAME_RE.search(part) or \
                 _BARE_OPERAND_RE.search(part)
             if not nm:
                 continue
             operands.append(nm.group(1))
-            opshapes.append(parse_shape(part))
+            opshapes.append(parse_shape(part) or shapes.get(nm.group(1)))
+        shapes[name] = shape
         called = [c for c in _CALLS_RE.findall(attr_tail)]
         opm = _METADATA_OP_RE.search(attr_tail)
         current.instructions.append(HloInstruction(

@@ -22,15 +22,17 @@ and models each one:
 
 Peaks come from a calibration artifact (`benchmark/results/
 roofline_calib.json`, written by `tools/bandwidth.py --calib`) so the ridge
-point tracks the attached hardware, with spec-table fallbacks when no
-calibration ran (the bench-trend 22.4 bf16 TFLOP/s attainable for TPU v5e).
+point tracks the attached hardware. With no calibration, a TPU v5e takes
+its published bf16 peak (`telemetry.device_peak_flops`) and HBM bandwidth;
+any other TPU `device_kind` is an error, not a default, because only the
+v5e's bandwidth is recorded here. The CPU takes a modest fixed row.
 """
 from __future__ import annotations
 
 import json
 import os
 
-from ..base import get_env
+from ..base import MXNetError, get_env
 from . import hlo as _hlo
 
 __all__ = ["instr_flops", "unit_cost", "kernel_units", "analyze_module",
@@ -41,19 +43,39 @@ __all__ = ["instr_flops", "unit_cost", "kernel_units", "analyze_module",
 # repo-relative home of the calibration artifact (tools/bandwidth.py --calib)
 CALIB_PATH = os.path.join("benchmark", "results", "roofline_calib.json")
 
-# spec fallbacks by platform when no measured calibration exists. TPU row:
-# the repo's measured attainable 22.4 bf16 TFLOP/s (bench.py calib phase,
-# BENCH_r03+) and the v5e HBM spec 819 GB/s. CPU row: deliberately modest
-# figures so CPU-only smoke runs classify sanely; real numbers come from
-# the calib artifact.
+# fallback when no measured calibration exists and the platform is not a
+# TPU: deliberately modest figures so CPU-only smoke runs classify sanely;
+# real numbers come from the calib artifact. A TPU's fallback is its
+# published spec (`_tpu_spec_calibration`).
 DEFAULT_CALIBRATIONS = {
-    "tpu": {"peak_flops": 22.4e12, "peak_bytes_per_sec": 819e9,
-            "source": "spec-fallback"},
     "cpu": {"peak_flops": 1.0e11, "peak_bytes_per_sec": 20e9,
             "source": "spec-fallback"},
-    "gpu": {"peak_flops": 100e12, "peak_bytes_per_sec": 900e9,
-            "source": "spec-fallback"},
 }
+
+# HBM bandwidth of one TPU v5e chip (Google Cloud documentation, "TPU
+# v5e") — the one TPU this repo runs on today; the benchmark's peaks table
+# (ROADMAP S1) takes this over, keyed by device_kind like the FLOP/s.
+_TPU_V5E_HBM_BYTES_PER_SEC = 819e9
+_TPU_V5E_KINDS = ("v5 lite", "v5e")
+
+
+def _tpu_spec_calibration():
+    import jax
+    from ..telemetry.steptrace import device_peak_flops
+    kind = getattr(jax.devices()[0], "device_kind", "")
+    peak = device_peak_flops()
+    if peak is None or not any(k in kind.lower() for k in _TPU_V5E_KINDS):
+        # the FLOP/s table knows other TPU kinds; the bandwidth here is
+        # the v5e's alone, and a ridge point from a mixed pair is wrong
+        raise MXNetError(
+            f"no published peak FLOP/s and HBM bandwidth for TPU "
+            f"device_kind {kind!r} (only the v5e's are recorded), and no "
+            "roofline calibration: pass one (path= / MXNET_INSPECT_CALIB) "
+            "— a peak is never guessed")
+    return {"peak_flops": peak,
+            "peak_bytes_per_sec": _TPU_V5E_HBM_BYTES_PER_SEC,
+            "source": "published-spec"}
+
 
 # opcodes that move/relabel data without arithmetic: zero flops, and when
 # they appear standalone (outside a fusion) they are pure-bandwidth units
@@ -260,7 +282,8 @@ def classify(intensity, ridge):
 def load_calibration(path=None, platform=None):
     """Resolve the roofline peaks: explicit path > MXNET_INSPECT_CALIB >
     the committed `benchmark/results/roofline_calib.json` > the platform
-    spec fallback. Returns a dict with at least `peak_flops`,
+    fallback (a TPU's published spec, which raises for a device_kind it
+    does not know; the fixed CPU row otherwise). Returns a dict with at least `peak_flops`,
     `peak_bytes_per_sec`, `ridge_flop_per_byte`, `source`."""
     if platform is None:
         platform = _ambient_platform()
@@ -292,8 +315,8 @@ def load_calibration(path=None, platform=None):
         calib.setdefault("source", cand)
         break
     if calib is None:
-        calib = dict(DEFAULT_CALIBRATIONS.get(
-            platform, DEFAULT_CALIBRATIONS["cpu"]))
+        calib = (_tpu_spec_calibration() if platform == "tpu"
+                 else dict(DEFAULT_CALIBRATIONS["cpu"]))
     calib["ridge_flop_per_byte"] = (
         float(calib["peak_flops"]) / float(calib["peak_bytes_per_sec"]))
     return calib
@@ -382,8 +405,6 @@ def cost_analysis_summary(compiled):
         ca = compiled.cost_analysis()
     except Exception:
         return out
-    if isinstance(ca, (list, tuple)):        # older jax returns [dict]
-        ca = ca[0] if ca else None
     if not ca:
         return out
     try:

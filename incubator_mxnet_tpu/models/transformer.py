@@ -179,7 +179,7 @@ def _attention(x, layer, cfg, mask=None, mesh=None):
                 q_, k_, v_, axis_name="sp", causal=True,
                 use_flash=cfg.ring_flash),
             mesh, in_specs=(spec, spec, spec), out_specs=spec,
-            check_rep=False)(q, k, v)
+            check_vma=False)(q, k, v)
     else:
         from ..ops import nn as _nn
         o = _nn.scaled_dot_product_attention(q, k, v, causal=True)
@@ -272,7 +272,7 @@ def _moe_mlp(x, layer, cfg, mesh=None):
     y, aux = _shard_map(
         body, mesh,
         in_specs=(act_spec, P(), P(ep, None, None), P(ep, None, None)),
-        out_specs=(act_spec, P()), check_rep=False)(
+        out_specs=(act_spec, P()), check_vma=False)(
             x, layer["gate"], layer["mlp_in"], layer["mlp_out"])
     return y, aux
 
@@ -491,7 +491,7 @@ def make_pipeline_train_step(cfg: TransformerConfig, mesh, num_microbatches,
 
     sharded_loss = _shard_map(
         local_loss, jmesh, in_specs=(pspec, P("dp", None)), out_specs=P(),
-        check_rep=False)
+        check_vma=False)
 
     def step_fn(params, opt_state, batch, step):
         loss, grads = jax.value_and_grad(

@@ -417,8 +417,13 @@ def _auto_blocks(tq, tk, d, vmem_budget=8 * 1024 * 1024):
     the sequence lengths (halving preserves divisibility, so the kernel —
     not the dense fallback — runs for any even-pow2-factor length) and
     whose working set — q/k/v/do tiles, the (bq, bk) score tile, and f32
-    accumulators — fits the VMEM budget. Bigger tiles amortize HBM
-    traffic; the cap keeps double-buffering viable."""
+    accumulators — fits the VMEM budget, with `d` counted as the
+    128-lane multiple a (block, d) tile is padded to (head_dim 64 takes
+    the room of 128). Bigger tiles amortize HBM traffic; the cap keeps
+    double-buffering viable."""
+    from .pallas_kernels import _LANES, _round_up
+    d = _round_up(d, _LANES)
+
     def fits(bq, bk):
         tiles = (bq * d * 4 * 2          # q tile + do tile
                  + bk * d * 4 * 4        # k, v tiles + dk/dv accums
@@ -453,7 +458,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     (T, T) score matrix in either direction. Block sizes default to the
     VMEM-budget autotune (_auto_blocks); pass block_q/block_k to pin.
     Falls back to the differentiable blockwise scan off-TPU and to the
-    einsum composition on ragged shapes."""
+    einsum composition on ragged shapes; either way the choice is
+    counted in `fused_stats()` (`pallas_calls` / `fallback_calls`, per
+    trace) like the rest of the kernel tier."""
     import jax
     import jax.numpy as jnp
 
@@ -463,8 +470,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         scale = 1.0 / math.sqrt(d)
 
     from ..device import tpu_platform_available
-    on_tpu = tpu_platform_available()
-    if not (on_tpu or interpret):
+    from .fused import FUSED_STATS, _fell_back, _resolve_interpret
+    interpret = _resolve_interpret(interpret)
+    if not (tpu_platform_available() or interpret):
+        _fell_back("flash_attention", "no TPU: blockwise scan")
         return _blockwise(q, k, v, scale, causal,
                           block_k if block_k else 512)
 
@@ -473,7 +482,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     block_k = min(block_k or auto_k, tk)
     if tq % block_q or tk % block_k:
         # ragged tails: fall back (padding support comes with masked loads)
+        _fell_back("flash_attention",
+                   f"ragged tq={tq} tk={tk} for blocks "
+                   f"({block_q}, {block_k}): dense einsum")
         return _reference(q, k, v, scale, causal)
+    FUSED_STATS["pallas_calls"] += 1
 
     @jax.custom_vjp
     def _fa(q, k, v):

@@ -44,10 +44,41 @@ __all__ = ["apply_scale_shift_act", "avg_pool2d_fwd", "avg_pool2d_bwd",
            "paged_attention_fwd", "supported_act", "ACTS"]
 
 # activation set the kernels (and their hand-derived VJPs) support; None
-# means identity. Kept in sync with ops/fused.py's dispatch tables.
-ACTS = (None, "relu", "sigmoid", "tanh", "silu", "gelu")
+# means identity. Kept in sync with ops/fused.py's dispatch tables. Exact
+# gelu is not here: erf/erfc have no Pallas TPU lowering, so a gelu layer
+# keeps its unfused composition (gluon checks this set before rewriting).
+ACTS = (None, "relu", "sigmoid", "tanh", "silu")
 
 _VMEM_BUDGET = 4 * 1024 * 1024   # bytes of f32 working set per program
+# what one kernel may allocate in VMEM: the TPU compiler's default scoped
+# limit is 16 MiB (v5e), and `_paged_blocks` sizes against 3/4 of it
+_VMEM_SCOPED = 12 * 1024 * 1024
+_LANES = 128
+
+
+def _round_up(n, k):
+    return -(-n // k) * k
+
+
+def _tile_bytes(shape, itemsize):
+    """Bytes a block of `shape` really occupies in VMEM: the minor dim is
+    padded to 128 lanes and the second-minor to the dtype's sublane tile
+    (8 rows of 32-bit, 16 of 16-bit, 32 of 8-bit). A (bt, 12, 64) bf16
+    block takes bt*16*128*2 bytes, 2.7x its unpadded size."""
+    *lead, rows, cols = shape
+    n = _round_up(rows, 8 * (4 // itemsize)) * _round_up(cols, _LANES)
+    for dim in lead:
+        n *= dim
+    return n * itemsize
+
+
+def _pow2_block(n, footprint):
+    """Largest power-of-two divisor `b` of `n` with `footprint(b)` inside
+    `_VMEM_SCOPED`, or 0 when not even b = 1 fits."""
+    b = n & -n                                # largest 2^k dividing n
+    while b > 1 and footprint(b) > _VMEM_SCOPED:
+        b //= 2
+    return b if footprint(b) <= _VMEM_SCOPED else 0
 
 
 def supported_act(act_type):
@@ -65,23 +96,23 @@ def _act_f32(jax, jnp, u, act_type):
         return jnp.tanh(u)
     if act_type == "silu":
         return jax.nn.silu(u)
-    if act_type == "gelu":
-        return jax.nn.gelu(u, approximate=False)
     raise ValueError(f"unsupported fused activation {act_type!r}")
 
 
 def _block_rows(m, c, n_row_bufs, cap=1024):
     """Largest power-of-two row tile that divides `m` and keeps
-    `n_row_bufs` (M, C)-shaped f32 buffers inside the VMEM budget.
-    Returns 0 when even a single row of C floats cannot fit."""
-    if c * 4 * n_row_bufs > _VMEM_BUDGET:
-        return 0
+    `n_row_bufs` (M, C)-shaped f32 buffers inside the VMEM budget (C
+    counted as the 128-lane multiple a tile pads it to). A row tile must
+    be a multiple of 8 sublanes or all of `m` (the TPU block rule), so
+    an `m` without a factor of 8 is taken whole when it fits.
+    Returns 0 when no legal tile fits."""
+    row = _round_up(c, _LANES) * 4 * n_row_bufs
     bm = min(m & -m, cap)                     # largest 2^k dividing m
-    while bm > 1 and bm * c * 4 * n_row_bufs > _VMEM_BUDGET:
+    while bm > 8 and bm * row > _VMEM_BUDGET:
         bm //= 2
-    if bm * c * 4 * n_row_bufs > _VMEM_BUDGET:
-        return 0
-    return bm
+    if bm % 8:
+        bm = m
+    return bm if bm * row <= _VMEM_BUDGET else 0
 
 
 # ---------------------------------------------------------------------------
@@ -172,15 +203,20 @@ def _pool_bwd_kernel(dy_ref, dx_ref, *, ph, pw):
 
 
 def _pool_blocks(n, h, w, c, ph, pw):
-    """(grid, bh) row tiling for the pooling kernels, or None."""
+    """(grid, bh) row tiling for the pooling kernels, or None. The in
+    and out blocks keep (W, C) whole as their tiled minor dims, so any
+    `bh` is a legal block; it is sized so both blocks, double-buffered,
+    and their f32 working copies fit (all counted at f32 width)."""
     if h % ph or w % pw:
         return None
     ho = h // ph
-    # in + out tiles: (bh*ph, W, C) + (bh, W/pw, C) floats
-    bm = _block_rows(ho, w * c * ph + (w // pw) * c, 1)
-    if bm == 0 or ho % bm:
-        return None
-    return (n, ho // bm), bm
+
+    def footprint(bh):
+        return 4 * (_tile_bytes((bh * ph, w, c), 4)
+                    + _tile_bytes((bh, w // pw, c), 4))
+
+    bh = _pow2_block(ho, footprint)
+    return ((n, ho // bh), bh) if bh else None
 
 
 def avg_pool2d_fwd(x, ph, pw, interpret=False):
@@ -207,7 +243,7 @@ def avg_pool2d_fwd(x, ph, pw, interpret=False):
 # paged decode attention over the slotted KV slab
 # ---------------------------------------------------------------------------
 def _paged_attn_kernel(lens_ref, *refs, bt, n_blocks, chunk, scale,
-                       quantized):
+                       layer, quantized):
     """One (lane, token-block) grid step of paged decode attention.
 
     Grid is (S, nT) with the token dimension minor, so the VMEM scratch
@@ -215,7 +251,14 @@ def _paged_attn_kernel(lens_ref, *refs, bt, n_blocks, chunk, scale,
     persist across a lane's sequential token blocks — classic online
     softmax. `lens_ref` is scalar-prefetched: block `t` only computes
     when `t*bt <= len + chunk - 1` (the index map already clamped its
-    HBM fetch to the live prefix)."""
+    HBM fetch to the live prefix).
+
+    int8 slabs: the scale blocks hold EVERY layer's scales for the token
+    block (`(1, L, bt)` — a one-layer block would break the TPU rule
+    that a block's second-minor dim is a multiple of 8 or the array's
+    own) and row `layer` is picked here. A position's scale multiplies
+    its score column / probability column, where positions already sit
+    on the lane axis, instead of the (bt, H, D) codes."""
     import jax
     import jax.numpy as jnp
     import jax.experimental.pallas as pl
@@ -246,10 +289,9 @@ def _paged_attn_kernel(lens_ref, *refs, bt, n_blocks, chunk, scale,
         qf = q_ref[0].astype(jnp.float32)          # (C, H, D)
         kf = k_ref[0, 0].astype(jnp.float32)       # (bt, H, D)
         vf = v_ref[0, 0].astype(jnp.float32)
-        if quantized:
-            kf = kf * ks_ref[0, 0].astype(jnp.float32)[:, None, None]
-            vf = vf * vs_ref[0, 0].astype(jnp.float32)[:, None, None]
         sco = jnp.einsum("chd,thd->hct", qf, kf) * scale
+        if quantized:
+            sco = sco * ks_ref[0, layer:layer + 1, :][None]   # (1, 1, bt)
         # query j (the j-th chunk position) may read KV positions
         # [0, lane_len + j]: the in-chunk causal extension of the
         # engine's `t <= lengths` decode mask
@@ -262,6 +304,8 @@ def _paged_attn_kernel(lens_ref, *refs, bt, n_blocks, chunk, scale,
         p = jnp.exp(sco - m_new[..., None])
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
+        if quantized:
+            p = p * vs_ref[0, layer:layer + 1, :][None]
         acc_ref[...] = (acc_ref[...] * alpha[..., None]
                         + jnp.einsum("hct,thd->hcd", p, vf))
         m_ref[...] = m_new
@@ -274,17 +318,32 @@ def _paged_attn_kernel(lens_ref, *refs, bt, n_blocks, chunk, scale,
             .astype(o_ref.dtype)
 
 
-def _paged_blocks(t, c, h, d):
+def _paged_blocks(t, c, h, d, q_bytes, kv_bytes, n_layers=0):
     """Token-block size for paged attention: the largest power-of-two
-    divisor of `t` whose k+v(+scale) working set stays inside the VMEM
-    budget alongside the per-lane q/out/accumulator buffers, or 0."""
-    fixed = (3 * c * h * d + 2 * c * h) * 4     # q, out, acc, m, l
-    if fixed + 2 * h * d * 4 > _VMEM_BUDGET:
-        return 0
-    bt = t & -t                                  # largest 2^k dividing t
-    while bt > 1 and fixed + 2 * bt * h * (d + 1) * 4 > _VMEM_BUDGET:
-        bt //= 2
-    if fixed + 2 * bt * h * (d + 1) * 4 > _VMEM_BUDGET:
+    divisor of `t` whose VMEM footprint stays inside `_VMEM_SCOPED`, or
+    0. The footprint is counted on PADDED tiles (`_tile_bytes`), the way
+    the TPU compiler allocates it: the pipelined q/out/k/v(/scale)
+    blocks twice over (double buffering), the f32 scratch accumulators,
+    and the body's f32 temporaries — the k/v casts and the head-major
+    copies the two einsums make of them come to 8 (bt, H, D) f32 tiles
+    at (16, 128) heads and under 4 at (12, 64), measured by bisecting
+    the compiler's scoped limit; 8 is used for all — plus the q cast
+    and the (H, C, bt) score and probability tiles. `n_layers > 0`
+    sizes an int8 slab's (L, bt) scale blocks, whose lane dim bt must
+    then be a multiple of 128 or all of `t`."""
+    def footprint(bt):
+        pipelined = 2 * (_tile_bytes((c, h, d), q_bytes)
+                         + _tile_bytes((bt, h, d), kv_bytes))
+        if n_layers:
+            pipelined += 2 * _tile_bytes((n_layers, bt), 4)
+        scratch = _tile_bytes((h, c, d), 4) + 2 * _tile_bytes((h, c), 4)
+        body = (8 * _tile_bytes((bt, h, d), 4)
+                + _tile_bytes((c, h, d), 4)
+                + 2 * _tile_bytes((h, c, bt), 4))
+        return 2 * pipelined + scratch + body
+
+    bt = _pow2_block(t, footprint)
+    if n_layers and bt % _LANES and bt != t:
         return 0
     return bt
 
@@ -298,7 +357,7 @@ def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
     positions clamped to `[0, lengths[s] + j]`. `k_scale`/`v_scale`:
     per-position f32 dequant scales (rows, layers, T) for int8 slabs.
     Returns (S, C, H, D) in q.dtype, or None when the shape does not
-    tile (caller falls back)."""
+    tile (the caller falls back and counts it)."""
     import jax
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -309,8 +368,11 @@ def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
     if k_slab.shape[0] <= s_lanes or k_slab.shape[1] <= layer:
         return None
     quantized = k_scale is not None
-    bt = _paged_blocks(t, c, h, d)
-    if bt == 0 or t % bt:
+    n_layers = k_slab.shape[1]
+    bt = _paged_blocks(t, c, h, d, q.dtype.itemsize,
+                       k_slab.dtype.itemsize,
+                       n_layers if quantized else 0)
+    if bt == 0:
         return None
     n_blocks = t // bt
     scale = 1.0 / float(d) ** 0.5
@@ -327,7 +389,7 @@ def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
 
     def sidx(s, tt, lens_ref):
         need = (lens_ref[s] + c - 1) // bt
-        return (s, layer, jnp.minimum(tt, need))
+        return (s, 0, jnp.minimum(tt, need))
 
     in_specs = [
         pl.BlockSpec((1, c, h, d), qidx),
@@ -336,8 +398,8 @@ def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
     ]
     args = [lengths, q, k_slab, v_slab]
     if quantized:
-        in_specs.append(pl.BlockSpec((1, 1, bt), sidx))
-        in_specs.append(pl.BlockSpec((1, 1, bt), sidx))
+        in_specs.append(pl.BlockSpec((1, n_layers, bt), sidx))
+        in_specs.append(pl.BlockSpec((1, n_layers, bt), sidx))
         args.extend([k_scale, v_scale])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -352,7 +414,7 @@ def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
     )
     kernel = functools.partial(
         _paged_attn_kernel, bt=bt, n_blocks=n_blocks,
-        chunk=c, scale=scale, quantized=quantized)
+        chunk=c, scale=scale, layer=layer, quantized=quantized)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

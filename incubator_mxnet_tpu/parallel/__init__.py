@@ -322,27 +322,17 @@ def replicate(x, mesh=None):
     return shard(x, mesh=mesh)
 
 
-def shard_map(fn, mesh, in_specs, out_specs, check_rep=False):
+def shard_map(fn, mesh, in_specs, out_specs, check_vma=False):
     """Wrap jax.shard_map, tracking bound axis names so framework layers
     (SyncBatchNorm) can detect their collective axes."""
     import jax
-    from jax.sharding import PartitionSpec as P
-    import inspect
-    _sm = getattr(jax, "shard_map", None)
-    if _sm is None:  # older jax
-        from jax.experimental.shard_map import shard_map as _sm
-    kw = {}
-    params = inspect.signature(_sm).parameters
-    if "check_rep" in params:
-        kw["check_rep"] = check_rep
-    elif "check_vma" in params:
-        kw["check_vma"] = check_rep
 
     names = tuple(mesh.axis_names if isinstance(mesh, Mesh)
                   else mesh.axis_names)
     jmesh = mesh.jax_mesh if isinstance(mesh, Mesh) else mesh
 
-    inner = _sm(fn, mesh=jmesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    inner = jax.shard_map(fn, mesh=jmesh, in_specs=in_specs,
+                          out_specs=out_specs, check_vma=check_vma)
 
     def wrapped(*args):
         with _axis_scope(list(names)):

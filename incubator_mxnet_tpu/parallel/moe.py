@@ -39,7 +39,7 @@ def moe_dispatch(x, gate_logits, expert_fn, axis_name="ep", capacity=None,
     import jax.numpy as jnp
 
     T, D = x.shape
-    E = jax.lax.psum(1, axis_name)  # ≙ lax.axis_size (absent in jax<0.5): static int
+    E = jax.lax.axis_size(axis_name)
     assert gate_logits.shape[-1] == E, "one expert per ep rank"
     if capacity is None:
         # capacity scales with top_k (GShard): K*T assignments share the
@@ -115,7 +115,7 @@ def moe_dispatch_expert_choice(x, gate_logits, expert_fn, axis_name="ep",
     import jax.numpy as jnp
 
     T, D = x.shape
-    E = jax.lax.psum(1, axis_name)  # ≙ lax.axis_size (absent in jax<0.5): static int
+    E = jax.lax.axis_size(axis_name)
     assert gate_logits.shape[-1] == E
     C = capacity if capacity is not None else max(2 * T // E, 1)
 

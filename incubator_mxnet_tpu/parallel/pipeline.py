@@ -83,7 +83,7 @@ def pipeline_apply(stage_fn, stage_params, x_microbatches, axis_name="pp"):
     import jax
     import jax.numpy as jnp
 
-    S = jax.lax.psum(1, axis_name)  # ≙ lax.axis_size (absent in jax<0.5): static int
+    S = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     M = x_microbatches.shape[0]
     mb_shape = x_microbatches.shape[1:]
@@ -152,7 +152,7 @@ def pipeline_train_1f1b(stage_fn, stage_params, x_microbatches, loss_fn,
     import jax
     import jax.numpy as jnp
 
-    S = jax.lax.psum(1, axis_name)  # ≙ lax.axis_size (absent in jax<0.5): static int
+    S = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     M = x_microbatches.shape[0]
     mb_shape = x_microbatches.shape[1:]

@@ -65,7 +65,7 @@ def ring_attention(q, k, v, axis_name="sp", causal=False, scale=None,
     d = q.shape[-1]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    n = jax.lax.psum(1, axis_name)  # ≙ lax.axis_size (absent in jax<0.5): static int
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     t_local = q.shape[-2]
 
@@ -135,7 +135,7 @@ def _ring_attention_flash(q, k, v, axis_name, causal, scale):
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     interp = not _on_accel()
-    n = jax.lax.psum(1, axis_name)  # ≙ lax.axis_size (absent in jax<0.5): static int
+    n = jax.lax.axis_size(axis_name)
     rank = jax.lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]
     bq, bk = _auto_blocks(q.shape[1], k.shape[1], d)

@@ -189,16 +189,28 @@ def _sample_tokens(logits, temps, top_ks, top_ps, keys, positions):
     program serves any greedy/sampled mix. The draw key is
     `fold_in(lane_key, position)` (position = the query token's cache
     position), a pure function of request state, so any wave schedule
-    draws the same tokens."""
+    draws the same tokens.
+
+    The truncation and the draw happen in SORTED order, on the one array
+    the sort returns, and the drawn rank maps back through the sort's
+    own permutation. A threshold taken from the sorted values must never
+    be compared with a second evaluation of `logits / temps`: the
+    compiler may feed the sort from the logits matmul's float32
+    accumulators and re-derive the other copy from their bfloat16
+    rounding (seen on the TPU at 10 lanes), and a top logit that rounded
+    down then fails its own threshold — the whole row is masked and
+    token 0 comes out."""
     import jax
     import jax.numpy as jnp
     V = logits.shape[-1]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    srt = jnp.sort(scaled, axis=-1)[:, ::-1]
+    ids = jax.lax.broadcasted_iota(jnp.int32, scaled.shape, 1)
+    neg, order = jax.lax.sort_key_val(-scaled, ids, dimension=1)
+    srt = -neg                                   # descending, ties by id
     kth = jnp.take_along_axis(
         srt, jnp.clip(top_ks - 1, 0, V - 1)[:, None], axis=-1)
-    keep_k = (top_ks[:, None] <= 0) | (scaled >= kth)
+    keep_k = (top_ks[:, None] <= 0) | (srt >= kth)
     probs = jax.nn.softmax(srt, axis=-1)
     csum = jnp.cumsum(probs, axis=-1)
     # smallest prefix whose mass reaches top_p (the kept-set INCLUDES
@@ -206,10 +218,11 @@ def _sample_tokens(logits, temps, top_ks, top_ps, keys, positions):
     keepn = jnp.sum((csum - probs) < top_ps[:, None], axis=-1)
     pth = jnp.take_along_axis(
         srt, jnp.clip(keepn - 1, 0, V - 1)[:, None], axis=-1)
-    masked = jnp.where(keep_k & (scaled >= pth), scaled, -1e30)
+    masked = jnp.where(keep_k & (srt >= pth), srt, -1e30)
     kfold = jax.vmap(jax.random.fold_in)(keys, positions)
-    sampled = jax.vmap(
+    rank = jax.vmap(
         lambda kk, lg: jax.random.categorical(kk, lg))(kfold, masked)
+    sampled = jnp.take_along_axis(order, rank[:, None], axis=-1)[:, 0]
     return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
 
 
@@ -1471,18 +1484,16 @@ class ContinuousEngine:
                 f"— a shape leaked into the compiled step")
         return r
 
-    def memory_plans(self):
-        """Predicted device-memory plans of the TWO compiled step
-        programs (`mx.inspect.memory.memory_plan` over the prefill and
-        decode jits, lowered at the exact warmup shapes via abstract
-        avals — no buffers touched, no extra compile in steady state:
-        the lowering hits the same jit cache entry the engine replays).
-        The KV slab dominates both plans' argument size and is donated,
-        so `alias_size` covering ~2x the slab is the zero-copy-update
-        evidence."""
+    def lowered_programs(self):
+        """The prefill and decode jits lowered at the exact warmup shapes
+        via abstract avals (`{"prefill", "decode"}` of
+        `jax.stages.Lowered`) — no buffers touched, no extra compile in
+        steady state: the lowering hits the same jit cache entry the
+        engine replays. The inspection surface `memory_plans()` reads,
+        and where `chip_smoke.py` looks for the paged-attention kernel
+        in the compiled decode program."""
         import jax
         import jax.tree_util as jtu
-        from ..inspect.memory import memory_plan
 
         def aval(shape, dtype="int32"):
             return jax.ShapeDtypeStruct(shape, dtype)
@@ -1508,10 +1519,17 @@ class ContinuousEngine:
         if self.draft_tokens:
             dec_avals.append(aval((S, self.max_len)))
         decode = self._decode_prog.lower(*dec_avals)
-        return {
-            "prefill": memory_plan(prefill, name=f"{self.name}.prefill"),
-            "decode": memory_plan(decode, name=f"{self.name}.decode"),
-        }
+        return {"prefill": prefill, "decode": decode}
+
+    def memory_plans(self):
+        """Predicted device-memory plans of the TWO compiled step
+        programs (`mx.inspect.memory.memory_plan` over
+        `lowered_programs()`). The KV slab dominates both plans'
+        argument size and is donated, so `alias_size` covering ~2x the
+        slab is the zero-copy-update evidence."""
+        from ..inspect.memory import memory_plan
+        return {name: memory_plan(low, name=f"{self.name}.{name}")
+                for name, low in self.lowered_programs().items()}
 
     def stats(self):
         """Plain-data snapshot: counters, slot occupancy, TTFT/TPOT

@@ -440,17 +440,15 @@ def _sig(a):
 
 def cost_flops(lowered, what="program"):
     """FLOPs from a lowered jit program's XLA cost analysis (MAC = 2 —
-    the same convention as accelerator peak specs), with the older-jax
-    quirks handled once for every MFU numerator (`model_flops`,
-    `FusedTrainStep.flops_per_call`): pre-compile fallback when
-    `.compile().cost_analysis()` raises, list-wrapped results unwrapped."""
+    the same convention as accelerator peak specs), the one numerator
+    every MFU uses (`model_flops`, `FusedTrainStep.flops_per_call`);
+    falls back to the pre-compile analysis when
+    `.compile().cost_analysis()` raises."""
     import numpy as _np
     try:
         ca = lowered.compile().cost_analysis()
     except Exception:
         ca = lowered.cost_analysis()
-    if isinstance(ca, (list, tuple)):   # older jax returns [dict]
-        ca = ca[0]
     if not ca or "flops" not in ca:
         raise MXNetError(
             f"XLA cost analysis returned no flops for {what}")

@@ -48,7 +48,15 @@ def main():
         assert e.missing_ranks == [1], \
             f"expected missing_ranks [1], got {e.missing_ranks}: {e}"
         print("barrier timeout peer-skip OK", flush=True)
-        return 0
+        # leave the way a torn-down job does. The watcher thread is still
+        # inside the abandoned rendezvous, and CPython ends such a daemon
+        # thread at finalization with pthread_exit, which aborts inside
+        # the C++ collective ("FATAL: exception not rethrown", rc 250).
+        # So: disconnect from the coordinator first (rank 1's own
+        # shutdown waits for this one), then exit without finalizing.
+        import jax
+        jax.distributed.shutdown()
+        os._exit(0)
     raise AssertionError("barrier with an absent peer did not time out")
 
 

@@ -1,0 +1,169 @@
+"""Compiles for the chip, without the chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached (`jax.experimental.topologies`). These cases hand it
+the Pallas kernels of the two main paths at the widths `chip_smoke.py` runs —
+what interpret mode cannot see: block shapes the lowering refuses, and more
+VMEM than a kernel may allocate. Each is a second or two and costs no chip
+time. Nothing runs, so nothing here says anything about results or speed.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU's library, and every xdist worker imports
+every test file. The compiles happen in the test's own process, with the
+persistent compilation cache off (an entry written for a described device
+cannot be read back without one).
+"""
+import math
+import os
+
+import pytest
+
+SERVE = dict(lanes=8, heads=12, head_dim=64, max_len=1024, layers=12)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    had = os.environ.get("TPU_LOG_DIR")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")   # no logs under /tmp
+    try:
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:       # noqa: BLE001 — any failure to describe
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        if had is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """Shape factory for arguments placed on one described v5e chip."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    yield shape
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+def _compile(fn, *args):
+    import jax
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _holds_kernel(text):
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+
+
+@pytest.mark.parametrize("kv_dtype,chunk", [
+    ("bfloat16", 1), ("bfloat16", 5), ("int8", 1)],
+    ids=["bf16-C1", "bf16-C5-verify", "int8-C1"])
+def test_paged_attention_compiles_at_gpt2_small_widths(chip, kv_dtype, chunk):
+    """The serve engine's decode attention at the smoke's shapes: plain
+    decode, the speculative-verify chunk, and the int8 slab with its
+    per-position scales."""
+    from incubator_mxnet_tpu.ops import pallas_kernels as pk
+    s = SERVE
+    q = chip((s["lanes"], chunk, s["heads"], s["head_dim"]), "bfloat16")
+    slab = chip((s["lanes"] + 1, s["layers"], s["max_len"], s["heads"],
+                 s["head_dim"]), kv_dtype)
+    lengths = chip((s["lanes"],), "int32")
+    if kv_dtype == "int8":
+        scale = chip((s["lanes"] + 1, s["layers"], s["max_len"]), "float32")
+        text = _compile(
+            lambda q, k, v, n, ks, vs: pk.paged_attention_fwd(
+                q, k, v, n, 3, k_scale=ks, v_scale=vs),
+            q, slab, slab, lengths, scale, scale)
+    else:
+        text = _compile(
+            lambda q, k, v, n: pk.paged_attention_fwd(q, k, v, n, 3),
+            q, slab, slab, lengths)
+    _holds_kernel(text)
+
+
+@pytest.mark.parametrize("shape", [(48, 1024, 64), (16, 4096, 128)],
+                         ids=["gpt2-small-1k", "hd128-4k"])
+@pytest.mark.parametrize("sweep", ["fwd", "bwd"])
+def test_flash_attention_compiles(chip, shape, sweep):
+    from incubator_mxnet_tpu.ops import pallas_attention as pa
+    bh, t, d = shape
+    bq, bk = pa._auto_blocks(t, t, d)
+    scale = 1.0 / math.sqrt(d)
+    x = chip(shape, "bfloat16")
+    row = chip((bh, t, 1), "float32")
+    if sweep == "fwd":
+        text = _compile(
+            lambda q, k, v: pa._flash_forward_lse(
+                q, k, v, True, scale, bq, bk, False), x, x, x)
+    else:
+        text = _compile(
+            lambda q, k, v, do, lse, delta: pa._flash_backward(
+                q, k, v, do, lse, delta, True, scale, bq, bk, False),
+            x, x, x, x, row, row)
+    _holds_kernel(text)
+
+
+@pytest.mark.parametrize("rows,channels", [(100352, 256), (1568, 2048)],
+                         ids=["resnet50-stage1", "resnet50-stage4"])
+def test_fused_apply_compiles_at_resnet50_shapes(chip, rows, channels):
+    """scale/shift + residual + relu, ResNet-50 batch 32, first and last
+    stage."""
+    from incubator_mxnet_tpu.ops import pallas_kernels as pk
+    x = chip((rows, channels), "bfloat16")
+    vec = chip((channels,), "float32")
+    text = _compile(
+        lambda x, s, b, r: pk.apply_scale_shift_act(x, s, b, r, "relu"),
+        x, vec, vec, x)
+    _holds_kernel(text)
+
+
+@pytest.mark.parametrize("sweep", ["fwd", "bwd"])
+def test_global_avg_pool_compiles_at_resnet50_shape(chip, sweep):
+    from incubator_mxnet_tpu.ops import pallas_kernels as pk
+    n, h, w, c = 32, 7, 7, 2048
+    if sweep == "fwd":
+        text = _compile(lambda x: pk.avg_pool2d_fwd(x, h, w),
+                        chip((n, h, w, c), "bfloat16"))
+    else:
+        text = _compile(lambda dy: pk.avg_pool2d_bwd(dy, h, w, h, w),
+                        chip((n, 1, 1, c), "bfloat16"))
+    _holds_kernel(text)
+
+
+def test_hlo_parser_reads_a_tpu_compiled_module(chip):
+    """`mx.inspect` on what the TPU's compiler prints: operands named
+    without shapes, tiled layouts, a dot lowered to a convolution inside a
+    fusion, a Pallas custom call. Every operand resolves to a shape and the
+    matmul's flops are counted whole."""
+    from incubator_mxnet_tpu.inspect import hlo, roofline
+    from incubator_mxnet_tpu.ops import pallas_kernels as pk
+    m, k, n = 256, 512, 128
+
+    def fn(a, b, scale, shift):
+        return pk.apply_scale_shift_act(a @ b, scale, shift, None, "relu")
+
+    vec = chip((n,), "float32")
+    text = _compile(fn, chip((m, k), "bfloat16"), chip((k, n), "bfloat16"),
+                    vec, vec)
+    module = hlo.parse_module(text)
+    unresolved = [(ins.name, ins.opcode)
+                  for comp in module.computations.values()
+                  for ins in comp.instructions
+                  if any(shape is None for shape in ins.operand_shapes)]
+    assert not unresolved
+    calib = {"peak_flops": 197e12, "peak_bytes_per_sec": 819e9}
+    records, totals = roofline.analyze_module(module, calib=calib)
+    assert totals["flops"] == 2.0 * m * k * n
+    assert any(r["opcode"] == "custom-call" for r in records)

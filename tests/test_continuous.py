@@ -676,6 +676,77 @@ def test_compile_cache_dir_warms_second_replica(tmp_path):
     assert warm <= cold * 1.2, (cold, warm)
 
 
+_CACHE_RULE_PROG = """
+import json, os, sys
+import jax
+from incubator_mxnet_tpu import deploy
+if {runner}:
+    deploy.default_compile_cache_to_checkout()
+armed = deploy.maybe_enable_compile_cache()
+before = jax.config.jax_compilation_cache_dir
+after = before
+if {experiment}:
+    sys.path.insert(0, os.path.join({repo!r}, "benchmark"))
+    import serve_bench
+    out = serve_bench.bench_compile_cache_skip(quick=True)
+    assert out["compile_cache_entries"] > 0, out
+    after = jax.config.jax_compilation_cache_dir
+print(json.dumps({{"armed": armed, "dir": before, "after": after,
+                  "checkout": deploy.CHECKOUT_COMPILE_CACHE_DIR}}))
+"""
+
+
+def _cache_rule(tmp_path, env, runner=False, experiment=False):
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    base = {k: v for k, v in os.environ.items()
+            if k not in ("JAX_COMPILATION_CACHE_DIR",
+                         "MXNET_COMPILE_CACHE_DIR")}
+    r = subprocess.run(
+        [sys.executable, "-c", _CACHE_RULE_PROG.format(
+            runner=runner, experiment=experiment, repo=repo)],
+        env=dict(base, JAX_PLATFORMS="cpu", **env), cwd=repo,
+        capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, f"stdout={r.stdout}\nstderr={r.stderr}"
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def test_compile_cache_placed_from_outside_is_left_alone(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: jax already uses it, and no other
+    directory is set in code — MXNET_COMPILE_CACHE_DIR included."""
+    placed, other = str(tmp_path / "placed"), str(tmp_path / "other")
+    got = _cache_rule(tmp_path, {"JAX_COMPILATION_CACHE_DIR": placed,
+                                 "MXNET_COMPILE_CACHE_DIR": other},
+                      runner=True)
+    assert got["armed"] is True and got["dir"] == placed
+
+
+def test_compile_cache_default_is_the_fixed_checkout_path(tmp_path):
+    """No variable: library callers arm nothing; the repo's runners
+    (chip_smoke.py, bench.py: `default_compile_cache_to_checkout()` first)
+    get `<checkout>/.jax_cache`, a fixed path."""
+    got = _cache_rule(tmp_path, {})
+    assert got["armed"] is False and got["dir"] is None
+    got = _cache_rule(tmp_path, {}, runner=True)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert got["armed"] is True
+    assert got["dir"] == got["checkout"] == os.path.join(repo, ".jax_cache")
+    # MXNET_COMPILE_CACHE_DIR still places it when jax's own is unset
+    mine = str(tmp_path / "mine")
+    got = _cache_rule(tmp_path, {"MXNET_COMPILE_CACHE_DIR": mine},
+                      runner=True)
+    assert got["dir"] == mine
+
+
+def test_compile_cache_experiment_restores_what_it_found(tmp_path):
+    """serve_bench's cold/warm experiment runs in a private directory and
+    then restores exactly the configured one (it used to reset jax to the
+    MXNET_ value — possibly None — dropping an externally placed cache)."""
+    placed = str(tmp_path / "placed")
+    got = _cache_rule(tmp_path, {"JAX_COMPILATION_CACHE_DIR": placed},
+                      experiment=True)
+    assert got["dir"] == placed and got["after"] == placed
+
+
 # ---------------------------------------------------------------------------
 # bench smoke + committed artifact acceptance
 # ---------------------------------------------------------------------------

@@ -276,9 +276,10 @@ def test_spec_acceptance_variance_never_retraces(decoder, spec_engine):
 # ---------------------------------------------------------------------------
 def test_paged_attention_kernel_matches_ref_interpret():
     """Pallas kernel (interpret mode) vs the masked-einsum reference over
-    a multi-block slab (T=48 -> 16-wide blocks): float32 and int8+scales,
+    a multi-block slab (T=384 -> three 128-wide blocks, the narrowest an
+    int8 slab's scale blocks may be on a TPU): float32 and int8+scales,
     chunk widths 1 (plain decode) and 3 (speculative verify)."""
-    S, H, D, T, L = 4, 4, 8, 48, 2
+    S, H, D, T, L = 4, 4, 8, 384, 2
     rng = np.random.RandomState(0)
     k_slab = jnp.asarray(rng.randn(S + 1, L, T, H, D).astype(np.float32))
     v_slab = jnp.asarray(rng.randn(S + 1, L, T, H, D).astype(np.float32))
@@ -292,7 +293,7 @@ def test_paged_attention_kernel_matches_ref_interpret():
         (rng.rand(S + 1, L, T) * 0.1 + 0.01).astype(np.float32))
     for C in (1, 3):
         q = jnp.asarray(rng.randn(S, C, H, D).astype(np.float32))
-        lengths = jnp.asarray([1, 7, T - C, 16], dtype=jnp.int32)
+        lengths = jnp.asarray([1, 130, T - C, 127], dtype=jnp.int32)
         layer = 1           # non-zero: the slab's layer stride is live
         out = PK.paged_attention_fwd(q, k_slab, v_slab, lengths,
                                      layer, interpret=True)

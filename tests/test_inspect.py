@@ -202,6 +202,32 @@ def test_load_calibration_platform_guard(tmp_path, monkeypatch):
         platform="cpu")["source"] == "spec-fallback"
 
 
+@pytest.mark.parametrize("kind,known", [
+    ("TPU v5 lite", True), ("TPU v4", False), ("TPU v7x", False)])
+def test_tpu_spec_fallback_is_the_v5e_pair_or_an_error(
+        tmp_path, monkeypatch, kind, known):
+    """With no calibration a TPU's peaks come from the published spec —
+    FLOP/s and HBM bandwidth of the SAME chip. Only the v5e's bandwidth
+    is recorded, so a v4 (whose FLOP/s the table knows) is an error like
+    an unknown kind: a ridge from a mixed pair would be wrong."""
+    import jax
+
+    class _Dev:
+        platform, device_kind = "tpu", kind
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
+    monkeypatch.setattr(roofline, "CALIB_PATH", str(tmp_path / "none.json"))
+    monkeypatch.delenv("MXNET_INSPECT_CALIB", raising=False)
+    if known:
+        cal = roofline.load_calibration(platform="tpu")
+        assert cal["source"] == "published-spec"
+        assert cal["peak_flops"] == 197e12
+        assert cal["peak_bytes_per_sec"] == 819e9
+    else:
+        with pytest.raises(MXNetError, match="never guessed"):
+            roofline.load_calibration(platform="tpu")
+
+
 def _flat_calib():
     return {"peak_flops": 1e12, "peak_bytes_per_sec": 1e11,
             "ridge_flop_per_byte": 10.0, "source": "test"}
@@ -244,11 +270,11 @@ def test_cost_analysis_summary_variants():
         _FakeCompiled("", {"flops": 12.0, "bytes accessed": 34.0}))
     assert ok == {"flops": 12.0, "bytes_accessed": 34.0,
                   "bytes_estimated": True}
-    # older jax returns [dict]
-    lst = roofline.cost_analysis_summary(
-        _FakeCompiled("", [{"flops": 5.0}]))
-    assert lst["flops"] == 5.0
-    assert lst["bytes_accessed"] is None and not lst["bytes_estimated"]
+    # a backend that reports flops only: bytes stay unknown
+    part = roofline.cost_analysis_summary(
+        _FakeCompiled("", {"flops": 5.0}))
+    assert part["flops"] == 5.0
+    assert part["bytes_accessed"] is None and not part["bytes_estimated"]
     # raising backends degrade to all-None, never crash
     bad = roofline.cost_analysis_summary(
         _FakeCompiled("", RuntimeError("unsupported")))
@@ -271,7 +297,7 @@ def test_flops_only_degradation_when_bytes_unknowable():
     text = """\
 HloModule opaque
 ENTRY %main (p: f32[8]) -> f32[8] {
-  %p = f32[8]{0} parameter(0)
+  %p = garbage parameter(0)
   ROOT %custom-call.1 = garbage custom-call(%p), custom_call_target="x"
 }
 """

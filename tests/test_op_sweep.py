@@ -46,9 +46,10 @@ def BOOLS(shape=(3, 4)):
 SPECS = {}
 
 
-def spec(name, inputs, kw=None, ref=None, grad=False, rtol=2e-5, atol=1e-5):
+def spec(name, inputs, kw=None, ref=None, grad=False, rtol=2e-5, atol=1e-5,
+         grad_atol=2e-3):
     SPECS[name] = dict(inputs=inputs, kw=kw or {}, ref=ref, grad=grad,
-                       rtol=rtol, atol=atol)
+                       rtol=rtol, atol=atol, grad_atol=grad_atol)
 
 
 def u(name, gen=F, grad=True, **k):
@@ -390,9 +391,13 @@ def _np_sdpa(q, k, v):
     return a @ v
 
 
+# grad_atol: the finite-difference side runs in float32 (x64 is off) with
+# eps 1e-3 on loss = sum(out**2) ~ 20, so its rounding noise is about
+# 20 * 2**-24 / 1e-3 = 1.2e-3 per element — the default 2e-3 sits on that
+# floor (a 2.2e-3 miss on a gradient entry of 2e-3), 5e-3 clears it
 spec("npx.scaled_dot_product_attention",
      lambda: [F((2, 3, 4)), F((2, 3, 4)), F((2, 3, 4))],
-     ref=_np_sdpa, grad=True, rtol=1e-4, atol=1e-4)
+     ref=_np_sdpa, grad=True, rtol=1e-4, atol=1e-4, grad_atol=5e-3)
 spec("npx.stop_gradient", lambda: [F()], ref=lambda x: x)
 
 # ---- fused kernel tier (PR 8; ops/fused.py — off-TPU these ARE the jnp
@@ -653,4 +658,4 @@ def test_backward_numeric(name):
         out = fn(*nds, **s["kw"])
         return (out * out).sum() if name != "np.prod" else out.sum()
 
-    check_numeric_gradient(loss, arrays, rtol=2e-2, atol=2e-3)
+    check_numeric_gradient(loss, arrays, rtol=2e-2, atol=s["grad_atol"])

@@ -88,7 +88,7 @@ def _run_moe(router, top_k=1, capacity=None):
     f = parallel.shard_map(inner, m,
                            in_specs=(P("ep", None), P("ep", None)),
                            out_specs=(P("ep", None), P()),
-                           check_rep=False)
+                           check_vma=False)
     with m:
         y, aux = f(x, logits)
     return x, logits, np.asarray(y), float(np.asarray(aux).reshape(-1)[0])

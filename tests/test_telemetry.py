@@ -593,21 +593,36 @@ def test_benchdiff_dead_backend_is_skipped_not_failed(tmp_path):
     assert rep["reason"] == "backend_dead_new"
 
 
-def test_benchdiff_compares_committed_trend_rounds():
-    """The real repo trend: r04 (no JSON) / r05 (dead backend) must read
-    as skipped — the exact false-signal classes this tool exists for."""
+def test_benchdiff_compares_committed_trend_rounds(tmp_path):
+    """Rounds in the driver's wrapper shape ({"n", "cmd", "rc", "tail",
+    "parsed"}): a run that died before any JSON (parsed null) and a
+    pre-`backend_ok` round that reported an error with value 0 must both
+    read as a dead backend and compare as skipped — the false-signal
+    classes this tool exists for."""
     sys.path.insert(0, os.path.join(REPO, "tools"))
     try:
         import benchdiff
     finally:
         sys.path.pop(0)
-    rounds = benchdiff.find_rounds(REPO)
-    assert len(rounds) >= 5
-    r4 = benchdiff.load_round(os.path.join(REPO, "BENCH_r04.json"))
+    wrap = {"cmd": "python bench.py", "tail": ""}
+    rounds = {
+        3: dict(wrap, n=3, rc=0, parsed={
+            "metric": "resnet50_train_images_per_sec_bs32",
+            "value": 2602.56, "unit": "images/sec"}),
+        4: dict(wrap, n=4, rc=1, parsed=None),
+        5: dict(wrap, n=5, rc=0, parsed={
+            "metric": "resnet50_train_images_per_sec_bs32", "value": 0.0,
+            "error": "accelerator backend unavailable"}),
+    }
+    for n, payload in rounds.items():
+        with open(tmp_path / f"BENCH_r{n:02d}.json", "w") as f:
+            json.dump(payload, f)
+    assert len(benchdiff.find_rounds(str(tmp_path))) == 3
+    r4 = benchdiff.load_round(str(tmp_path / "BENCH_r04.json"))
     assert benchdiff.backend_dead(r4)
-    r5 = benchdiff.load_round(os.path.join(REPO, "BENCH_r05.json"))
+    r5 = benchdiff.load_round(str(tmp_path / "BENCH_r05.json"))
     assert benchdiff.backend_dead(r5)
-    r3 = benchdiff.load_round(os.path.join(REPO, "BENCH_r03.json"))
+    r3 = benchdiff.load_round(str(tmp_path / "BENCH_r03.json"))
     assert not benchdiff.backend_dead(r3)
     rep = benchdiff.compare(r3, r5)
     assert rep["status"] == "skipped"

@@ -78,7 +78,7 @@ def measure(sizes_mb, reps):
         if n > 1:
             x = jax.device_put(host, shard)
             # allreduce: psum inside shard_map over the axis
-            from jax.experimental.shard_map import shard_map
+            from jax import shard_map
             f_ar = jax.jit(shard_map(lambda v: jax.lax.psum(v, "x"),
                                      mesh=mesh, in_specs=P("x"),
                                      out_specs=P("x")))

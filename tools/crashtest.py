@@ -452,11 +452,11 @@ def _fleet_mode(workdir, args):
     import time
 
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    cache = os.path.join(workdir, "compile_cache")
-    os.makedirs(cache, exist_ok=True)
-    os.environ["MXNET_COMPILE_CACHE_DIR"] = cache
     sys.path.insert(0, REPO)
     from incubator_mxnet_tpu import serve
+    # the replicas' shared persistent cache (warm respawn), at a fixed path
+    from incubator_mxnet_tpu.deploy import default_compile_cache_to_checkout
+    default_compile_cache_to_checkout()
 
     spec = {"version": "v1", "seed": args.seed,
             "config": dict(vocab=64, embed=32, layers=2, heads=4,
