@@ -29,6 +29,7 @@ import numpy as _np
 
 from ...base import MXNetError
 from ...ops import fused as _fused_mod
+from ...telemetry import span as _span, NO_SPAN, trace as _trace
 
 # "argument not given" marker for knobs whose default is a real value
 # (None = XLA-default remat, True = donate) — lets the mx.tune profile
@@ -266,6 +267,7 @@ class FusedTrainStep:
                             if p.grad_req == "null"]
         self._states = None
         self._jit = None
+        self._calls = 0                 # the live `train.step` span's number
         self._meta = {"aux_idx": None}  # frozen params mutated in forward
 
     # ------------------------------------------------------------------
@@ -310,7 +312,8 @@ class FusedTrainStep:
                 try:
                     with autograd._Scope(recording=False, training=True), \
                             _random.trace_key_scope(key), \
-                            _fused_mod.fusion_scope(use_fusion):
+                            _fused_mod.fusion_scope(use_fusion), \
+                            jax.named_scope("forward"):
                         out = fn(net, *[_wrap(r) for r in in_raw])
                     if isinstance(out, (tuple, list)):
                         loss, extras = out[0], tuple(out[1:])
@@ -347,6 +350,8 @@ class FusedTrainStep:
                 loss_of = jax.checkpoint(
                     loss_of, policy=jax.checkpoint_policies.dots_saveable,
                     prevent_cse=False)
+            # the backward pass carries the forward's scope under jax's
+            # own transform names: `transpose(jvp(forward))`
             (loss, (extras, aux_bufs)), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(list(train_bufs))
 
@@ -365,16 +370,18 @@ class FusedTrainStep:
             opt.rescale_grad = rescale  # mxlint: disable=trace-closure-mutation
             try:
                 new_w, new_s = [], []
-                for k, i in enumerate(train_idx):
-                    w = _wrap(train_bufs[k])
-                    g = _wrap(grads[k])
-                    st = _wrap_state(sbufs[k])
-                    if takes_t:
-                        opt.step_one(i, w, g, st, lrs[k], wds[k], t=ts[k])
-                    else:
-                        opt.step_one(i, w, g, st, lrs[k], wds[k])
-                    new_w.append(w._arr)
-                    new_s.append(_state_bufs(st))
+                with jax.named_scope("update"):
+                    for k, i in enumerate(train_idx):
+                        w = _wrap(train_bufs[k])
+                        g = _wrap(grads[k])
+                        st = _wrap_state(sbufs[k])
+                        if takes_t:
+                            opt.step_one(i, w, g, st, lrs[k], wds[k],
+                                         t=ts[k])
+                        else:
+                            opt.step_one(i, w, g, st, lrs[k], wds[k])
+                        new_w.append(w._arr)
+                        new_s.append(_state_bufs(st))
             finally:
                 opt.rescale_grad = prev  # mxlint: disable=trace-closure-mutation -- restore of the trace-time swap
             # fold BN-stat updates back into the frozen set so a scanned
@@ -474,6 +481,15 @@ class FusedTrainStep:
         return cost_flops(self.lowered(*inputs), what="the fused step")
 
     def __call__(self, *inputs):
+        # live spans on the profiler's clock while a collector is armed
+        # (docs/OBSERVABILITY.md "Hot-path spans"); one gate a call
+        on = _trace.armed()
+        self._calls += 1
+        with (_span("train.step", step_num=self._calls) if on
+              else NO_SPAN):
+            return self._run(inputs, on)
+
+    def _run(self, inputs, on):
         from ... import random as _random
         from ...ndarray import NDArray, _wrap
         from ...optimizer import _state_bufs, _state_restore
@@ -507,9 +523,10 @@ class FusedTrainStep:
             _stage_raw(a._arr if isinstance(a, NDArray) else a)
             for a in inputs)
 
-        new_w, new_s, loss, extras, aux_bufs = self._jit(
-            train_bufs, sbufs, frozen_bufs, key, lrs, wds,
-            _np.float32(opt.rescale_grad), ts, *in_raw)
+        with (_span("train.step.dispatch") if on else NO_SPAN):
+            new_w, new_s, loss, extras, aux_bufs = self._jit(
+                train_bufs, sbufs, frozen_bufs, key, lrs, wds,
+                _np.float32(opt.rescale_grad), ts, *in_raw)
 
         for k, i in enumerate(self._train_idx):
             self._params[i].data()._set_arr(new_w[k])

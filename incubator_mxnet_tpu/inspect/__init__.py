@@ -14,7 +14,7 @@ rank offenders by estimated time share:
 CLI: `python tools/offenders.py --model resnet18 --json out.json`.
 Calibration: `python tools/bandwidth.py --calib` writes
 `benchmark/results/roofline_calib.json` (see docs/PERF.md). Knobs:
-`MXNET_INSPECT_TOP_K`, `MXNET_INSPECT_MEASURED`, `MXNET_INSPECT_CALIB`.
+`MXNET_INSPECT_TOP_K`, `MXNET_INSPECT_CALIB`.
 Catalog of the `inspect.*` registry metrics: docs/OBSERVABILITY.md.
 """
 from __future__ import annotations

@@ -28,12 +28,10 @@ Trend scalars (bench.py `offenders` phase, benchdiff TREND_KEYS):
                             roofline bound; the honest target for kernel
                             work, diffable round over round
 
-Measured mode (`MXNET_INSPECT_MEASURED=1` + an `execute=` callback):
-attempts a `jax.profiler` device trace around real executions. When the
-backend/toolchain cannot produce a readable device trace (CPU containers),
-the report keeps the cost-model estimate and says so — `measured: false`
-with the reason — rather than inventing numbers; wall-clock timing of the
-executions is reported either way (`measured_wall_ms`).
+With an `execute=` callback the report also carries the wall-clock time
+of real executions (`measured_wall_ms`); its shares stay cost-model
+estimates. Device time per program and per kernel comes from a traced
+run of the benchmark (`chipbench`, PERF.md), not from here.
 """
 from __future__ import annotations
 
@@ -52,10 +50,6 @@ _register_env("MXNET_INSPECT_TOP_K", int, 10,
               "Offender-report depth: fusions listed by tools/offenders.py "
               "and the bench offenders phase (totals always cover the "
               "whole module)")
-_register_env("MXNET_INSPECT_MEASURED", bool, False,
-              "1 = inspect_step attempts a jax.profiler device trace "
-              "around real executions; falls back to the cost-model "
-              "estimate (measured: false) when the backend cannot trace")
 _register_env("MXNET_INSPECT_CALIB", str, None,
               "Path to a roofline calibration JSON overriding "
               "benchmark/results/roofline_calib.json "
@@ -106,15 +100,14 @@ def lower_any(obj, *args):
 
 
 def inspect_step(obj, *args, name=None, top_k=None, calib=None,
-                 measured=None, execute=None):
+                 execute=None):
     """Offender report for one compiled step. See module docstring.
 
     `execute`: zero-arg callable running the program once on real buffers;
-    enables measured mode and `measured_wall_ms`."""
+    adds `measured_wall_ms`."""
     compiled = lower_any(obj, *args)
     return inspect_compiled(compiled, name=name or _name_of(obj),
-                            top_k=top_k, calib=calib, measured=measured,
-                            execute=execute)
+                            top_k=top_k, calib=calib, execute=execute)
 
 
 def _name_of(obj):
@@ -123,12 +116,10 @@ def _name_of(obj):
 
 
 def inspect_compiled(compiled, name="step", top_k=None, calib=None,
-                     measured=None, execute=None):
+                     execute=None):
     """Report dict for an already compiled stage (json.dumps-safe)."""
     if top_k is None:
         top_k = get_env("MXNET_INSPECT_TOP_K", 10, typ=int)
-    if measured is None:
-        measured = get_env("MXNET_INSPECT_MEASURED", False, typ=bool)
     if calib is None:
         calib = _roofline.load_calibration()
     with span("inspect.analyze", target=name):
@@ -174,16 +165,12 @@ def inspect_compiled(compiled, name="step", top_k=None, calib=None,
         "topk_byte_coverage": _byte_coverage(groups, top_k, totals),
         "topk_time_coverage": round(
             sum(g["time_share"] for g in groups[:top_k]), 6),
-        "measured": False,
     }
     if ca["flops"] is not None and totals["flops"] > 0:
         report["model_vs_xla_flops"] = round(
             totals["flops"] / ca["flops"], 4) if ca["flops"] else None
     if execute is not None:
-        report.update(_measure(execute, measured))
-    elif measured:
-        report["measured_unavailable_reason"] = (
-            "measured mode needs an execute= callback with real buffers")
+        report["measured_wall_ms"] = _wall_ms(execute)
     INSPECT_RUNS.inc()
     INSPECT_UNITS.inc(totals["units"])
     _TOP1.set(report["offender_top1_share"])
@@ -256,47 +243,14 @@ def _byte_coverage(records, k, totals):
     return round(sum(r["bytes"] for r in records[:k]) / totals["bytes"], 6)
 
 
-def _measure(execute, measured, reps=3):
-    """Wall-clock the executions always; attempt a device trace when
-    measured mode is on. A backend that cannot produce a readable trace
-    (CPU containers without the profiler toolchain) degrades to the
-    cost-model numbers with `measured: false` + the reason."""
+def _wall_ms(execute, reps=3):
+    """Wall-clock ms per execution, the first (compiling) one left out."""
     import time as _time
-    out = {}
-    execute()                                   # warm (compile outside clock)
+    execute()
     t0 = _time.perf_counter()
     for _ in range(reps):
         execute()
-    out["measured_wall_ms"] = round(
-        (_time.perf_counter() - t0) / reps * 1e3, 3)
-    if not measured:
-        return out
-    import glob
-    import tempfile
-    try:
-        import jax
-        with tempfile.TemporaryDirectory() as d:
-            with jax.profiler.trace(d):
-                execute()
-            planes = glob.glob(
-                os.path.join(d, "**", "*.xplane.pb"), recursive=True)
-            if not planes:
-                raise RuntimeError("profiler produced no device trace")
-            # device-plane attribution needs the xplane toolchain; absent
-            # (no tensorflow/xprof in this runtime) the honest answer is
-            # the estimate, flagged unmeasured — never fabricated timings
-            out["measured"] = False
-            out["measured_trace_files"] = len(planes)
-            out["measured_unavailable_reason"] = (
-                "device trace captured but no xplane parser available in "
-                "this runtime; per-fusion shares remain cost-model "
-                "estimates")
-    except Exception as e:
-        out["measured"] = False
-        out["measured_unavailable_reason"] = (
-            f"device trace unavailable on this backend: "
-            f"{type(e).__name__}: {e}")
-    return out
+    return round((_time.perf_counter() - t0) / reps * 1e3, 3)
 
 
 def render_markdown(report):
@@ -320,8 +274,7 @@ def render_markdown(report):
     lines.append(
         f"MFU ceiling for this fusion structure: "
         f"{report['est_step_mfu_ceiling']:.3f}  |  top-1 class share: "
-        f"{report['offender_top1_share'] * 100:.1f}%  |  measured: "
-        f"{report['measured']}")
+        f"{report['offender_top1_share'] * 100:.1f}%")
     lines.append("")
     lines.append(f"## Offender classes ({report['n_groups']} total)")
     lines.append("")

@@ -41,6 +41,7 @@ import time
 import numpy as _np
 
 from ..base import MXNetError, get_env
+from ..telemetry import span as _span, NO_SPAN, trace as _trace
 from ..telemetry.registry import stats_group as _stats_group
 
 __all__ = ["DeviceFeed", "prefetch_to_device", "feed_stats",
@@ -267,8 +268,13 @@ class DeviceFeed:
             if self._exhausted:    # stays exhausted until iter() restarts
                 raise StopIteration
             self._start_epoch()
+        # the consumer's wait, live on the profiler's clock while a
+        # collector is armed (the terminal sentinel's wait shows too; the
+        # counters below still leave it out)
         t0 = time.perf_counter()
-        item = self._queue.get()
+        with (_span("io.feed", cat="io", buffer=self._queue.qsize())
+              if _trace.armed() else NO_SPAN):
+            item = self._queue.get()
         if item is None:
             self._finish_epoch()
             self._exhausted = True
@@ -286,9 +292,6 @@ class DeviceFeed:
             FEED_STATS["occupancy_sum"] += self._queue.qsize() + 1
             FEED_STATS["occupancy_samples"] += 1
             FEED_STATS["batches_consumed"] += 1
-        from ..telemetry import record_span
-        record_span("io.feed", waited_us, ts_us=t0 * 1e6, cat="io",
-                    buffer=self._queue.qsize())
         return item
 
     next = __next__

@@ -307,6 +307,7 @@ def _flash_forward_kernel(q, k, v, causal, scale, block_q, block_k,
             pltpu.VMEM((block_q, d), jnp.float32),   # acc
         ],
         interpret=interpret,
+        name="flash_forward_kernel",
     )(q, k, v)
 
 
@@ -346,6 +347,7 @@ def _flash_forward_lse(q, k, v, causal, scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_forward_lse",
     )(q, k, v)
 
 
@@ -380,6 +382,7 @@ def _flash_backward(q, k, v, do, lse, delta, causal, scale, block_q,
         out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_backward_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -408,6 +411,7 @@ def _flash_backward(q, k, v, do, lse, delta, causal, scale, block_q,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_backward_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

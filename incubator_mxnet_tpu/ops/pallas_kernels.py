@@ -177,6 +177,7 @@ def apply_scale_shift_act(x2d, scale, shift, residual, act_type,
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((m, c), x2d.dtype),
         interpret=interpret,
+        name="apply_scale_shift_act",
     )(*args)
 
 
@@ -236,6 +237,7 @@ def avg_pool2d_fwd(x, ph, pw, interpret=False):
         out_specs=pl.BlockSpec((1, bh, w // pw, c), lambda b, i: (b, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, h // ph, w // pw, c), x.dtype),
         interpret=interpret,
+        name="avg_pool2d_fwd",
     )(x)
 
 
@@ -420,6 +422,7 @@ def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_lanes, c, h, d), q.dtype),
         interpret=interpret,
+        name="paged_attention_fwd",
     )(*args)
 
 
@@ -441,4 +444,5 @@ def avg_pool2d_bwd(dy, h, w, ph, pw, interpret=False):
         out_specs=pl.BlockSpec((1, bh * ph, w, c), lambda b, i: (b, i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, h, w, c), dy.dtype),
         interpret=interpret,
+        name="avg_pool2d_bwd",
     )(dy)

@@ -14,19 +14,23 @@ TPU-native: two layers.
 from __future__ import annotations
 
 import json
+import sys
 import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
 
 from .base import MXNetError, get_env
 
 __all__ = ["set_config", "start", "stop", "pause", "resume", "dump", "dumps",
            "state", "Task", "Frame", "Event", "Counter", "Domain", "Marker",
            "profiler_scope", "scope", "dispatch_stats", "serve_stats",
-           "feed_stats"]
+           "feed_stats", "events", "collecting"]
 
 _lock = threading.Lock()
-_events = []          # chrome trace events
+# chrome trace events, newest EVENTS_CAP kept: a collector left running
+# (or a long jax.profiler session) must not grow the process without bound
+EVENTS_CAP = 1 << 17
+_events = deque(maxlen=EVENTS_CAP)
 _state = {"running": False, "config": {}, "jax_trace_dir": None,
           "t0": None}
 
@@ -95,9 +99,44 @@ def is_running():
     return _state["running"]
 
 
+# jax.profiler.TraceAnnotation, resolved once jax is loaded: this module
+# stays importable without jax, and a session needs jax to be open
+_annotation = [None]
+
+
+def jax_session_open():
+    """True while a `jax.profiler` trace session is collecting (whoever
+    opened it: `start_trace`, `jax.profiler.trace`, the profiler server).
+    Costs one C++ flag read; never imports jax itself."""
+    ann = _annotation[0]
+    if ann is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation as ann
+        _annotation[0] = ann
+    return ann.is_enabled()
+
+
+def collecting():
+    """True while the event buffer accepts events: between `start()` and
+    `stop()`, or while a `jax.profiler` session is open."""
+    return _state["running"] or jax_session_open()
+
+
+def events(cat=None):
+    """A copy of the in-memory event buffer, oldest first (the newest
+    `EVENTS_CAP` events; `cat` keeps one category, e.g. `"span"` or
+    `"serve"`). The events are Chrome-trace dicts: `name`, `cat`, `ts` and
+    `dur` in microseconds on `time.perf_counter`'s clock, `tid`, `args`
+    (a span's attributes, its `parent` name and its trace ids)."""
+    with _lock:
+        return [dict(e) for e in _events
+                if cat is None or e["cat"] == cat]
+
+
 def record_event(name, category, dur_us, ts_us=None, args=None):
     """Internal hook: ops.registry calls this when profiling is on."""
-    if not _state["running"]:
+    if not collecting():
         return
     with _lock:
         _events.append({

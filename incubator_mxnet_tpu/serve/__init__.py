@@ -60,7 +60,7 @@ from .kv_pool import (KVCachePool, SlotsFullError, KVPOOL_STATS,
 from .prefix_cache import (PrefixCache, PrefixCacheError, PREFIX_STATS,
                            prefix_stats)
 from .continuous import (ContinuousEngine, CachedDecoder, DecoderConfig,
-                         init_decoder_params)
+                         RequestTiming, init_decoder_params)
 from .fleet import (Fleet, FleetError, ReplicaDied, FLEET_STATS,
                     fleet_stats)
 
@@ -71,7 +71,8 @@ __all__ = [
     "metrics_text", "start_metrics_server",
     # continuous (iteration-level) batching
     "ContinuousEngine", "CachedDecoder", "DecoderConfig",
-    "init_decoder_params", "KVCachePool", "SlotsFullError",
+    "RequestTiming", "init_decoder_params", "KVCachePool",
+    "SlotsFullError",
     "KVPOOL_STATS", "kvpool_stats",
     # shared-prefix KV cache
     "PrefixCache", "PrefixCacheError", "PREFIX_STATS", "prefix_stats",

@@ -97,8 +97,8 @@ import numpy as _np
 from ..base import MXNetError, get_env
 from .. import fault as _fault
 from .. import sanitize as _sanitize
-from ..telemetry import (record_span, trace as _trace, mem_on_oom,
-                         mem_install_oom_hook)
+from ..telemetry import (record_span, span as _span, NO_SPAN,
+                         trace as _trace, mem_on_oom, mem_install_oom_hook)
 from .batcher import (ServeError, QueueFullError, RequestTimeout,
                       ServerClosed, ReplicaDraining, _fail, _profiler_on)
 from .metrics import SERVE_STATS, _STATS_LOCK, percentile
@@ -106,7 +106,7 @@ from .kv_pool import KVCachePool, SlotsFullError
 from .prefix_cache import PrefixCache
 
 __all__ = ["DecoderConfig", "CachedDecoder", "ContinuousEngine",
-           "init_decoder_params"]
+           "RequestTiming", "init_decoder_params"]
 
 
 # ---------------------------------------------------------------------------
@@ -202,28 +202,29 @@ def _sample_tokens(logits, temps, top_ks, top_ps, keys, positions):
     token 0 comes out."""
     import jax
     import jax.numpy as jnp
-    V = logits.shape[-1]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    ids = jax.lax.broadcasted_iota(jnp.int32, scaled.shape, 1)
-    neg, order = jax.lax.sort_key_val(-scaled, ids, dimension=1)
-    srt = -neg                                   # descending, ties by id
-    kth = jnp.take_along_axis(
-        srt, jnp.clip(top_ks - 1, 0, V - 1)[:, None], axis=-1)
-    keep_k = (top_ks[:, None] <= 0) | (srt >= kth)
-    probs = jax.nn.softmax(srt, axis=-1)
-    csum = jnp.cumsum(probs, axis=-1)
-    # smallest prefix whose mass reaches top_p (the kept-set INCLUDES
-    # the crossing token, hence the exclusive-cumsum comparison)
-    keepn = jnp.sum((csum - probs) < top_ps[:, None], axis=-1)
-    pth = jnp.take_along_axis(
-        srt, jnp.clip(keepn - 1, 0, V - 1)[:, None], axis=-1)
-    masked = jnp.where(keep_k & (srt >= pth), srt, -1e30)
-    kfold = jax.vmap(jax.random.fold_in)(keys, positions)
-    rank = jax.vmap(
-        lambda kk, lg: jax.random.categorical(kk, lg))(kfold, masked)
-    sampled = jnp.take_along_axis(order, rank[:, None], axis=-1)[:, 0]
-    return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
+    with jax.named_scope("sampler"):
+        V = logits.shape[-1]
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+        ids = jax.lax.broadcasted_iota(jnp.int32, scaled.shape, 1)
+        neg, order = jax.lax.sort_key_val(-scaled, ids, dimension=1)
+        srt = -neg                                   # descending, ties by id
+        kth = jnp.take_along_axis(
+            srt, jnp.clip(top_ks - 1, 0, V - 1)[:, None], axis=-1)
+        keep_k = (top_ks[:, None] <= 0) | (srt >= kth)
+        probs = jax.nn.softmax(srt, axis=-1)
+        csum = jnp.cumsum(probs, axis=-1)
+        # smallest prefix whose mass reaches top_p (the kept-set INCLUDES
+        # the crossing token, hence the exclusive-cumsum comparison)
+        keepn = jnp.sum((csum - probs) < top_ps[:, None], axis=-1)
+        pth = jnp.take_along_axis(
+            srt, jnp.clip(keepn - 1, 0, V - 1)[:, None], axis=-1)
+        masked = jnp.where(keep_k & (srt >= pth), srt, -1e30)
+        kfold = jax.vmap(jax.random.fold_in)(keys, positions)
+        rank = jax.vmap(
+            lambda kk, lg: jax.random.categorical(kk, lg))(kfold, masked)
+        sampled = jnp.take_along_axis(order, rank[:, None], axis=-1)[:, 0]
+        return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
 
 
 _SAMPLE_JIT = None
@@ -334,31 +335,35 @@ def _make_prefill(config, window=None):
     def prefill(params, k_cache, v_cache, tokens, lengths, slot_rows):
         # tokens (P, W) int32, lengths (P,) int32, slot_rows (P,) int32
         P = tokens.shape[0]
-        x = params["emb"][tokens] + params["pos"][None, :W]
+        with jax.named_scope("embed"):
+            x = params["emb"][tokens] + params["pos"][None, :W]
         pos = jnp.arange(W)
         key_valid = pos[None, :] < lengths[:, None]            # (P, W)
         causal = pos[:, None] >= pos[None, :]                  # (W, W)
         mask = causal[None, None] & key_valid[:, None, None]   # (P,1,W,W)
         for l in range(c.layers):
-            h = _rmsnorm(x, params["ln1"][l])
-            q = (h @ params["wq"][l]).reshape(P, W, c.heads, c.head_dim)
-            k = (h @ params["wk"][l]).reshape(P, W, c.heads, c.head_dim)
-            v = (h @ params["wv"][l]).reshape(P, W, c.heads, c.head_dim)
-            # positions past `lengths` hold pad-token KV, positions past
-            # the window hold the previous tenant's bytes; both are
-            # unreachable through the decode mask
-            k_cache = _store_page(k_cache, slot_rows, l, W, k)
-            v_cache = _store_page(v_cache, slot_rows, l, W, v)
-            scores = jnp.einsum("pqhd,pkhd->phqk", q, k) * scale
-            scores = jnp.where(mask, scores, -1e30)
-            att = jnp.einsum("phqk,pkhd->pqhd",
-                             jax.nn.softmax(scores, axis=-1), v)
-            x = x + att.reshape(P, W, c.embed) @ params["wo"][l]
-            h2 = _rmsnorm(x, params["ln2"][l])
-            x = x + jax.nn.gelu(h2 @ params["w1"][l]) @ params["w2"][l]
-        xf = _rmsnorm(x, params["lnf"])
-        last = xf[jnp.arange(P), jnp.maximum(lengths - 1, 0)]   # (P, E)
-        logits = last @ params["emb"].T
+            with jax.named_scope(f"layer{l}/attn"):
+                h = _rmsnorm(x, params["ln1"][l])
+                q = (h @ params["wq"][l]).reshape(P, W, c.heads, c.head_dim)
+                k = (h @ params["wk"][l]).reshape(P, W, c.heads, c.head_dim)
+                v = (h @ params["wv"][l]).reshape(P, W, c.heads, c.head_dim)
+                # positions past `lengths` hold pad-token KV, positions past
+                # the window hold the previous tenant's bytes; both are
+                # unreachable through the decode mask
+                k_cache = _store_page(k_cache, slot_rows, l, W, k)
+                v_cache = _store_page(v_cache, slot_rows, l, W, v)
+                scores = jnp.einsum("pqhd,pkhd->phqk", q, k) * scale
+                scores = jnp.where(mask, scores, -1e30)
+                att = jnp.einsum("phqk,pkhd->pqhd",
+                                 jax.nn.softmax(scores, axis=-1), v)
+                x = x + att.reshape(P, W, c.embed) @ params["wo"][l]
+            with jax.named_scope(f"layer{l}/mlp"):
+                h2 = _rmsnorm(x, params["ln2"][l])
+                x = x + jax.nn.gelu(h2 @ params["w1"][l]) @ params["w2"][l]
+        with jax.named_scope("head"):
+            xf = _rmsnorm(x, params["lnf"])
+            last = xf[jnp.arange(P), jnp.maximum(lengths - 1, 0)]  # (P, E)
+            logits = last @ params["emb"].T
         # the FIRST token is drawn from these logits by the caller
         # (`_sample_first`, the process-shared sampler program) at fold
         # position lengths-1, continuing into decode at `lengths`
@@ -413,21 +418,25 @@ def _make_chunk_prefill(config, window=None, extent=None):
         wposs = jnp.clip(offsets[:, None] + j[None, :], 0, T - 1)
         valid = j[None, :] < nvalid[:, None]                    # (S, W)
         rows = jnp.where(valid, jnp.arange(S)[:, None], S)   # garbage=S
-        x = params["emb"][tokens] + params["pos"][wposs]
+        with jax.named_scope("embed"):
+            x = params["emb"][tokens] + params["pos"][wposs]
         for l in range(c.layers):
-            h = _rmsnorm(x, params["ln1"][l])
-            q = (h @ params["wq"][l]).reshape(S, W, c.heads, c.head_dim)
-            k = (h @ params["wk"][l]).reshape(S, W, c.heads, c.head_dim)
-            v = (h @ params["wv"][l]).reshape(S, W, c.heads, c.head_dim)
-            k_cache = _store_pos(k_cache, rows, l, wposs, k)
-            v_cache = _store_pos(v_cache, rows, l, wposs, v)
-            att = _paged_attn(k_cache, v_cache, q, offsets, l, extent=E)
-            x = x + att.reshape(S, W, c.embed) @ params["wo"][l]
-            h2 = _rmsnorm(x, params["ln2"][l])
-            x = x + jax.nn.gelu(h2 @ params["w1"][l]) @ params["w2"][l]
-        xf = _rmsnorm(x, params["lnf"])
-        last = xf[jnp.arange(S), jnp.maximum(nvalid - 1, 0)]
-        logits = last @ params["emb"].T
+            with jax.named_scope(f"layer{l}/attn"):
+                h = _rmsnorm(x, params["ln1"][l])
+                q = (h @ params["wq"][l]).reshape(S, W, c.heads, c.head_dim)
+                k = (h @ params["wk"][l]).reshape(S, W, c.heads, c.head_dim)
+                v = (h @ params["wv"][l]).reshape(S, W, c.heads, c.head_dim)
+                k_cache = _store_pos(k_cache, rows, l, wposs, k)
+                v_cache = _store_pos(v_cache, rows, l, wposs, v)
+                att = _paged_attn(k_cache, v_cache, q, offsets, l, extent=E)
+                x = x + att.reshape(S, W, c.embed) @ params["wo"][l]
+            with jax.named_scope(f"layer{l}/mlp"):
+                h2 = _rmsnorm(x, params["ln2"][l])
+                x = x + jax.nn.gelu(h2 @ params["w1"][l]) @ params["w2"][l]
+        with jax.named_scope("head"):
+            xf = _rmsnorm(x, params["lnf"])
+            last = xf[jnp.arange(S), jnp.maximum(nvalid - 1, 0)]
+            logits = last @ params["emb"].T
         return k_cache, v_cache, logits
 
     return chunk_prefill
@@ -484,24 +493,28 @@ def _make_decode(config, steps=1, eos_id=None):
         T = c.max_len
         rows = jnp.where(active, jnp.arange(S), S)       # garbage row = S
         wpos = jnp.clip(lengths, 0, T - 1)
-        x = params["emb"][tokens] + params["pos"][wpos]  # (S, E)
+        with jax.named_scope("embed"):
+            x = params["emb"][tokens] + params["pos"][wpos]  # (S, E)
         # attention reads positions 0..lengths INCLUSIVE (the new token's
         # KV is written before the read); anything past that — pad-token
         # KV from prefill or a previous tenant's garbage — is masked
         # inside paged_attention's [0, lengths + chunk_offset] clamp
         for l in range(c.layers):
-            h = _rmsnorm(x, params["ln1"][l])
-            q = (h @ params["wq"][l]).reshape(S, c.heads, c.head_dim)
-            k = (h @ params["wk"][l]).reshape(S, c.heads, c.head_dim)
-            v = (h @ params["wv"][l]).reshape(S, c.heads, c.head_dim)
-            k_cache = _store_pos(k_cache, rows, l, wpos, k)
-            v_cache = _store_pos(v_cache, rows, l, wpos, v)
-            att = _paged_attn(k_cache, v_cache, q[:, None], lengths,
-                              l)[:, 0]
-            x = x + att.reshape(S, c.embed) @ params["wo"][l]
-            h2 = _rmsnorm(x, params["ln2"][l])
-            x = x + jax.nn.gelu(h2 @ params["w1"][l]) @ params["w2"][l]
-        logits = _rmsnorm(x, params["lnf"]) @ params["emb"].T
+            with jax.named_scope(f"layer{l}/attn"):
+                h = _rmsnorm(x, params["ln1"][l])
+                q = (h @ params["wq"][l]).reshape(S, c.heads, c.head_dim)
+                k = (h @ params["wk"][l]).reshape(S, c.heads, c.head_dim)
+                v = (h @ params["wv"][l]).reshape(S, c.heads, c.head_dim)
+                k_cache = _store_pos(k_cache, rows, l, wpos, k)
+                v_cache = _store_pos(v_cache, rows, l, wpos, v)
+                att = _paged_attn(k_cache, v_cache, q[:, None], lengths,
+                                  l)[:, 0]
+                x = x + att.reshape(S, c.embed) @ params["wo"][l]
+            with jax.named_scope(f"layer{l}/mlp"):
+                h2 = _rmsnorm(x, params["ln2"][l])
+                x = x + jax.nn.gelu(h2 @ params["w1"][l]) @ params["w2"][l]
+        with jax.named_scope("head"):
+            logits = _rmsnorm(x, params["lnf"]) @ params["emb"].T
         nxt = _sample_tokens(logits, temps, top_ks, top_ps, keys,
                              lengths)
         return k_cache, v_cache, jnp.where(active, nxt, 0)
@@ -588,19 +601,23 @@ def _make_spec_decode(config, steps=1, eos_id=None, draft=2):
         # -- ONE verify forward over the whole chunk [last, drafts...]
         chunk = jnp.concatenate([last[:, None], drafts], axis=1)  # (S, C)
         wposs = jnp.clip(lens[:, None] + coffs[None, :], 0, T - 1)
-        x = params["emb"][chunk] + params["pos"][wposs]   # (S, C, E)
+        with jax.named_scope("embed"):
+            x = params["emb"][chunk] + params["pos"][wposs]   # (S, C, E)
         for l in range(c.layers):
-            h = _rmsnorm(x, params["ln1"][l])
-            q = (h @ params["wq"][l]).reshape(S, C, c.heads, c.head_dim)
-            k = (h @ params["wk"][l]).reshape(S, C, c.heads, c.head_dim)
-            v = (h @ params["wv"][l]).reshape(S, C, c.heads, c.head_dim)
-            k_cache = _store_pos(k_cache, rows[:, None], l, wposs, k)
-            v_cache = _store_pos(v_cache, rows[:, None], l, wposs, v)
-            att = _paged_attn(k_cache, v_cache, q, lens, l)
-            x = x + att.reshape(S, C, c.embed) @ params["wo"][l]
-            h2 = _rmsnorm(x, params["ln2"][l])
-            x = x + jax.nn.gelu(h2 @ params["w1"][l]) @ params["w2"][l]
-        logits = _rmsnorm(x, params["lnf"]) @ params["emb"].T  # (S,C,V)
+            with jax.named_scope(f"layer{l}/attn"):
+                h = _rmsnorm(x, params["ln1"][l])
+                q = (h @ params["wq"][l]).reshape(S, C, c.heads, c.head_dim)
+                k = (h @ params["wk"][l]).reshape(S, C, c.heads, c.head_dim)
+                v = (h @ params["wv"][l]).reshape(S, C, c.heads, c.head_dim)
+                k_cache = _store_pos(k_cache, rows[:, None], l, wposs, k)
+                v_cache = _store_pos(v_cache, rows[:, None], l, wposs, v)
+                att = _paged_attn(k_cache, v_cache, q, lens, l)
+                x = x + att.reshape(S, C, c.embed) @ params["wo"][l]
+            with jax.named_scope(f"layer{l}/mlp"):
+                h2 = _rmsnorm(x, params["ln2"][l])
+                x = x + jax.nn.gelu(h2 @ params["w1"][l]) @ params["w2"][l]
+        with jax.named_scope("head"):
+            logits = _rmsnorm(x, params["lnf"]) @ params["emb"].T
         # -- the base model's own choice at EVERY chunk position, keyed
         # by that position — identical draws to non-spec decode
         positions = (lens[:, None] + coffs[None, :]).reshape(-1)
@@ -948,25 +965,61 @@ class CachedDecoder:
 # ---------------------------------------------------------------------------
 # engine
 # ---------------------------------------------------------------------------
+class RequestTiming:
+    """A request's timeline, as `submit()`'s future carries it
+    (`fut.timing`) from the moment it is returned: read-only, filled in by
+    the engine as the request moves. Times are `time.perf_counter()`
+    seconds and None until reached: `t_submit` (enqueued), `t_admit` (KV
+    slot claimed), `t_first` (first token out of prefill), `t_done` (set
+    just before the future resolves; stays None for a request that
+    failed). `prompt_tokens`, `cached_tokens` (served from the prefix
+    cache) and `tokens` (generated so far) are counts. `stats()`'s
+    ttft/tpot/e2e percentiles are computed from these same fields."""
+
+    __slots__ = ("_req",)
+
+    def __init__(self, req):
+        self._req = req
+
+    t_submit = property(lambda self: self._req.t_submit)
+    t_admit = property(lambda self: self._req.t_admit)
+    t_first = property(lambda self: self._req.t_first)
+    t_done = property(lambda self: self._req.t_done)
+    prompt_tokens = property(lambda self: int(self._req.prompt.size))
+    cached_tokens = property(lambda self: self._req.cached_len)
+    tokens = property(lambda self: len(self._req.generated))
+
+    def as_dict(self):
+        return {k: getattr(self, k) for k in (
+            "t_submit", "t_admit", "t_first", "t_done", "prompt_tokens",
+            "cached_tokens", "tokens")}
+
+    def __repr__(self):
+        return f"RequestTiming({self.as_dict()})"
+
+
 class _GenRequest:
     __slots__ = ("prompt", "max_new", "future", "deadline", "t_submit",
-                 "ctx", "slot", "generated", "cache_len", "t_first",
-                 "t_last", "temperature", "top_k", "top_p", "key",
-                 "entry", "cached_len", "prefill_pos")
+                 "ctx", "slot", "generated", "cache_len", "t_admit",
+                 "t_first", "t_last", "t_done", "temperature", "top_k",
+                 "top_p", "key", "entry", "cached_len", "prefill_pos")
 
     def __init__(self, prompt, max_new, deadline, ctx,
                  temperature=0.0, top_k=0, top_p=1.0, key=None):
         self.prompt = prompt                 # np.int32 (plen,)
         self.max_new = max_new
         self.future = Future()
+        self.future.timing = RequestTiming(self)
         self.deadline = deadline             # perf_counter deadline or None
         self.t_submit = time.perf_counter()
         self.ctx = ctx                       # serve.request root context
         self.slot = None
         self.generated = []
         self.cache_len = 0
+        self.t_admit = None                  # KV slot claimed
         self.t_first = None                  # first token (TTFT anchor)
         self.t_last = None
+        self.t_done = None                   # just before the future resolves
         self.temperature = temperature       # 0.0 = greedy lane
         self.top_k = top_k
         self.top_p = top_p
@@ -1209,9 +1262,9 @@ class ContinuousEngine:
             "draft_rejected", "prefix_hits", "prefix_misses",
             "prefix_cached_tokens")}
         self._auto_seed = 0                  # per-engine seed fountain
-        self._ttft_ms = deque(maxlen=4096)
-        self._tpot_ms = deque(maxlen=4096)
-        self._e2e_ms = deque(maxlen=4096)
+        # (ttft, tpot or None, e2e) ms of the newest retired requests, from
+        # their RequestTiming fields: stats()'s one source of percentiles
+        self._latencies = deque(maxlen=4096)
 
     # -- lifecycle ---------------------------------------------------------
     def start(self, warmup=True):
@@ -1227,6 +1280,10 @@ class ContinuousEngine:
             self._warm_cache_size = self.model.compile_cache_size()
             self._started = True
         self.warmup_s = round(time.perf_counter() - t0, 3)
+        with self._mlock:
+            # rates and percentiles describe serving: the clock of
+            # `elapsed_s` starts once warm-up is over
+            self._t0 = time.perf_counter()
         if _sanitize.enabled("retrace"):
             # warmup compiled everything; from here any growth is a
             # broken zero-retrace contract (polled once per decode wave)
@@ -1536,10 +1593,10 @@ class ContinuousEngine:
         percentiles, decode tokens/s, and the zero-retrace observables."""
         with self._mlock:
             c = dict(self._counters)
-            ttft = sorted(self._ttft_ms)
-            tpot = sorted(self._tpot_ms)
-            e2e = sorted(self._e2e_ms)
+            lat = list(self._latencies)
             elapsed = time.perf_counter() - self._t0
+        ttft, tpot, e2e = (sorted(r[i] for r in lat if r[i] is not None)
+                           for i in range(3))
         out = dict(c)
         out["elapsed_s"] = round(elapsed, 3)
         out["decode_tokens_per_sec"] = round(
@@ -1582,11 +1639,29 @@ class ContinuousEngine:
     # -- scheduler ---------------------------------------------------------
     def _loop(self):
         import jax.numpy as jnp
+        wave, token = 0, None
         while True:
+            # ONE gate per iteration for every live span of the wave
+            # (docs/OBSERVABILITY.md "Hot-path spans"): armed, the
+            # iteration's spans form one tree under a `serve.wave` context
+            # whose trace id is the wave number; un-armed, each site
+            # enters the shared NO_SPAN
+            if token is not None:
+                _trace._reset(token)
+            on = _trace.armed()
+            token = _trace._push(_trace.TraceContext(
+                f"wave-{wave}", f"wave-{wave}", "serve.wave")) \
+                if on else None
+            wave += 1
             with self._cv:
-                while (not self._waiting and not self._running
-                       and not self._prefilling and not self._closing):
-                    self._cv.wait()
+                if (not self._waiting and not self._running
+                        and not self._prefilling and not self._closing):
+                    with (_span("serve.idle", cat="serve") if on
+                          else NO_SPAN):
+                        while (not self._waiting and not self._running
+                               and not self._prefilling
+                               and not self._closing):
+                            self._cv.wait()
                 if self._closing and not self._running \
                         and not self._prefilling \
                         and (not self._drain or not self._waiting):
@@ -1595,7 +1670,13 @@ class ContinuousEngine:
                             "engine closed before admission"))
                     self._waiting.clear()
                     return
-                admitted, expired = self._admit_locked()
+                with (_span("serve.admit", cat="serve",
+                            waiting=len(self._waiting)) if on
+                      else NO_SPAN) as sp:
+                    admitted, expired = self._admit_locked()
+                    if on:
+                        sp.set(admitted=len(admitted),
+                               expired=len(expired))
             # expired waiters resolve OUTSIDE self._cv: Future callbacks
             # run inline and may re-enter submit()
             now = time.perf_counter()
@@ -1623,9 +1704,17 @@ class ContinuousEngine:
                 # _prefilling is only ever mutated on this thread, so the
                 # unlocked read is single-writer safe
                 if admitted or self._prefilling:
-                    self._run_prefill(admitted, jnp)
+                    with (_span("serve.prefill_batch", cat="serve",
+                                requests=len(admitted)) if on
+                          else NO_SPAN) as sp:
+                        done = self._run_prefill(admitted, jnp, on, sp)
+                    self._retire(done, on)
                 if self._running:
-                    self._run_decode(jnp)
+                    with (_span("serve.decode_batch", cat="serve",
+                                steps=self.decode_steps) if on
+                          else NO_SPAN) as sp:
+                        done = self._run_decode(jnp, on, sp)
+                    self._retire(done, on)
             except BaseException as e:
                 # a step failure fails the IN-FLIGHT requests, frees
                 # their slots, and the engine keeps serving (the PR-3
@@ -1716,6 +1805,7 @@ class ContinuousEngine:
                     req.slot = self.pool.claim()
                 except SlotsFullError:   # raced a test's direct claim
                     break
+                req.t_admit = now
                 if self._cache is not None:
                     # pin the matched prefix for this request's lifetime
                     # (released at retire); eviction can never reclaim
@@ -1736,25 +1826,32 @@ class ContinuousEngine:
             self._prefilling[req.slot] = req  # mxlint: disable=lock-shared-mutation -- _admit_locked runs with self._cv held by its only caller (_loop)
         return admitted, expired
 
-    def _run_prefill(self, admitted, jnp):
+    def _run_prefill(self, admitted, jnp, on, sp):
         """One prefill wave: slab-to-slab KV row copies for the admitted
         prefix-cache hits, the fixed-shape windowed program for lanes
         starting at page offset 0, then ONE chunk dispatch advancing
         EVERY lane with pending suffix/chunk work (admitted hits and
         long prompts mid-stream alike). A request emits its first token
         the wave its prefill completes — `prefill_tokens` bills only
-        tokens a program actually processed (suffix-only on a hit)."""
+        tokens a program actually processed (suffix-only on a hit).
+        Returns the requests that finished at their first token.
+
+        Runs inside the loop's `serve.prefill_batch` span `sp`; armed
+        (`on`), each program's host side is split into `.pack` (NumPy
+        arrays and their transfers), `.dispatch` (the jit calls
+        returning) and `.readback` (the blocking read of the first
+        tokens)."""
         _fault.inject("serve.execute")
         W = self.prefill_window
         g = self.pool.garbage_row
-        t0 = time.perf_counter()
         hits = [r for r in admitted if r.cached_len > 0]
         cold = [r for r in admitted if r.cached_len == 0]
         if hits:
             # memory-bound copy replaces compute-bound prefill: the
             # pinned cache rows land in the claimed slots before this
             # wave's programs run (same thread, same device stream)
-            self._dispatch_copy([(r.entry.row, r.slot) for r in hits])
+            self._dispatch_copy([(r.entry.row, r.slot) for r in hits],
+                                "hit", on)
             self._count("prefix_hits", len(hits))
             self._count("prefix_cached_tokens",
                         int(sum(r.cached_len for r in hits)))
@@ -1763,33 +1860,39 @@ class ContinuousEngine:
         n_tokens = 0
         finished = []                        # (req, first token)
         if cold:
-            P = self.prefill_lanes
-            toks = _np.zeros((P, W), dtype=_np.int32)
-            lens = _np.ones((P,), dtype=_np.int32)
-            rows = _np.full((P,), g, dtype=_np.int32)
-            temps = _np.zeros((P,), dtype=_np.float32)
-            tks = _np.zeros((P,), dtype=_np.int32)
-            tps = _np.ones((P,), dtype=_np.float32)
-            keys = _np.zeros((P, 2), dtype=_np.uint32)
-            for i, req in enumerate(cold):
-                head = min(int(req.prompt.size), W)
-                toks[i, :head] = req.prompt[:head]
-                lens[i] = head
-                rows[i] = req.slot
-                temps[i] = req.temperature
-                tks[i] = req.top_k
-                tps[i] = req.top_p
-                keys[i] = req.key
-            kb, vb = self.pool.buffers()
-            jlens = jnp.asarray(lens)
-            k, v, logits = self._prefill_prog(
-                self.model.params, kb, vb,
-                jnp.asarray(toks), jlens, jnp.asarray(rows))
-            first = _sample_first(logits, jnp.asarray(temps),
-                                  jnp.asarray(tks), jnp.asarray(tps),
-                                  jnp.asarray(keys), jlens - 1)
-            self.pool.swap_buffers(k, v)
-            first_host = _np.asarray(first)
+            with (_span("serve.prefill_batch.pack", cat="serve") if on
+                  else NO_SPAN):
+                P = self.prefill_lanes
+                toks = _np.zeros((P, W), dtype=_np.int32)
+                lens = _np.ones((P,), dtype=_np.int32)
+                rows = _np.full((P,), g, dtype=_np.int32)
+                temps = _np.zeros((P,), dtype=_np.float32)
+                tks = _np.zeros((P,), dtype=_np.int32)
+                tps = _np.ones((P,), dtype=_np.float32)
+                keys = _np.zeros((P, 2), dtype=_np.uint32)
+                for i, req in enumerate(cold):
+                    head = min(int(req.prompt.size), W)
+                    toks[i, :head] = req.prompt[:head]
+                    lens[i] = head
+                    rows[i] = req.slot
+                    temps[i] = req.temperature
+                    tks[i] = req.top_k
+                    tps[i] = req.top_p
+                    keys[i] = req.key
+                jtoks, jlens, jrows = (jnp.asarray(toks), jnp.asarray(lens),
+                                       jnp.asarray(rows))
+                sample = (jnp.asarray(temps), jnp.asarray(tks),
+                          jnp.asarray(tps), jnp.asarray(keys), jlens - 1)
+            with (_span("serve.prefill_batch.dispatch", cat="serve")
+                  if on else NO_SPAN):
+                kb, vb = self.pool.buffers()
+                k, v, logits = self._prefill_prog(
+                    self.model.params, kb, vb, jtoks, jlens, jrows)
+                first = _sample_first(logits, *sample)
+                self.pool.swap_buffers(k, v)
+            with (_span("serve.prefill_batch.readback", cat="serve")
+                  if on else NO_SPAN):
+                first_host = _np.asarray(first)
             for i, req in enumerate(cold):
                 head = min(int(req.prompt.size), W)
                 req.prefill_pos = head
@@ -1806,41 +1909,49 @@ class ContinuousEngine:
                     if id(r) not in coldset
                     and r.prefill_pos < int(r.prompt.size)]
         if chunkers:
-            S = self.pool.max_slots
-            ctoks = _np.zeros((S, W), dtype=_np.int32)
-            offs = _np.zeros((S,), dtype=_np.int32)
-            nval = _np.zeros((S,), dtype=_np.int32)
-            temps = _np.zeros((S,), dtype=_np.float32)
-            tks = _np.zeros((S,), dtype=_np.int32)
-            tps = _np.ones((S,), dtype=_np.float32)
-            keys = _np.zeros((S, 2), dtype=_np.uint32)
-            fold = _np.zeros((S,), dtype=_np.int32)
-            for req in chunkers:
-                s = req.slot
-                n = min(W, int(req.prompt.size) - req.prefill_pos)
-                ctoks[s, :n] = req.prompt[req.prefill_pos:
-                                          req.prefill_pos + n]
-                offs[s] = req.prefill_pos
-                nval[s] = n
-                temps[s] = req.temperature
-                tks[s] = req.top_k
-                tps[s] = req.top_p
-                keys[s] = req.key
-                fold[s] = int(req.prompt.size) - 1
-            # smallest warmed extent covering the furthest lane: the
-            # wave's attention read scales with streamed progress
-            need = max(int(offs[r.slot]) + int(nval[r.slot])
-                       for r in chunkers)
-            ext = next(x for x in self._chunk_extents if x >= need)
-            kb, vb = self.pool.buffers()
-            k, v, logits = self._chunk_progs[ext](
-                self.model.params, kb, vb, jnp.asarray(ctoks),
-                jnp.asarray(offs), jnp.asarray(nval))
-            first = _sample_first(logits, jnp.asarray(temps),
-                                  jnp.asarray(tks), jnp.asarray(tps),
-                                  jnp.asarray(keys), jnp.asarray(fold))
-            self.pool.swap_buffers(k, v)
-            first_host = _np.asarray(first)
+            with (_span("serve.prefill_batch.pack", cat="serve") if on
+                  else NO_SPAN):
+                S = self.pool.max_slots
+                ctoks = _np.zeros((S, W), dtype=_np.int32)
+                offs = _np.zeros((S,), dtype=_np.int32)
+                nval = _np.zeros((S,), dtype=_np.int32)
+                temps = _np.zeros((S,), dtype=_np.float32)
+                tks = _np.zeros((S,), dtype=_np.int32)
+                tps = _np.ones((S,), dtype=_np.float32)
+                keys = _np.zeros((S, 2), dtype=_np.uint32)
+                fold = _np.zeros((S,), dtype=_np.int32)
+                for req in chunkers:
+                    s = req.slot
+                    n = min(W, int(req.prompt.size) - req.prefill_pos)
+                    ctoks[s, :n] = req.prompt[req.prefill_pos:
+                                              req.prefill_pos + n]
+                    offs[s] = req.prefill_pos
+                    nval[s] = n
+                    temps[s] = req.temperature
+                    tks[s] = req.top_k
+                    tps[s] = req.top_p
+                    keys[s] = req.key
+                    fold[s] = int(req.prompt.size) - 1
+                # smallest warmed extent covering the furthest lane: the
+                # wave's attention read scales with streamed progress
+                need = max(int(offs[r.slot]) + int(nval[r.slot])
+                           for r in chunkers)
+                ext = next(x for x in self._chunk_extents if x >= need)
+                jtoks, joffs, jnval = (jnp.asarray(ctoks), jnp.asarray(offs),
+                                       jnp.asarray(nval))
+                sample = (jnp.asarray(temps), jnp.asarray(tks),
+                          jnp.asarray(tps), jnp.asarray(keys),
+                          jnp.asarray(fold))
+            with (_span("serve.prefill_batch.dispatch", cat="serve")
+                  if on else NO_SPAN):
+                kb, vb = self.pool.buffers()
+                k, v, logits = self._chunk_progs[ext](
+                    self.model.params, kb, vb, jtoks, joffs, jnval)
+                first = _sample_first(logits, *sample)
+                self.pool.swap_buffers(k, v)
+            with (_span("serve.prefill_batch.readback", cat="serve")
+                  if on else NO_SPAN):
+                first_host = _np.asarray(first)
             for req in chunkers:
                 n = int(nval[req.slot])
                 req.prefill_pos += n
@@ -1863,8 +1974,6 @@ class ContinuousEngine:
             req.cache_len = int(req.prompt.size)
             req.generated.append(tok)
             req.t_first = req.t_last = now
-            with self._mlock:
-                self._ttft_ms.append((now - req.t_submit) * 1e3)
             if req.ctx is not None and prof:
                 # admission -> first token, child of the request root:
                 # iteration 0 of the request's one trace
@@ -1881,126 +1990,142 @@ class ContinuousEngine:
             for req, _ in finished:
                 self._prefilling.pop(req.slot, None)
                 self._running[req.slot] = req
-        if _trace.enabled() and _trace.collector_active():
-            record_span("serve.prefill_batch", (now - t0) * 1e6,
-                        ts_us=t0 * 1e6, cat="serve",
-                        requests=len(admitted), tokens=n_tokens)
-        self._retire(done)
+        if on:
+            sp.set(tokens=n_tokens)
+        return done
 
-    def _dispatch_copy(self, pairs):
+    def _dispatch_copy(self, pairs, why, on):
         """ONE fixed-shape donated gather program copies whole KV slot
-        rows slab-to-slab: cache row -> claimed slot at admission,
-        retiring slot -> cache row at publish. Idle lanes copy the
-        garbage row onto itself."""
+        rows slab-to-slab: cache row -> claimed slot at admission (`why`
+        "hit"), retiring slot -> cache row at "publish". Idle lanes copy
+        the garbage row onto itself."""
         import jax.numpy as jnp
-        g = self.pool.garbage_row
-        src = _np.full((self.prefill_lanes,), g, dtype=_np.int32)
-        dst = _np.full((self.prefill_lanes,), g, dtype=_np.int32)
-        for i, (s, d) in enumerate(pairs):
-            src[i] = s
-            dst[i] = d
-        kb, vb = self.pool.buffers()
-        k, v = self._copy_prog(kb, vb, jnp.asarray(src),
-                               jnp.asarray(dst))
-        self.pool.swap_buffers(k, v)
+        with (_span("serve.copy.dispatch", cat="serve", pairs=len(pairs),
+                    why=why) if on else NO_SPAN):
+            g = self.pool.garbage_row
+            src = _np.full((self.prefill_lanes,), g, dtype=_np.int32)
+            dst = _np.full((self.prefill_lanes,), g, dtype=_np.int32)
+            for i, (s, d) in enumerate(pairs):
+                src[i] = s
+                dst[i] = d
+            kb, vb = self.pool.buffers()
+            k, v = self._copy_prog(kb, vb, jnp.asarray(src),
+                                   jnp.asarray(dst))
+            self.pool.swap_buffers(k, v)
 
-    def _run_decode(self, jnp):
+    def _run_decode(self, jnp, on, sp):
         """ONE decode wave: every active slot advances up to
         `decode_steps` tokens (times up to `draft_tokens + 1` when
         speculating) through the compiled multi-step program. Lanes are
         ALL pool rows (request slots, mid-prefill slots, and prefix-cache
         rows alike) so lane index == slab row; non-decoding lanes are
-        inactive and scatter into the garbage row."""
+        inactive and scatter into the garbage row. Returns the requests
+        that finished.
+
+        Runs inside the loop's `serve.decode_batch` span `sp`; armed
+        (`on`), its host side is split into `.pack` (the seven NumPy
+        arrays and their transfers), `.dispatch` (the program call
+        returning), `.readback` (the blocking reads of the wave's tokens)
+        and `.emit` (per-lane bookkeeping and counters)."""
         S = self.pool.max_slots
         draft = self.draft_tokens
-        toks = _np.zeros((S,), dtype=_np.int32)
-        lens = _np.zeros((S,), dtype=_np.int32)
-        left = _np.zeros((S,), dtype=_np.int32)
-        temps = _np.zeros((S,), dtype=_np.float32)
-        tks = _np.zeros((S,), dtype=_np.int32)
-        tps = _np.ones((S,), dtype=_np.float32)
-        keys = _np.zeros((S, 2), dtype=_np.uint32)
-        buf = (_np.zeros((S, self.max_len), dtype=_np.int32)
-               if draft else None)
-        with self._cv:
-            running = dict(self._running)
-        for slot, req in running.items():
-            toks[slot] = req.generated[-1]
-            lens[slot] = req.cache_len
-            # this wave's per-lane budget: what the request still wants,
-            # capped by its page space. The cap mirrors _finished's
-            # `cache_len + 1 >= max_len` stop: the K=1 engine (and the
-            # reference) emit their last token FROM state max_len - 2,
-            # so a multi-step wave may advance cache_len at most to
-            # max_len - 1 — not max_len, which would emit one extra
-            # token and break the K-invariance contract
-            left[slot] = min(req.max_new - len(req.generated),
-                             self.max_len - 1 - req.cache_len)
-            temps[slot] = req.temperature
-            tks[slot] = req.top_k
-            tps[slot] = req.top_p
-            keys[slot] = req.key
-            if draft:
-                # the draft source: token history = prompt + generated,
-                # exactly cache_len + 1 valid entries (tail not yet in KV)
-                plen = req.prompt.size
-                buf[slot, :plen] = req.prompt
-                buf[slot, plen:plen + len(req.generated)] = req.generated
-        t0 = time.perf_counter()
-        kb, vb = self.pool.buffers()
-        args = [self.model.params, kb, vb, jnp.asarray(toks),
-                jnp.asarray(lens), jnp.asarray(left),
-                jnp.asarray(temps), jnp.asarray(tks), jnp.asarray(tps),
-                jnp.asarray(keys)]
-        if draft:
-            k, v, blocks, n_emits, emitted, acc, rej = \
-                self._decode_prog(*args, jnp.asarray(buf))
-            blocks_host = _np.asarray(blocks)   # (steps, S, draft+1)
-            nem_host = _np.asarray(n_emits)     # (steps, S)
-        else:
-            k, v, out_toks, emitted = self._decode_prog(*args)
-            out_host = _np.asarray(out_toks)    # (decode_steps, S)
-        self.pool.swap_buffers(k, v)
-        if self._canary is not None:
-            self._canary.check(where="serve.decode")
-        _sanitize.poll(where="serve.decode")
-        emitted_host = _np.asarray(emitted)
-        now = time.perf_counter()
-        n_active = len(running)
-        n_tokens = 0
-        n_sampled = 0
-        done = []
-        for slot, req in running.items():
-            n_new = int(emitted_host[slot])
-            if n_new > 0:
+        with (_span("serve.decode_batch.pack", cat="serve") if on
+              else NO_SPAN):
+            toks = _np.zeros((S,), dtype=_np.int32)
+            lens = _np.zeros((S,), dtype=_np.int32)
+            left = _np.zeros((S,), dtype=_np.int32)
+            temps = _np.zeros((S,), dtype=_np.float32)
+            tks = _np.zeros((S,), dtype=_np.int32)
+            tps = _np.ones((S,), dtype=_np.float32)
+            keys = _np.zeros((S, 2), dtype=_np.uint32)
+            buf = (_np.zeros((S, self.max_len), dtype=_np.int32)
+                   if draft else None)
+            with self._cv:
+                running = dict(self._running)
+            for slot, req in running.items():
+                toks[slot] = req.generated[-1]
+                lens[slot] = req.cache_len
+                # this wave's per-lane budget: what the request still
+                # wants, capped by its page space. The cap mirrors
+                # _finished's `cache_len + 1 >= max_len` stop: the K=1
+                # engine (and the reference) emit their last token FROM
+                # state max_len - 2, so a multi-step wave may advance
+                # cache_len at most to max_len - 1 — not max_len, which
+                # would emit one extra token and break the K-invariance
+                # contract
+                left[slot] = min(req.max_new - len(req.generated),
+                                 self.max_len - 1 - req.cache_len)
+                temps[slot] = req.temperature
+                tks[slot] = req.top_k
+                tps[slot] = req.top_p
+                keys[slot] = req.key
                 if draft:
-                    for i in range(nem_host.shape[0]):
-                        m = int(nem_host[i, slot])
+                    # the draft source: token history = prompt +
+                    # generated, exactly cache_len + 1 valid entries (tail
+                    # not yet in KV)
+                    plen = req.prompt.size
+                    buf[slot, :plen] = req.prompt
+                    buf[slot, plen:plen + len(req.generated)] = \
+                        req.generated
+            args = [jnp.asarray(toks), jnp.asarray(lens),
+                    jnp.asarray(left), jnp.asarray(temps),
+                    jnp.asarray(tks), jnp.asarray(tps), jnp.asarray(keys)]
+            if draft:
+                args.append(jnp.asarray(buf))
+        with (_span("serve.decode_batch.dispatch", cat="serve") if on
+              else NO_SPAN):
+            kb, vb = self.pool.buffers()
+            out = self._decode_prog(self.model.params, kb, vb, *args)
+            self.pool.swap_buffers(out[0], out[1])
+        with (_span("serve.decode_batch.readback", cat="serve") if on
+              else NO_SPAN):
+            if draft:
+                _, _, blocks, n_emits, emitted, acc, rej = out
+                blocks_host = _np.asarray(blocks)   # (steps, S, draft+1)
+                nem_host = _np.asarray(n_emits)     # (steps, S)
+            else:
+                _, _, out_toks, emitted = out
+                out_host = _np.asarray(out_toks)    # (decode_steps, S)
+            if self._canary is not None:
+                self._canary.check(where="serve.decode")
+            _sanitize.poll(where="serve.decode")
+            emitted_host = _np.asarray(emitted)
+        with (_span("serve.decode_batch.emit", cat="serve") if on
+              else NO_SPAN):
+            now = time.perf_counter()
+            n_active = len(running)
+            n_tokens = 0
+            n_sampled = 0
+            done = []
+            for slot, req in running.items():
+                n_new = int(emitted_host[slot])
+                if n_new > 0:
+                    if draft:
+                        for i in range(nem_host.shape[0]):
+                            m = int(nem_host[i, slot])
+                            req.generated.extend(
+                                int(t) for t in blocks_host[i, slot, :m])
+                    else:
                         req.generated.extend(
-                            int(t) for t in blocks_host[i, slot, :m])
-                else:
-                    req.generated.extend(
-                        int(t) for t in out_host[:n_new, slot])
-                req.cache_len += n_new
-                req.t_last = now
-                n_tokens += n_new
-                if req.temperature > 0:
-                    n_sampled += n_new
-            if self._finished(req):
-                done.append(req)
-        self._count("decode_iterations")
-        self._count("decode_tokens", n_tokens)
-        self._count("active_sum", n_active)
-        if n_sampled:
-            self._count("sampled_tokens", n_sampled)
-        if draft:
-            self._count("draft_accepted", int(_np.asarray(acc).sum()))
-            self._count("draft_rejected", int(_np.asarray(rej).sum()))
-        if _trace.enabled() and _trace.collector_active():
-            record_span("serve.decode_batch", (now - t0) * 1e6,
-                        ts_us=t0 * 1e6, cat="serve", active=n_active,
-                        tokens=n_tokens, steps=self.decode_steps)
-        self._retire(done)
+                            int(t) for t in out_host[:n_new, slot])
+                    req.cache_len += n_new
+                    req.t_last = now
+                    n_tokens += n_new
+                    if req.temperature > 0:
+                        n_sampled += n_new
+                if self._finished(req):
+                    done.append(req)
+            self._count("decode_iterations")
+            self._count("decode_tokens", n_tokens)
+            self._count("active_sum", n_active)
+            if n_sampled:
+                self._count("sampled_tokens", n_sampled)
+            if draft:
+                self._count("draft_accepted", int(_np.asarray(acc).sum()))
+                self._count("draft_rejected", int(_np.asarray(rej).sum()))
+        if on:
+            sp.set(active=n_active, tokens=n_tokens)
+        return done
 
     def _finished(self, req):
         if len(req.generated) >= req.max_new:
@@ -2010,11 +2135,16 @@ class ContinuousEngine:
         # page full: the NEXT decode would write past the slot
         return req.cache_len + 1 >= self.max_len
 
-    def _retire(self, done):
+    def _retire(self, done, on):
         """Free slots and resolve futures; one request's whole life —
         prefill + N decode iterations — closes as ONE trace here."""
         if not done:
             return
+        with (_span("serve.retire", cat="serve", n=len(done)) if on
+              else NO_SPAN):
+            self._retire_each(done, on)
+
+    def _retire_each(self, done, on):
         prof = _profiler_on()
         for req in done:
             with self._cv:
@@ -2033,23 +2163,26 @@ class ContinuousEngine:
                         # publish BEFORE free: the copy is dispatched on
                         # this thread ahead of any wave that could
                         # rewrite the retiring slot's row
-                        self._dispatch_copy([(req.slot, row)])
+                        self._dispatch_copy([(req.slot, row)], "publish",
+                                            on)
             self.pool.free(req.slot)
             out = _np.asarray(req.generated, dtype=_np.int32)
             if self.eos_id is not None:
                 hits = _np.nonzero(out == self.eos_id)[0]
                 if hits.size:
                     out = out[:int(hits[0]) + 1]
+            # the timeline is whole before the future resolves: a done
+            # callback reads every field of `fut.timing`
+            now = req.t_done = time.perf_counter()
             if req.future.set_running_or_notify_cancel():
                 req.future.set_result(out)
-            now = time.perf_counter()
             total_ms = (now - req.t_submit) * 1e3
+            n = len(req.generated)
             with self._mlock:
-                self._e2e_ms.append(total_ms)
-                if len(req.generated) > 1 and req.t_first is not None:
-                    self._tpot_ms.append(
-                        (req.t_last - req.t_first) * 1e3
-                        / (len(req.generated) - 1))
+                self._latencies.append((
+                    (req.t_first - req.t_submit) * 1e3,
+                    (now - req.t_first) * 1e3 / (n - 1) if n > 1 else None,
+                    total_ms))
             self._count("replies")
             self._count("retired")
             if req.ctx is not None and prof:

@@ -42,7 +42,8 @@ from .trace import (TraceContext, current_context, attach, detach,
                     attached, new_context, child_context,
                     flightrec_record, flightrec_dump, flightrec_maybe_dump,
                     flightrec_events, install_crash_hooks, FLIGHTREC)
-from .steptrace import (span, current_span, record_span, StepTimeline,
+from .steptrace import (span, NO_SPAN, current_span, record_span,
+                        StepTimeline,
                         model_flops, block_fwd_flops, cost_flops,
                         device_peak_flops)
 
@@ -50,7 +51,8 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "StatsGroup", "Registry", "REGISTRY",
     "counter", "gauge", "histogram", "stats_group", "snapshot",
     "snapshot_json", "prometheus_text", "DEFAULT_BUCKETS",
-    "span", "current_span", "record_span", "StepTimeline", "model_flops",
+    "span", "NO_SPAN", "current_span", "record_span", "StepTimeline",
+    "model_flops",
     "block_fwd_flops", "cost_flops", "device_peak_flops",
     "metrics_text", "scalar_snapshot", "start_metrics_server",
     "ensure_metrics_server", "mem_on_oom", "mem_install_oom_hook",

@@ -42,7 +42,7 @@ from ..base import MXNetError, get_env
 from .registry import REGISTRY
 from . import trace as _trace
 
-__all__ = ["span", "current_span", "record_span", "StepTimeline",
+__all__ = ["span", "NO_SPAN", "current_span", "record_span", "StepTimeline",
            "model_flops", "block_fwd_flops", "cost_flops",
            "device_peak_flops", "SPAN_DURATION", "SPAN_COUNT"]
 
@@ -141,6 +141,11 @@ def record_span(name, dur_us, ts_us=None, cat="span", ctx=None, **attrs):
                                              ts_us=ts_us, args=attrs)
 
 
+# (TraceAnnotation, StepTraceAnnotation), resolved under the first open
+# `jax.profiler` session: this module never imports jax on its own
+_annotations = []
+
+
 class span:
     """`with telemetry.span("train.step", step=n):` — time a region.
 
@@ -150,19 +155,29 @@ class span:
     carries the enclosing span's name in `args["parent"]` PLUS the
     trace/span/parent ids, and — after `trace.attach(ctx)` on a worker
     thread — nesting survives thread hops. The registry histogram
-    `span.duration_us{name=...}` aggregates durations, and the open/close
-    pair feeds the flight recorder (an in-flight span at process death is
-    named by its `span_open` spool line). A span is cheap when
-    `MXNET_TELEMETRY=0` (no clock reads, no records) and never touches
-    jax. Reentrant and exception-safe (the span closes on the error path
-    too — ContextVar tokens reset correctly even when an inner span
-    leaked open, so traces stay balanced)."""
+    `span.duration_us{name=...}` aggregates durations, and a ROOT span's
+    open/close pair feeds the flight recorder (an in-flight span at
+    process death is named by its `span_open` spool line; nested spans
+    write none, so wave-scale children cannot evict the ring).
 
-    __slots__ = ("name", "attrs", "_t0", "_parent", "_armed", "_dur",
-                 "_ctx", "_token")
+    While a `jax.profiler` session is open the span also enters a
+    `jax.profiler.TraceAnnotation(name, **attrs)` (a `StepTraceAnnotation`
+    when given a `step_num`), so it lands on `/host:CPU` of that trace,
+    on the clock the device planes are on. `set(**attrs)` adds attributes
+    known only inside the region. `cat` names the Chrome-trace lane.
 
-    def __init__(self, name, **attrs):
+    A span is cheap when `MXNET_TELEMETRY=0` (no clock reads, no
+    records) and touches jax only under an open session. Reentrant and
+    exception-safe (the span closes on the error path too — ContextVar
+    tokens reset correctly even when an inner span leaked open, so
+    traces stay balanced)."""
+
+    __slots__ = ("name", "cat", "attrs", "_t0", "_parent", "_armed",
+                 "_dur", "_ctx", "_token", "_ann")
+
+    def __init__(self, name, cat="span", **attrs):
         self.name = name
+        self.cat = cat
         self.attrs = attrs
         self._t0 = None
         self._parent = None
@@ -170,6 +185,7 @@ class span:
         self._dur = None
         self._ctx = None
         self._token = None
+        self._ann = None
 
     def __enter__(self):
         self._armed = _enabled()
@@ -189,18 +205,40 @@ class span:
             self._ctx = _trace.child_context(raw, self.name)
             if self._ctx is not None:
                 self._token = _trace._push(self._ctx)
-                _trace.flightrec_record("span_open", self.name,
-                                        **self.attrs)
+                if raw is None:
+                    _trace.flightrec_record("span_open", self.name,
+                                            **self.attrs)
             elif raw is None:
                 # root draw came up sampled-out: mark the subtree
                 self._token = _trace._push(_trace.NOT_SAMPLED)
         self._t0 = profiler._now_us()
+        if profiler.jax_session_open():
+            if not _annotations:
+                from jax.profiler import (StepTraceAnnotation,
+                                          TraceAnnotation)
+                _annotations.extend((TraceAnnotation, StepTraceAnnotation))
+            kind = _annotations["step_num" in self.attrs]
+            kw = self.attrs if self._parent is None \
+                else dict(self.attrs, parent=self._parent)
+            self._ann = kind(self.name, **kw)
+            self._ann.__enter__()
         return self
+
+    def set(self, **attrs):
+        """Attributes known only inside the region (counts an admission
+        produced, tokens a wave emitted); a no-op on an un-armed span."""
+        if self._armed:
+            self.attrs.update(attrs)
+            if self._ann is not None:
+                self._ann.set_metadata(**attrs)
 
     def __exit__(self, *exc):
         if not self._armed:
             return False
         from .. import profiler
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         t1 = profiler._now_us()
         if self._token is not None:
             _trace._reset(self._token)
@@ -209,8 +247,8 @@ class span:
         if self._parent is not None:
             attrs["parent"] = self._parent
         self._dur = t1 - self._t0
-        record_span(self.name, self._dur, ts_us=self._t0, ctx=self._ctx,
-                    **attrs)
+        record_span(self.name, self._dur, ts_us=self._t0, cat=self.cat,
+                    ctx=self._ctx, **attrs)
         return False
 
     @property
@@ -223,6 +261,25 @@ class span:
         """The span's TraceContext (None before entry, when telemetry is
         off, or when the root was sampled out)."""
         return self._ctx
+
+
+class _NoSpan:
+    """What a hot path enters in a span's place while nothing collects:
+    one shared object, no clock read, nothing built."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **attrs):
+        pass
+
+
+NO_SPAN = _NoSpan()
 
 
 def _stall_counters():

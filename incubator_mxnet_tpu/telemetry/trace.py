@@ -230,21 +230,8 @@ def enabled():
     return _env_memo["enabled"]
 
 
-def collector_active():
-    """True when something can actually CONSUME per-request trace ids:
-    the profiler is collecting a Chrome trace, the flight-recorder spool
-    is armed (`MXNET_FLIGHTREC_DIR`), or `MXNET_TRACE_SAMPLE` is
-    explicitly set (an operator forcing request tracing, e.g. for the
-    slowest-requests table). The per-REQUEST root-mint hot path (serve
-    submit) gates on this: at ~10k req/s even a few microseconds of
-    mint work per request measurably taxes a GIL-saturated server, and
-    ids nobody can see are pure cost. Step-scale spans
-    (`telemetry.span`) are NOT gated — their rate is harmless and their
-    ids feed the flight-recorder ring either way."""
-    now = time.monotonic()
-    if now > _env_deadline[0]:
-        _env_deadline[0] = now + _ENV_TTL_S
-        _env_refresh()
+def _has_collector():
+    """Something can consume trace ids and spans (the env memo is fresh)."""
     if _env_memo["explicit_sample"]:
         return True
     f = FLIGHTREC
@@ -256,6 +243,25 @@ def collector_active():
     return _profiler_running()
 
 
+def collector_active():
+    """True when something can actually CONSUME per-request trace ids:
+    the profiler is collecting a Chrome trace, a `jax.profiler` session
+    is open, the flight-recorder spool is armed (`MXNET_FLIGHTREC_DIR`),
+    or `MXNET_TRACE_SAMPLE` is explicitly set (an operator forcing
+    request tracing, e.g. for the slowest-requests table). The
+    per-REQUEST root-mint hot path (serve submit) and the hot paths'
+    live spans gate on this through `armed()`: at ~10k req/s even a few
+    microseconds of mint work per request measurably taxes a
+    GIL-saturated server, and ids nobody can see are pure cost.
+    Step-scale spans (`telemetry.span`) are NOT gated — their rate is
+    harmless and their ids feed the flight-recorder ring either way."""
+    now = time.monotonic()
+    if now > _env_deadline[0]:
+        _env_deadline[0] = now + _ENV_TTL_S
+        _env_refresh()
+    return _has_collector()
+
+
 # the profiler module, resolved once: `from .. import profiler` per call
 # runs the import machinery (~1us + import-lock traffic) on a
 # per-request path
@@ -263,31 +269,32 @@ _profiler_mod = [None]
 
 
 def _profiler_running():
+    """The event buffer has a collector: `mx.profiler` is started or a
+    `jax.profiler` session is open (`profiler.collecting`)."""
     p = _profiler_mod[0]
     if p is None:
         from .. import profiler as p
         _profiler_mod[0] = p
-    return p._state["running"]
+    return p.collecting()
 
 
-def request_root(name):
-    """Mint a request-root context iff tracing is enabled AND a
-    collector is active — `enabled() and collector_active()` fused into
-    ONE TTL check for the per-request serve hot path. Returns None
-    otherwise (and None when the root is sampled out)."""
+def armed():
+    """`enabled() and collector_active()` fused into ONE TTL check: the
+    gate of the per-request root mint (serve submit) and of the hot
+    paths' live spans (the engine's wave loop, the fused train step, the
+    device feed), which ask it once per wave, step or batch."""
     now = time.monotonic()
     if now > _env_deadline[0]:
         _env_deadline[0] = now + _ENV_TTL_S
         _env_refresh()
-    if not _env_memo["enabled"]:
+    return _env_memo["enabled"] and _has_collector()
+
+
+def request_root(name):
+    """Mint a request-root context iff tracing is `armed()`. Returns None
+    otherwise (and None when the root is sampled out)."""
+    if not armed():
         return None
-    if not _env_memo["explicit_sample"]:
-        f = FLIGHTREC
-        if f._ring is None:
-            if f._spool_dir() is None and not _profiler_running():
-                return None
-        elif f._spool_dir_memo is None and not _profiler_running():
-            return None
     parent = _CTX.get()
     if parent is NOT_SAMPLED:   # a request is its own sampling domain
         parent = None

@@ -9,7 +9,7 @@ a platform guard > spec fallback), the cost-analysis degradation contract
 (missing bytes keys / raising backends -> flops-only ranking, never a
 crash), inspection of every framework surface (jitted fn, FusedTrainStep,
 FusedInferStep, deploy.ExportedModel), fusion-class grouping + coverage,
-measured-mode fallback on CPU, the registry metrics, and the CLI/bench
+the wall-clock callback, the registry metrics, and the CLI/bench
 smokes (`tools/offenders.py --quick`, `benchmark/opperf.py --quick`,
 `bench.py --quick --phases offenders`) plus the committed ResNet-18
 artifact's acceptance numbers.
@@ -460,19 +460,19 @@ def test_top_k_env_knob(monkeypatch):
     assert rep["totals"]["units"] == 3          # totals stay whole-module
 
 
-def test_measured_mode_degrades_honestly_on_cpu():
-    """CPU containers cannot attribute a device trace: measured stays
-    False with a reason, wall timing is still reported, and the
-    cost-model numbers stand."""
+def test_execute_callback_adds_wall_clock_only():
+    """`execute=` times real executions on the wall clock; the report
+    claims no device measurement (measured mode is gone)."""
     import jax.numpy as jnp
-
     x = jnp.ones((32, 32), jnp.float32)
     rep = mxinspect.inspect_step(
         lambda a: (a @ a).sum(), x,
-        measured=True, execute=lambda: (x @ x).sum().block_until_ready())
-    assert rep["measured"] is False
-    assert "measured_unavailable_reason" in rep
+        execute=lambda: (x @ x).sum().block_until_ready())
     assert rep["measured_wall_ms"] > 0
+    assert "measured" not in rep
+    assert "measured_unavailable_reason" not in rep
+    assert "measured_wall_ms" not in mxinspect.inspect_step(
+        lambda a: (a @ a).sum(), x)
 
 
 def test_lower_any_rejects_unknown():

@@ -1,0 +1,475 @@
+"""The hot paths' live spans on the profiler's clock (counts and
+structure, never times): the engine's wave loop and the fused train step
+under a `jax.profiler` session, read back from `/host:CPU` of the
+`.xplane.pb` and from `profiler.events()`; the off path; the bounded
+buffer; a request's public timeline; and the names the benchmark's
+reducer relies on (program module names, kernel names, scopes)."""
+import ast
+import glob
+import json
+import os
+import re
+import time
+
+import numpy as np
+import pytest
+
+import incubator_mxnet_tpu as mx
+from incubator_mxnet_tpu import gluon, profiler, serve, telemetry
+from incubator_mxnet_tpu import optimizer as opt_mod
+from incubator_mxnet_tpu.gluon import nn
+from incubator_mxnet_tpu.gluon.contrib import FusedTrainStep
+from incubator_mxnet_tpu.io.device_feed import DeviceFeed
+from incubator_mxnet_tpu.serve.metrics import percentile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+KERNEL_FILES = ("incubator_mxnet_tpu/ops/pallas_kernels.py",
+                "incubator_mxnet_tpu/ops/pallas_attention.py")
+PROGRAM_METRICS = sorted(glob.glob(os.path.join(
+    ROOT, "chipbench", "layer_metrics", "*_prog_ms.*.json")))
+DECODE_CHILDREN = ["serve.decode_batch.pack", "serve.decode_batch.dispatch",
+                   "serve.decode_batch.readback", "serve.decode_batch.emit"]
+PREFILL_CHILDREN = ["serve.prefill_batch.pack",
+                    "serve.prefill_batch.dispatch",
+                    "serve.prefill_batch.readback"]
+
+
+@pytest.fixture(autouse=True)
+def _no_collector(monkeypatch):
+    """No collector but the one a test opens."""
+    monkeypatch.delenv("MXNET_TRACE_SAMPLE", raising=False)
+    monkeypatch.delenv("MXNET_FLIGHTREC_DIR", raising=False)
+    telemetry.FLIGHTREC._reset_for_tests()
+    telemetry.trace._expire_env_memo()
+    profiler.stop()
+    profiler._events.clear()
+    yield
+    profiler._events.clear()
+
+
+def toy_engine(**kw):
+    cfg = serve.DecoderConfig(vocab=64, embed=32, layers=2, heads=4,
+                              head_dim=8, max_len=48)
+    return serve.ContinuousEngine(
+        serve.CachedDecoder(cfg, seed=11), max_slots=4,
+        prefix_cache_slots=1, prefix_block=4, prefill_window=16,
+        decode_steps=2, **kw)
+
+
+def toy_step():
+    mx.seed(3)
+    net = nn.HybridSequential()
+    net.add(nn.Dense(16, activation="relu", in_units=8),
+            nn.Dense(4, in_units=16))
+    net.initialize()
+    loss_fn = gluon.loss.L2Loss()
+    step = FusedTrainStep(net, lambda n, x, y: loss_fn(n(x), y).mean(),
+                          opt_mod.create("sgd", learning_rate=0.1))
+    rng = np.random.RandomState(0)
+    data = [(rng.randn(8, 8).astype(np.float32),
+             rng.randn(8, 4).astype(np.float32)) for _ in range(3)]
+    return step, data
+
+
+def host_events(trace_dir, prefix):
+    """[(thread line, name, start_ns, end_ns, stats)] of the `/host:CPU`
+    events whose name starts with `prefix`, by start."""
+    import jax
+    pb = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                   recursive=True)[0]
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(pb).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(prefix):
+                    out.append((line.name, ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                dict(ev.stats)))
+    return sorted(out, key=lambda e: (e[2], -e[3]))
+
+
+def inside(events, outer):
+    """Names of the events that lie inside `outer`, in order."""
+    return [e[1] for e in events
+            if e is not outer and e[0] == outer[0]
+            and outer[2] <= e[2] and e[3] <= outer[3]]
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """A toy engine under one `jax.profiler` session: a cold wave, then a
+    request whose prompt hits the prefix the first wave published."""
+    import jax
+    trace_dir = str(tmp_path_factory.mktemp("serve_trace"))
+    profiler.stop()
+    profiler._events.clear()
+    eng = toy_engine().start()
+    try:
+        jax.profiler.start_trace(trace_dir)
+        try:
+            # the iteration that was waiting when the session opened had
+            # asked its gate before: one request takes it
+            eng.generate([9, 9, 9], 2)
+            s0, t0_us = eng.stats(), profiler._now_us()
+            futs = [eng.submit([1, 2, 3, 4, 5 + i], 6) for i in range(3)]
+            [f.result(timeout=120) for f in futs]
+            futs.append(eng.submit([1, 2, 3, 4, 5, 7, 8], 5))
+            futs[-1].result(timeout=120)
+            s1 = eng.stats()
+        finally:
+            jax.profiler.stop_trace()
+        buffered = profiler.events()
+        after = len(profiler.events())
+        eng.generate([4, 4, 4], 2)           # the session is closed
+        quiet = len(profiler.events()) == after
+    finally:
+        eng.close()
+    return {"host": host_events(trace_dir, "serve."), "buffer": buffered,
+            "s0": s0, "s1": s1, "t0_us": t0_us, "futs": futs,
+            "quiet": quiet}
+
+
+def test_one_decode_span_per_decode_iteration_on_host_plane(served):
+    grew = (served["s1"]["decode_iterations"]
+            - served["s0"]["decode_iterations"])
+    assert grew >= 3
+    buffered = [e for e in served["buffer"]
+                if e["name"] == "serve.decode_batch"]
+    assert sum(1 for e in buffered if e["ts"] >= served["t0_us"]) == grew
+    on_host = [e for e in served["host"] if e[1] == "serve.decode_batch"]
+    assert len(on_host) == len(buffered)
+    assert sum(e[4]["tokens"] for e in on_host[-grew:]) == (
+        served["s1"]["decode_tokens"] - served["s0"]["decode_tokens"])
+
+
+def test_decode_span_has_its_four_children_in_order(served):
+    waves = [e for e in served["host"] if e[1] == "serve.decode_batch"]
+    assert waves
+    for w in waves:
+        assert inside(served["host"], w) == DECODE_CHILDREN
+        assert w[4]["steps"] == 2 and w[4]["active"] >= 1
+        assert w[4]["parent"] == "serve.wave"
+
+
+def test_prefill_span_children_and_copy_spans(served):
+    host = served["host"]
+    waves = [e for e in host if e[1] == "serve.prefill_batch"]
+    assert waves
+    cold = [w for w in waves if inside(host, w) == PREFILL_CHILDREN]
+    hit = [w for w in waves if "serve.copy.dispatch" in inside(host, w)]
+    assert cold and len(hit) == 1
+    # a hit copies the cached row in, then prefills its suffix by chunks
+    assert inside(host, hit[0]) == ["serve.copy.dispatch"] + PREFILL_CHILDREN
+    copies = {e[4]["why"] for e in host if e[1] == "serve.copy.dispatch"}
+    assert copies == {"hit", "publish"}
+    retires = [e for e in host if e[1] == "serve.retire"]
+    # the lead request ran in the iteration that was not yet armed
+    assert retires and sum(e[4]["n"] for e in retires) == 4
+    published = [r for r in retires
+                 if "serve.copy.dispatch" in inside(host, r)]
+    assert published
+    admits = [e for e in host if e[1] == "serve.admit"]
+    assert sum(e[4]["admitted"] for e in admits) == 4
+    assert all("waiting" in e[4] and "expired" in e[4] for e in admits)
+
+
+def test_buffer_holds_the_same_spans_with_wave_trace_ids(served):
+    buf = [e for e in served["buffer"] if e["cat"] == "serve"]
+    names = [e["name"] for e in buf]
+    host_names = [e[1] for e in served["host"]]
+    for name in set(host_names):
+        assert names.count(name) == host_names.count(name), name
+    waves = [e for e in buf if e["name"] == "serve.decode_batch"]
+    for w in waves:
+        tid = w["args"]["trace_id"]
+        assert re.fullmatch(r"wave-\d+", tid)
+        kids = [e["name"] for e in buf
+                if e["args"].get("trace_id") == tid
+                and e["args"].get("parent") == "serve.decode_batch"]
+        assert kids == DECODE_CHILDREN
+    assert len({w["args"]["trace_id"] for w in waves}) == len(waves)
+    # per-request spans stay request scale: buffer only, their own traces
+    assert names.count("serve.request") == 5      # request scale: all
+    assert not any(n in host_names for n in
+                   ("serve.request", "serve.prefill", "serve.decode"))
+    assert served["quiet"], "spans were buffered after the session closed"
+
+
+def test_events_accessor_filters_and_copies():
+    profiler.start()
+    try:
+        with telemetry.span("outer", k=1):
+            with telemetry.span("inner", cat="io"):
+                pass
+    finally:
+        profiler.stop()
+    assert [e["name"] for e in profiler.events("io")] == ["inner"]
+    got = profiler.events()
+    assert [e["name"] for e in got] == ["inner", "outer"]
+    assert got[0]["args"]["parent"] == "outer"
+    got[0]["name"] = "changed"
+    assert profiler.events()[0]["name"] == "inner"
+
+
+def test_unarmed_engine_buffers_nothing_and_builds_no_annotation(
+        monkeypatch):
+    import jax
+
+    class Refuse:
+        is_enabled = staticmethod(lambda: False)
+
+        def __init__(self, *a, **k):
+            raise AssertionError("an annotation was built off the hot "
+                                 "path's gate")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Refuse)
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation", Refuse)
+    monkeypatch.setattr(profiler, "_annotation", [Refuse])
+    assert not telemetry.trace.armed()
+    opens = telemetry.snapshot()["flightrec.events"]
+    eng = toy_engine().start()
+    try:
+        out = eng.generate([1, 2, 3], 6)
+        assert eng.stats()["decode_iterations"] >= 2
+    finally:
+        eng.close()
+    step, data = toy_step()
+    for x, y in DeviceFeed(data):
+        step(x, y)
+    assert len(out) == 6
+    assert profiler.events() == []
+    assert telemetry.snapshot()["flightrec.events"] == opens
+
+
+def test_armed_without_a_session_spans_reach_no_buffer(monkeypatch):
+    """`MXNET_TRACE_SAMPLE` arms the collectors; the buffer still belongs
+    to `mx.profiler` or a `jax.profiler` session."""
+    monkeypatch.setenv("MXNET_TRACE_SAMPLE", "1")
+    telemetry.trace._expire_env_memo()
+    assert telemetry.trace.armed()
+    before = telemetry.snapshot().get(
+        'span.count{name="serve.decode_batch.pack"}', 0)
+    ring = telemetry.snapshot()["flightrec.events"]
+    eng = toy_engine().start()
+    try:
+        eng.generate([1, 2, 3], 6)
+        waves = eng.stats()["decode_iterations"]
+    finally:
+        eng.close()
+    assert profiler.events() == []
+    assert telemetry.snapshot()[
+        'span.count{name="serve.decode_batch.pack"}'] - before == waves
+    # wave-scale spans open under the wave's context: none of them
+    # writes an in-flight marker into the flight recorder's ring
+    opened = [e for e in telemetry.flightrec_events()
+              if e["kind"] == "span_open" and e["name"].startswith("serve.")]
+    assert opened == []
+    assert telemetry.snapshot()["flightrec.events"] - ring < waves
+
+
+def test_event_buffer_is_bounded():
+    assert profiler._events.maxlen == profiler.EVENTS_CAP
+    profiler.start()
+    try:
+        for i in range(profiler.EVENTS_CAP + 7):
+            profiler.record_event("e", "op", 0.0, ts_us=i)
+    finally:
+        profiler.stop()
+    assert len(profiler._events) == profiler.EVENTS_CAP
+    assert profiler.events()[0]["ts"] == 7      # the oldest went first
+
+
+def test_request_timing_is_public_monotone_and_whole_at_resolution(served):
+    seen = []
+    eng = toy_engine().start()
+    try:
+        fut = eng.submit([1, 2, 3, 4, 5], 6)
+        fut.add_done_callback(lambda f: seen.append(f.timing.as_dict()))
+        t = fut.timing
+        assert t.t_submit is not None and t.prompt_tokens == 5
+        fut.result(timeout=120)
+    finally:
+        eng.close()
+    for tm in [f.timing for f in served["futs"]] + [fut.timing]:
+        assert tm.t_submit <= tm.t_admit <= tm.t_first <= tm.t_done
+        assert tm.tokens >= 5
+    # whole before the future resolved: the callback saw the final record
+    assert seen and seen[0] == fut.timing.as_dict()
+    assert seen[0]["t_done"] is not None and seen[0]["tokens"] == 6
+    hit = served["futs"][-1].timing
+    assert hit.cached_tokens == 4 and hit.prompt_tokens == 7
+    assert served["futs"][0].timing.cached_tokens == 0
+    with pytest.raises(AttributeError):
+        fut.timing.t_done = 0.0
+
+
+def test_stats_percentiles_come_from_the_timing_records():
+    eng = toy_engine().start()
+    t_started = time.perf_counter()
+    try:
+        st = eng.stats()
+        # nothing from warm-up: no record, and the clock starts after it
+        assert st["ttft_p50_ms"] is None and st["e2e_p99_ms"] is None
+        assert st["elapsed_s"] <= time.perf_counter() - t_started + 0.01
+        futs = [eng.submit([1, 2, 3 + i], 4 + i) for i in range(5)]
+        [f.result(timeout=120) for f in futs]
+        st = eng.stats()
+    finally:
+        eng.close()
+    tm = [f.timing for f in futs]
+    ttft = sorted((t.t_first - t.t_submit) * 1e3 for t in tm)
+    e2e = sorted((t.t_done - t.t_submit) * 1e3 for t in tm)
+    tpot = sorted((t.t_done - t.t_first) * 1e3 / (t.tokens - 1) for t in tm)
+    for name, vals in (("ttft", ttft), ("e2e", e2e), ("tpot", tpot)):
+        for q in (50, 99):
+            assert st[f"{name}_p{q}_ms"] == round(percentile(vals, q), 3)
+
+
+@pytest.fixture(scope="module")
+def trained(tmp_path_factory):
+    """Three calls of a toy fused step, fed by a DeviceFeed, under one
+    `jax.profiler` session."""
+    import jax
+    trace_dir = str(tmp_path_factory.mktemp("train_trace"))
+    profiler.stop()
+    profiler._events.clear()
+    step, data = toy_step()
+    step(*data[0]).wait_to_read()           # compiled before the session
+    jax.profiler.start_trace(trace_dir)
+    try:
+        for x, y in DeviceFeed(data):
+            loss = step(x, y)
+        loss.wait_to_read()
+    finally:
+        jax.profiler.stop_trace()
+    return {"host": host_events(trace_dir, "train.")
+            + host_events(trace_dir, "io.feed"),
+            "buffer": profiler.events()}
+
+
+def test_train_step_spans_over_three_calls(trained):
+    steps = [e for e in trained["host"] if e[1] == "train.step"]
+    assert len(steps) == 3
+    assert [e[4]["step_num"] for e in steps] == [2, 3, 4]
+    for s in steps:
+        assert inside(trained["host"], s) == ["train.step.dispatch"]
+    buf = [e for e in trained["buffer"] if e["name"].startswith("train.")]
+    assert [e["name"] for e in buf] == ["train.step.dispatch",
+                                        "train.step"] * 3
+    assert all(e["args"]["parent"] == "train.step" for e in buf[::2])
+
+
+def test_feed_wait_is_a_live_span(trained):
+    waits = [e for e in trained["host"] if e[1] == "io.feed"]
+    # three batches and the terminal sentinel
+    assert len(waits) == 4
+    assert all("buffer" in e[4] for e in waits)
+    steps = [e for e in trained["host"] if e[1] == "train.step"]
+    # a wait lies between steps, not inside one
+    assert not any(s[2] <= w[2] and w[3] <= s[3]
+                   for w in waits for s in steps)
+
+
+def module_name(lowered):
+    return re.search(r"module @(\S+)", lowered.as_text()).group(1)
+
+
+@pytest.fixture(scope="module")
+def program_names():
+    """Module names of the five programs, lowered at toy size."""
+    import jax
+    eng = toy_engine()
+    low = eng.lowered_programs()
+    slab = jax.ShapeDtypeStruct(eng.pool.shape, eng.pool.dtype)
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), eng.model.params)
+    S, P, W = eng.pool.max_slots, eng.prefill_lanes, eng.prefill_window
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, "int32")  # noqa: E731
+    chunk = eng._chunk_progs[eng._chunk_extents[0]].lower(
+        params, slab, slab, i32(S, W), i32(S), i32(S))
+    copy = eng._copy_prog.lower(slab, slab, i32(P), i32(P))
+    step, data = toy_step()
+    return {"decode": module_name(low["decode"]),
+            "prefill": module_name(low["prefill"]),
+            "chunk_prefill": module_name(chunk),
+            "copy": module_name(copy),
+            "train": module_name(step.lowered(*data[0]))}
+
+
+FOUND_BY = {"decode_prog_ms.serve": ["decode"],
+            "prefill_prog_ms.serve": ["prefill", "chunk_prefill"],
+            "copy_prog_ms.serve": ["copy"],
+            "train_prog_ms.train": ["train"]}
+
+
+def test_every_program_metric_is_under_the_contract():
+    names = {os.path.basename(p)[:-len(".json")] for p in PROGRAM_METRICS}
+    assert names == set(FOUND_BY)
+
+
+@pytest.mark.parametrize("path", PROGRAM_METRICS,
+                         ids=[os.path.basename(p) for p in PROGRAM_METRICS])
+def test_program_metric_pattern_finds_its_module_name(path, program_names):
+    """The profiler's `XLA Modules` line names an execution
+    `<module>(<fingerprint>)`; the benchmark's patterns find the programs
+    by the names their factories give them."""
+    with open(path) as f:
+        pattern = json.load(f)["params"]["pattern"]
+    metric = os.path.basename(path)[:-len(".json")]
+    for prog, name in program_names.items():
+        found = re.search(pattern, name + "(1234)") is not None
+        assert found == (prog in FOUND_BY[metric]), (metric, prog, name)
+
+
+def pallas_calls(node):
+    return [n for n in ast.walk(node) if isinstance(n, ast.Call)
+            and isinstance(n.func, ast.Attribute)
+            and n.func.attr == "pallas_call"]
+
+
+@pytest.mark.parametrize("path", KERNEL_FILES)
+def test_every_pallas_call_is_named_after_its_wrapper(path):
+    with open(os.path.join(ROOT, path)) as f:
+        tree = ast.parse(f.read())
+    seen = []
+    for fn in [n for n in ast.walk(tree) if isinstance(n, ast.FunctionDef)]:
+        for call in pallas_calls(fn):
+            name = {k.arg: k.value for k in call.keywords}.get("name")
+            assert isinstance(name, ast.Constant), (path, call.lineno)
+            assert name.value.startswith(fn.name.lstrip("_")), \
+                (fn.name, name.value)
+            seen.append(name.value)
+    assert len(seen) == len(pallas_calls(tree)) == 4
+    assert len(set(seen)) == len(seen)
+
+
+def test_kernel_name_reaches_the_lowered_program():
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.ops import pallas_kernels
+
+    def f(x, shift):
+        return pallas_kernels.apply_scale_shift_act(
+            x, None, shift, None, "relu", interpret=False)
+
+    text = jax.jit(f).trace(
+        jax.ShapeDtypeStruct((256, 128), jnp.float32),
+        jax.ShapeDtypeStruct((128,), jnp.float32)).jaxpr.pretty_print()
+    assert "apply_scale_shift_act" in text
+
+
+def test_program_scopes_are_in_the_lowered_programs():
+    eng = toy_engine()
+    low = eng.lowered_programs()
+    decode = low["decode"].as_text(debug_info=True)
+    for scope in ("embed", "layer0/attn", "layer0/mlp", "layer1/attn",
+                  "layer1/mlp", "head", "sampler"):
+        assert f'"{scope}' in decode or f"/{scope}" in decode, scope
+    prefill = low["prefill"].as_text(debug_info=True)
+    assert "layer1/mlp" in prefill and "sampler" not in prefill
+    step, data = toy_step()
+    text = step.lowered(*data[0]).as_text(debug_info=True)
+    for scope in ("forward", "transpose(jvp(forward))", "update"):
+        assert scope in text, scope

@@ -16,8 +16,7 @@ calibrated ridge point, and estimated time share. "MFU is 0.15" becomes
 
 Calibration comes from `benchmark/results/roofline_calib.json`
 (`tools/bandwidth.py --calib`; docs/PERF.md has the recalibration
-workflow). Knobs: MXNET_INSPECT_TOP_K, MXNET_INSPECT_MEASURED,
-MXNET_INSPECT_CALIB.
+workflow). Knobs: MXNET_INSPECT_TOP_K, MXNET_INSPECT_CALIB.
 """
 import argparse
 import json
@@ -32,7 +31,7 @@ import numpy as np
 def build_step(model, batch_size, layout, mode, use_amp=True,
                use_fusion=None):
     """(step_obj, inputs, execute) for one model name. `execute` runs the
-    real program once (enables measured mode + wall timing). `use_fusion`
+    real program once (wall timing, `--wall`). `use_fusion`
     routes the forward through the fused kernel tier (None = the fused
     steps' MXNET_USE_FUSION default); `--no-fusion` turns it off — the
     before/after offender pair is exactly this A/B."""
@@ -97,9 +96,9 @@ def main(argv=None):
                          "for stdout)")
     ap.add_argument("--markdown", nargs="?", const="-", default=None,
                     help="write the markdown report (path or stdout)")
-    ap.add_argument("--measured", action="store_true",
-                    help="attempt a jax.profiler device trace "
-                         "(falls back to estimates, flagged, on CPU)")
+    ap.add_argument("--wall", action="store_true",
+                    help="also run the program and report its wall-clock "
+                         "time per execution (measured_wall_ms)")
     ap.add_argument("--quick", action="store_true",
                     help="CI smoke: tiny net, batch 4")
     ap.add_argument("--hlo-file", default=None,
@@ -125,8 +124,7 @@ def main(argv=None):
             name=f"{model}_{args.mode}_bs{bs}"
                  + ("_unfused" if args.no_fusion else ""),
             top_k=args.top_k,
-            measured=args.measured or None,
-            execute=execute if args.measured else None)
+            execute=execute if args.wall else None)
 
     if args.markdown:
         text = mxinspect.render_markdown(report)
