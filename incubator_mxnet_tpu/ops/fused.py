@@ -76,6 +76,10 @@ FUSED_STATS = _stats_group("fused", {
     "device_augment_calls": 0,  # image_augment programs built (per trace)
     "paged_attention_calls": 0,  # paged_attention dispatches (per trace
                                  # inside the jitted decode programs)
+    # of those that took the kernel, by the block body their static
+    # shapes select (`pallas_kernels.paged_body`)
+    "paged_flat_traces": 0,
+    "paged_head_major_traces": 0,
 })
 _STATS = FUSED_STATS
 
@@ -301,6 +305,8 @@ def paged_attention(q, k_slab, v_slab, lengths, layer,
                                       interpret=interpret)
         if out is not None:
             _STATS["pallas_calls"] += 1
+            body = _pk.paged_body(q, k_slab, k_scale)
+            _STATS[f"paged_{body}_traces"] += 1
             return out
     _fell_back("paged_attention",
                f"q {tuple(q.shape)} over slab {tuple(k_slab.shape)} "

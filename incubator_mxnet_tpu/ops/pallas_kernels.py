@@ -18,14 +18,17 @@ kernels instead:
     (the 0.18-intensity `reduce-window` offender class).
   * `paged_attention_fwd` — decode attention over the serve.kv_pool
     slotted KV slab, read IN PLACE (no per-layer gather/copy of the
-    `(slots, max_len, ...)` cache). Block-sparse: per-lane `lengths`
-    are scalar-prefetched so the token-block index map CLAMPS to each
-    lane's `[0, cur_len + C)` — blocks past a lane's live prefix are
-    never fetched from HBM (the clamped index revisits the last live
-    block, whose copy is elided) and their compute is `pl.when`-skipped.
-    Online-softmax VMEM accumulators carry across the sequential token
-    grid. Optional per-position f32 scales dequantize int8 slabs on the
-    fly (serve.kv_pool `dtype="int8"`).
+    `(slots, max_len, ...)` cache). Block-sparse by construction: the
+    grid has one step per LIVE (lane, token-block) pair — its length is
+    a run-time value, and the scalar-prefetched work list built from the
+    per-lane `lengths` names each step's lane and block — so blocks past
+    a lane's `[0, cur_len + C)` are neither fetched nor stepped over,
+    and an idle lane costs one step. Online-softmax VMEM accumulators
+    carry across a lane's consecutive blocks. At tile-aligned heads a
+    plain-decode or verify block feeds K and V to the MXU as stored
+    (bf16 codes, float32 sums; `_paged_body`). Optional per-position f32
+    scales dequantize int8 slabs on the fly (serve.kv_pool
+    `dtype="int8"`).
 
 Everything here takes and returns raw jax arrays and is shape-strict: the
 caller (ops/fused.py) owns fallback policy, custom_vjp wiring and layout
@@ -244,23 +247,86 @@ def avg_pool2d_fwd(x, ph, pw, interpret=False):
 # ---------------------------------------------------------------------------
 # paged decode attention over the slotted KV slab
 # ---------------------------------------------------------------------------
-def _paged_attn_kernel(lens_ref, *refs, bt, n_blocks, chunk, scale,
-                       layer, quantized):
-    """One (lane, token-block) grid step of paged decode attention.
+def _paged_body(c, h, q_bytes, kv_bytes, quantized):
+    """Which of the two block bodies serves these static shapes (`c`
+    queries a lane, `h` heads, `q_bytes`-wide queries over a slab of
+    `kv_bytes`-wide elements).
 
-    Grid is (S, nT) with the token dimension minor, so the VMEM scratch
-    accumulators (running max `m`, normalizer `l`, weighted sum `acc`)
-    persist across a lane's sequential token blocks — classic online
-    softmax. `lens_ref` is scalar-prefetched: block `t` only computes
-    when `t*bt <= len + chunk - 1` (the index map already clamped its
-    HBM fetch to the live prefix).
+    "flat": the (bt, H, D) tile is read as the (bt*H, D) matrix it already
+    is in VMEM and every query row meets every (position, head) column on
+    the MXU; the columns of other heads are masked. It needs H to be a
+    whole number of sublane tiles of the narrower of the two dtypes (the
+    views of the tile and of the (C, H, D) queries then move no byte) and
+    no int8 scales (they sit on a position axis this view does not have).
+    Its H-fold redundant columns cost matmul time in proportion to the
+    C*H query rows, for every byte the block brings: on a v5e at (16, 128)
+    bf16 heads it beats the head-major body 4x at 16 rows and 1.7x at
+    256, loses from 512 on and cannot hold chunk prefill's 2048 rows in
+    VMEM at all (PERF.md section 5), so it serves up to 256 rows.
+    "head_major": the per-head batched contraction, for everything else."""
+    sublanes = 8 * (4 // min(q_bytes, kv_bytes))
+    if not quantized and h % sublanes == 0 and c * h <= 2 * _LANES:
+        return "flat"
+    return "head_major"
 
-    int8 slabs: the scale blocks hold EVERY layer's scales for the token
-    block (`(1, L, bt)` — a one-layer block would break the TPU rule
-    that a block's second-minor dim is a multiple of 8 or the array's
-    own) and row `layer` is picked here. A position's scale multiplies
-    its score column / probability column, where positions already sit
-    on the lane axis, instead of the (bt, H, D) codes."""
+
+def paged_body(q, k_slab, k_scale=None):
+    """The body `paged_attention_fwd` runs these arguments with: the one
+    place that reads it off the arrays, for the kernel and for whoever
+    counts its traces (`fused_stats()`)."""
+    return _paged_body(q.shape[1], q.shape[2], q.dtype.itemsize,
+                       k_slab.dtype.itemsize, k_scale is not None)
+
+
+def _split_bf16(p):
+    """The three bfloat16 pieces whose sum is the float32 `p` exactly
+    (8 + 8 + 8 mantissa bits), stacked on the row axis: bf16 codes meet
+    full-precision probabilities in single MXU passes, float32 sums."""
+    import jax.numpy as jnp
+    pieces = []
+    for _ in range(3):
+        piece = p.astype(jnp.bfloat16)
+        pieces.append(piece)
+        p = p - piece.astype(jnp.float32)
+    return jnp.concatenate(pieces, axis=0)
+
+
+def _live_blocks(length, chunk, bt, n_blocks):
+    """Token blocks a lane at cache length `length` reads: its C queries
+    see positions [0, length + C - 1]. An empty lane still reads one."""
+    import jax.numpy as jnp
+    return jnp.clip((length + chunk - 1) // bt + 1, 1, n_blocks)
+
+
+def _paged_attn_kernel(lens_ref, lane_ref, blk_ref, *refs, bt, n_blocks,
+                       chunk, scale, layer, quantized, body):
+    """One LIVE (lane, token-block) pair of paged decode attention.
+
+    The grid is one-dimensional and as long as the work: step i serves
+    block `blk_ref[i]` of lane `lane_ref[i]`, the scalar-prefetched list
+    of every lane's live blocks in lane order (`paged_attention_fwd`
+    builds it from `lens_ref`), so a lane costs `ceil((len + C) / bt)`
+    steps, an idle one a single step, and a dead tail none. A lane's
+    blocks are consecutive steps: the VMEM scratch accumulators (running
+    max `m`, normaliser `l`, weighted sum `acc`, all float32) carry its
+    online softmax from its block 0 to its last, where the out block is
+    written.
+
+    Bodies (`_paged_body`), same mathematics, float32 scores,
+    probabilities and sums in both:
+      * flat — K and V enter the MXU as stored. Scores are one
+        (C*H, D) x (bt*H, D)^T product: row (j, g) against column
+        (t, h), kept where g == h and position t is inside the lane's
+        `[0, len + j]`. Probabilities go to V as three bf16 pieces that
+        sum to the float32 value (`_split_bf16`).
+      * head_major — `chd,thd->hct` / `hct,thd->hcd` over float32 casts.
+        int8 slabs: the scale blocks hold EVERY layer's scales for the
+        token block (`(1, L, bt)` — a one-layer block would break the
+        TPU rule that a block's second-minor dim is a multiple of 8 or
+        the array's own) and row `layer` is picked here. A position's
+        scale multiplies its score column / probability column, where
+        positions already sit on the lane axis, instead of the
+        (bt, H, D) codes."""
     import jax
     import jax.numpy as jnp
     import jax.experimental.pallas as pl
@@ -276,32 +342,61 @@ def _paged_attn_kernel(lens_ref, *refs, bt, n_blocks, chunk, scale,
     l_ref = next(it)
     acc_ref = next(it)
 
-    s = pl.program_id(0)
-    t = pl.program_id(1)
-    lane_len = lens_ref[s]
+    i = pl.program_id(0)
+    blk = blk_ref[i]
+    lane_len = lens_ref[lane_ref[i]]
+    _, h, d = q_ref.shape[1:]
 
-    @pl.when(t == 0)
+    @pl.when(blk == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, -1e30)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(t * bt <= lane_len + chunk - 1)
-    def _accumulate():
-        qf = q_ref[0].astype(jnp.float32)          # (C, H, D)
-        kf = k_ref[0, 0].astype(jnp.float32)       # (bt, H, D)
+    # query j (the j-th chunk position) may read KV positions
+    # [0, lane_len + j]: the in-chunk causal extension of the engine's
+    # `t <= lengths` decode mask
+    if body == "flat":
+        rows, cols = chunk * h, bt * h
+        mm_dtype = jnp.promote_types(q_ref.dtype, k_ref.dtype)
+        q2 = q_ref[0].reshape(rows, d).astype(mm_dtype)
+        k2 = k_ref[0, 0].reshape(cols, d).astype(mm_dtype)
+        sco = jax.lax.dot_general(
+            q2, k2, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # (rows, cols)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        valid = jnp.logical_and(
+            row % h == col % h,
+            blk * bt + col // h <= lane_len + row // h)
+        sco = jnp.where(valid, sco, -1e30)
+        m_prev = m_ref[...]                               # (rows, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(sco, axis=-1, keepdims=True))
+        p = jnp.exp(sco - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1,
+                                                  keepdims=True)
+        v2 = v_ref[0, 0].reshape(cols, d)
+        if v2.dtype == jnp.bfloat16:
+            pv = jnp.dot(_split_bf16(p), v2,
+                         preferred_element_type=jnp.float32)
+            pv = pv[:rows] + pv[rows:2 * rows] + pv[2 * rows:]
+        else:
+            pv = jnp.dot(p, v2.astype(jnp.float32),
+                         preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = m_new
+    else:
+        qf = q_ref[0].astype(jnp.float32)                 # (C, H, D)
+        kf = k_ref[0, 0].astype(jnp.float32)              # (bt, H, D)
         vf = v_ref[0, 0].astype(jnp.float32)
         sco = jnp.einsum("chd,thd->hct", qf, kf) * scale
         if quantized:
             sco = sco * ks_ref[0, layer:layer + 1, :][None]   # (1, 1, bt)
-        # query j (the j-th chunk position) may read KV positions
-        # [0, lane_len + j]: the in-chunk causal extension of the
-        # engine's `t <= lengths` decode mask
-        pos = t * bt + jax.lax.broadcasted_iota(jnp.int32, (chunk, bt), 1)
+        pos = blk * bt + jax.lax.broadcasted_iota(jnp.int32, (chunk, bt), 1)
         qoff = jax.lax.broadcasted_iota(jnp.int32, (chunk, bt), 0)
-        valid = pos <= lane_len + qoff
-        sco = jnp.where(valid[None], sco, -1e30)
-        m_prev = m_ref[...]                        # (H, C)
+        sco = jnp.where((pos <= lane_len + qoff)[None], sco, -1e30)
+        m_prev = m_ref[...]                               # (H, C)
         m_new = jnp.maximum(m_prev, jnp.max(sco, axis=-1))
         p = jnp.exp(sco - m_new[..., None])
         alpha = jnp.exp(m_prev - m_new)
@@ -312,39 +407,59 @@ def _paged_attn_kernel(lens_ref, *refs, bt, n_blocks, chunk, scale,
                         + jnp.einsum("hct,thd->hcd", p, vf))
         m_ref[...] = m_new
 
-    @pl.when(t == n_blocks - 1)
+    @pl.when(blk == _live_blocks(lane_len, chunk, bt, n_blocks) - 1)
     def _finalize():
-        acc = acc_ref[...]
-        l = l_ref[...]
-        o_ref[0] = (acc / l[..., None]).transpose(1, 0, 2) \
-            .astype(o_ref.dtype)
+        if body == "flat":
+            out = (acc_ref[...] / l_ref[...]).reshape(chunk, h, d)
+        else:
+            out = (acc_ref[...] / l_ref[...][..., None]).transpose(1, 0, 2)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _paged_blocks(t, c, h, d, q_bytes, kv_bytes, n_layers=0):
-    """Token-block size for paged attention: the largest power-of-two
-    divisor of `t` whose VMEM footprint stays inside `_VMEM_SCOPED`, or
-    0. The footprint is counted on PADDED tiles (`_tile_bytes`), the way
-    the TPU compiler allocates it: the pipelined q/out/k/v(/scale)
-    blocks twice over (double buffering), the f32 scratch accumulators,
-    and the body's f32 temporaries — the k/v casts and the head-major
-    copies the two einsums make of them come to 8 (bt, H, D) f32 tiles
-    at (16, 128) heads and under 4 at (12, 64), measured by bisecting
-    the compiler's scoped limit; 8 is used for all — plus the q cast
-    and the (H, C, bt) score and probability tiles. `n_layers > 0`
-    sizes an int8 slab's (L, bt) scale blocks, whose lane dim bt must
-    then be a multiple of 128 or all of `t`."""
-    def footprint(bt):
-        pipelined = 2 * (_tile_bytes((c, h, d), q_bytes)
-                         + _tile_bytes((bt, h, d), kv_bytes))
-        if n_layers:
-            pipelined += 2 * _tile_bytes((n_layers, bt), 4)
-        scratch = _tile_bytes((h, c, d), 4) + 2 * _tile_bytes((h, c), 4)
-        body = (8 * _tile_bytes((bt, h, d), 4)
-                + _tile_bytes((c, h, d), 4)
-                + 2 * _tile_bytes((h, c, bt), 4))
-        return 2 * pipelined + scratch + body
+    """Token-block size for paged attention, or 0 when nothing fits.
 
-    bt = _pow2_block(t, footprint)
+    A lane pays for whole blocks, so the block is the SMALLEST that keeps
+    the lanes full: one lane width (128) of positions — the head-major
+    score tile (H, C, bt) and an int8 slab's (L, bt) scale block have
+    positions on the lane axis, and the flat score tile (C*H, bt*H) is
+    whole vregs from bt*H >= 128 on. It is halved while the VMEM
+    footprint exceeds `_VMEM_SCOPED`, and a `t` that 128 does not divide
+    takes its largest power-of-two divisor. The footprint is counted on
+    PADDED tiles (`_tile_bytes`), the way the TPU compiler allocates
+    them, from what the kernel holds: the pipelined q/out/k/v(/scale)
+    blocks twice over (double buffering), the float32 scratch
+    accumulators, and the body's temporaries. `n_layers > 0` sizes an
+    int8 slab's (L, bt) scale blocks, whose lane dim bt must be a
+    multiple of 128 or all of `t`."""
+    body = _paged_body(c, h, q_bytes, kv_bytes, bool(n_layers))
+
+    def footprint(bt):
+        pipelined = 2 * (2 * _tile_bytes((c, h, d), q_bytes)
+                         + 2 * _tile_bytes((bt, h, d), kv_bytes))
+        if n_layers:
+            pipelined += 4 * _tile_bytes((n_layers, bt), 4)
+        if body == "flat":
+            rows, cols = c * h, bt * h
+            scratch = (2 * _tile_bytes((rows, 1), 4)
+                       + _tile_bytes((rows, d), 4))
+            # scores, mask, probabilities and their residual in float32,
+            # the three stacked bf16 pieces, the (3*rows, D) product
+            temps = (4 * _tile_bytes((rows, cols), 4)
+                     + _tile_bytes((3 * rows, cols), 2)
+                     + _tile_bytes((3 * rows, d), 4))
+        else:
+            scratch = _tile_bytes((h, c, d), 4) + 2 * _tile_bytes((h, c), 4)
+            # the float32 casts of the K and V tiles and the head-major
+            # copy each einsum makes of its cast, the q cast, the (H, C,
+            # bt) score and probability tiles
+            temps = (2 * _tile_bytes((bt, h, d), 4)
+                     + 2 * _tile_bytes((h, bt, d), 4)
+                     + _tile_bytes((c, h, d), 4)
+                     + 2 * _tile_bytes((h, c, bt), 4))
+        return pipelined + scratch + temps
+
+    bt = _pow2_block(_LANES if t % _LANES == 0 else t & -t, footprint)
     if n_layers and bt % _LANES and bt != t:
         return 0
     return bt
@@ -354,10 +469,18 @@ def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
                         k_scale=None, v_scale=None, interpret=False):
     """Pallas paged decode attention. `q`: (S, C, H, D) — C queries per
     lane at positions `lengths[s] + j` (C == 1 plain decode, C == k+1
-    speculative verify). `k_slab`/`v_slab`: the whole KV pool slab
-    (rows, layers, T, H, D); lane s reads row s of layer `layer`,
-    positions clamped to `[0, lengths[s] + j]`. `k_scale`/`v_scale`:
-    per-position f32 dequant scales (rows, layers, T) for int8 slabs.
+    speculative verify, C == the window in chunk prefill).
+    `k_slab`/`v_slab`: the whole KV pool slab (rows, layers, T, H, D);
+    lane s reads row s of layer `layer`, positions clamped to
+    `[0, lengths[s] + j]`. `k_scale`/`v_scale`: per-position f32 dequant
+    scales (rows, layers, T) for int8 slabs.
+
+    The grid has one step per LIVE (lane, token-block) pair and no other:
+    its length is a run-time value (the sum of every lane's
+    `ceil((len + C) / bt)`), and the two lists that name each step's lane
+    and block ride as scalar prefetch beside `lengths`, for the index
+    maps and the kernel. The call's time follows the live KV bytes.
+
     Returns (S, C, H, D) in q.dtype, or None when the shape does not
     tile (the caller falls back and counts it)."""
     import jax
@@ -371,6 +494,7 @@ def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
         return None
     quantized = k_scale is not None
     n_layers = k_slab.shape[1]
+    body = paged_body(q, k_slab, k_scale)
     bt = _paged_blocks(t, c, h, d, q.dtype.itemsize,
                        k_slab.dtype.itemsize,
                        n_layers if quantized else 0)
@@ -379,44 +503,52 @@ def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
     n_blocks = t // bt
     scale = 1.0 / float(d) ** 0.5
 
-    def qidx(s, tt, lens_ref):
-        return (s, 0, 0, 0)
+    # the work list: lane s owns steps [ends[s] - live[s], ends[s]). Plain
+    # XLA ops outside the kernel; the calls of a micro-step's layers pass
+    # the same `lengths`, so the compiler keeps one copy of them
+    live = _live_blocks(lengths, c, bt, n_blocks)
+    ends = jnp.cumsum(live)
+    steps = jnp.arange(s_lanes * n_blocks, dtype=jnp.int32)
+    lane_of = jnp.minimum(
+        jnp.sum(steps[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+        s_lanes - 1)
+    blk_of = steps - (ends - live)[lane_of]
 
-    def kidx(s, tt, lens_ref):
-        # clamp the fetched block to the lane's live prefix: out-of-range
-        # grid steps re-name the last live block (copy elided) and their
-        # compute is skipped in the kernel body
-        need = (lens_ref[s] + c - 1) // bt
-        return (s, layer, jnp.minimum(tt, need), 0, 0)
+    def qidx(i, lens_ref, lane_ref, blk_ref):
+        return (lane_ref[i], 0, 0, 0)
 
-    def sidx(s, tt, lens_ref):
-        need = (lens_ref[s] + c - 1) // bt
-        return (s, 0, jnp.minimum(tt, need))
+    def kidx(i, lens_ref, lane_ref, blk_ref):
+        return (lane_ref[i], layer, blk_ref[i], 0, 0)
+
+    def sidx(i, lens_ref, lane_ref, blk_ref):
+        return (lane_ref[i], 0, blk_ref[i])
 
     in_specs = [
         pl.BlockSpec((1, c, h, d), qidx),
         pl.BlockSpec((1, 1, bt, h, d), kidx),
         pl.BlockSpec((1, 1, bt, h, d), kidx),
     ]
-    args = [lengths, q, k_slab, v_slab]
+    args = [lengths, lane_of, blk_of, q, k_slab, v_slab]
     if quantized:
         in_specs.append(pl.BlockSpec((1, n_layers, bt), sidx))
         in_specs.append(pl.BlockSpec((1, n_layers, bt), sidx))
         args.extend([k_scale, v_scale])
+    acc_shape = (c * h, d) if body == "flat" else (h, c, d)
+    ml_shape = (c * h, 1) if body == "flat" else (h, c)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(s_lanes, n_blocks),
+        num_scalar_prefetch=3,
+        grid=(ends[-1],),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, c, h, d), qidx),
         scratch_shapes=[
-            pltpu.VMEM((h, c), jnp.float32),
-            pltpu.VMEM((h, c), jnp.float32),
-            pltpu.VMEM((h, c, d), jnp.float32),
+            pltpu.VMEM(ml_shape, jnp.float32),
+            pltpu.VMEM(ml_shape, jnp.float32),
+            pltpu.VMEM(acc_shape, jnp.float32),
         ],
     )
     kernel = functools.partial(
         _paged_attn_kernel, bt=bt, n_blocks=n_blocks,
-        chunk=c, scale=scale, layer=layer, quantized=quantized)
+        chunk=c, scale=scale, layer=layer, quantized=quantized, body=body)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -19,6 +19,8 @@ import os
 import pytest
 
 SERVE = dict(lanes=8, heads=12, head_dim=64, max_len=1024, layers=12)
+# `chipbench/configs/cgpt13b_serve.json`: 16 slots + 2 prefix-cache rows
+CELL = dict(lanes=18, heads=16, head_dim=128, max_len=2048, layers=24)
 
 
 @pytest.fixture(scope="module")
@@ -67,15 +69,8 @@ def _holds_kernel(text):
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
 
 
-@pytest.mark.parametrize("kv_dtype,chunk", [
-    ("bfloat16", 1), ("bfloat16", 5), ("int8", 1)],
-    ids=["bf16-C1", "bf16-C5-verify", "int8-C1"])
-def test_paged_attention_compiles_at_gpt2_small_widths(chip, kv_dtype, chunk):
-    """The serve engine's decode attention at the smoke's shapes: plain
-    decode, the speculative-verify chunk, and the int8 slab with its
-    per-position scales."""
+def _compile_paged_attention(chip, s, kv_dtype, chunk):
     from incubator_mxnet_tpu.ops import pallas_kernels as pk
-    s = SERVE
     q = chip((s["lanes"], chunk, s["heads"], s["head_dim"]), "bfloat16")
     slab = chip((s["lanes"] + 1, s["layers"], s["max_len"], s["heads"],
                  s["head_dim"]), kv_dtype)
@@ -91,6 +86,54 @@ def test_paged_attention_compiles_at_gpt2_small_widths(chip, kv_dtype, chunk):
             lambda q, k, v, n: pk.paged_attention_fwd(q, k, v, n, 3),
             q, slab, slab, lengths)
     _holds_kernel(text)
+
+
+@pytest.mark.parametrize("kv_dtype,chunk", [
+    ("bfloat16", 1), ("bfloat16", 5), ("int8", 1)],
+    ids=["bf16-C1", "bf16-C5-verify", "int8-C1"])
+def test_paged_attention_compiles_at_gpt2_small_widths(chip, kv_dtype, chunk):
+    """The serve engine's decode attention at the smoke's shapes: plain
+    decode, the speculative-verify chunk, and the int8 slab with its
+    per-position scales. (12, 64) heads are no whole tile: the head-major
+    body."""
+    _compile_paged_attention(chip, SERVE, kv_dtype, chunk)
+
+
+@pytest.mark.parametrize("kv_dtype,chunk", [
+    ("bfloat16", 1), ("bfloat16", 128), ("int8", 1), ("float32", 5),
+    ("bfloat16", 16)],
+    ids=["bf16-C1", "bf16-C128-chunk-prefill-extent-2048", "int8-C1",
+         "f32-slab-under-bf16-q-C5", "bf16-C16-flat-at-its-256-rows"])
+def test_paged_attention_compiles_at_cgpt13b_widths(chip, kv_dtype, chunk):
+    """The benchmark's serving cell (Cerebras-GPT-1.3B: 16 slots + 2
+    prefix rows, (16, 128) heads, 2048 positions, 24 layers): plain
+    decode (the flat body), a 128-wide chunk-prefill window over the
+    whole extent and the int8 slab (both head-major), bf16 queries over
+    a float32 slab (flat: 16 heads are a whole tile of either) and the
+    widest chunk the flat body takes (256 query rows, a 64-wide block).
+    A block size the v5e lowering refuses, or more VMEM than a kernel
+    may hold, fails here."""
+    _compile_paged_attention(chip, CELL, kv_dtype, chunk)
+
+
+@pytest.mark.parametrize("widths", [SERVE, CELL],
+                         ids=["gpt2-small", "cgpt13b"])
+@pytest.mark.parametrize("kv_bytes,chunk", [
+    (2, 1), (2, 5), (2, 16), (2, 128), (1, 1), (4, 1)],
+    ids=["bf16-C1", "bf16-C5", "bf16-C16", "bf16-C128", "int8-C1", "f32-C1"])
+def test_paged_blocks_divide_max_len_and_honour_int8_rule(widths, kv_bytes,
+                                                          chunk):
+    """`_paged_blocks` at the serving widths: a block that divides the
+    slab's positions, is no wider than a lane width (a lane pays for
+    whole blocks), and whose int8 scale block (L, bt) has a legal lane
+    dim: a multiple of 128 or all of `max_len`."""
+    from incubator_mxnet_tpu.ops import pallas_kernels as pk
+    t = widths["max_len"]
+    bt = pk._paged_blocks(t, chunk, widths["heads"], widths["head_dim"], 2,
+                          kv_bytes, widths["layers"] if kv_bytes == 1 else 0)
+    assert 0 < bt <= 128 and t % bt == 0
+    if kv_bytes == 1:
+        assert bt % 128 == 0 or bt == t
 
 
 @pytest.mark.parametrize("shape", [(48, 1024, 64), (16, 4096, 128)],

@@ -274,43 +274,117 @@ def test_spec_acceptance_variance_never_retraces(decoder, spec_engine):
 # ---------------------------------------------------------------------------
 # paged-attention kernel: interpret-mode exactness, routing counters
 # ---------------------------------------------------------------------------
-def test_paged_attention_kernel_matches_ref_interpret():
-    """Pallas kernel (interpret mode) vs the masked-einsum reference over
-    a multi-block slab (T=384 -> three 128-wide blocks, the narrowest an
-    int8 slab's scale blocks may be on a TPU): float32 and int8+scales,
-    chunk widths 1 (plain decode) and 3 (speculative verify)."""
-    S, H, D, T, L = 4, 4, 8, 384, 2
+def _paged_oracle(q, k, v, lengths):
+    """float64 numpy evaluation of the masked read, lane by lane."""
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    T, D = k.shape[1], q.shape[-1]
+    scores = np.einsum("schd,sthd->shct", q, k) / np.sqrt(D)
+    reach = lengths[:, None, None] + np.arange(q.shape[1])[None, :, None]
+    scores = np.where((np.arange(T)[None, None, :] <= reach)[:, None],
+                      scores, -np.inf)
+    p = np.exp(scores - scores.max(-1, keepdims=True))
+    return np.einsum("shct,sthd->schd", p / p.sum(-1, keepdims=True), v)
+
+
+# bf16 slabs: the largest distance from the float64 oracle that the kernel
+# this one replaced (grid (S, T/bt), float32 casts, `pl.when`-skipped dead
+# blocks) read on the same inputs, by (heads, chunk); the kernel may not be
+# further away. Nearly all of it is the output's own rounding to bf16.
+_PAGED_BF16_BOUND = {
+    ((4, 8), 1): 0.000748, ((4, 8), 3): 0.003818,
+    ((2, 128), 1): 0.000975, ((2, 128), 3): 0.006951,
+    ((16, 32), 1): 0.001561, ((16, 32), 3): 0.007553,
+}
+
+
+@pytest.mark.parametrize("heads", [(4, 8), (2, 128), (16, 32)],
+                         ids=["h4x8", "h2x128-lane-width", "h16x32-flat"])
+@pytest.mark.parametrize("kv", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("C", [1, 3], ids=["C1", "C3-verify"])
+def test_paged_attention_kernel_matches_ref_interpret(C, kv, heads):
+    """Pallas kernel (interpret mode) vs the references over a
+    multi-block slab (T=384 -> three 128-wide blocks, the narrowest an
+    int8 slab's scale blocks may be on a TPU): chunk widths 1 (plain
+    decode) and 3 (speculative verify); float32, bf16 and int8+scales;
+    heads that take the head-major body ((4, 8), (2, 128)) and the flat
+    one ((16, 32): whole sublane tiles); lanes at cache lengths 0,
+    bt - 1, bt, bt + 1 and T - C, the last beside an inactive lane."""
+    H, D = heads
+    T, L, bt = 384, 2, 128
+    lengths = np.asarray([0, bt - 1, bt, bt + 1, T - C, 0], np.int32)
+    S = len(lengths)
     rng = np.random.RandomState(0)
-    k_slab = jnp.asarray(rng.randn(S + 1, L, T, H, D).astype(np.float32))
-    v_slab = jnp.asarray(rng.randn(S + 1, L, T, H, D).astype(np.float32))
-    k_codes = jnp.asarray(rng.randint(-127, 128, (S + 1, L, T, H, D),
-                                      dtype=np.int64).astype(np.int8))
-    v_codes = jnp.asarray(rng.randint(-127, 128, (S + 1, L, T, H, D),
-                                      dtype=np.int64).astype(np.int8))
-    k_scale = jnp.asarray(
-        (rng.rand(S + 1, L, T) * 0.1 + 0.01).astype(np.float32))
-    v_scale = jnp.asarray(
-        (rng.rand(S + 1, L, T) * 0.1 + 0.01).astype(np.float32))
-    for C in (1, 3):
-        q = jnp.asarray(rng.randn(S, C, H, D).astype(np.float32))
-        lengths = jnp.asarray([1, 130, T - C, 127], dtype=jnp.int32)
-        layer = 1           # non-zero: the slab's layer stride is live
-        out = PK.paged_attention_fwd(q, k_slab, v_slab, lengths,
-                                     layer, interpret=True)
-        assert out is not None
-        np.testing.assert_allclose(
-            out, F.paged_attention_ref(q, k_slab, v_slab, lengths,
-                                       layer),
-            rtol=2e-5, atol=2e-5)
-        out8 = PK.paged_attention_fwd(q, k_codes, v_codes, lengths,
-                                      layer, k_scale=k_scale,
-                                      v_scale=v_scale, interpret=True)
-        assert out8 is not None
-        np.testing.assert_allclose(
-            out8, F.paged_attention_ref(q, k_codes, v_codes, lengths,
-                                        layer, k_scale=k_scale,
-                                        v_scale=v_scale),
-            rtol=2e-5, atol=2e-5)
+    layer = 1               # non-zero: the slab's layer stride is live
+    slab = (S + 1, L, T, H, D)
+    scales = {}
+    if kv == "int8":
+        k_slab, v_slab = (jnp.asarray(rng.randint(
+            -127, 128, slab, dtype=np.int64).astype(np.int8))
+            for _ in range(2))
+        scales = {n: jnp.asarray(
+            (rng.rand(S + 1, L, T) * 0.1 + 0.01).astype(np.float32))
+            for n in ("k_scale", "v_scale")}
+        dtype = jnp.float32
+    else:
+        dtype = jnp.dtype(kv)
+        k_slab, v_slab = (jnp.asarray(rng.randn(*slab), dtype)
+                          for _ in range(2))
+    q = jnp.asarray(rng.randn(S, C, H, D), dtype)
+    assert PK._paged_blocks(T, C, H, D, q.dtype.itemsize,
+                            k_slab.dtype.itemsize,
+                            L if kv == "int8" else 0) == bt
+    out = PK.paged_attention_fwd(q, k_slab, v_slab, jnp.asarray(lengths),
+                                 layer, interpret=True, **scales)
+    assert out is not None and out.dtype == q.dtype
+    body = PK.paged_body(q, k_slab, scales.get("k_scale"))
+    assert body == ("flat" if heads == (16, 32) and kv != "int8"
+                    else "head_major")
+    if kv == "bfloat16":
+        want = _paged_oracle(q, k_slab[:S, layer], v_slab[:S, layer],
+                             lengths)
+        err = np.abs(np.asarray(out, np.float64) - want).max()
+        assert err <= _PAGED_BF16_BOUND[heads, C], err
+    else:
+        ref = F.paged_attention_ref(q, k_slab, v_slab,
+                                    jnp.asarray(lengths), layer, **scales)
+        # 2e-5 everywhere but int8 at 128-wide heads: 128-term float32 sums
+        # of dequantized values up to 12 in a different order; the kernel
+        # this one replaced needs 4.5e-5 on the same inputs, to the digit
+        atol = 6e-5 if (kv, heads) == ("int8", (2, 128)) else 2e-5
+        np.testing.assert_allclose(out, ref, rtol=2e-5, atol=atol)
+
+
+@pytest.mark.parametrize("q_dtype,kv_dtype,H,body", [
+    ("bfloat16", "float32", 8, "head_major"),
+    ("bfloat16", "float32", 16, "flat"),
+    ("float32", "bfloat16", 8, "head_major"),
+    ("float32", "float32", 8, "flat"),
+    ("bfloat16", "bfloat16", 48, "flat"),
+    ("bfloat16", "bfloat16", 96, "head_major")],
+    ids=["bf16q-f32kv-h8", "bf16q-f32kv-h16", "f32q-bf16kv-h8", "f32-h8",
+         "bf16-h48-144-rows", "bf16-h96-288-rows"])
+def test_paged_attention_body_follows_the_narrower_dtype(q_dtype, kv_dtype,
+                                                         H, body):
+    """Queries and slab of different widths: the flat body views both the
+    (C, H, D) queries and the (bt, H, D) tile as matrices, so H has to be
+    whole sublane tiles of the NARROWER dtype (16 rows of bf16, 8 of
+    float32), and C*H within the 256 rows up to which it was measured to
+    win; either body reads the same answer."""
+    S, C, D, T, L, layer = 3, 3, 32, 256, 2, 1
+    rng = np.random.RandomState(2)
+    q = jnp.asarray(rng.randn(S, C, H, D), jnp.dtype(q_dtype))
+    k_slab, v_slab = (jnp.asarray(rng.randn(S + 1, L, T, H, D),
+                                  jnp.dtype(kv_dtype)) for _ in range(2))
+    lengths = np.asarray([0, 127, T - C], np.int32)
+    assert PK.paged_body(q, k_slab) == body
+    out = PK.paged_attention_fwd(q, k_slab, v_slab, jnp.asarray(lengths),
+                                 layer, interpret=True)
+    assert out is not None and out.dtype == q.dtype
+    want = _paged_oracle(q, k_slab[:S, layer], v_slab[:S, layer], lengths)
+    # a bf16 output carries its own rounding: half an ulp of values to 4
+    tol = 2e-5 if q_dtype == "float32" else 2.0 ** -7
+    np.testing.assert_allclose(np.asarray(out, np.float64), want,
+                               rtol=tol, atol=tol)
 
 
 def test_paged_attention_routing_and_counters():
@@ -337,6 +411,8 @@ def test_paged_attention_routing_and_counters():
     st = F.fused_stats(reset=True)
     assert st["paged_attention_calls"] == 1
     assert st["pallas_calls"] == 1 and st["fallback_calls"] == 0
+    # four heads are no whole sublane tile: the head-major body
+    assert (st["paged_head_major_traces"], st["paged_flat_traces"]) == (1, 0)
     np.testing.assert_allclose(k_out, ref_out, rtol=2e-5, atol=2e-5)
 
 

@@ -309,7 +309,9 @@ def test_fused_stats_counters_move():
     assert set(profiler.fused_stats()) == {"pallas_calls",
                                            "fallback_calls",
                                            "device_augment_calls",
-                                           "paged_attention_calls"}
+                                           "paged_attention_calls",
+                                           "paged_flat_traces",
+                                           "paged_head_major_traces"}
 
 
 def test_set_interpret_toggle_not_served_stale_programs():
