@@ -80,6 +80,11 @@ FUSED_STATS = _stats_group("fused", {
     # shapes select (`pallas_kernels.paged_body`)
     "paged_flat_traces": 0,
     "paged_head_major_traces": 0,
+    # reads of a named cache LEAF (`layer=None`), kernel or composition,
+    # by kind: a leaf that grows with the request and may be another
+    # layer's (`shared`), a ring under a window (`window`)
+    "paged_shared_traces": 0,
+    "paged_window_traces": 0,
 })
 _STATS = FUSED_STATS
 
@@ -256,7 +261,8 @@ def bn_inference_ref(x, gamma, beta, mean, var, eps=1e-5, axis=-1,
 
 
 def paged_attention_ref(q, k_slab, v_slab, lengths, layer,
-                        k_scale=None, v_scale=None):
+                        k_scale=None, v_scale=None, rows=None, window=None,
+                        scale=None, out_dtype=None):
     """Unfused composition of paged decode attention over the serve
     KV-pool slab — the fallback and parity oracle. Reads the WHOLE
     (S, T) page per lane and masks to `[0, lengths + j]` per chunk
@@ -266,10 +272,23 @@ def paged_attention_ref(q, k_slab, v_slab, lengths, layer,
     `q`: (S, C, H, D) — C chunk queries per lane at positions
     `lengths[s] + j`. `k_slab`/`v_slab`: (rows, layers, T, H, D) with
     rows > S (lane s reads row s). `k_scale`/`v_scale`: optional
-    per-position f32 dequant scales (rows, layers, T) for int8 slabs."""
+    per-position f32 dequant scales (rows, layers, T) for int8 slabs.
+
+    `layer=None` reads a cache LEAF instead: `k_slab`/`v_slab` are
+    (rows, T, Hkv * D), one named array that any layer may read, with
+    Hkv KV heads of q's width D; query head g reads KV head
+    `g // (H // Hkv)`. Lane s reads row `rows[s]` (default s).
+    `window=w` makes the leaf a RING of T positions (position p at
+    `p % T`, positions `[0, lengths]` written so far): the one query of
+    a lane (C == 1) sees positions `(lengths - w, lengths]`.
+    `scale` replaces `1 / sqrt(D)`, `out_dtype` q's dtype."""
     import jax
     jnp = _jnp()
     s_lanes, c, _h, d = q.shape
+    scale = (1.0 / float(d) ** 0.5) if scale is None else float(scale)
+    if layer is None:
+        return _leaf_attention_ref(q, k_slab, v_slab, lengths, rows,
+                                   window, scale).astype(out_dtype or q.dtype)
     t = k_slab.shape[2]
     kk = k_slab[:s_lanes, layer]
     vv = v_slab[:s_lanes, layer]
@@ -279,7 +298,7 @@ def paged_attention_ref(q, k_slab, v_slab, lengths, layer,
     if v_scale is not None:
         vv = vv.astype(jnp.float32) * v_scale[:s_lanes, layer][..., None,
                                                                None]
-    scores = jnp.einsum("schd,sthd->shct", q, kk) * (1.0 / float(d) ** 0.5)
+    scores = jnp.einsum("schd,sthd->shct", q, kk) * scale
     pos = jnp.arange(t)
     mask = pos[None, None, :] <= (lengths[:, None, None]
                                   + jnp.arange(c)[None, :, None])
@@ -289,20 +308,74 @@ def paged_attention_ref(q, k_slab, v_slab, lengths, layer,
     return att.astype(q.dtype)
 
 
+def _leaf_attention_ref(q, k_leaf, v_leaf, lengths, rows, window, scale):
+    """`paged_attention_ref`'s leaf mode: grouped heads, rows as data,
+    the ring's window. Scores and probabilities in float32."""
+    import jax
+    jnp = _jnp()
+    s_lanes, c, h, d = q.shape
+    t = k_leaf.shape[1]
+    hkv = k_leaf.shape[2] // d
+    g = h // hkv
+    if rows is None:
+        # lane s reads row s: every row of the leaf gets a lane (the
+        # garbage row's is idle) rather than the leaf a sliced copy — on
+        # the chip the slice of a (65, 512, 1280) ring was a copy of its
+        # own, 16 a micro-step
+        pad = k_leaf.shape[0] - s_lanes
+        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0), (0, 0)))
+        lengths = jnp.pad(lengths, (0, pad))
+        kk, vv = k_leaf, v_leaf
+    else:
+        kk, vv = k_leaf[rows], v_leaf[rows]
+    n = q.shape[0]
+    kk = kk.reshape(n, t, hkv, d)
+    vv = vv.reshape(n, t, hkv, d)
+    qg = q.reshape(n, c, hkv, g, d)
+    scores = jnp.einsum("scpgd,stpd->spgct", qg, kk,
+                        preferred_element_type=jnp.float32) * scale
+    slot = jnp.arange(t)
+    if window is None:
+        mask = slot[None, None, :] <= (lengths[:, None, None]
+                                       + jnp.arange(c)[None, :, None])
+    else:
+        # slot j holds the newest written position congruent to j
+        last = lengths[:, None]                             # (S, 1)
+        held = slot[None, :] + t * ((last - slot[None, :]) // t)
+        mask = ((held >= 0) & (held > last - window))[:, None, :]
+    scores = jnp.where(mask[:, None, None], scores, -1e30)
+    att = jnp.einsum("spgct,stpd->scpgd", jax.nn.softmax(scores, axis=-1),
+                     vv.astype(jnp.float32))
+    return att.reshape(n, c, h, d)[:s_lanes]
+
+
 def paged_attention(q, k_slab, v_slab, lengths, layer,
-                    k_scale=None, v_scale=None, interpret=None):
+                    k_scale=None, v_scale=None, interpret=None, *,
+                    rows=None, window=None, scale=None, out_dtype=None):
     """Paged decode attention over the slotted KV slab — the serve
     engine's per-layer attention read, in place (no per-layer copy of
     the cache). Routes to the Pallas block-sparse kernel on TPU (or in
     interpret mode for CPU CI) and to the identical masked-einsum
     composition otherwise; the choice is static per trace. Honors the
-    MXNET_USE_FUSION kill switch (falls back, never fails)."""
+    MXNET_USE_FUSION kill switch (falls back, never fails).
+
+    `layer=None` reads a named cache leaf (rows, T, Hkv * D) with
+    grouped heads, `rows` as data and an optional ring `window`
+    (`paged_attention_ref` has the contract). The leaf that grows with
+    the request runs in the kernel, on the live-block grid; the ring
+    read (at most `window` positions a lane) is the composition."""
     interpret = _resolve_interpret(interpret)
     _STATS["paged_attention_calls"] += 1
+    if layer is None:
+        return _paged_leaf(q, k_slab, v_slab, lengths, rows, window, scale,
+                           out_dtype or q.dtype, interpret)
+    if rows is not None or window is not None or out_dtype is not None:
+        raise ValueError("rows=, window= and out_dtype= read a cache leaf "
+                         "(layer=None)")
     if (_on_tpu() or interpret) and _env_use_fusion():
         out = _pk.paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
                                       k_scale=k_scale, v_scale=v_scale,
-                                      interpret=interpret)
+                                      interpret=interpret, scale=scale)
         if out is not None:
             _STATS["pallas_calls"] += 1
             body = _pk.paged_body(q, k_slab, k_scale)
@@ -312,7 +385,35 @@ def paged_attention(q, k_slab, v_slab, lengths, layer,
                f"q {tuple(q.shape)} over slab {tuple(k_slab.shape)} "
                f"{k_slab.dtype} does not tile, or no TPU / fusion off")
     return paged_attention_ref(q, k_slab, v_slab, lengths, layer,
-                               k_scale=k_scale, v_scale=v_scale)
+                               k_scale=k_scale, v_scale=v_scale, scale=scale)
+
+
+def _paged_leaf(q, k_leaf, v_leaf, lengths, rows, window, scale, out_dtype,
+                interpret):
+    if q.shape[2] % (k_leaf.shape[2] // q.shape[3]):
+        raise ValueError(
+            f"{k_leaf.shape[2] // q.shape[3]} KV heads do not divide "
+            f"{q.shape[2]} query heads")
+    if window is not None:
+        if q.shape[1] != 1:
+            raise ValueError("a ring is read by one query a lane")
+        _STATS["paged_window_traces"] += 1
+        return paged_attention_ref(q, k_leaf, v_leaf, lengths, None,
+                                   rows=rows, window=window, scale=scale,
+                                   out_dtype=out_dtype)
+    _STATS["paged_shared_traces"] += 1
+    if (_on_tpu() or interpret) and _env_use_fusion():
+        out = _pk.paged_attention_fwd(q, k_leaf, v_leaf, lengths, None,
+                                      interpret=interpret, scale=scale,
+                                      rows=rows, out_dtype=out_dtype)
+        if out is not None:
+            _STATS["pallas_calls"] += 1
+            return out
+    _fell_back("paged_attention",
+               f"q {tuple(q.shape)} over leaf {tuple(k_leaf.shape)} "
+               f"{k_leaf.dtype} does not tile, or no TPU / fusion off")
+    return paged_attention_ref(q, k_leaf, v_leaf, lengths, None, rows=rows,
+                               scale=scale, out_dtype=out_dtype)
 
 
 def avg_pool2d_ref(x, pool_size, layout="NHWC"):

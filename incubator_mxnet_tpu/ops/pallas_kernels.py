@@ -465,24 +465,29 @@ def _paged_blocks(t, c, h, d, q_bytes, kv_bytes, n_layers=0):
     return bt
 
 
-def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
-                        k_scale=None, v_scale=None, interpret=False):
-    """Pallas paged decode attention. `q`: (S, C, H, D) — C queries per
-    lane at positions `lengths[s] + j` (C == 1 plain decode, C == k+1
-    speculative verify, C == the window in chunk prefill).
-    `k_slab`/`v_slab`: the whole KV pool slab (rows, layers, T, H, D);
-    lane s reads row s of layer `layer`, positions clamped to
-    `[0, lengths[s] + j]`. `k_scale`/`v_scale`: per-position f32 dequant
-    scales (rows, layers, T) for int8 slabs.
+def _live_steps(lengths, c, bt, n_blocks):
+    """The work list of a live-block grid: lane s owns steps
+    [ends[s] - live[s], ends[s]) -> (ends, lane of each step, block of
+    each step). Plain XLA ops outside the kernel; the calls of a
+    micro-step's layers pass the same `lengths`, so the compiler keeps
+    one copy of them."""
+    import jax.numpy as jnp
+    s_lanes = lengths.shape[0]
+    live = _live_blocks(lengths, c, bt, n_blocks)
+    ends = jnp.cumsum(live)
+    steps = jnp.arange(s_lanes * n_blocks, dtype=jnp.int32)
+    lane_of = jnp.minimum(
+        jnp.sum(steps[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
+        s_lanes - 1)
+    blk_of = steps - (ends - live)[lane_of]
+    return ends, lane_of, blk_of
 
-    The grid has one step per LIVE (lane, token-block) pair and no other:
-    its length is a run-time value (the sum of every lane's
-    `ceil((len + C) / bt)`), and the two lists that name each step's lane
-    and block ride as scalar prefetch beside `lengths`, for the index
-    maps and the kernel. The call's time follows the live KV bytes.
 
-    Returns (S, C, H, D) in q.dtype, or None when the shape does not
-    tile (the caller falls back and counts it)."""
+def _paged_slab_call(q, k_slab, v_slab, lengths, layer, k_scale, v_scale,
+                     scale):
+    """The pieces of `paged_attention_fwd`'s one `pallas_call` over the
+    K/V slab pair (kernel, grid spec, out shape, arguments), or None when
+    the shape does not tile."""
     import jax
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -501,18 +506,8 @@ def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
     if bt == 0:
         return None
     n_blocks = t // bt
-    scale = 1.0 / float(d) ** 0.5
-
-    # the work list: lane s owns steps [ends[s] - live[s], ends[s]). Plain
-    # XLA ops outside the kernel; the calls of a micro-step's layers pass
-    # the same `lengths`, so the compiler keeps one copy of them
-    live = _live_blocks(lengths, c, bt, n_blocks)
-    ends = jnp.cumsum(live)
-    steps = jnp.arange(s_lanes * n_blocks, dtype=jnp.int32)
-    lane_of = jnp.minimum(
-        jnp.sum(steps[:, None] >= ends[None, :], axis=1, dtype=jnp.int32),
-        s_lanes - 1)
-    blk_of = steps - (ends - live)[lane_of]
+    scale = (1.0 / float(d) ** 0.5) if scale is None else float(scale)
+    ends, lane_of, blk_of = _live_steps(lengths, c, bt, n_blocks)
 
     def qidx(i, lens_ref, lane_ref, blk_ref):
         return (lane_ref[i], 0, 0, 0)
@@ -549,13 +544,195 @@ def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
     kernel = functools.partial(
         _paged_attn_kernel, bt=bt, n_blocks=n_blocks,
         chunk=c, scale=scale, layer=layer, quantized=quantized, body=body)
+    return (kernel, grid_spec,
+            jax.ShapeDtypeStruct((s_lanes, c, h, d), q.dtype), tuple(args))
+
+
+def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
+                        k_scale=None, v_scale=None, interpret=False,
+                        scale=None, rows=None, out_dtype=None):
+    """Pallas paged decode attention. `q`: (S, C, H, D) — C queries per
+    lane at positions `lengths[s] + j` (C == 1 plain decode, C == k+1
+    speculative verify, C == the window in chunk prefill).
+    `k_slab`/`v_slab`: the whole KV pool slab (rows, layers, T, H, D);
+    lane s reads row s of layer `layer`, positions clamped to
+    `[0, lengths[s] + j]`. `k_scale`/`v_scale`: per-position f32 dequant
+    scales (rows, layers, T) for int8 slabs.
+
+    The grid has one step per LIVE (lane, token-block) pair and no other:
+    its length is a run-time value (the sum of every lane's
+    `ceil((len + C) / bt)`), and the two lists that name each step's lane
+    and block ride as scalar prefetch beside `lengths`, for the index
+    maps and the kernel. The call's time follows the live KV bytes.
+
+    `layer=None` reads a cache LEAF (rows, T, Hkv * D) instead, any
+    layer's, with grouped heads, `rows` as data and `out_dtype`
+    (`_paged_leaf_call` has the contract): the same grid, the same name.
+
+    Returns (S, C, H, D) in q.dtype, or None when the shape does not
+    tile (the caller falls back and counts it)."""
+    import jax.experimental.pallas as pl
+
+    if layer is None:
+        call = _paged_leaf_call(q, k_slab, v_slab, lengths, rows, scale,
+                                out_dtype, interpret)
+    else:
+        call = _paged_slab_call(q, k_slab, v_slab, lengths, layer, k_scale,
+                                v_scale, scale)
+    if call is None:
+        return None
+    kernel, grid_spec, out_shape, args = call
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_lanes, c, h, d), q.dtype),
+        out_shape=out_shape,
         interpret=interpret,
         name="paged_attention_fwd",
-    )(*args)
+    )(*args).reshape(q.shape)
+
+
+def _paged_leaf_kernel(lens_ref, rows_ref, lane_ref, blk_ref, q_ref, k_ref,
+                       v_ref, o_ref, m_ref, l_ref, acc_ref, *, bt, n_blocks,
+                       chunk, heads, kv_heads, scale):
+    """One LIVE (lane, token-block) pair of a cache LEAF's read: the
+    block is the (bt, Hkv * D) matrix a `full` leaf stores per position
+    run, every KV head side by side on the lane axis.
+
+    Grouped heads cost no mask and no copy: query row (j, g) arrives
+    zero outside the D columns of its KV head `g // (H // Hkv)`
+    (`paged_leaf_attention_fwd` pads it), so ONE (C*H, Hkv*D) x
+    (bt, Hkv*D)^T product gives each row the scores of its own head. The
+    probabilities meet the whole V block (three bf16 pieces,
+    `_split_bf16`) and the last block keeps, of each row's Hkv*D sums,
+    the D columns of its own head. Live-block grid, online softmax and
+    float32 sums as in `_paged_attn_kernel`."""
+    import jax
+    import jax.numpy as jnp
+    import jax.experimental.pallas as pl
+
+    i = pl.program_id(0)
+    blk = blk_ref[i]
+    lane_len = lens_ref[lane_ref[i]]
+    rows_n, width = q_ref.shape[1:]
+    d = width // kv_heads
+
+    @pl.when(blk == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    mm_dtype = jnp.promote_types(q_ref.dtype, k_ref.dtype)
+    sco = jax.lax.dot_general(
+        q_ref[0].astype(mm_dtype), k_ref[0].astype(mm_dtype),
+        (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale       # (rows, bt)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows_n, 1), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+    sco = jnp.where(blk * bt + col <= lane_len + row // heads, sco, -1e30)
+    m_prev = m_ref[...]                                   # (rows, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(sco, axis=-1, keepdims=True))
+    p = jnp.exp(sco - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    v2 = v_ref[0]
+    if v2.dtype == jnp.bfloat16:
+        pv = jnp.dot(_split_bf16(p), v2, preferred_element_type=jnp.float32)
+        pv = pv[:rows_n] + pv[rows_n:2 * rows_n] + pv[2 * rows_n:]
+    else:
+        pv = jnp.dot(p, v2.astype(jnp.float32),
+                     preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * alpha + pv
+    m_ref[...] = m_new
+
+    @pl.when(blk == _live_blocks(lane_len, chunk, bt, n_blocks) - 1)
+    def _finalize():
+        full = acc_ref[...] / l_ref[...]                  # (rows, Hkv * D)
+        own = (row % heads) // (heads // kv_heads)        # (rows, 1)
+        out = jnp.zeros((rows_n, d), jnp.float32)
+        for kv in range(kv_heads):
+            out = out + jnp.where(own == kv, full[:, kv * d:(kv + 1) * d],
+                                  0.0)
+        o_ref[0] = out.astype(o_ref.dtype)
+
+
+def _paged_leaf_call(q, k_leaf, v_leaf, lengths, rows, scale, out_dtype,
+                     interpret):
+    """`paged_attention_fwd` over a named cache LEAF. `q`: (S, C, H, D)
+    — C queries per lane at positions `lengths[s] + j`. `k_leaf`/
+    `v_leaf`: (rows, T, Hkv * D), Hkv dividing H; lane s reads row
+    `rows[s]` (default s), positions clamped to `[0, lengths[s] + j]`,
+    query head g over KV head `g // (H // Hkv)`. The leaf may be any
+    layer's: nothing here names one. The grid is `paged_attention_fwd`'s:
+    one step per live (lane, token-block) pair.
+
+    Returns the pieces of the one `pallas_call` (kernel, grid spec, out
+    shape (S, C * H, D) in `out_dtype`, default q.dtype, arguments), or
+    None when the shape does not tile."""
+    import jax
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    import jax.numpy as jnp
+
+    s_lanes, c, h, d = q.shape
+    out_dtype = jnp.dtype(out_dtype or q.dtype)
+    t, width = k_leaf.shape[1:]
+    kv_heads = width // d
+    if kv_heads * d != width or h % kv_heads:
+        return None
+    if width % _LANES and not interpret:      # a block's lane axis, whole
+        return None
+    q_rows = c * h
+
+    def footprint(bt):
+        kv = _tile_bytes((bt, width), k_leaf.dtype.itemsize)
+        return (2 * (_tile_bytes((q_rows, width), q.dtype.itemsize)
+                     + _tile_bytes((q_rows, d), out_dtype.itemsize) + 2 * kv)
+                + _tile_bytes((q_rows, width), 4)
+                + 2 * _tile_bytes((q_rows, 1), 4)
+                # scores and probabilities, the three stacked pieces, the
+                # (3 * rows, width) product and its sum
+                + 3 * _tile_bytes((q_rows, bt), 4)
+                + _tile_bytes((3 * q_rows, bt), 2)
+                + 4 * _tile_bytes((q_rows, width), 4))
+
+    bt = _pow2_block(_LANES if t % _LANES == 0 else t & -t, footprint)
+    if bt == 0:
+        return None
+    n_blocks = t // bt
+    scale = (1.0 / float(d) ** 0.5) if scale is None else float(scale)
+    if rows is None:
+        rows = jnp.arange(s_lanes, dtype=jnp.int32)
+    # row (j, g) holds its D values in the columns of its KV head
+    own = jnp.arange(h) // (h // kv_heads)
+    onehot = (own[:, None] == jnp.arange(kv_heads)[None, :]).astype(q.dtype)
+    q_wide = (q[:, :, :, None, :] * onehot[None, None, :, :, None]).reshape(
+        s_lanes, q_rows, width)
+    ends, lane_of, blk_of = _live_steps(lengths, c, bt, n_blocks)
+
+    def qidx(i, lens_ref, rows_ref, lane_ref, blk_ref):
+        return (lane_ref[i], 0, 0)
+
+    def kidx(i, lens_ref, rows_ref, lane_ref, blk_ref):
+        return (rows_ref[lane_ref[i]], blk_ref[i], 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(ends[-1],),
+        in_specs=[pl.BlockSpec((1, q_rows, width), qidx),
+                  pl.BlockSpec((1, bt, width), kidx),
+                  pl.BlockSpec((1, bt, width), kidx)],
+        out_specs=pl.BlockSpec((1, q_rows, d), qidx),
+        scratch_shapes=[pltpu.VMEM((q_rows, 1), jnp.float32),
+                        pltpu.VMEM((q_rows, 1), jnp.float32),
+                        pltpu.VMEM((q_rows, width), jnp.float32)],
+    )
+    kernel = functools.partial(
+        _paged_leaf_kernel, bt=bt, n_blocks=n_blocks, chunk=c, heads=h,
+        kv_heads=kv_heads, scale=scale)
+    return (kernel, grid_spec,
+            jax.ShapeDtypeStruct((s_lanes, q_rows, d), out_dtype),
+            (lengths, rows, lane_of, blk_of, q_wide, k_leaf, v_leaf))
 
 
 def avg_pool2d_bwd(dy, h, w, ph, pw, interpret=False):

@@ -386,50 +386,54 @@ def maybe_wrap_donated(fn, donate_argnums, name):
 # slot mode: the canary row
 # ---------------------------------------------------------------------------
 class SlotCanary:
-    """One claimed-and-poisoned KV pool slot, checked every decode wave.
+    """One claimed-and-poisoned pool slot, checked every decode wave.
 
     The decode program runs over ALL pool rows as lanes; the canary slot
     is never handed to a request, so its lane is permanently inactive
-    and must scatter into the garbage row — if the sentinel row ever
-    changes, a program wrote through the slot masks. `rearm()` after
-    `pool.reallocate()` (the slab was replaced wholesale)."""
-
-    #: probe positions along max_len — row start, middle, and tail catch
-    #: both scatter-offset and full-row overwrites
-    _PROBES = 3
+    and must scatter into the garbage row and keep whatever state it
+    holds — if the sentinel row of ANY cache leaf ever changes (K/V
+    slabs, rings, recurrent state alike), a program wrote through the
+    slot masks. `rearm()` after `pool.reallocate()` (the leaves were
+    replaced wholesale)."""
 
     def __init__(self, pool, value=1e9):
         import jax
-        import jax.numpy as jnp
         self.pool = pool
         self.value = float(value)
         self.slot = pool.claim()
         self.waves = 0
         self._arm()
-        L = pool.max_len
-        idx = jnp.asarray(sorted({0, L // 2, L - 1}))
-        expect = 1 if pool.quantized else self.value
         slot = self.slot
+        self._expect = pool._sentinels(self.value)
+        # probe elements of the canary row of every leaf — its first, its
+        # middle and its last catch both scatter-offset and full-row
+        # overwrites
+        self._probes = {
+            leaf.name: sorted({tuple(0 for _ in leaf.shape),
+                               tuple(d // 2 for d in leaf.shape),
+                               tuple(d - 1 for d in leaf.shape)})
+            for leaf in pool.spec}
 
-        # ONE compiled fused probe per wave (both slabs -> a scalar):
-        # a naive per-slab fancy-index gather + np.asarray costs ~3ms
+        # ONE compiled fused probe per wave (every leaf -> a scalar):
+        # a naive per-leaf fancy-index gather + np.asarray costs ~3ms
         # on the quick-bench host, ~100x this
-        def _ok(k, v):
-            return ((k[slot, 0, idx] == expect).all()
-                    & (v[slot, 0, idx] == expect).all())
+        def _ok(leaves):
+            ok = True
+            for name, at in self._probes.items():
+                for idx in at:
+                    ok &= leaves[name][(slot,) + idx] == self._expect[name]
+            return ok
 
         self._probe_ok = jax.jit(_ok)
-        self._probe_idx = idx
-        self._expect = expect
         self._pending = None
 
     def _arm(self):
         self.pool.poison_slot(self.slot, self.value)
 
     def rearm(self):
-        """Re-poison after the slab was replaced (pool.reallocate())."""
+        """Re-poison after the leaves were replaced (pool.reallocate())."""
         self._arm()
-        self._pending = None        # drop a probe of the dead slab
+        self._pending = None        # drop a probe of the dead buffers
 
     def check(self, where="decode wave"):
         """Probe the canary row; raise SlotCanaryError when it lost its
@@ -441,22 +445,22 @@ class SlotCanary:
         import numpy as _np
         self.waves += 1
         pending, self._pending = (self._pending,
-                                  self._probe_ok(self.pool.k,
-                                                 self.pool.v))
+                                  self._probe_ok(dict(self.pool.leaves)))
         if pending is None or bool(pending):
             return
         self._pending = None
-        # slow path (violation only): name the slab and what we found
-        for nm, slab in (("k", self.pool.k), ("v", self.pool.v)):
-            got = _np.asarray(slab[self.slot, 0, self._probe_idx])
-            if not _np.all(got == _np.asarray(self._expect,
+        # slow path (violation only): name the leaf and what we found
+        for nm, at in self._probes.items():
+            got = _np.asarray([self.pool.leaves[nm][(self.slot,) + idx]
+                               for idx in at])
+            if not _np.all(got == _np.asarray(self._expect[nm],
                                               dtype=got.dtype)):
                 _flightrec("sanitize.slot", nm, slot=self.slot,
                            where=where, waves=self.waves)
                 raise SlotCanaryError(
                     f"canary KV slot {self.slot} ({nm} slab) was "
                     f"overwritten at {where} (wave {self.waves}): "
-                    f"expected sentinel {self._expect}, found "
+                    f"expected sentinel {self._expect[nm]}, found "
                     f"{got.ravel()[:4].tolist()} — a compiled program "
                     f"wrote outside its slot masks")
         raise SlotCanaryError(

@@ -102,8 +102,10 @@ from ..telemetry import (record_span, span as _span, NO_SPAN,
 from .batcher import (ServeError, QueueFullError, RequestTimeout,
                       ServerClosed, ReplicaDraining, _fail, _profiler_on)
 from .metrics import SERVE_STATS, _STATS_LOCK, percentile
-from .kv_pool import KVCachePool, SlotsFullError
+from .kv_pool import CacheKindError, KVCachePool, SlotsFullError
 from .prefix_cache import PrefixCache
+from .sampling import (sample_first as _sample_first,
+                       sample_tokens as _sample_tokens)
 
 __all__ = ["DecoderConfig", "CachedDecoder", "ContinuousEngine",
            "RequestTiming", "init_decoder_params"]
@@ -180,68 +182,6 @@ def _seed_key(seed):
     seed = int(seed) & 0xFFFFFFFFFFFFFFFF
     return _np.array([(seed >> 32) & 0xFFFFFFFF, seed & 0xFFFFFFFF],
                      dtype=_np.uint32)
-
-
-def _sample_tokens(logits, temps, top_ks, top_ps, keys, positions):
-    """Per-lane next-token choice with sampling params AS DATA: every
-    lane runs the same temperature/top-k/top-p/categorical math and a
-    `temps > 0` select keeps greedy lanes exactly argmax — one compiled
-    program serves any greedy/sampled mix. The draw key is
-    `fold_in(lane_key, position)` (position = the query token's cache
-    position), a pure function of request state, so any wave schedule
-    draws the same tokens.
-
-    The truncation and the draw happen in SORTED order, on the one array
-    the sort returns, and the drawn rank maps back through the sort's
-    own permutation. A threshold taken from the sorted values must never
-    be compared with a second evaluation of `logits / temps`: the
-    compiler may feed the sort from the logits matmul's float32
-    accumulators and re-derive the other copy from their bfloat16
-    rounding (seen on the TPU at 10 lanes), and a top logit that rounded
-    down then fails its own threshold — the whole row is masked and
-    token 0 comes out."""
-    import jax
-    import jax.numpy as jnp
-    with jax.named_scope("sampler"):
-        V = logits.shape[-1]
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-        ids = jax.lax.broadcasted_iota(jnp.int32, scaled.shape, 1)
-        neg, order = jax.lax.sort_key_val(-scaled, ids, dimension=1)
-        srt = -neg                                   # descending, ties by id
-        kth = jnp.take_along_axis(
-            srt, jnp.clip(top_ks - 1, 0, V - 1)[:, None], axis=-1)
-        keep_k = (top_ks[:, None] <= 0) | (srt >= kth)
-        probs = jax.nn.softmax(srt, axis=-1)
-        csum = jnp.cumsum(probs, axis=-1)
-        # smallest prefix whose mass reaches top_p (the kept-set INCLUDES
-        # the crossing token, hence the exclusive-cumsum comparison)
-        keepn = jnp.sum((csum - probs) < top_ps[:, None], axis=-1)
-        pth = jnp.take_along_axis(
-            srt, jnp.clip(keepn - 1, 0, V - 1)[:, None], axis=-1)
-        masked = jnp.where(keep_k & (srt >= pth), srt, -1e30)
-        kfold = jax.vmap(jax.random.fold_in)(keys, positions)
-        rank = jax.vmap(
-            lambda kk, lg: jax.random.categorical(kk, lg))(kfold, masked)
-        sampled = jnp.take_along_axis(order, rank[:, None], axis=-1)[:, 0]
-        return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
-
-
-_SAMPLE_JIT = None
-
-
-def _sample_first(logits, temps, top_ks, top_ps, keys, positions):
-    """First-token draw from prefill logits through ONE process-wide
-    jitted sampler. The sampling math compiles once per (lanes, vocab)
-    shape for every model and engine in the process, instead of being
-    re-traced into each model's prefill program (the decode program
-    keeps its own in-scan copy, where it must live). Identical math
-    either way, so engine == reference still holds bit-for-bit."""
-    global _SAMPLE_JIT
-    if _SAMPLE_JIT is None:
-        import jax
-        _SAMPLE_JIT = jax.jit(_sample_tokens)
-    return _SAMPLE_JIT(logits, temps, top_ks, top_ps, keys, positions)
 
 
 def _kv_split(cache):
@@ -736,11 +676,24 @@ class CachedDecoder:
             keys = jnp.zeros((n, 2), dtype=jnp.uint32)
         return temps, top_ks, top_ps, keys
 
+    # lane s of the chunk program is pool row s (see `_make_chunk_prefill`)
+    chunk_rows_as_data = False
+
     def new_pool(self, max_slots=None, dtype=None):
         c = self.config
         return KVCachePool(max_slots, layers=c.layers, max_len=c.max_len,
                            heads=c.heads, head_dim=c.head_dim,
                            dtype=dtype or c.dtype)
+
+    def cache_spec(self, dtype=None):
+        """The cache leaves of one slot row: the K and V slabs, all of
+        kind `full` (what `new_pool` allocates, as the pool states it)."""
+        return KVCachePool(1, layers=self.config.layers,
+                           max_len=self.config.max_len,
+                           heads=self.config.heads,
+                           head_dim=self.config.head_dim,
+                           dtype=dtype or self.config.dtype,
+                           allocate=False).spec
 
     def prefill_program(self, window):
         """The jitted prefill program for a prompt-page width."""
@@ -1149,6 +1102,33 @@ class ContinuousEngine:
         self.max_slots = int(max_slots)
         if self.max_slots < 1:
             raise ServeError("max_slots must be >= 1")
+        if draft_tokens is None:
+            draft_tokens = _tune_resolve("serve.draft_tokens")
+        self.draft_tokens = int(
+            draft_tokens if draft_tokens is not None
+            else get_env("MXNET_SERVE_DRAFT_TOKENS", 0, typ=int))
+        if self.draft_tokens < 0:
+            raise ServeError("draft_tokens must be >= 0")
+        # a request's past is rows of K and V only where every leaf is of
+        # kind `full`: a ring forgets positions and a recurrent state has
+        # none, so what copies, rolls back or requantizes rows is refused
+        stateful = sorted({leaf.kind for leaf in model.cache_spec()}
+                          - {"full"})
+        if stateful:
+            for on, what, needs in (
+                    (self.prefix_cache_slots > 0, "prefix_cache_slots > 0",
+                     "a prefix hit copies rows of K and V, and this cache "
+                     "would need a snapshot of its state at the prefix's "
+                     "end"),
+                    (self.draft_tokens > 0, "draft_tokens > 0",
+                     "a rejected draft needs the state rolled back to the "
+                     "last accepted token"),
+                    (str(kv_dtype) == "int8", 'kv_dtype="int8"',
+                     "these leaves have no quantized form")):
+                if on:
+                    raise CacheKindError(
+                        f"{what} cannot serve a model whose cache holds "
+                        f"{' and '.join(stateful)} leaves: {needs}")
         # the pool is carved with max_slots REQUEST rows plus the
         # dedicated prefix-cache rows; self.max_slots stays the request
         # capacity every admission/queue bound sees
@@ -1170,13 +1150,6 @@ class ContinuousEngine:
                 decode_steps = get_env("MXNET_SERVE_DECODE_STEPS", 4,
                                        typ=int)
         self.decode_steps = max(1, int(decode_steps))
-        if draft_tokens is None:
-            draft_tokens = _tune_resolve("serve.draft_tokens")
-        self.draft_tokens = int(
-            draft_tokens if draft_tokens is not None
-            else get_env("MXNET_SERVE_DRAFT_TOKENS", 0, typ=int))
-        if self.draft_tokens < 0:
-            raise ServeError("draft_tokens must be >= 0")
         self._decode_prog = model.decode_program(self.decode_steps,
                                                  eos_id,
                                                  self.draft_tokens)
@@ -1214,6 +1187,9 @@ class ContinuousEngine:
                 for x in exts}
         self._copy_prog = (model.copy_program()
                            if self._cache is not None else None)
+        # the chunk program's lanes: pool rows (lane s writes row s), or
+        # prefill lanes whose rows ride as data (the model says which)
+        self._chunk_compact = bool(model.chunk_rows_as_data)
         self.prefill_budget = int(
             prefill_budget if prefill_budget is not None
             else get_env("MXNET_SERVE_PREFILL_BUDGET", 256, typ=int))
@@ -1261,6 +1237,9 @@ class ContinuousEngine:
             "active_sum", "sampled_tokens", "draft_accepted",
             "draft_rejected", "prefix_hits", "prefix_misses",
             "prefix_cached_tokens")}
+        # per cache kind: the bytes the lanes of each decode wave held
+        # live, summed over waves (beside `decode_iterations`)
+        self._cache_live = {k: 0 for k in self.pool.kinds()}
         self._auto_seed = 0                  # per-engine seed fountain
         # (ttft, tpot or None, e2e) ms of the newest retired requests, from
         # their RequestTiming fields: stats()'s one source of percentiles
@@ -1307,22 +1286,20 @@ class ContinuousEngine:
         g = self.pool.garbage_row
         P = self.prefill_lanes
         S = self.pool.max_slots
-        kb, vb = self.pool.buffers()
         lens = jnp.ones((P,), dtype=jnp.int32)
-        k, v, logits = self._prefill_prog(
-            self.model.params, kb, vb,
+        cache = self.pool.buffers()
+        *cache, logits = self._prefill_prog(
+            self.model.params, *cache,
             jnp.zeros((P, self.prefill_window), dtype=jnp.int32),
             lens, jnp.full((P,), g, dtype=jnp.int32))
+        self.pool.swap_buffers(*cache)
         # warm the shared first-token sampler at this (P, vocab) shape
         # too — it is part of the steady-state prefill wave
         _sample_first(logits, jnp.zeros((P,), dtype=jnp.float32),
                       jnp.zeros((P,), dtype=jnp.int32),
                       jnp.ones((P,), dtype=jnp.float32),
                       jnp.zeros((P, 2), dtype=jnp.uint32), lens - 1)
-        self.pool.swap_buffers(k, v)
-        kb, vb = self.pool.buffers()
-        args = [self.model.params, kb, vb,
-                jnp.zeros((S,), dtype=jnp.int32),
+        args = [jnp.zeros((S,), dtype=jnp.int32),
                 jnp.zeros((S,), dtype=jnp.int32),
                 jnp.zeros((S,), dtype=jnp.int32),
                 jnp.zeros((S,), dtype=jnp.float32),
@@ -1331,9 +1308,10 @@ class ContinuousEngine:
                 jnp.zeros((S, 2), dtype=jnp.uint32)]
         if self.draft_tokens:
             args.append(jnp.zeros((S, self.max_len), dtype=jnp.int32))
-        out = self._decode_prog(*args)
-        k, v = out[0], out[1]
-        self.pool.swap_buffers(k, v)
+        cache = self.pool.buffers()
+        n = len(cache)
+        out = self._decode_prog(self.model.params, *cache, *args)
+        self.pool.swap_buffers(*out[:n])
         n_progs = 2
         if self._chunk_progs is not None:
             # all-idle chunk wave (every lane scatters into garbage)
@@ -1341,20 +1319,22 @@ class ContinuousEngine:
             # never compiles; warm the first-token sampler at the
             # (S, vocab) shape the chunk path samples from too
             logits = None
+            C = P if self._chunk_compact else S
+            idle = [jnp.zeros((C, self.prefill_window), dtype=jnp.int32),
+                    jnp.zeros((C,), dtype=jnp.int32),
+                    jnp.zeros((C,), dtype=jnp.int32)]
+            if self._chunk_compact:
+                idle.append(jnp.full((C,), g, dtype=jnp.int32))
             for prog in self._chunk_progs.values():
-                kb, vb = self.pool.buffers()
-                k, v, logits = prog(
-                    self.model.params, kb, vb,
-                    jnp.zeros((S, self.prefill_window), dtype=jnp.int32),
-                    jnp.zeros((S,), dtype=jnp.int32),
-                    jnp.zeros((S,), dtype=jnp.int32))
-                self.pool.swap_buffers(k, v)
+                cache = self.pool.buffers()
+                *cache, logits = prog(self.model.params, *cache, *idle)
+                self.pool.swap_buffers(*cache)
                 n_progs += 1
-            _sample_first(logits, jnp.zeros((S,), dtype=jnp.float32),
-                          jnp.zeros((S,), dtype=jnp.int32),
-                          jnp.ones((S,), dtype=jnp.float32),
-                          jnp.zeros((S, 2), dtype=jnp.uint32),
-                          jnp.zeros((S,), dtype=jnp.int32))
+            _sample_first(logits, jnp.zeros((C,), dtype=jnp.float32),
+                          jnp.zeros((C,), dtype=jnp.int32),
+                          jnp.ones((C,), dtype=jnp.float32),
+                          jnp.zeros((C, 2), dtype=jnp.uint32),
+                          jnp.zeros((C,), dtype=jnp.int32))
         if self._copy_prog is not None:
             # garbage-onto-garbage row copy
             kb, vb = self.pool.buffers()
@@ -1364,8 +1344,9 @@ class ContinuousEngine:
             self.pool.swap_buffers(k, v)
             n_progs += 1
         # wait for the compiles to actually finish so warmup_s is honest
-        jax.block_until_ready(self.pool.buffers()[0])
+        jax.block_until_ready(self.pool.buffers())
         self._count("programs_compiled", n_progs)
+
 
     def __enter__(self):
         return self.start()
@@ -1558,18 +1539,13 @@ class ContinuousEngine:
         params_avals = jtu.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
             self.model.params)
-        slab = jax.ShapeDtypeStruct(self.pool.shape, self.pool.dtype)
-        if self.pool.quantized:
-            pool_aval = (slab, jax.ShapeDtypeStruct(
-                self.pool.scale_shape, "float32"))
-        else:
-            pool_aval = slab
+        cache_avals = self.pool.avals()
         P, S, W = (self.prefill_lanes, self.pool.max_slots,
                    self.prefill_window)
         prefill = self._prefill_prog.lower(
-            params_avals, pool_aval, pool_aval, aval((P, W)), aval((P,)),
+            params_avals, *cache_avals, aval((P, W)), aval((P,)),
             aval((P,)))
-        dec_avals = [params_avals, pool_aval, pool_aval, aval((S,)),
+        dec_avals = [params_avals, *cache_avals, aval((S,)),
                      aval((S,)), aval((S,)), aval((S,), "float32"),
                      aval((S,)), aval((S,), "float32"),
                      aval((S, 2), "uint32")]
@@ -1610,6 +1586,11 @@ class ContinuousEngine:
                 out[f"{nm}_p{q}_ms"] = round(v, 3) if v is not None \
                     else None
         out["pool"] = self.pool.stats()
+        with self._mlock:
+            live = dict(self._cache_live)
+        out["cache"] = {
+            kind: {"bytes": n, "live_bytes_sum": live[kind]}
+            for kind, n in self.pool.bytes_by_kind().items()}
         out["decode_steps"] = self.decode_steps
         out["draft_tokens"] = self.draft_tokens
         if c["draft_accepted"] + c["draft_rejected"] > 0:
@@ -1885,11 +1866,11 @@ class ContinuousEngine:
                           jnp.asarray(tps), jnp.asarray(keys), jlens - 1)
             with (_span("serve.prefill_batch.dispatch", cat="serve")
                   if on else NO_SPAN):
-                kb, vb = self.pool.buffers()
-                k, v, logits = self._prefill_prog(
-                    self.model.params, kb, vb, jtoks, jlens, jrows)
+                cache = self.pool.buffers()
+                *cache, logits = self._prefill_prog(
+                    self.model.params, *cache, jtoks, jlens, jrows)
                 first = _sample_first(logits, *sample)
-                self.pool.swap_buffers(k, v)
+                self.pool.swap_buffers(*cache)
             with (_span("serve.prefill_batch.readback", cat="serve")
                   if on else NO_SPAN):
                 first_host = _np.asarray(first)
@@ -1908,20 +1889,31 @@ class ContinuousEngine:
         chunkers = [r for r in pre
                     if id(r) not in coldset
                     and r.prefill_pos < int(r.prompt.size)]
+        if self._chunk_compact:
+            # lanes are prefill lanes: the oldest admissions take them,
+            # the chunkers beyond them wait a wave
+            chunkers = sorted(chunkers, key=lambda r: r.t_admit)[
+                :self.prefill_lanes]
+            lane_of = {r.slot: i for i, r in enumerate(chunkers)}
+        else:
+            lane_of = {r.slot: r.slot for r in chunkers}
         if chunkers:
             with (_span("serve.prefill_batch.pack", cat="serve") if on
                   else NO_SPAN):
-                S = self.pool.max_slots
+                S = (self.prefill_lanes if self._chunk_compact
+                     else self.pool.max_slots)
                 ctoks = _np.zeros((S, W), dtype=_np.int32)
                 offs = _np.zeros((S,), dtype=_np.int32)
                 nval = _np.zeros((S,), dtype=_np.int32)
+                crows = _np.full((S,), g, dtype=_np.int32)
                 temps = _np.zeros((S,), dtype=_np.float32)
                 tks = _np.zeros((S,), dtype=_np.int32)
                 tps = _np.ones((S,), dtype=_np.float32)
                 keys = _np.zeros((S, 2), dtype=_np.uint32)
                 fold = _np.zeros((S,), dtype=_np.int32)
                 for req in chunkers:
-                    s = req.slot
+                    s = lane_of[req.slot]
+                    crows[s] = req.slot
                     n = min(W, int(req.prompt.size) - req.prefill_pos)
                     ctoks[s, :n] = req.prompt[req.prefill_pos:
                                               req.prefill_pos + n]
@@ -1934,30 +1926,32 @@ class ContinuousEngine:
                     fold[s] = int(req.prompt.size) - 1
                 # smallest warmed extent covering the furthest lane: the
                 # wave's attention read scales with streamed progress
-                need = max(int(offs[r.slot]) + int(nval[r.slot])
-                           for r in chunkers)
+                need = int((offs + nval).max())
                 ext = next(x for x in self._chunk_extents if x >= need)
-                jtoks, joffs, jnval = (jnp.asarray(ctoks), jnp.asarray(offs),
-                                       jnp.asarray(nval))
+                chunk_args = [jnp.asarray(ctoks), jnp.asarray(offs),
+                              jnp.asarray(nval)]
+                if self._chunk_compact:
+                    chunk_args.append(jnp.asarray(crows))
                 sample = (jnp.asarray(temps), jnp.asarray(tks),
                           jnp.asarray(tps), jnp.asarray(keys),
                           jnp.asarray(fold))
             with (_span("serve.prefill_batch.dispatch", cat="serve")
                   if on else NO_SPAN):
-                kb, vb = self.pool.buffers()
-                k, v, logits = self._chunk_progs[ext](
-                    self.model.params, kb, vb, jtoks, joffs, jnval)
+                cache = self.pool.buffers()
+                *cache, logits = self._chunk_progs[ext](
+                    self.model.params, *cache, *chunk_args)
                 first = _sample_first(logits, *sample)
-                self.pool.swap_buffers(k, v)
+                self.pool.swap_buffers(*cache)
             with (_span("serve.prefill_batch.readback", cat="serve")
                   if on else NO_SPAN):
                 first_host = _np.asarray(first)
             for req in chunkers:
-                n = int(nval[req.slot])
+                s = lane_of[req.slot]
+                n = int(nval[s])
                 req.prefill_pos += n
                 n_tokens += n
                 if req.prefill_pos == int(req.prompt.size):
-                    finished.append((req, int(first_host[req.slot])))
+                    finished.append((req, int(first_host[s])))
         now = time.perf_counter()
         if admitted:
             self._count("admitted", len(admitted))
@@ -2074,17 +2068,19 @@ class ContinuousEngine:
                 args.append(jnp.asarray(buf))
         with (_span("serve.decode_batch.dispatch", cat="serve") if on
               else NO_SPAN):
-            kb, vb = self.pool.buffers()
-            out = self._decode_prog(self.model.params, kb, vb, *args)
-            self.pool.swap_buffers(out[0], out[1])
+            cache = self.pool.buffers()
+            n = len(cache)
+            out = self._decode_prog(self.model.params, *cache, *args)
+            self.pool.swap_buffers(*out[:n])
+            out = out[n:]
         with (_span("serve.decode_batch.readback", cat="serve") if on
               else NO_SPAN):
             if draft:
-                _, _, blocks, n_emits, emitted, acc, rej = out
+                blocks, n_emits, emitted, acc, rej = out
                 blocks_host = _np.asarray(blocks)   # (steps, S, draft+1)
                 nem_host = _np.asarray(n_emits)     # (steps, S)
             else:
-                _, _, out_toks, emitted = out
+                out_toks, emitted = out
                 out_host = _np.asarray(out_toks)    # (decode_steps, S)
             if self._canary is not None:
                 self._canary.check(where="serve.decode")
@@ -2118,6 +2114,13 @@ class ContinuousEngine:
             self._count("decode_iterations")
             self._count("decode_tokens", n_tokens)
             self._count("active_sum", n_active)
+            # the lanes' lengths after this wave, from the array the wave
+            # packed: one vectorised sum a cache kind, no loop over lanes
+            at = _np.fromiter(running, dtype=_np.intp, count=n_active)
+            live = self.pool.bytes_by_kind(lens[at] + emitted_host[at])
+            with self._mlock:
+                for kind, n in live.items():
+                    self._cache_live[kind] += n
             if n_sampled:
                 self._count("sampled_tokens", n_sampled)
             if draft:

@@ -32,6 +32,20 @@ reads and writes, never as per-request Python objects the tracer sees):
     it). ~3-4x more slots per HBM byte (`slots_per_gb()`), the number
     the memory bench commits.
 
+A pool is built from a CACHE SPEC: a list of `CacheLeaf`s, each naming
+one device array with a row per slot (`shape` is one row's), its dtype and
+its `kind` — `full` (grows with the request, one position a token, read
+through a `[0, cur_len]` mask), `ring` (the newest `positions` positions,
+position p at `p % positions`) or `state` (a recurrent state, rewritten
+whole at every token). The model declares the spec and `new_pool` hands it
+over; the classic decoder's spec is the `k`/`v` slab pair (plus the int8
+scales) that `layers=..., heads=...` describe. The unzeroed-free-slot
+contract above is the `full` kind's: a `ring` hides stale positions by the
+same arithmetic, but a `state` leaf has no mask, so the model's programs
+must overwrite it before they read it (`HybridDecoder`: the prefill at
+offset 0 starts every state from zero; tests/test_hybrid_decoder.py
+poison-fills every leaf to show it).
+
 Exhaustion is typed: `claim()` past capacity raises `SlotsFullError`
 (a `ServeError`), the admission signal the engine's deadline-aware
 scheduler acts on instead of blocking.
@@ -42,12 +56,40 @@ via `serve.kv_pool.kvpool_stats()`; catalog in docs/OBSERVABILITY.md).
 from __future__ import annotations
 
 import threading
+from collections import namedtuple
+
+import numpy as _np
 
 from ..base import get_env
 from ..telemetry.registry import REGISTRY, stats_group as _stats_group
 from .batcher import ServeError
 
-__all__ = ["SlotsFullError", "KVCachePool", "KVPOOL_STATS", "kvpool_stats"]
+__all__ = ["SlotsFullError", "CacheKindError", "CacheLeaf", "CACHE_KINDS", "KVCachePool",
+           "KVPOOL_STATS", "kvpool_stats"]
+
+CACHE_KINDS = ("full", "ring", "state")
+
+# one leaf of a cache spec: `shape` is ONE ROW's shape (the pool adds the
+# slot axis in front), `positions` the length of the axis that a request
+# fills token by token (a `full` leaf's max_len, a `ring`'s capacity; 0
+# for a `state`, which is live whole from the first token)
+CacheLeaf = namedtuple("CacheLeaf", "name shape dtype kind positions")
+
+
+def _itemsize(dtype):
+    import numpy as _np
+    import ml_dtypes  # noqa: F401  (bf16 dtype string resolution)
+    try:
+        return _np.dtype(dtype).itemsize
+    except TypeError:
+        return 2      # bfloat16
+
+
+def _numel(shape):
+    n = 1
+    for d in shape:
+        n *= d
+    return n
 
 
 class SlotsFullError(ServeError):
@@ -55,6 +97,12 @@ class SlotsFullError(ServeError):
     engine's admission loop treats this as "stay queued" (and fails the
     request only when its deadline expires); direct callers get a typed,
     actionable error instead of an index out of range."""
+
+
+class CacheKindError(ServeError):
+    """An engine option that treats a request's past as rows of K and V
+    (prefix reuse, speculation, int8 storage) met a model whose cache
+    spec holds a `ring` or `state` leaf."""
 
 
 # Guards every KVPOOL_STATS mutation AND the free-list bookkeeping of all
@@ -94,10 +142,7 @@ def _note_slab(pool):
     try:
         _SLAB_GAUGE.set(pool.nbytes())
         from ..inspect import memory as _mem
-        bufs = (pool.k, pool.v)
-        if pool.quantized:
-            bufs = bufs + (pool.k_scale, pool.v_scale)
-        _mem.register(bufs, owner="kv_pool")
+        _mem.register(tuple(pool.leaves.values()), owner="kv_pool")
     except Exception:
         pass
 
@@ -124,30 +169,62 @@ class KVCachePool:
     (exactly one scheduler thread runs the compiled steps).
     """
 
-    def __init__(self, max_slots=None, *, layers, max_len, heads,
-                 head_dim, dtype="float32", allocate=True):
+    def __init__(self, max_slots=None, *, layers=None, max_len=None,
+                 heads=None, head_dim=None, dtype="float32", spec=None,
+                 allocate=True):
         self.max_slots = int(
             max_slots if max_slots is not None
             else get_env("MXNET_SERVE_MAX_SLOTS", 8, typ=int))
         if self.max_slots < 1:
             raise ServeError("KVCachePool needs max_slots >= 1")
-        self.layers = int(layers)
-        self.max_len = int(max_len)
-        self.heads = int(heads)
-        self.head_dim = int(head_dim)
         self.dtype = str(dtype)
         # int8 = quantized storage: slabs hold int8 codes, the paired
         # k_scale/v_scale buffers hold one f32 dequant factor per
         # written (slot, layer, position)
         self.quantized = self.dtype == "int8"
+        # the classic layout is the decoder's K/V slab pair, handed to the
+        # programs as two arguments; a model's own spec rides as one dict
+        self.classic = spec is None
+        if self.classic:
+            self.layers = int(layers)
+            self.max_len = int(max_len)
+            self.heads = int(heads)
+            self.head_dim = int(head_dim)
+            row = (self.layers, self.max_len, self.heads, self.head_dim)
+            spec = [CacheLeaf("k", row, self.dtype, "full", self.max_len),
+                    CacheLeaf("v", row, self.dtype, "full", self.max_len)]
+            if self.quantized:
+                spec += [CacheLeaf(n, row[:2], "float32", "full",
+                                   self.max_len)
+                         for n in ("k_scale", "v_scale")]
+        self.spec = tuple(CacheLeaf(*leaf) for leaf in spec)
+        for leaf in self.spec:
+            if leaf.kind not in CACHE_KINDS:
+                raise ServeError(f"cache leaf {leaf.name!r}: unknown kind "
+                                 f"{leaf.kind!r} (one of {CACHE_KINDS})")
+        # a row's bytes by (kind, positions): what `bytes_by_kind` sums
+        self._row_bytes = {}
+        for leaf in self.spec:
+            key = (leaf.kind, leaf.positions)
+            self._row_bytes[key] = self._row_bytes.get(key, 0) \
+                + _numel(leaf.shape) * _itemsize(leaf.dtype)
         # LIFO free list: a just-freed slot is re-claimed first, which is
         # exactly what the poison-fill reuse test needs to exercise
         self._free = list(range(self.max_slots - 1, -1, -1))
         self._claimed = set()
-        self.k = self.v = None
-        self.k_scale = self.v_scale = None
+        self.leaves = {}
         if allocate:
             self._allocate()
+
+    # the classic slab pair by name (tests and the slot canary write them)
+    k = property(lambda self: self.leaves.get("k"),
+                 lambda self, a: self.leaves.__setitem__("k", a))
+    v = property(lambda self: self.leaves.get("v"),
+                 lambda self, a: self.leaves.__setitem__("v", a))
+    k_scale = property(lambda self: self.leaves.get("k_scale"),
+                       lambda self, a: self.leaves.__setitem__("k_scale", a))
+    v_scale = property(lambda self: self.leaves.get("v_scale"),
+                       lambda self, a: self.leaves.__setitem__("v_scale", a))
 
     # -- buffers -----------------------------------------------------------
     @property
@@ -166,23 +243,38 @@ class KVCachePool:
         """Scatter target for a fixed-shape step's inactive lanes."""
         return self.max_slots
 
+    def kinds(self):
+        """The cache kinds this pool's spec holds."""
+        return {leaf.kind for leaf in self.spec}
+
     def _allocate(self):
         import jax.numpy as jnp
-        self.k = jnp.zeros(self.shape, dtype=self.dtype)
-        self.v = jnp.zeros(self.shape, dtype=self.dtype)
-        if self.quantized:
-            self.k_scale = jnp.zeros(self.scale_shape, dtype="float32")
-            self.v_scale = jnp.zeros(self.scale_shape, dtype="float32")
+        self.leaves = {
+            leaf.name: jnp.zeros((self.max_slots + 1,) + tuple(leaf.shape),
+                                 dtype=leaf.dtype)
+            for leaf in self.spec}
         _note_slab(self)
 
     def buffers(self):
-        """The (k, v) arguments the step programs take: plain slabs, or
-        `(slab, scales)` pytree pairs for a quantized pool (the program
-        variant is chosen by `quantized` at build time, so the pytree
-        STRUCTURE is a trace-time constant)."""
+        """The cache arguments the step programs take after `params`, as
+        a tuple: the classic pair `(k, v)` (`(slab, scales)` pairs for a
+        quantized pool — the program variant is chosen by `quantized` at
+        build time, so the pytree STRUCTURE is a trace-time constant), or
+        the one `{name: array}` dict of a model's own spec. A program
+        returns them first, in the same order, for `swap_buffers`."""
+        if not self.classic:
+            return (dict(self.leaves),)
         if self.quantized:
             return (self.k, self.k_scale), (self.v, self.v_scale)
         return self.k, self.v
+
+    def avals(self):
+        """`buffers()` as `jax.ShapeDtypeStruct`s (lowering without
+        touching a buffer)."""
+        import jax
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+            self.buffers())
 
     def reallocate(self):
         """Replace the slab with fresh zeroed buffers. The engine's
@@ -193,38 +285,32 @@ class KVCachePool:
         self._allocate()
 
     def nbytes(self):
-        """Host-visible size of the slab pair incl. the quantized pools'
-        scale buffers (capacity-planning aid)."""
-        import numpy as _np
-        import ml_dtypes  # noqa: F401  (bf16 dtype string resolution)
-        try:
-            itemsize = _np.dtype(self.dtype).itemsize
-        except TypeError:
-            itemsize = 2      # bfloat16
-        n = 1
-        for d in self.shape:
-            n *= d
-        total = 2 * n * itemsize
-        if self.quantized:
-            s = 1
-            for d in self.scale_shape:
-                s *= d
-            total += 2 * s * 4
-        return total
+        """Host-visible size of every leaf incl. the garbage row
+        (capacity-planning aid)."""
+        return (self.max_slots + 1) * self.bytes_per_slot()
 
     def bytes_per_slot(self):
-        """Marginal device bytes one slot row costs (k + v pages, plus
-        scale rows on a quantized pool)."""
-        page = 2 * self.layers * self.max_len * self.heads * self.head_dim
-        import numpy as _np
-        try:
-            itemsize = _np.dtype(self.dtype).itemsize
-        except TypeError:
-            itemsize = 2
-        per = page * itemsize
-        if self.quantized:
-            per += 2 * self.layers * self.max_len * 4
-        return per
+        """Marginal device bytes one slot row costs, all leaves."""
+        return sum(self._row_bytes.values())
+
+    def bytes_by_kind(self, lengths=None):
+        """{kind: bytes}: allocated (`lengths` None, garbage row
+        included), or LIVE for lanes at these cache lengths — the
+        positions a `full` leaf holds so far, a `ring`'s newest
+        `positions`, a `state` whole."""
+        if lengths is not None:
+            lengths = _np.asarray(lengths, dtype=_np.int64)
+        out = {}
+        for (kind, positions), row in self._row_bytes.items():
+            if lengths is None:
+                n = row * (self.max_slots + 1)
+            elif kind == "state":
+                n = row * lengths.size
+            else:
+                n = int(_np.minimum(lengths, positions).sum()) \
+                    * row // positions
+            out[kind] = out.get(kind, 0) + n
+        return out
 
     def slots_per_gb(self):
         """KV slots one GiB of device memory buys at this pool's shape —
@@ -232,54 +318,54 @@ class KVCachePool:
         the slots of float32 at the same (layers, max_len, heads, dim))."""
         return round((1 << 30) / self.bytes_per_slot(), 2)
 
-    def swap_buffers(self, k, v):
+    def swap_buffers(self, *cache):
         """Install the step program's output buffers (the donated-update
-        swap idiom: the old arrays were consumed by donation). Quantized
-        pools take the `(slab, scales)` pairs `buffers()` hands out."""
-        if self.quantized:
-            (self.k, self.k_scale), (self.v, self.v_scale) = k, v
+        swap idiom: the old arrays were consumed by donation), in the
+        order and structure `buffers()` hands them out."""
+        if not self.classic:
+            (leaves,) = cache
+            self.leaves = dict(leaves)
+        elif self.quantized:
+            (self.k, self.k_scale), (self.v, self.v_scale) = cache
         else:
-            self.k, self.v = k, v
+            self.k, self.v = cache
         _note_slab(self)
 
+    def _sentinels(self, value):
+        """{leaf: the poison value}: on a quantized pool the codes are
+        set to 1 and the SCALES to `value`, so a stale-scale read is as
+        loud as a stale-code one."""
+        return {leaf.name: 1 if leaf.dtype == "int8" else value
+                for leaf in self.spec}
+
     def poison(self, value=1e9):
-        """Overwrite the WHOLE slab with a sentinel. Test hook for the
+        """Overwrite EVERY leaf whole with a sentinel. Test hook for the
         slot-reuse isolation contract: after poisoning, any read that
-        escapes the `[0, cur_len]` mask shows up as the sentinel in the
-        output. On a quantized pool the codes are set to 1 and the SCALES
-        to `value`, so a stale-scale read is as loud as a stale-code one.
-        Never called on the serving path."""
+        escapes the `[0, cur_len]` mask — or a recurrent state that a
+        new tenant did not start from zero — shows up as the sentinel in
+        the output. Never called on the serving path."""
         import jax.numpy as jnp
-        if self.quantized:
-            self.k = jnp.full(self.shape, 1, dtype=self.dtype)
-            self.v = jnp.full(self.shape, 1, dtype=self.dtype)
-            self.k_scale = jnp.full(self.scale_shape, value,
-                                    dtype="float32")
-            self.v_scale = jnp.full(self.scale_shape, value,
-                                    dtype="float32")
-        else:
-            self.k = jnp.full(self.shape, value, dtype=self.dtype)
-            self.v = jnp.full(self.shape, value, dtype=self.dtype)
+        fill = self._sentinels(value)
+        self.leaves = {n: jnp.full(a.shape, fill[n], dtype=a.dtype)
+                       for n, a in self.leaves.items()}
         _note_slab(self)
 
     def poison_slot(self, slot, value=1e9):
-        """`poison()` at slot granularity: overwrite ONE row (both slabs,
-        plus its scale rows on a quantized pool) with the sentinel,
-        leaving every other slot's live KV intact. Test hook for the
-        shared-prefix isolation contract: poison a FREED prefix-cache
-        row, keep serving, and any tenant that could still read it shows
-        the sentinel. Never called on the serving path."""
+        """`poison()` at slot granularity: overwrite ONE row of every
+        leaf with the sentinel, leaving every other slot's live cache
+        intact. Test hook for the shared-prefix isolation contract:
+        poison a FREED prefix-cache row, keep serving, and any tenant
+        that could still read it shows the sentinel. Never called on the
+        serving path."""
         import jax.numpy as jnp
         slot = int(slot)
         if not 0 <= slot <= self.max_slots:
             raise ServeError(
                 f"slot {slot} outside [0, {self.max_slots}]")
-        code = 1 if self.quantized else value
-        self.k = self.k.at[slot].set(jnp.asarray(code, dtype=self.dtype))
-        self.v = self.v.at[slot].set(jnp.asarray(code, dtype=self.dtype))
-        if self.quantized:
-            self.k_scale = self.k_scale.at[slot].set(value)
-            self.v_scale = self.v_scale.at[slot].set(value)
+        fill = self._sentinels(value)
+        self.leaves = {
+            n: a.at[slot].set(jnp.asarray(fill[n], dtype=a.dtype))
+            for n, a in self.leaves.items()}
         _note_slab(self)
 
     # -- slot bookkeeping --------------------------------------------------
@@ -325,4 +411,4 @@ class KVCachePool:
                 "free": self.max_slots - used,
                 "dtype": self.dtype,
                 "slots_per_gb": self.slots_per_gb(),
-                "slab_bytes": self.nbytes() if self.k is not None else 0}
+                "slab_bytes": self.nbytes() if self.leaves else 0}

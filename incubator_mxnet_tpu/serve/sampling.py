@@ -1,0 +1,68 @@
+"""The sampler that every served model's programs share: per-lane
+temperature / top-k / top-p with the parameters as data. It lives apart
+from the engine (`serve.continuous`) so that a model's program factory
+(`serve.CachedDecoder`, `models.hybrid_decoder.HybridDecoder`) needs to know
+the sampler and not the engine."""
+from __future__ import annotations
+
+
+def sample_tokens(logits, temps, top_ks, top_ps, keys, positions):
+    """Per-lane next-token choice with sampling params AS DATA: every
+    lane runs the same temperature/top-k/top-p/categorical math and a
+    `temps > 0` select keeps greedy lanes exactly argmax — one compiled
+    program serves any greedy/sampled mix. The draw key is
+    `fold_in(lane_key, position)` (position = the query token's cache
+    position), a pure function of request state, so any wave schedule
+    draws the same tokens.
+
+    The truncation and the draw happen in SORTED order, on the one array
+    the sort returns, and the drawn rank maps back through the sort's
+    own permutation. A threshold taken from the sorted values must never
+    be compared with a second evaluation of `logits / temps`: the
+    compiler may feed the sort from the logits matmul's float32
+    accumulators and re-derive the other copy from their bfloat16
+    rounding (seen on the TPU at 10 lanes), and a top logit that rounded
+    down then fails its own threshold — the whole row is masked and
+    token 0 comes out."""
+    import jax
+    import jax.numpy as jnp
+    with jax.named_scope("sampler"):
+        V = logits.shape[-1]
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+        ids = jax.lax.broadcasted_iota(jnp.int32, scaled.shape, 1)
+        neg, order = jax.lax.sort_key_val(-scaled, ids, dimension=1)
+        srt = -neg                                   # descending, ties by id
+        kth = jnp.take_along_axis(
+            srt, jnp.clip(top_ks - 1, 0, V - 1)[:, None], axis=-1)
+        keep_k = (top_ks[:, None] <= 0) | (srt >= kth)
+        probs = jax.nn.softmax(srt, axis=-1)
+        csum = jnp.cumsum(probs, axis=-1)
+        # smallest prefix whose mass reaches top_p (the kept-set INCLUDES
+        # the crossing token, hence the exclusive-cumsum comparison)
+        keepn = jnp.sum((csum - probs) < top_ps[:, None], axis=-1)
+        pth = jnp.take_along_axis(
+            srt, jnp.clip(keepn - 1, 0, V - 1)[:, None], axis=-1)
+        masked = jnp.where(keep_k & (srt >= pth), srt, -1e30)
+        kfold = jax.vmap(jax.random.fold_in)(keys, positions)
+        rank = jax.vmap(
+            lambda kk, lg: jax.random.categorical(kk, lg))(kfold, masked)
+        sampled = jnp.take_along_axis(order, rank[:, None], axis=-1)[:, 0]
+        return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
+
+
+_SAMPLE_JIT = None
+
+
+def sample_first(logits, temps, top_ks, top_ps, keys, positions):
+    """First-token draw from prefill logits through ONE process-wide
+    jitted sampler. The sampling math compiles once per (lanes, vocab)
+    shape for every model and engine in the process, instead of being
+    re-traced into each model's prefill program (the decode program
+    keeps its own in-scan copy, where it must live). Identical math
+    either way, so engine == reference still holds bit-for-bit."""
+    global _SAMPLE_JIT
+    if _SAMPLE_JIT is None:
+        import jax
+        _SAMPLE_JIT = jax.jit(sample_tokens)
+    return _SAMPLE_JIT(logits, temps, top_ks, top_ps, keys, positions)

@@ -116,6 +116,30 @@ def test_paged_attention_compiles_at_cgpt13b_widths(chip, kv_dtype, chunk):
     _compile_paged_attention(chip, CELL, kv_dtype, chunk)
 
 
+@pytest.mark.parametrize("lanes,rows", [(64, False), (4, True)],
+                         ids=["decode-64-lanes", "prefill-4-lanes-rows-as-data"])
+def test_shared_leaf_read_compiles_at_phi4mf_widths(chip, lanes, rows):
+    """`chipbench/configs/phi4mf_serve.json`'s shared-cache read: 40
+    zero-padded query rows of 128 over a (65, 4096, 1280) leaf (10 KV
+    pairs of 128), float32 out; lane s reads row s in decode, a row that
+    rides as data in prefill."""
+    from incubator_mxnet_tpu.ops import pallas_kernels as pk
+    q = chip((lanes, 1, 40, 128), "bfloat16")
+    leaf = chip((65, 4096, 1280), "bfloat16")
+    ints = chip((lanes,), "int32")
+    if rows:
+        text = _compile(
+            lambda q, k, v, n, r: pk.paged_attention_fwd(
+                q, k, v, n, None, scale=0.125, rows=r, out_dtype="float32"),
+            q, leaf, leaf, ints, ints)
+    else:
+        text = _compile(
+            lambda q, k, v, n: pk.paged_attention_fwd(
+                q, k, v, n, None, scale=0.125, out_dtype="float32"),
+            q, leaf, leaf, ints)
+    _holds_kernel(text)
+
+
 @pytest.mark.parametrize("widths", [SERVE, CELL],
                          ids=["gpt2-small", "cgpt13b"])
 @pytest.mark.parametrize("kv_bytes,chunk", [

@@ -311,7 +311,9 @@ def test_fused_stats_counters_move():
                                            "device_augment_calls",
                                            "paged_attention_calls",
                                            "paged_flat_traces",
-                                           "paged_head_major_traces"}
+                                           "paged_head_major_traces",
+                                           "paged_shared_traces",
+                                           "paged_window_traces"}
 
 
 def test_set_interpret_toggle_not_served_stale_programs():
