@@ -43,6 +43,7 @@ _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\(?.*?\)?)\s+"
     r"([\w\-]+)\((.*)$")
 _COMP_RE = re.compile(r"^\s*(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->\s*.*\{")
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
 _METADATA_OP_RE = re.compile(r'op_name="([^"]*)"')
 _CALLS_RE = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
 _DIM_LABELS_RE = re.compile(r"dim_labels=([\w?]+_[\w?]+->[\w?]+)")
@@ -230,8 +231,10 @@ def parse_module(text):
             current = None
             continue
         cm = _COMP_RE.match(stripped)
-        if cm and stripped.endswith("{") and "=" not in stripped.split(
-                "->")[0]:
+        # (a wide tuple parameter prints `/*index=5*/` marks: not an `=`
+        # of an instruction)
+        if cm and stripped.endswith("{") and "=" not in _COMMENT_RE.sub(
+                "", stripped.split("->")[0]):
             comp = HloComputation(cm.group(2), is_entry=bool(cm.group(1)))
             module.computations[comp.name] = comp
             if comp.is_entry:

@@ -746,12 +746,15 @@ class HybridDecoder:
         return -1 if any(s < 0 for s in sizes) else sum(sizes)
 
     def reference_generate(self, prompt, max_new_tokens, eos_id=None,
-                           window=None):
-        """Greedy generation through a PRIVATE 1-slot pool with the same
+                           window=None, temperature=0.0, top_k=0,
+                           top_p=1.0, seed=0):
+        """Generation through a PRIVATE 1-slot pool with the same
         compiled math: a windowed prefill at offset 0, the remainder in
-        window-sized chunks, then one decode step at a time."""
+        window-sized chunks, then one decode step at a time. Greedy by
+        default; `temperature > 0` draws as the engine does for that
+        request seed (the key is a function of seed and position)."""
         import jax.numpy as jnp
-        from ..serve.sampling import sample_first
+        from ..serve.sampling import sample_first, seed_key
         c = self.config
         pool = self.new_pool(max_slots=1)
         W = int(window if window is not None else min(c.window, c.max_len))
@@ -761,8 +764,8 @@ class HybridDecoder:
             raise ServeError(f"prompt length {plen} outside "
                              f"[1, max_len-1={c.max_len - 1}]")
         one = lambda v, dt=jnp.int32: jnp.asarray([v], dtype=dt)  # noqa: E731
-        greedy = (one(0.0, jnp.float32), one(0), one(1.0, jnp.float32),
-                  jnp.zeros((1, 2), jnp.uint32))
+        sample = (one(temperature, jnp.float32), one(top_k),
+                  one(top_p, jnp.float32), jnp.asarray(seed_key(seed)[None]))
         pos, logits = 0, None
         while pos < plen:
             n = min(W, plen - pos)
@@ -778,7 +781,7 @@ class HybridDecoder:
                     one(0))
             pool.swap_buffers(cache)
             pos += n
-        out = [int(sample_first(logits, *greedy, one(plen - 1))[0])]
+        out = [int(sample_first(logits, *sample, one(plen - 1))[0])]
         cache_len = plen
         decode = self.decode_program(1, eos_id)
         while (len(out) < max_new_tokens
@@ -786,7 +789,7 @@ class HybridDecoder:
                and cache_len + 1 < c.max_len):
             (cache,) = pool.buffers()
             cache, toks1, _ = decode(self.params, cache, one(out[-1]),
-                                     one(cache_len), one(1), *greedy)
+                                     one(cache_len), one(1), *sample)
             pool.swap_buffers(cache)
             out.append(int(toks1[0, 0]))
             cache_len += 1

@@ -56,7 +56,9 @@ fixed-shape programs so the zero-retrace contract survives untouched:
   * **Sampling as data**: temperature/top-k/top-p and a per-lane PRNG
     key ride into the compiled programs as (S,)-shaped ARRAYS (the PR-9
     key-as-data idiom) — a mixed greedy/sampled batch is just different
-    array values through one program. The per-token key is
+    array values through one program, whose sampler sorts the
+    vocabulary only in a wave that holds a sampled lane (one on-device
+    branch, `serve/sampling.py`). The per-token key is
     `fold_in(request_key, position)`, a pure function of the token's
     page position, so the key schedule is WAVE-INVARIANT: the engine
     (any decode_steps, any join/leave pattern) and the 1-slot
@@ -105,7 +107,8 @@ from .metrics import SERVE_STATS, _STATS_LOCK, percentile
 from .kv_pool import CacheKindError, KVCachePool, SlotsFullError
 from .prefix_cache import PrefixCache
 from .sampling import (sample_first as _sample_first,
-                       sample_tokens as _sample_tokens)
+                       sample_tokens as _sample_tokens,
+                       seed_key as _seed_key)
 
 __all__ = ["DecoderConfig", "CachedDecoder", "ContinuousEngine",
            "RequestTiming", "init_decoder_params"]
@@ -173,15 +176,6 @@ def _rmsnorm(x, scale):
     import jax.numpy as jnp
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     return x * scale / jnp.sqrt(var + 1e-6)
-
-
-def _seed_key(seed):
-    """Host-side PRNG key bytes for a request seed — the same uint32
-    pair `jax.random.PRNGKey(seed)` holds, built without a device
-    round-trip so submit() stays cheap."""
-    seed = int(seed) & 0xFFFFFFFFFFFFFFFF
-    return _np.array([(seed >> 32) & 0xFFFFFFFF, seed & 0xFFFFFFFF],
-                     dtype=_np.uint32)
 
 
 def _kv_split(cache):
@@ -1234,7 +1228,8 @@ class ContinuousEngine:
             "requests", "replies", "rejected", "timeouts", "errors",
             "admitted", "retired", "decode_iterations", "decode_tokens",
             "prefill_tokens", "prefill_batches", "programs_compiled",
-            "active_sum", "sampled_tokens", "draft_accepted",
+            "active_sum", "sampled_tokens", "sampled_waves",
+            "draft_accepted",
             "draft_rejected", "prefix_hits", "prefix_misses",
             "prefix_cached_tokens")}
         # per cache kind: the bytes the lanes of each decode wave held
@@ -2114,6 +2109,11 @@ class ContinuousEngine:
             self._count("decode_iterations")
             self._count("decode_tokens", n_tokens)
             self._count("active_sum", n_active)
+            # lanes whose temperature the wave packed above 0: with any,
+            # the program's sampler took its sorting side for every lane
+            sampled_lanes = int(_np.count_nonzero(temps))
+            if sampled_lanes:
+                self._count("sampled_waves")
             # the lanes' lengths after this wave, from the array the wave
             # packed: one vectorised sum a cache kind, no loop over lanes
             at = _np.fromiter(running, dtype=_np.intp, count=n_active)
@@ -2127,7 +2127,7 @@ class ContinuousEngine:
                 self._count("draft_accepted", int(_np.asarray(acc).sum()))
                 self._count("draft_rejected", int(_np.asarray(rej).sum()))
         if on:
-            sp.set(active=n_active, tokens=n_tokens)
+            sp.set(active=n_active, tokens=n_tokens, sampled=sampled_lanes)
         return done
 
     def _finished(self, req):

@@ -5,15 +5,34 @@ from the engine (`serve.continuous`) so that a model's program factory
 the sampler and not the engine."""
 from __future__ import annotations
 
+import numpy as _np
+
+
+def seed_key(seed):
+    """Host-side PRNG key bytes for a request seed — the same uint32
+    pair `jax.random.PRNGKey(seed)` holds, built without a device
+    round-trip so submit() stays cheap."""
+    seed = int(seed) & 0xFFFFFFFFFFFFFFFF
+    return _np.array([(seed >> 32) & 0xFFFFFFFF, seed & 0xFFFFFFFF],
+                     dtype=_np.uint32)
+
 
 def sample_tokens(logits, temps, top_ks, top_ps, keys, positions):
-    """Per-lane next-token choice with sampling params AS DATA: every
-    lane runs the same temperature/top-k/top-p/categorical math and a
-    `temps > 0` select keeps greedy lanes exactly argmax — one compiled
-    program serves any greedy/sampled mix. The draw key is
-    `fold_in(lane_key, position)` (position = the query token's cache
-    position), a pure function of request state, so any wave schedule
-    draws the same tokens.
+    """Per-lane next-token choice with sampling params AS DATA: one
+    compiled program serves any greedy/sampled mix. Every lane's greedy
+    `argmax` is always evaluated; the temperature / top-k / top-p /
+    categorical body (a sort of the whole `(lanes, vocab)` array) sits
+    in the taken side of ONE on-device `lax.cond` on `any(temps > 0)`,
+    so a wave whose lanes are all greedy (an idle lane's temperature is
+    0) returns the argmax and never sorts, and a wave with one sampled
+    lane runs the body for every lane, where a `temps > 0` select keeps
+    its greedy lanes exactly argmax. The predicate is a device scalar
+    read from the program's own input: no host read, no second program.
+    `lax.cond` under `vmap` becomes a select that runs both sides: never
+    vmap this function (flatten lanes instead, as the speculative path
+    does). The draw key is `fold_in(lane_key, position)` (position = the
+    query token's cache position), a pure function of request state, so
+    any wave schedule draws the same tokens.
 
     The truncation and the draw happen in SORTED order, on the one array
     the sort returns, and the drawn rank maps back through the sort's
@@ -29,26 +48,32 @@ def sample_tokens(logits, temps, top_ks, top_ps, keys, positions):
     with jax.named_scope("sampler"):
         V = logits.shape[-1]
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-        ids = jax.lax.broadcasted_iota(jnp.int32, scaled.shape, 1)
-        neg, order = jax.lax.sort_key_val(-scaled, ids, dimension=1)
-        srt = -neg                                   # descending, ties by id
-        kth = jnp.take_along_axis(
-            srt, jnp.clip(top_ks - 1, 0, V - 1)[:, None], axis=-1)
-        keep_k = (top_ks[:, None] <= 0) | (srt >= kth)
-        probs = jax.nn.softmax(srt, axis=-1)
-        csum = jnp.cumsum(probs, axis=-1)
-        # smallest prefix whose mass reaches top_p (the kept-set INCLUDES
-        # the crossing token, hence the exclusive-cumsum comparison)
-        keepn = jnp.sum((csum - probs) < top_ps[:, None], axis=-1)
-        pth = jnp.take_along_axis(
-            srt, jnp.clip(keepn - 1, 0, V - 1)[:, None], axis=-1)
-        masked = jnp.where(keep_k & (srt >= pth), srt, -1e30)
-        kfold = jax.vmap(jax.random.fold_in)(keys, positions)
-        rank = jax.vmap(
-            lambda kk, lg: jax.random.categorical(kk, lg))(kfold, masked)
-        sampled = jnp.take_along_axis(order, rank[:, None], axis=-1)[:, 0]
-        return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
+
+        def draw():
+            scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+            ids = jax.lax.broadcasted_iota(jnp.int32, scaled.shape, 1)
+            neg, order = jax.lax.sort_key_val(-scaled, ids, dimension=1)
+            srt = -neg                               # descending, ties by id
+            kth = jnp.take_along_axis(
+                srt, jnp.clip(top_ks - 1, 0, V - 1)[:, None], axis=-1)
+            keep_k = (top_ks[:, None] <= 0) | (srt >= kth)
+            probs = jax.nn.softmax(srt, axis=-1)
+            csum = jnp.cumsum(probs, axis=-1)
+            # smallest prefix whose mass reaches top_p (the kept-set
+            # INCLUDES the crossing token, hence the exclusive-cumsum
+            # comparison)
+            keepn = jnp.sum((csum - probs) < top_ps[:, None], axis=-1)
+            pth = jnp.take_along_axis(
+                srt, jnp.clip(keepn - 1, 0, V - 1)[:, None], axis=-1)
+            masked = jnp.where(keep_k & (srt >= pth), srt, -1e30)
+            kfold = jax.vmap(jax.random.fold_in)(keys, positions)
+            rank = jax.vmap(
+                lambda kk, lg: jax.random.categorical(kk, lg))(kfold, masked)
+            sampled = jnp.take_along_axis(order, rank[:, None],
+                                          axis=-1)[:, 0]
+            return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
+
+        return jax.lax.cond(jnp.any(temps > 0), draw, lambda: greedy)
 
 
 _SAMPLE_JIT = None

@@ -26,13 +26,17 @@ Contracts under test (ISSUE 17 acceptance):
 import json
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from hlo_branches import sorts_and_conditionals
+
 from incubator_mxnet_tpu import profiler, serve
 from incubator_mxnet_tpu.ops import fused as F
 from incubator_mxnet_tpu.ops import pallas_kernels as PK
+from incubator_mxnet_tpu.serve import sampling
 from incubator_mxnet_tpu.serve.continuous import _sample_tokens, _seed_key
 
 CFG = dict(vocab=64, embed=32, layers=2, heads=4, head_dim=8, max_len=48)
@@ -95,7 +99,7 @@ def test_mixed_greedy_sampled_matches_reference_zero_retraces(decoder):
     key depends on (seed, position), not on which wave served it)."""
     model, ref = decoder
     work = _workload(8, seed=1)
-    sampling = [
+    mix = [
         {} if i % 2 == 0
         else {"temperature": 3.0, "top_k": 8, "seed": 100 + i}
         for i in range(len(work))]
@@ -104,18 +108,20 @@ def test_mixed_greedy_sampled_matches_reference_zero_retraces(decoder):
         warm_ccs = eng.compile_cache_size()
         warm_programs = profiler.serve_stats()["programs_compiled"]
         futs = [eng.submit(p, m, **kw)
-                for (p, m), kw in zip(work, sampling)]
+                for (p, m), kw in zip(work, mix)]
         outs = [f.result(timeout=120) for f in futs]
         assert eng.assert_no_retraces() == 0
         assert eng.compile_cache_size() == warm_ccs
         assert profiler.serve_stats()["programs_compiled"] == warm_programs
-    for (p, m), kw, o in zip(work, sampling, outs):
+        stats = eng.stats()
+    assert 0 < stats["sampled_waves"] <= stats["decode_iterations"]
+    for (p, m), kw, o in zip(work, mix, outs):
         np.testing.assert_array_equal(
             o, ref.reference_generate(p, m, **kw),
             err_msg=f"engine diverged for prompt {p} sampling {kw}")
         assert len(o) == m
     # only temperature > 0 lanes count as sampled
-    sampled_max_new = sum(m for (_, m), kw in zip(work, sampling) if kw)
+    sampled_max_new = sum(m for (_, m), kw in zip(work, mix) if kw)
     after = profiler.serve_stats()
     delta = after["decode_sampled_tokens"] - before["decode_sampled_tokens"]
     assert 0 < delta <= sampled_max_new
@@ -169,6 +175,152 @@ def test_sample_tokens_distribution_chi_square():
         logits, ones, zeros_i, jnp.full((n,), 0.69, jnp.float32), keys,
         positions))
     assert set(np.unique(topp)) == {0, 1}
+
+
+def _always_sort(logits, temps, top_ks, top_ps, keys, positions):
+    """The sampler as it stood before its branch: every lane sorted,
+    truncated and drawn, greedy lanes selected at the last line. The oracle
+    `sample_tokens` has to agree with element for element."""
+    V = logits.shape[-1]
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+    ids = jax.lax.broadcasted_iota(jnp.int32, scaled.shape, 1)
+    neg, order = jax.lax.sort_key_val(-scaled, ids, dimension=1)
+    srt = -neg
+    kth = jnp.take_along_axis(
+        srt, jnp.clip(top_ks - 1, 0, V - 1)[:, None], axis=-1)
+    keep_k = (top_ks[:, None] <= 0) | (srt >= kth)
+    probs = jax.nn.softmax(srt, axis=-1)
+    csum = jnp.cumsum(probs, axis=-1)
+    keepn = jnp.sum((csum - probs) < top_ps[:, None], axis=-1)
+    pth = jnp.take_along_axis(
+        srt, jnp.clip(keepn - 1, 0, V - 1)[:, None], axis=-1)
+    masked = jnp.where(keep_k & (srt >= pth), srt, -1e30)
+    kfold = jax.vmap(jax.random.fold_in)(keys, positions)
+    rank = jax.vmap(
+        lambda kk, lg: jax.random.categorical(kk, lg))(kfold, masked)
+    sampled = jnp.take_along_axis(order, rank[:, None], axis=-1)[:, 0]
+    return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
+
+
+def _sampler_case(mix, dtype, lanes=16, vocab=1000, seed=0):
+    """Random lanes for the sampler: temperatures by `mix`, random top-k,
+    top-p, keys and positions, one row whose maximum is tied."""
+    rng = np.random.RandomState(seed)
+    logits = 3.0 * rng.standard_normal((lanes, vocab))
+    logits[3, [17, vocab // 2, vocab - 1]] = logits[3].max() + 1.0
+    warm = {"all_greedy": np.zeros(lanes, bool),
+            "mixed": rng.rand(lanes) < 0.5,
+            "all_sampled": np.ones(lanes, bool)}[mix]
+    if mix == "mixed":
+        warm[3], warm[4] = True, False
+    temps = np.where(warm, rng.uniform(0.3, 2.0, lanes), 0.0)
+    return (jnp.asarray(logits, dtype=dtype),
+            jnp.asarray(temps, dtype=jnp.float32),
+            jnp.asarray(rng.choice([0, 1, 5, 50], lanes), dtype=jnp.int32),
+            jnp.asarray(rng.choice([1.0, 0.9, 0.5], lanes),
+                        dtype=jnp.float32),
+            jnp.asarray(rng.randint(0, 2 ** 31, (lanes, 2)),
+                        dtype=jnp.uint32),
+            jnp.asarray(rng.randint(0, 2048, lanes), dtype=jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("mix", ["all_greedy", "mixed", "all_sampled"])
+def test_sample_tokens_matches_the_always_sort_oracle(mix, dtype):
+    """The branch changes no answer: alone and as the tail of a scanned
+    micro-step (positions advancing), `sample_tokens` returns the always-
+    sort formulation's tokens, lane for lane."""
+    args = _sampler_case(mix, dtype)
+    want = np.asarray(jax.jit(_always_sort)(*args))
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(_sample_tokens)(*args)), want)
+    np.testing.assert_array_equal(np.asarray(_sample_tokens(*args)), want)
+    greedy = np.asarray(args[1]) == 0
+    np.testing.assert_array_equal(
+        want[greedy], np.argmax(np.asarray(args[0], np.float32), -1)[greedy])
+    if mix == "all_greedy":
+        assert want[3] == 17                    # first of the tied maxima
+
+    def scanned(fn):
+        def run(logits, temps, top_ks, top_ps, keys, positions):
+            def step(pos, _):
+                return pos + 1, fn(logits, temps, top_ks, top_ps, keys, pos)
+            return jax.lax.scan(step, positions, None, length=3)[1]
+        return np.asarray(jax.jit(run)(*args))
+    np.testing.assert_array_equal(scanned(_sample_tokens),
+                                  scanned(_always_sort))
+
+
+@pytest.mark.parametrize("draft", [0, 2], ids=["plain", "spec"])
+def test_sampled_request_joins_greedy_waves_and_leaves(decoder, draft):
+    """`stats()["sampled_waves"]` stays 0 over a greedy run; then a short
+    sampled request queues behind greedy ones, takes a slot while other
+    lanes are mid-decode, and leaves before them: every request still
+    draws its reference's tokens, nothing retraces, and the counter reads
+    the waves the sampled request lived through and no other."""
+    model, ref = decoder
+    twin = serve.CachedDecoder(serve.DecoderConfig(**CFG),
+                               params=model.params)
+    K, kw = 2, {"temperature": 1.5, "top_k": 8, "top_p": 0.9, "seed": 77}
+    with serve.ContinuousEngine(twin, max_slots=4, decode_steps=K,
+                                draft_tokens=draft) as eng:
+        first = _workload(5, seed=21)
+        outs = [f.result(timeout=120)
+                for f in [eng.submit(p, m) for p, m in first]]
+        quiet = eng.stats()
+        assert quiet["decode_iterations"] > 0
+        assert quiet["sampled_waves"] == 0
+        jobs = ([(p, m + 20, {}) for p, m in _workload(5, seed=22)]
+                + [([3, 4, 5], 7, kw)]
+                + [(p, m + 20, {}) for p, m in _workload(3, seed=23)])
+        outs += [f.result(timeout=120)
+                 for f in [eng.submit(p, m, **k) for p, m, k in jobs]]
+        busy = eng.stats()
+        assert eng.assert_no_retraces() == 0
+    for (p, m, k), o in zip([(p, m, {}) for p, m in first] + jobs, outs):
+        np.testing.assert_array_equal(
+            o, ref.reference_generate(p, m, **k),
+            err_msg=f"engine diverged for prompt {p} sampling {k}")
+    waves = busy["sampled_waves"]
+    # the first token comes from prefill; plain decode then emits K a wave
+    assert (waves == 3) if not draft else (1 <= waves <= 6)
+    assert waves < busy["decode_iterations"] - quiet["decode_iterations"]
+
+
+def _vmapped_sampler(*args):
+    """What the branch must never become: under `vmap` a `lax.cond` is a
+    select and both sides run."""
+    return jax.vmap(
+        lambda *row: _sample_tokens(*(a[None] for a in row))[0])(*args)
+
+
+@pytest.mark.parametrize("program", ["sample_first", "decode", "spec_decode",
+                                     "vmapped"])
+def test_the_sort_lies_behind_one_conditional(decoder, program):
+    """From the compiled HLO: every `sort` lies inside a branch computation
+    of a `conditional` and there is one conditional per sampler call (the
+    decode programs call it once, in the scanned micro-step). The vmapped
+    sampler is the control that the check can fail."""
+    model, _ = decoder
+    args = _sampler_case("all_greedy", "float32", lanes=4, vocab=CFG["vocab"])
+    if program == "sample_first":
+        sampling.sample_first(*args)
+        compiled = sampling._SAMPLE_JIT.lower(*args).compile()
+    elif program == "vmapped":
+        compiled = jax.jit(_vmapped_sampler).lower(*args).compile()
+    else:
+        eng = serve.ContinuousEngine(          # never started: shapes only
+            model, max_slots=4, decode_steps=3,
+            draft_tokens=2 if program == "spec_decode" else 0)
+        compiled = eng.lowered_programs()["decode"].compile()
+    sorts, conditionals, unguarded = sorts_and_conditionals(compiled)
+    assert sorts >= 1, "the sampled body is not in the program"
+    if program == "vmapped":
+        assert conditionals == 0 and unguarded
+    else:
+        assert conditionals == 1
+        assert unguarded == [], f"sorts that always run: {unguarded}"
 
 
 def test_submit_validates_sampling_params(decoder):

@@ -34,6 +34,7 @@ from incubator_mxnet_tpu.models import hybrid_decoder as hd  # noqa: E402
 from incubator_mxnet_tpu.ops import fused  # noqa: E402
 from incubator_mxnet_tpu.serve.kv_pool import CacheKindError  # noqa: E402
 from chipbench.reference import sambay  # noqa: E402
+from hlo_branches import sorts_and_conditionals  # noqa: E402
 
 TOL = 3e-5
 WINDOW = 8
@@ -289,6 +290,11 @@ def test_planted_window_off_by_one_fails_the_tolerance(tiny):
 # ---------------------------------------------------------------------------
 # through the engine
 # ---------------------------------------------------------------------------
+ENGINE = dict(max_slots=3, prefill_lanes=2, prefill_window=6,
+              prefill_budget=64, decode_steps=3, prefix_cache_slots=0,
+              draft_tokens=0)
+
+
 def served_gap(tiny, prompt, tokens):
     """How far below the reference's best logit the served tokens lie."""
     return sambay.served_gaps(tiny["forward"], tiny["params"], prompt,
@@ -304,10 +310,7 @@ def test_engine_lanes_join_and_leave_mid_wave(tiny):
     jobs = [(prompt_of(n, 10 + i), out) for i, (n, out) in enumerate(
         [(3, 9), (19, 4), (8, 17), (30, 2), (11, 11), (5, 1), (23, 7)])]
     want = [model.reference_generate(p, n, window=6) for p, n in jobs]
-    with serve.ContinuousEngine(model, max_slots=3, prefill_lanes=2,
-                                prefill_window=6, prefill_budget=64,
-                                decode_steps=3, prefix_cache_slots=0,
-                                draft_tokens=0) as eng:
+    with serve.ContinuousEngine(model, **ENGINE) as eng:
         futs = [eng.submit(p, n) for p, n in jobs]
         got = [f.result(timeout=120) for f in futs]
         stats = eng.stats()
@@ -326,6 +329,53 @@ def test_engine_lanes_join_and_leave_mid_wave(tiny):
         "full": 2 * 4 * 32 * 4, "ring": 2 * 2 * 4 * 32 * 4,
         "state": 3 * (4 * 128 * 4 + 3 * 128 * 4)}
     assert pool.bytes_by_kind([40])["ring"] == 2 * 2 * 8 * 32 * 4
+
+
+def test_sampled_request_joins_greedy_waves_and_leaves(tiny):
+    """The shared sampler's branch through this model's decode program: no
+    wave of a greedy run counts as sampled; a short sampled request (the
+    model card's temperature 0.6, top-p 0.95) queues behind greedy ones,
+    rides with lanes that are mid-decode and leaves before them; every
+    request draws its 1-slot reference's tokens, with no retrace, and
+    `sampled_waves` reads the waves that request lived through."""
+    model = tiny["model"]
+    kw = {"temperature": 0.6, "top_p": 0.95, "seed": 9}
+    quiet_jobs = [(prompt_of(n, 30 + i), out, {}) for i, (n, out) in
+                  enumerate([(4, 6), (9, 3), (13, 8)])]
+    jobs = ([(prompt_of(n, 40 + i), out, {}) for i, (n, out) in
+             enumerate([(5, 21), (17, 26), (8, 24), (3, 30)])]
+            + [(prompt_of(7, 50), 7, kw)]
+            + [(prompt_of(n, 60 + i), out, {}) for i, (n, out) in
+               enumerate([(6, 25), (10, 28)])])
+    want = [model.reference_generate(p, n, window=6, **k)
+            for p, n, k in quiet_jobs + jobs]
+    greedy_twin = model.reference_generate(jobs[4][0], 7, window=6)
+    assert not np.array_equal(want[len(quiet_jobs) + 4], greedy_twin)
+    with serve.ContinuousEngine(model, **ENGINE) as eng:
+        got = [f.result(timeout=120) for f in
+               [eng.submit(p, n) for p, n, _ in quiet_jobs]]
+        quiet = eng.stats()
+        assert quiet["decode_iterations"] > 0
+        assert quiet["sampled_waves"] == 0
+        got += [f.result(timeout=120) for f in
+                [eng.submit(p, n, **k) for p, n, k in jobs]]
+        busy = eng.stats()
+        assert eng.retraces_after_warmup() == 0
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+    # one token from prefill, then three a wave: six tokens, two waves
+    assert busy["sampled_waves"] == 2
+    assert busy["decode_iterations"] - quiet["decode_iterations"] > 2
+
+
+def test_decode_program_sorts_only_behind_the_conditional(tiny):
+    """From the compiled decode program's HLO: the sampler's sort lies in a
+    branch computation of the one `conditional` of the scanned micro-step."""
+    eng = serve.ContinuousEngine(tiny["model"], **ENGINE)   # never started
+    sorts, conditionals, unguarded = sorts_and_conditionals(
+        eng.lowered_programs()["decode"].compile())
+    assert sorts >= 1 and conditionals == 1
+    assert unguarded == [], f"sorts that always run: {unguarded}"
 
 
 def test_slot_reused_after_a_poison_fill_of_every_leaf(tiny):

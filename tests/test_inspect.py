@@ -149,6 +149,27 @@ def test_parse_module_without_name_sigils():
     assert cost_bare["flops"] == cost_sig["flops"]
 
 
+def test_parse_module_keeps_computations_with_wide_tuple_parameters():
+    """XLA marks every fifth element of a tuple shape `/*index=5*/`; the
+    `=` in the mark is not an instruction's, and a loop body or a branch
+    with such a parameter (every scanned decode step) is a computation."""
+    text = """\
+HloModule wide
+%body (arg: (s32[], f32[4], f32[4], f32[4], f32[4], /*index=5*/f32[4])) -> (s32[], f32[4], f32[4], f32[4], f32[4], /*index=5*/f32[4]) {
+  %arg = (s32[], f32[4], f32[4], f32[4], f32[4], /*index=5*/f32[4]) parameter(0)
+  %x = f32[4]{0} get-tuple-element(%arg), index=5
+  ROOT %sort.1 = f32[4]{0} sort(%x), dimensions={0}, to_apply=%lt
+}
+ENTRY %main (a: f32[4]) -> f32[4] {
+  ROOT %a = f32[4]{0} parameter(0)
+}
+"""
+    m = hlo.parse_module(text)
+    assert set(m.computations) == {"body", "main"}
+    assert [i.opcode for i in m.computations["body"].instructions] == [
+        "parameter", "get-tuple-element", "sort"]
+
+
 def test_kernel_units_descend_call_wrappers():
     m = hlo.parse_module(HLO_TEXT)
     units = roofline.kernel_units(m)

@@ -269,6 +269,28 @@ def test_armed_without_a_session_spans_reach_no_buffer(monkeypatch):
     assert telemetry.snapshot()["flightrec.events"] - ring < waves
 
 
+def test_decode_span_says_how_many_lanes_sampled():
+    """`serve.decode_batch` carries `sampled=<lanes>`: 0 on a greedy wave,
+    the lanes with `temperature > 0` otherwise; the waves with any are
+    `stats()["sampled_waves"]`."""
+    eng = toy_engine().start()
+    profiler.start()
+    try:
+        eng.generate([1, 2, 3], 6)
+        greedy = [e["args"]["sampled"] for e in profiler.events("serve")
+                  if e["name"] == "serve.decode_batch"]
+        eng.generate([4, 5, 6], 7, temperature=0.8, top_p=0.9, seed=2)
+        both = [e["args"]["sampled"] for e in profiler.events("serve")
+                if e["name"] == "serve.decode_batch"]
+        stats = eng.stats()
+    finally:
+        profiler.stop()
+        eng.close()
+    assert greedy and set(greedy) == {0}
+    assert both[len(greedy):] == [1, 1, 1]       # 1 + 3 waves x 2 tokens
+    assert stats["sampled_waves"] == 3
+
+
 def test_event_buffer_is_bounded():
     assert profiler._events.maxlen == profiler.EVENTS_CAP
     profiler.start()
