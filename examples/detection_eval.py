@@ -1,10 +1,10 @@
 """Detection 'works' proof (VERDICT-r4 Weak #8): train the SSD operator
 tail (multibox_prior -> multibox_target -> NMS detection) and record a
-loss + VOC07 mAP TRAJECTORY on a held-out set, written as a JSON artifact
-(benchmark/results/detection_eval_r5.json) so the detection preset has a
-measured learning curve, not just a smoke run.
+loss + VOC07 mAP TRAJECTORY on a held-out set, written as JSON where
+`--json` says, so the detection preset has a learning curve, not just a
+smoke run.
 
-    python benchmark/detection_eval.py [--steps 160] [--json out.json]
+    python examples/detection_eval.py [--steps 160] [--json out.json]
 """
 import argparse
 import importlib.util
@@ -91,8 +91,7 @@ def run(steps=160, batch_size=16, eval_every=20, seed=0):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=160)
-    ap.add_argument("--json", default=os.path.join(
-        REPO, "benchmark", "results", "detection_eval_r5.json"))
+    ap.add_argument("--json", default="detection_eval.json")
     args = ap.parse_args()
     traj = run(steps=args.steps)
     out = {

@@ -39,8 +39,8 @@ _register_env("MXNET_COMPILE_CACHE_DIR", str, None,
               "Ignored when JAX_COMPILATION_CACHE_DIR is set: jax already "
               "uses that directory and no other is set in code")
 
-# where the repo's own runners (chip_smoke.py, bench.py, fleet_bench,
-# crashtest) keep the cache when no variable places it: a FIXED path inside
+# where the repo's own runners (chip_smoke.py, tools/crashtest.py) keep
+# the cache when no variable places it: a FIXED path inside
 # the checkout (the path is part of a cache key's provenance — a directory
 # that moves between runs never hits)
 CHECKOUT_COMPILE_CACHE_DIR = os.path.join(

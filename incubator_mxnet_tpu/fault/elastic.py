@@ -280,8 +280,8 @@ class ElasticTrainer:
     def overlap_fraction(self):
         """Event-based overlap: the fraction of steps whose reduce-scatter
         bucket dispatch completed while the backward program was provably
-        still in flight (`Array.is_ready()` on the last gradient — the
-        same certificate `overlap_bench` uses). None before any step."""
+        still in flight (`Array.is_ready()` on the last gradient). None
+        before any step."""
         if not self._overlap_total:
             return None
         return self._overlap_hits / self._overlap_total

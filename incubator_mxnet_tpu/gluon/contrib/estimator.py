@@ -287,8 +287,7 @@ class StepTimelineHandler(TrainBegin, BatchBegin, BatchEnd, TrainEnd):
 
     `flops_per_batch`: FLOPs of one train step. Default: on the first
     batch, XLA-count the forward via `telemetry.block_fwd_flops` and use
-    the conventional 3x (fwd + 2x bwd) — the same numerator bench.py
-    uses. Pass `flops_per_batch=None, auto_flops=False` to skip MFU.
+    the conventional 3x (fwd + 2x bwd). Pass `flops_per_batch=None, auto_flops=False` to skip MFU.
     `peak_flops`: denominator; default `telemetry.device_peak_flops()`
     (None on CPU — MFU is then omitted rather than wrong).
 

@@ -233,14 +233,14 @@ class FusedTrainStep:
         # residuals — None (XLA default), "full" (recompute the whole
         # forward; near-zero residual traffic), "dots" (save matmul
         # outputs, recompute elementwise/conv chains). Which wins is
-        # hardware-bound: bench.py A/Bs them on the attached chip.
+        # hardware-bound; not measured on the chip (no cell sets it).
         if remat not in (None, "full", "dots"):
             raise MXNetError(f"unknown remat policy {remat!r}")
         self._remat = remat
         # donate: hand the trainable weight + optimizer-state buffers to
         # XLA (in-place update, halves the peak weight footprint). The
-        # off switch is the other arm of the bench policy sweep — some
-        # program shapes schedule better without donation aliasing.
+        # off switch is for program shapes that schedule better without
+        # donation aliasing.
         self._donate = bool(donate)
         # fused kernel tier (ops/fused.py) — default ON for the fused
         # step per MXNET_USE_FUSION; the scope engages around the
@@ -423,8 +423,7 @@ class FusedTrainStep:
             return list(new_w), list(new_s), losses, extras, aux
 
         # donate only the trainable weight + optimizer-state buffers; frozen
-        # params keep their buffers live across calls. donate=False is the
-        # other arm of the bench policy sweep (docs/PERF.md "Kernel tier").
+        # params keep their buffers live across calls.
         from ... import sanitize as _sanitize
         donate = (0, 1) if self._donate else ()
         return _sanitize.maybe_wrap_donated(

@@ -12,8 +12,8 @@ rank offenders by estimated time share:
     print(mxinspect.render_markdown(report))
 
 CLI: `python tools/offenders.py --model resnet18 --json out.json`.
-Calibration: `python tools/bandwidth.py --calib` writes
-`benchmark/results/roofline_calib.json` (see docs/PERF.md). Knobs:
+Calibration: `python tools/bandwidth.py --calib` writes a file for
+`MXNET_INSPECT_CALIB` (see docs/PERF.md). Knobs:
 `MXNET_INSPECT_TOP_K`, `MXNET_INSPECT_CALIB`.
 Catalog of the `inspect.*` registry metrics: docs/OBSERVABILITY.md.
 """
@@ -29,7 +29,7 @@ from .report import (inspect_step, inspect_compiled, inspect_hlo_text,
 from .memory import (memory_plan, plan_from_compiled, assert_donation,
                      collective_memory_plans, active_plans, note_plan,
                      tag, register, current_tag, census, census_diff,
-                     leakcheck, live_bytes, MemoryLeakError,
+                     leakcheck, MemoryLeakError,
                      is_oom_error, on_oom, oom_report, dump_oom,
                      install_oom_hook)
 
@@ -44,7 +44,7 @@ __all__ = [
     "memory_plan", "plan_from_compiled", "assert_donation",
     "collective_memory_plans", "active_plans", "note_plan",
     "tag", "register", "current_tag", "census", "census_diff",
-    "leakcheck", "live_bytes", "MemoryLeakError",
+    "leakcheck", "MemoryLeakError",
     "is_oom_error", "on_oom", "oom_report", "dump_oom",
     "install_oom_hook",
 ]

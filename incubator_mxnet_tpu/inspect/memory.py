@@ -47,10 +47,8 @@ four connected pieces:
     `peak_hbm_bytes` lane from the same `profiler.read_memory_sample()`
     the MemoryMonitor uses (honest `device` vs `host_rss` source stamp).
 
-  * **Trend gating** — the bench `memory` phase emits
-    `train_peak_hbm_mb` / `serve_kv_slab_mb` /
-    `mem_plan_vs_measured_ratio` / `leakcheck_growth_mb`, gated in
-    `tools/benchdiff.py`; `tools/memscope.py` is the operator CLI.
+  * **Operator CLI** — `tools/memscope.py`. The chip's peak is the
+    benchmark's `memory_peak_bytes` (PERF.md §4, §7).
 
 Owner names are flat `[a-z0-9_]+` tokens ON PURPOSE: dotted names would
 collide with the telemetry metric namespace in the docs tables, and
@@ -85,7 +83,7 @@ __all__ = [
     "memory_plan", "plan_from_compiled", "assert_donation",
     "collective_memory_plans", "active_plans", "note_plan",
     "tag", "register", "current_tag", "census", "census_diff",
-    "leakcheck", "live_bytes", "MemoryLeakError",
+    "leakcheck", "MemoryLeakError",
     "is_oom_error", "on_oom", "oom_report", "dump_oom",
     "install_oom_hook",
 ]
@@ -369,19 +367,6 @@ def registered_count():
     """Live registry entries (test/diagnostic aid)."""
     with _reg_lock:
         return len(_owned)
-
-
-def live_bytes():
-    """Total bytes of every live jax array (census totals without the
-    grouping — the cheap measured-peak probe the bench phase samples)."""
-    import jax
-    total = 0
-    for arr in jax.live_arrays():
-        try:
-            total += int(arr.nbytes)
-        except Exception:
-            continue
-    return total
 
 
 def census(depth=None):

@@ -1,5 +1,4 @@
-"""Offender attribution reports: rank a compiled step's fusions for humans
-and for the bench trend.
+"""Offender attribution reports: rank a compiled step's fusions.
 
 `inspect_step(obj, *args)` lowers+compiles whatever it is handed — a
 `gluon.contrib.FusedTrainStep`, a `deploy.ExportedModel` bucket program, a
@@ -16,9 +15,9 @@ granularities:
                    fusion after its constituent ops, so same pattern
                    across 20 ResNet layers = one class). A custom kernel
                    replaces a *class*, so this is the actionable ranking
-                   and the one the coverage/trend numbers gate.
+                   and the one the coverage number is taken over.
 
-Trend scalars (bench.py `offenders` phase, benchdiff TREND_KEYS):
+Summary scalars of a report:
 
   offender_top1_share       est. time share of the worst fusion class
   memory_bound_byte_share   fraction of step bytes in memory-bound units
@@ -26,7 +25,7 @@ Trend scalars (bench.py `offenders` phase, benchdiff TREND_KEYS):
                             peak flops) — the MFU the CURRENT fusion
                             structure could reach if every unit hit its
                             roofline bound; the honest target for kernel
-                            work, diffable round over round
+                            work
 
 With an `execute=` callback the report also carries the wall-clock time
 of real executions (`measured_wall_ms`); its shares stay cost-model
@@ -48,12 +47,10 @@ __all__ = ["inspect_step", "inspect_compiled", "render_markdown",
 
 _register_env("MXNET_INSPECT_TOP_K", int, 10,
               "Offender-report depth: fusions listed by tools/offenders.py "
-              "and the bench offenders phase (totals always cover the "
-              "whole module)")
+              "(totals always cover the whole module)")
 _register_env("MXNET_INSPECT_CALIB", str, None,
-              "Path to a roofline calibration JSON overriding "
-              "benchmark/results/roofline_calib.json "
-              "(see tools/bandwidth.py --calib)")
+              "Path to a roofline calibration JSON overriding the "
+              "platform's peak table (see tools/bandwidth.py --calib)")
 
 # inspection runs land in the registry so dashboards see profiling activity
 INSPECT_RUNS = REGISTRY.counter(

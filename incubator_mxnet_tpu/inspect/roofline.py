@@ -20,33 +20,29 @@ and models each one:
   est_time_s  max(flops / peak_flops, bytes / peak_bw) — the roofline
               execution-time estimate used to rank offenders.
 
-Peaks come from a calibration artifact (`benchmark/results/
-roofline_calib.json`, written by `tools/bandwidth.py --calib`) so the ridge
-point tracks the attached hardware. With no calibration, a TPU v5e takes
-its published bf16 peak (`telemetry.device_peak_flops`) and HBM bandwidth;
-any other TPU `device_kind` is an error, not a default, because only the
+Peaks come from a calibration file that the caller or `MXNET_INSPECT_CALIB`
+names (written by `tools/bandwidth.py --calib`), so the ridge point tracks
+the attached hardware; the package reads no file of its own accord. With
+no calibration, a TPU v5e takes its published bf16 peak
+(`telemetry.device_peak_flops`) and HBM bandwidth; any other TPU
+`device_kind` is an error, not a default, because only the
 v5e's bandwidth is recorded here. The CPU takes a modest fixed row.
 """
 from __future__ import annotations
 
 import json
-import os
 
 from ..base import MXNetError, get_env
 from . import hlo as _hlo
 
 __all__ = ["instr_flops", "unit_cost", "kernel_units", "analyze_module",
            "analyze_compiled", "load_calibration", "classify",
-           "cost_analysis_summary", "callable_cost", "CALIB_PATH",
+           "cost_analysis_summary", "callable_cost",
            "DEFAULT_CALIBRATIONS"]
 
-# repo-relative home of the calibration artifact (tools/bandwidth.py --calib)
-CALIB_PATH = os.path.join("benchmark", "results", "roofline_calib.json")
-
-# fallback when no measured calibration exists and the platform is not a
-# TPU: deliberately modest figures so CPU-only smoke runs classify sanely;
-# real numbers come from the calib artifact. A TPU's fallback is its
-# published spec (`_tpu_spec_calibration`).
+# fallback when no calibration is named and the platform is not a TPU:
+# deliberately modest figures so CPU-only smoke runs classify sanely. A
+# TPU's fallback is its published spec (`_tpu_spec_calibration`).
 DEFAULT_CALIBRATIONS = {
     "cpu": {"peak_flops": 1.0e11, "peak_bytes_per_sec": 20e9,
             "source": "spec-fallback"},
@@ -281,35 +277,23 @@ def classify(intensity, ridge):
 
 def load_calibration(path=None, platform=None):
     """Resolve the roofline peaks: explicit path > MXNET_INSPECT_CALIB >
-    the committed `benchmark/results/roofline_calib.json` > the platform
-    fallback (a TPU's published spec, which raises for a device_kind it
-    does not know; the fixed CPU row otherwise). Returns a dict with at least `peak_flops`,
-    `peak_bytes_per_sec`, `ridge_flop_per_byte`, `source`."""
+    the platform's table (a TPU's published spec, which raises for a
+    device_kind it does not know; the fixed CPU row otherwise). A named
+    file that cannot be read or lacks a peak is passed over. Returns a dict
+    with at least `peak_flops`, `peak_bytes_per_sec`, `ridge_flop_per_byte`,
+    `source`."""
     if platform is None:
         platform = _ambient_platform()
-    candidates = []
-    if path:
-        candidates.append((path, True))      # explicit: trust the caller
-    envp = get_env("MXNET_INSPECT_CALIB", None, typ=str)
-    if envp:
-        candidates.append((envp, True))
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    candidates.append((os.path.join(root, CALIB_PATH), False))
     calib = None
-    for cand, explicit in candidates:
+    for cand in (path, get_env("MXNET_INSPECT_CALIB", None, typ=str)):
+        if not cand:
+            continue
         try:
             with open(cand) as f:
                 data = json.load(f)
         except (OSError, ValueError):
             continue
         if not (data.get("peak_flops") and data.get("peak_bytes_per_sec")):
-            continue
-        # the committed artifact may have been calibrated on a different
-        # backend (a CPU-container calib must not set a TPU run's ridge);
-        # explicit paths (arg / env) override the check
-        if not explicit and data.get("platform") \
-                and data["platform"] != platform:
             continue
         calib = dict(data)
         calib.setdefault("source", cand)
@@ -420,7 +404,7 @@ def cost_analysis_summary(compiled):
 
 def callable_cost(fn, *args, calib=None):
     """Estimated cost of one execution of `fn(*args)` for the per-op
-    tables (benchmark/opperf.py): flops + bytes + arithmetic intensity +
+    tables (tools/opperf.py): flops + bytes + arithmetic intensity +
     roofline class. Prefers XLA's own cost analysis; falls back to the
     HLO shape model for bytes when the backend does not report them
     (`bytes_source: "hlo-model"`), and to the HLO model for flops when

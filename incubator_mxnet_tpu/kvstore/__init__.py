@@ -44,8 +44,8 @@ __all__ = ["KVStore", "KVStoreBase", "create", "KV_STATS",
 # DeviceFeed stall clock). Increments under _KV_STATS_LOCK; the `*_us`
 # clocks are DISPATCH-side wall time of the bucketed collective
 # (concatenate + collective issue + result split) — buckets dispatch
-# asynchronously, so device-side reduction overlap is measured by
-# benchmark/overlap_bench.py and benchmark/elastic_bench.py, not here.
+# asynchronously, so device-side reduction overlap is not in these clocks
+# (`ElasticTrainer.overlap_fraction()` counts it by events).
 _KV_STATS_LOCK = threading.Lock()
 
 KV_STATS = _stats_group("kvstore", {
@@ -191,7 +191,7 @@ def reduce_scatter_buckets(grads, mesh, axis="dp", scale=None,
 
     Buckets dispatch asynchronously, so bucket k+1's issue overlaps bucket
     k's reduction AND the still-in-flight backward that produced the
-    grads (the overlap `benchmark/elastic_bench.py` measures). Each bucket
+    grads. Each bucket
     hits the `kvstore.reduce_scatter` fault point and lands in
     KV_STATS reduce_scatter_us/buckets/bytes + the `kv.reduce_scatter`
     span lane.

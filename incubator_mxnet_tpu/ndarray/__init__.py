@@ -503,7 +503,7 @@ class NDArray:
             # them (segment const slots; jit traces them weak-typed exactly
             # like the eager jnp call), and skipping the NDArray ctor saves
             # a per-op host device_put — the single biggest cost of eager
-            # scalar arithmetic (PR2 dispatch bench). Weak typing also
+            # scalar arithmetic. Weak typing also
             # matches the reference's dtype-preserving scalar ops
             # (bf16 array * 2.0 stays bf16).
             a, b = (other, self) if reflect else (self, other)

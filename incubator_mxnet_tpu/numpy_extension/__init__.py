@@ -292,7 +292,7 @@ def flash_attention(query, key, value, causal=False, scale=None,
                     block_q=None, block_k=None):
     """Blockwise (flash) attention over (batch*heads, T, head_dim) —
     the ops.pallas_attention kernel registered as a first-class op:
-    dispatch record + AMP class, so opperf, AMP lists and inspect
+    dispatch record + AMP class, so tools/opperf.py, AMP lists and inspect
     reports see it like any other op."""
     from ..ops.pallas_attention import flash_attention as _fa
     kw = dict(causal=causal, scale=scale, block_q=block_q,

@@ -4,9 +4,9 @@ Reference: MXNet's `MXNET_USE_FUSION` pointwise RTC fusion
 (src/operator/fusion/fused_op.cu) and the oneDNN/AMP graph passes fused
 exactly these chains on GPU/CPU. TPU-native: the `mx.inspect` roofline
 attribution (PR 7) ranks the compiled step's fusions by bytes moved, and
-this module hand-fuses the top memory-bound classes it found
-(benchmark/results/offenders_resnet18_r09.json — 86.7% of step bytes are
-0.18–0.62-intensity fusions):
+this module hand-fuses the top memory-bound classes it found in the
+ResNet-18 train step (`tools/offenders.py --model resnet18` prints the
+ranking; the classes' intensities below are the cost model's):
 
   op                      kills offender class              kernel
   ----------------------  --------------------------------  ----------------
@@ -641,8 +641,8 @@ def image_augment(images, key, mean=None, std=None, crop_hw=None,
     float array already in [0, 1] (gradients flow through the affine for
     float inputs; the crop/mirror randomness does not block them).
     `key`: PRNGKey DATA as a uint32 (2,) array — an array argument, not a
-    static seed, so per-(epoch, batch) keys swap without a retrace (the
-    zero-retrace contract io_bench asserts). `mean`/`std` are static
+    static seed, so per-(epoch, batch) keys swap without a retrace.
+    `mean`/`std` are static
     per-channel tuples in [0, 1] units; `crop_hw`/`rand_mirror`/`out_dtype`
     are static too.
 

@@ -3,8 +3,8 @@
 Reference contrast: MXNet's `USE_FUSION` RTC machinery generated pointwise
 CUDA kernels at runtime (src/operator/fusion/fused_op.cu); here the worst
 memory-bound offender classes the `mx.inspect` roofline attribution ranks
-(benchmark/results/offenders_resnet18_r09.json) get hand-written TPU
-kernels instead:
+(`tools/offenders.py --model resnet18`) get hand-written TPU kernels
+instead:
 
   * `apply_scale_shift_act` — ONE pass of `act(x*scale + shift [+ res])`
     over a (rows, channels) view: the normalize-scale-shift(-residual-relu)

@@ -110,8 +110,7 @@ class OpInfo:
     fused kernels): the last layout the op dispatched with ("NHWC"/"NCHW"
     ...), written by the npx wrappers via `note_layout`. Introspection for
     the layout-autotune lever (ROADMAP item 2): `get_op(name).layout`
-    shows which layout a model actually ran, and the bench `fused_sweep`
-    phase records its NHWC/NCHW A-B winner next to it."""
+    shows which layout a model actually ran."""
 
     __slots__ = ("name", "fn", "amp", "doc", "key", "layout")
 

@@ -41,8 +41,8 @@ returns the jitted program unchanged):
 
 Every violation also lands in the flight recorder
 (`telemetry.flightrec_record`), so the crash black box names the
-contract breach. Overhead on the serve quick bench is stamped in
-``benchmark/results/sanitize_r20.json`` (guarded <= 5%).
+contract breach. The modes' cost on the chip is not measured: no cell
+runs with `MXNET_SANITIZE` set.
 """
 from __future__ import annotations
 
@@ -415,8 +415,8 @@ class SlotCanary:
             for leaf in pool.spec}
 
         # ONE compiled fused probe per wave (every leaf -> a scalar):
-        # a naive per-leaf fancy-index gather + np.asarray costs ~3ms
-        # on the quick-bench host, ~100x this
+        # a per-leaf fancy-index gather + np.asarray would be a host
+        # round trip for every leaf
         def _ok(leaves):
             ok = True
             for name, at in self._probes.items():

@@ -35,8 +35,8 @@ plain device buffers, zero retraces):
   * **O(load) warmup**: `deploy.maybe_enable_compile_cache()` wires
     `MXNET_COMPILE_CACHE_DIR` onto jax's persistent compilation cache
     before the first compile, so a second replica (or a restart) loads
-    the serialized executables instead of recompiling — measured by
-    `benchmark/serve_bench.py --autoregressive` (compile-skip section).
+    the serialized executables instead of recompiling (`setup_s` in
+    PERF.md §2 is where a cell pays for it).
 
 Tracing: one request = ONE trace across its N iterations. The root
 `serve.request` context is minted at `submit()` (PR-13 plumbing); the
@@ -46,7 +46,7 @@ closes the root at retirement — while the profiler collects, the whole
 request renders as a single tree in the Chrome trace.
 
 The bundled `CachedDecoder` is a small pre-norm transformer decoder over
-the slot pool — the LLM-shaped model side for tests and the bench; any
+the slot pool — the LLM-shaped model side for tests and the benchmark; any
 object with the same `prefill`/`decode`/`compile_cache_size` contract
 serves.
 
@@ -1602,8 +1602,7 @@ class ContinuousEngine:
                     c["prefix_hits"]
                     / (c["prefix_hits"] + c["prefix_misses"]), 4)
             if c["prefill_tokens"] + c["prefix_cached_tokens"] > 0:
-                # share of prompt tokens served by copy, not compute —
-                # the bench's prefill_cached_token_share trend number
+                # share of prompt tokens served by copy, not compute
                 out["prefill_cached_token_share"] = round(
                     c["prefix_cached_tokens"]
                     / (c["prefill_tokens"]

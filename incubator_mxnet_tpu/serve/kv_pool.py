@@ -29,8 +29,7 @@ reads and writes, never as per-request Python objects the tracer sees):
     written, so slot reuse never requantizes and a stale scale is exactly
     as unreachable as a stale KV row (the same `[0, cur_len]` mask
     governs both; `poison()` poisons the scales too so tests can prove
-    it). ~3-4x more slots per HBM byte (`slots_per_gb()`), the number
-    the memory bench commits.
+    it). ~3-4x more slots per HBM byte (`slots_per_gb()`).
 
 A pool is built from a CACHE SPEC: a list of `CacheLeaf`s, each naming
 one device array with a row per slot (`shape` is one row's), its dtype and
@@ -125,8 +124,8 @@ def kvpool_stats(reset=False):
 
 
 # the single biggest planned allocation in serving, previously invisible:
-# set at every carve (allocate/reallocate/poison) so dashboards and the
-# memory bench see the slab without holding a pool reference. Level, not
+# set at every carve (allocate/reallocate/poison) so dashboards see the
+# slab without holding a pool reference. Level, not
 # flow — survives snapshot(reset=True). With several pools alive it holds
 # the most recent carve; per-pool numbers live on pool.stats().
 _SLAB_GAUGE = REGISTRY.gauge(
@@ -313,9 +312,8 @@ class KVCachePool:
         return out
 
     def slots_per_gb(self):
-        """KV slots one GiB of device memory buys at this pool's shape —
-        the capacity number the memory bench trends (int8 pools fit ~3-4x
-        the slots of float32 at the same (layers, max_len, heads, dim))."""
+        """KV slots one GiB of device memory buys at this pool's shape
+        (int8 pools fit ~3-4x the slots of float32 at the same (layers, max_len, heads, dim))."""
         return round((1 << 30) / self.bytes_per_slot(), 2)
 
     def swap_buffers(self, *cache):

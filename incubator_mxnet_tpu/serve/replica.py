@@ -39,8 +39,7 @@ the actually-bound port in its hello.
 Warm start: the spawning supervisor sets `MXNET_COMPILE_CACHE_DIR`
 (inherited here), so `CachedDecoder.__init__` arms the persistent
 compilation cache and `ContinuousEngine.start()` deserializes both step
-programs instead of recompiling (the 2.37x warm skip measured in
-`serve_continuous_r14.json`).
+programs instead of recompiling.
 """
 from __future__ import annotations
 
@@ -222,7 +221,7 @@ def _resolve_profile(spec):
 
 def _build_engine(spec):
     """Engine from a version-pinned spec manifest. `stub: true` selects
-    the jax-free protocol stub (tests/bench harness plumbing); otherwise a
+    the jax-free protocol stub (test plumbing); otherwise a
     CachedDecoder + ContinuousEngine (warm via MXNET_COMPILE_CACHE_DIR,
     tuned via the activated deployment profile)."""
     if spec.get("stub"):

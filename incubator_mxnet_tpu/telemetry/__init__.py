@@ -8,7 +8,7 @@ KVStore server profiling):
                                       process-global REGISTRY
   telemetry.snapshot(reset=False)     flat dict over EVERY counter in the
                                       process — dispatch, serve, feed,
-                                      kvstore, spans, bench — one call
+                                      kvstore, spans — one call
   telemetry.prometheus_text()         Prometheus text exposition (0.0.4)
   telemetry.span("train.step", n=1)   nesting-aware tracer: Chrome-trace
                                       lane + duration histogram
@@ -54,7 +54,7 @@ __all__ = [
     "span", "NO_SPAN", "current_span", "record_span", "StepTimeline",
     "model_flops",
     "block_fwd_flops", "cost_flops", "device_peak_flops",
-    "metrics_text", "scalar_snapshot", "start_metrics_server",
+    "metrics_text", "start_metrics_server",
     "ensure_metrics_server", "mem_on_oom", "mem_install_oom_hook",
     "trace", "TraceContext", "current_context", "attach", "detach",
     "attached", "new_context", "child_context", "flightrec_record",
@@ -68,16 +68,6 @@ _register_env("MXNET_TELEMETRY", bool, True,
 _register_env("MXNET_METRICS_PORT", int, None,
               "When set, serve.Server.start() also serves the telemetry "
               "/metrics endpoint on this port (0 = ephemeral)")
-# bench.py (outside the package) reads these via os.environ; registered
-# here so env_flags() introspection and the ENV_VARS.md table know them
-_register_env("MXNET_BENCH_PHASE_TIMEOUT", float, None,
-              "Per-phase subprocess timeout override for bench.py, "
-              "seconds (a killed phase lands in phase_errors; the rest "
-              "of the run continues)")
-_register_env("MXNET_BENCH_FAULT_PHASE", str, None,
-              "Deterministic bench-phase crash injection: "
-              "'<phase>[:dtype|hang|exit]'")
-
 
 def mem_on_oom(error, where=""):
     """Crash-path-safe proxy to `inspect.memory.on_oom`: the ONE shared
@@ -107,19 +97,6 @@ def mem_install_oom_hook():
 def metrics_text():
     """The full registry in Prometheus text format — what /metrics serves."""
     return prometheus_text()
-
-
-def scalar_snapshot(nonzero=True):
-    """Scalar metrics only (histogram dicts dropped), by default nonzero —
-    the compact registry form the bench artifacts (bench.py phase children,
-    io_bench, serve_bench) embed. One implementation so the artifact shape
-    cannot drift between emitters."""
-    out = {}
-    for k, v in snapshot().items():
-        if isinstance(v, dict) or (nonzero and not v):
-            continue
-        out[k] = v
-    return out
 
 
 def start_metrics_server(port=0, host="127.0.0.1"):

@@ -13,7 +13,7 @@ Two metric tiers, deliberately:
 
   * `Counter`/`Gauge`/`Histogram` objects — registered by name, mutated
     under the single registry lock. For everything OFF the per-op hot path
-    (spans, serving, bench phases, step timelines).
+    (spans, serving, step timelines).
   * `StatsGroup` — a dict subclass that ADOPTS a legacy `*_STATS` counter
     dict into the registry without changing its hot path: `d[k] += 1`
     stays a native dict write (GIL-atomic read-modify-write hazards are
@@ -29,8 +29,8 @@ owner's lock (or the GIL where the owner documents lock-free); group
 snapshot/reset takes the owner lock, never the registry lock, so the only
 cross-lock order is registry -> group and no cycle can form.
 
-This module imports neither jax nor numpy: the mxlint import path and the
-bench orchestrator stay accelerator-free.
+This module imports neither jax nor numpy: the mxlint import path stays
+accelerator-free, and importing the package touches no chip.
 """
 from __future__ import annotations
 

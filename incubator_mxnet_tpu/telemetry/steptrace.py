@@ -22,8 +22,8 @@ single object could answer "where did this step's time go?". Now:
   * `model_flops` / `block_fwd_flops` — the MFU numerator computed ONCE
     from XLA's own cost analysis (`jax.jit(...).lower().cost_analysis()`),
     the same MAC=2 convention as the chip spec, so every reporter
-    (bench.py, estimator.fit) shares one number instead of three copies of
-    3.86-GMAC hand-math.
+    (estimator.fit, FusedTrainStep) shares one number instead of copies of
+    hand-math.
 
 Attribution semantics (documented, not magic): `data_stall_us` and the
 collective clocks (`allreduce_us`, and the ZeRO lanes `reduce_scatter_us`
@@ -348,8 +348,8 @@ class StepTimeline:
                       work, reported for visibility, never subtracted
       step_time_us    sum of the in-step spans (loop-body time only)
 
-    MFU = flops_per_step * steps / total_seconds / peak_flops — the same
-    live-counter number bench.py reports, available to any fit loop.
+    MFU = flops_per_step * steps / total_seconds / peak_flops, available
+    to any fit loop.
     """
 
     def __init__(self, flops_per_step=None, peak_flops=None,
@@ -593,8 +593,7 @@ def block_fwd_flops(net, x):
 
 
 # (platform, device-kind substring) -> advertised bf16 peak FLOP/s (MAC=2).
-# The honest denominator is still a measured attainable (bench.py calib
-# phase); these are the spec fallbacks when no calibration ran.
+# The published figures; what the chip attains is in PERF.md §5.
 _PEAKS = (
     ("tpu", "v5 lite", 197e12),
     ("tpu", "v5e", 197e12),

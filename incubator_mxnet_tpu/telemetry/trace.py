@@ -29,7 +29,7 @@ recorder):
     and `fault._log_event`, so every subsystem that already logs feeds the
     black box for free. `flightrec_dump()` snapshots the ring as one JSON
     file (wired into the fault watchdog, elastic `StragglerTimeout`, serve
-    overload shedding, bench's phase crash handler, and an atexit/SIGTERM
+    overload shedding, and an atexit/SIGTERM
     hook). For SIGKILL parity — where no handler can run — setting
     `MXNET_FLIGHTREC_DIR` additionally SPOOLS each event as one flushed
     JSONL line to `<dir>/flightrec-<pid>.jsonl`: a `write()` that reached
@@ -37,8 +37,8 @@ recorder):
     the in-flight span/step/rank (`tools/crashtest.py --flightrec` proves
     it under a real SIGKILL).
 
-No jax, no numpy: this module stays importable on the mxlint/bench
-orchestrator path like the registry it feeds.
+No jax, no numpy: this module stays importable on the mxlint path like
+the registry it feeds.
 """
 from __future__ import annotations
 

@@ -4,7 +4,7 @@ The repo's perf knobs (serve decode_steps / prefill_lanes / max_slots /
 draft_tokens / kv_dtype, train remat x donate and conv layout, io
 workers / lookahead / shm budget, batcher buckets, dispatch bulk size)
 all have measured, workload-dependent winners — found by hand, PR by PR,
-and living only in committed bench artifacts. This subsystem makes that
+and living only in `tune.search.HAND_TUNED`. This subsystem makes that
 a closed loop, the JAX-native equivalent of the reference's
 oneDNN/autotune layer:
 

@@ -11,7 +11,7 @@ could inherit. The child prints exactly one JSON line on stdout:
 
 and exits non-zero with ``"ok": false`` on any failure, so a crashing or
 hanging configuration is a failed *trial* with a recorded reason, never
-a failed sweep (the `run_phases_isolated` idiom from bench.py).
+a failed sweep.
 
 Each phase measures the knobs the catalog declares for it, on a small
 deterministic workload (seeded `np.random.RandomState`, no wall-clock

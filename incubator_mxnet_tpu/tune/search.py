@@ -2,8 +2,7 @@
 
 Coordinate descent over the declared knob space, per bench phase: start
 from the **hand-tuned committed assignment** (`HAND_TUNED`, the winners
-the repo's benchmark artifacts shipped with — trial 0 measures exactly
-that baseline), then walk each knob of the phase in sorted-name order,
+earlier PRs found by hand — trial 0 measures exactly that baseline), then walk each knob of the phase in sorted-name order,
 trying every declared choice and adopting strict improvements, until the
 trial budget runs out or a full round changes nothing. Everything about
 the schedule is a pure function of (catalog, start, budget, seed) — no
@@ -40,23 +39,21 @@ from .profile import (TUNE_STATS, _STATS_LOCK, DeploymentProfile,
 
 __all__ = ["HAND_TUNED", "sweep", "build_profile", "plan"]
 
-# The hand-tuned committed configurations (benchmark/results/*.json): the
-# winners previous PRs found by hand. Trial 0 of every phase measures
-# THIS assignment, so "profile >= hand-tuned" is checked inside one
+# The hand-tuned configurations: the winners previous PRs found by hand
+# on a CPU host. Trial 0 of every phase measures THIS assignment, so "profile >= hand-tuned" is checked inside one
 # sweep on one host — same process tree, same thermal envelope.
 HAND_TUNED = {
-    # serve_continuous_r14.json / decode_r17.json saturation arm:
-    # slots 32, decode_steps 4, no speculation (spec loses at CPU
-    # saturation), fp KV, derived prefill lanes
+    # saturation arm: slots 32, decode_steps 4, no speculation (spec
+    # loses at CPU saturation), fp KV, derived prefill lanes
     "serve_decode": {"serve.decode_steps": 4, "serve.draft_tokens": 0,
                      "serve.max_slots": 32, "serve.prefill_lanes": None,
                      "serve.kv_dtype": None},
-    # fused_r08/r10: XLA-default remat + donated buffers, NHWC
+    # XLA-default remat + donated buffers, NHWC
     "train_fused": {"train.remat": None, "train.donate": True,
                     "train.conv_layout": "NHWC"},
-    # io_r09: in-process thread pool, lookahead 2, 256 MB ring
+    # in-process thread pool, lookahead 2, 256 MB ring
     "io_pipeline": {"io.workers": 0, "io.lookahead": 2, "io.shm_mb": 256},
-    # serve_r03: the full pow2 bucket ladder
+    # the full pow2 bucket ladder
     "serve_batch": {"serve.batch_buckets": [1, 2, 4, 8, 16, 32]},
     # engine default bulked-segment size
     "dispatch": {"dispatch.bulk_size": 4096},

@@ -22,8 +22,7 @@ Kinds: `categorical` (enumerated values), `int` (small integer set),
 `choices` list — "pow2" is a type statement about the ladder, not an
 implicit generator, so the swept space is auditable by reading this file.
 
-`scrubbed_env()` is the shared scrub-and-set helper (tune trial runner +
-`bench.py` phase isolation): a child measurement process must start from
+`scrubbed_env()` is the trial runner's scrub-and-set helper: a child measurement process must start from
 a baseline with NO ambient knob exports — a knob set by one trial (or by
 the operator's shell) must never leak into the next trial's baseline.
 """
@@ -65,9 +64,9 @@ KNOBS = {
         "kind": "int", "default": 0, "choices": [0, 2, 4, 6],
         "env": "MXNET_SERVE_DRAFT_TOKENS", "phase": "serve_decode",
         "wire": "serve/continuous.py",
-        "help": "speculative decode depth k (0 = off); wins in the "
-                "latency-bound regime, loses at CPU saturation "
-                "(decode_r17.json) — exactly why it is swept per "
+        "help": "speculative decode depth k (0 = off); saves round "
+                "trips when one caller waits, costs a wide verify when "
+                "the slots are full — exactly why it is swept per "
                 "deployment"},
     "serve.kv_dtype": {
         "kind": "categorical", "default": None, "choices": [None, "int8"],
@@ -291,12 +290,12 @@ def scrubbed_env(overrides=None, base=None):
     active profile never leaks into a child's baseline — and `overrides`
     applied on top (value ``None`` deletes). Non-knob infra vars
     (``JAX_PLATFORMS``, ``MXNET_FAULT_SPEC``, ``MXNET_COMPILE_CACHE_DIR``,
-    ``MXNET_BENCH_FAULT_PHASE``, ...) pass through untouched: the scrub
-    removes exactly the tunable surface, nothing else.
+    ...) pass through untouched: the scrub removes exactly the tunable
+    surface, nothing else.
 
-    Used by the tune trial runner AND `bench.py run_phases_isolated` — the
-    fix for knob exports (one trial's, or the operator shell's) silently
-    contaminating the next trial's / the next bench phase's baseline.
+    Used by the tune trial runner — the fix for knob exports (one trial's,
+    or the operator shell's) silently contaminating the next trial's
+    baseline.
     """
     env = dict(os.environ if base is None else base)
     for var in knob_env_vars():

@@ -34,7 +34,7 @@ def _skip_cpu_convergence():
     _skip_cpu_convergence(),
     reason="CPU fallback platform: 120 ResNet-50 train steps blow the CI "
            "budget (MXTPU_NIGHTLY_CPU_CONVERGENCE=1 opts in); the "
-           "real-chip path is exercised by bench.py")
+           "chip's train step is `resnet50_train.feed` (chipbench)")
 def test_resnet50_loss_trajectory_on_chip():
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu import amp, gluon

@@ -662,39 +662,31 @@ def test_compile_cache_dir_warms_second_replica(tmp_path):
     warm, toks_warm = replica()
     # same executables -> same tokens; the warm replica deserializes
     # instead of compiling. On a busy CI host we only assert it is NOT
-    # SLOWER (the committed bench artifact carries the measured speedup)
+    # SLOWER (what a cell pays is `setup_s`, PERF.md)
     assert toks_cold == toks_warm
     assert warm <= cold * 1.2, (cold, warm)
 
 
 _CACHE_RULE_PROG = """
-import json, os, sys
+import json
 import jax
 from incubator_mxnet_tpu import deploy
 if {runner}:
     deploy.default_compile_cache_to_checkout()
 armed = deploy.maybe_enable_compile_cache()
-before = jax.config.jax_compilation_cache_dir
-after = before
-if {experiment}:
-    sys.path.insert(0, os.path.join({repo!r}, "benchmark"))
-    import serve_bench
-    out = serve_bench.bench_compile_cache_skip(quick=True)
-    assert out["compile_cache_entries"] > 0, out
-    after = jax.config.jax_compilation_cache_dir
-print(json.dumps({{"armed": armed, "dir": before, "after": after,
+print(json.dumps({{"armed": armed,
+                  "dir": jax.config.jax_compilation_cache_dir,
                   "checkout": deploy.CHECKOUT_COMPILE_CACHE_DIR}}))
 """
 
 
-def _cache_rule(tmp_path, env, runner=False, experiment=False):
+def _cache_rule(tmp_path, env, runner=False):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     base = {k: v for k, v in os.environ.items()
             if k not in ("JAX_COMPILATION_CACHE_DIR",
                          "MXNET_COMPILE_CACHE_DIR")}
     r = subprocess.run(
-        [sys.executable, "-c", _CACHE_RULE_PROG.format(
-            runner=runner, experiment=experiment, repo=repo)],
+        [sys.executable, "-c", _CACHE_RULE_PROG.format(runner=runner)],
         env=dict(base, JAX_PLATFORMS="cpu", **env), cwd=repo,
         capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, f"stdout={r.stdout}\nstderr={r.stderr}"
@@ -713,8 +705,8 @@ def test_compile_cache_placed_from_outside_is_left_alone(tmp_path):
 
 def test_compile_cache_default_is_the_fixed_checkout_path(tmp_path):
     """No variable: library callers arm nothing; the repo's runners
-    (chip_smoke.py, bench.py: `default_compile_cache_to_checkout()` first)
-    get `<checkout>/.jax_cache`, a fixed path."""
+    (chip_smoke.py, tools/crashtest.py: `default_compile_cache_to_checkout()`
+    first) get `<checkout>/.jax_cache`, a fixed path."""
     got = _cache_rule(tmp_path, {})
     assert got["armed"] is False and got["dir"] is None
     got = _cache_rule(tmp_path, {}, runner=True)
@@ -728,94 +720,41 @@ def test_compile_cache_default_is_the_fixed_checkout_path(tmp_path):
     assert got["dir"] == mine
 
 
-def test_compile_cache_experiment_restores_what_it_found(tmp_path):
-    """serve_bench's cold/warm experiment runs in a private directory and
-    then restores exactly the configured one (it used to reset jax to the
-    MXNET_ value — possibly None — dropping an externally placed cache)."""
-    placed = str(tmp_path / "placed")
-    got = _cache_rule(tmp_path, {"JAX_COMPILATION_CACHE_DIR": placed},
-                      experiment=True)
-    assert got["dir"] == placed and got["after"] == placed
-
-
 # ---------------------------------------------------------------------------
-# bench smoke + committed artifact acceptance
+# closed-loop callers (the benchmark's traffic shape)
 # ---------------------------------------------------------------------------
-def test_serve_bench_autoregressive_quick_smoke(tmp_path):
-    out = tmp_path / "autoreg.json"
-    script = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "serve_bench.py")
-    r = subprocess.run(
-        [sys.executable, script, "--autoregressive", "--quick",
-         "--duration", "1.0", "--out", str(out)],
-        capture_output=True, text=True, timeout=600)
-    assert r.returncode == 0, f"stdout={r.stdout}\nstderr={r.stderr}"
-    data = json.loads(out.read_text())
-    assert data["backend_ok"] is True
-    assert data["meta"]["mode"] == "autoregressive"
-    assert data["continuous"]["decode_tokens_per_sec"] > 0
-    assert data["continuous"]["retraces_after_warmup"] == 0
-    assert data["static"]["decode_tokens_per_sec"] > 0
-    assert data["serve_decode_tokens_per_sec"] > 0
-    assert data["serve_ttft_p99_ms"] > 0
-    assert data["compile_cache_entries"] > 0
+def test_closed_loop_callers_never_retrace_and_drain(decoder):
+    """More callers than slots, each sending its next request when the
+    last one's reply arrives (arrivals depend on completions, as in every
+    serve cell): token-exact, zero retraces after warm-up, the compiled
+    programs are there to be cached, and the pool ends empty."""
+    model, ref = decoder
+    callers, rounds = 6, 3
+    work = _workload(callers * rounds, seed=21)
+    outs, errors = {}, []
 
+    def caller(c):
+        try:
+            for r in range(rounds):
+                i = c * rounds + r
+                p, m = work[i]
+                outs[i] = eng.generate(p, m, timeout=120)
+        except Exception as e:       # surfaced below, not lost in a thread
+            errors.append(e)
 
-def test_committed_continuous_artifact_acceptance():
-    """The committed r14 artifact holds the ISSUE-14 acceptance: >= 2x
-    decode tokens/s over the static batcher at concurrency 32, zero
-    retraces, and a measurable warm-replica compile skip."""
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "results",
-        "serve_continuous_r14.json")
-    data = json.load(open(path))
-    assert data["backend_ok"] is True
-    assert data["meta"]["concurrency"] == 32
-    assert data["serve_continuous_speedup_vs_static"] >= 2.0
-    assert data["continuous"]["retraces_after_warmup"] == 0
-    # continuous TTFT tail beats static's by construction
-    assert data["continuous"]["ttft_p99_ms"] \
-        < data["static"]["ttft_p99_ms"]
-    assert data["serve_compile_cache_warm_speedup"] > 1.2
-    rows = data["autoreg_open_loop"]
-    assert len(rows) >= 4
-    offered = [r["offered_rps"] for r in rows]
-    assert offered == sorted(offered)
-    # the sweep crosses saturation: decode tokens/s stops tracking the
-    # offered load at the top rates
-    assert rows[-1]["achieved_rps"] < 0.9 * rows[-1]["offered_rps"]
-
-
-def test_committed_prefill_artifact_acceptance():
-    """The committed r19 artifact holds the ISSUE-19 acceptance: >= 1.5x
-    prefill tokens/s from prefix caching on the shared-prefix workload
-    at token-exact quality, zero retraces on every arm, and short-
-    request TTFT p99 under long-prompt interference bounded <= 2x the
-    no-long-prompt baseline — with an honest CPU provenance note."""
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "results",
-        "prefill_r19.json")
-    data = json.load(open(path))
-    assert data["backend_ok"] is True
-    assert data["meta"]["mode"] == "shared_prefix"
-    assert data["serve_prefill_speedup_cached"] >= 1.5
-    assert data["cache_on"]["prefill_tokens_per_sec"] \
-        > data["cache_off"]["prefill_tokens_per_sec"]
-    assert data["prefill_cached_token_share"] >= 0.5
-    assert data["cache_on"]["prefix_hit_rate"] > 0.9
-    assert data["prefill_token_exact"] is True
-    assert data["prefill_token_exact_checked"] >= 4
-    # the long-prompt interference bound: chunked prefill keeps short
-    # requests' TTFT p99 within 2x of the longs-free baseline
-    assert data["interference_ttft_p99_blowup"] <= 2.0
-    assert data["serve_ttft_p99_ms_interference"] \
-        <= 2.0 * data["serve_ttft_p99_ms_no_longs"]
-    for arm in ("cache_off", "cache_on", "shorts_alone",
-                "shorts_with_longs"):
-        assert data[arm]["retraces_after_warmup"] == 0, arm
-        assert data[arm].get("errors") == {}, arm
-    # the cached arm's uplift is real ingest: both arms bill the FULL
-    # prompt length client-side (the note must say so)
-    assert "suffix" in data["note"]
-    assert data["meta"]["workload"]["shared_prefix_len"] \
-        >= 2 * data["meta"]["workload"]["prefix_block"]
+    with serve.ContinuousEngine(model, max_slots=2, decode_steps=2) as eng:
+        warm = eng.compile_cache_size()
+        threads = [threading.Thread(target=caller, args=(c,))
+                   for c in range(callers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        st = eng.stats()
+        assert eng.assert_no_retraces() == 0
+        assert eng.compile_cache_size() == warm > 0
+    assert not errors, errors
+    assert st["replies"] == callers * rounds
+    assert st["pool"]["in_use"] == 0
+    for i, (p, m) in enumerate(work):
+        np.testing.assert_array_equal(outs[i], ref.reference_generate(p, m))

@@ -284,8 +284,11 @@ def test_opperf_harness_smoke():
     parity, /root/reference/benchmark/opperf)."""
     import sys, os
     sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))))
-    from benchmark import opperf
+        os.path.abspath(__file__))), "tools"))
+    try:
+        import opperf
+    finally:
+        sys.path.pop(0)
     res = opperf.run(categories=["optimizer"])
     rows = res["optimizer"]
     assert len(rows) == 2

@@ -24,7 +24,6 @@ Contracts under test (ISSUE 17 acceptance):
     shows up in the engine's memory plans
 """
 import json
-import os
 
 import jax
 import jax.numpy as jnp
@@ -680,44 +679,3 @@ def test_fleet_submit_validates_and_stub_wire_compat(tmp_path):
     # the stub's deterministic pattern ignores sampling: identical output
     # proves the extra wire fields were carried and tolerated
     np.testing.assert_array_equal(greedy, sampled)
-
-
-# ---------------------------------------------------------------------------
-# committed artifact: the ISSUE-17 acceptance numbers
-# ---------------------------------------------------------------------------
-def test_committed_decode_artifact_acceptance():
-    """The committed r17 artifact holds the ISSUE-17 acceptance: >= 1.5x
-    decode tokens/s from speculative decoding on the r14 workload
-    (wall-clock in the single-stream latency-bound arm — speculation's
-    deployment regime — plus the acceptance-weighted per-wave ceiling)
-    at token-exact quality, zero retraces on every arm, and int8 KV at
-    >= 2x slots-per-GB — with an honest paged_pallas_active stamp."""
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "results",
-        "decode_r17.json")
-    data = json.load(open(path))
-    assert data["backend_ok"] is True
-    assert data["meta"]["concurrency"] == 32
-    assert data["meta"]["draft_tokens"] >= 2
-    # the realized wall-clock win in the latency-bound arm, and the
-    # acceptance-weighted tokens-per-verify-wave ceiling (what a
-    # memory-bound accelerator converts to wall-clock at saturation)
-    assert data["serve_decode_speedup_spec"] >= 1.5
-    assert data["serve_decode_tokens_per_verify_wave"] >= 1.5
-    assert data["latency_spec"]["decode_tokens_per_sec"] \
-        > data["latency_plain"]["decode_tokens_per_sec"]
-    assert data["serve_decode_tokens_per_sec_spec"] \
-        == data["latency_spec"]["decode_tokens_per_sec"]
-    assert data["spec_token_exact"] is True
-    assert data["spec_token_exact_checked"] >= 4
-    for arm in ("plain", "spec", "spec_int8", "latency_plain",
-                "latency_spec"):
-        assert data[arm]["retraces_after_warmup"] == 0, arm
-    assert 0.0 < data["spec"]["draft_acceptance"] <= 1.0
-    kv = data["kv_slots_per_gb"]
-    assert kv["ratio"] >= 2.0
-    assert kv["int8"] > kv["float32"]
-    # honesty stamp: CPU CI must not claim the TPU kernel ran compiled,
-    # and the note must say which regime the committed speedup comes from
-    assert isinstance(data["paged_pallas_active"], bool)
-    assert "single-stream" in data["note"]

@@ -1,18 +1,13 @@
 """PR2 eager-dispatch fast path: per-op dispatch records, compiled-kernel
 caches, cached VJP taping, dispatch-stats counters — plus the satellite
-regressions (sparse retain ordering, ONNX NMS boundary, bench default-policy
-row, put_along_axis divergence warning).
+regressions (sparse retain ordering, ONNX NMS boundary, put_along_axis
+divergence warning).
 
 Semantics contract under test: AMP autocast, autograd taping (incl. the
 cached VJP), views, lazy/bulked inputs and MXNET_ENGINE_TYPE=NaiveEngine all
 produce IDENTICAL results through the fast path, and the counters report
 plausible hit rates (ISSUE 2 acceptance).
 """
-import json
-import os
-import subprocess
-import sys
-
 import numpy as np
 import pytest
 
@@ -275,20 +270,6 @@ def test_onnx_nms_keeps_boxes_at_score_threshold():
     assert set(sel[:, 2].tolist()) == {0, 1}
 
 
-def test_bench_sweep_emits_default_policy_row(monkeypatch):
-    sys.path.insert(0, os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    import bench
-    monkeypatch.setattr(
-        bench, "bench_resnet50_train",
-        lambda remat=None, **kw: {"none": 100.0, "dots": 90.0,
-                                  "full": 110.0}[remat or "none"])
-    row = bench._sweep_remat("train_bs32", (None, "dots", "full"))
-    assert row["train_bs32_images_per_sec"] == 110.0          # sweep max
-    assert row["train_bs32_remat_choice"] == "full"
-    assert row["train_bs32_images_per_sec_default"] == 100.0  # remat=None
-
-
 def test_put_along_axis_warns_on_raw_array():
     arr = mx.np.array(np.zeros((2, 3), np.float32))
     idx = mx.np.array(np.array([[1], [0]], np.int64))
@@ -301,24 +282,3 @@ def test_put_along_axis_warns_on_raw_array():
                                     np.array([[7.0], [8.0]], np.float32), 1)
     assert raw[0, 1] == 0.0                                   # NOT mutated
     assert out2.asnumpy()[0, 1] == 7.0
-
-
-# ---------------------------------------------------------------------------
-# CI smoke: the benchmark produces valid JSON in --quick mode
-# ---------------------------------------------------------------------------
-def test_dispatch_bench_quick_smoke(tmp_path):
-    out = tmp_path / "dispatch_quick.json"
-    script = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "dispatch_bench.py")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run([sys.executable, script, "--quick", "--iters", "2",
-                        "--out", str(out)],
-                       capture_output=True, text=True, timeout=420, env=env)
-    assert r.returncode == 0, r.stderr[-2000:]
-    data = json.loads(out.read_text())
-    assert data["meta"]["quick"] is True
-    assert "per_op" in data and "model_step" in data
-    for cfg in ("bulked", "immediate", "naive"):
-        assert data["per_op"][cfg]["sync_us"] > 0
-    # post-PR2 trees expose the counters in the artifact
-    assert data["dispatch_stats"]["dispatch"] > 0

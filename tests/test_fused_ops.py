@@ -8,8 +8,8 @@ add/null axis through the npx wrappers, gluon block rewires and
 model-zoo residual-block parity, FusedTrainStep fused-vs-unfused +
 donate on/off parity with ZERO retraces after warmup, fusion gating
 (scope / default / MXNET_USE_FUSION), the registration surface (AMP
-classes, dispatch-record layout stamps), and the bench `fused_sweep`
---quick smoke + committed artifact pair.
+classes, dispatch-record layout stamps), and `tools/opperf.py`'s fused
+category.
 """
 import json
 import os
@@ -506,77 +506,18 @@ def test_fused_train_step_kernel_path_end_to_end():
 
 
 # ---------------------------------------------------------------------------
-# bench phase smoke + committed artifacts
+# tools/opperf.py: the fused category
 # ---------------------------------------------------------------------------
-def test_bench_fused_sweep_quick_phase():
-    """Tier-1 smoke: the fused_sweep policy sweep rides the hermetic
-    bench runner — sweep keys, unfused baseline, zero retraces, honesty
-    marker."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"),
-         "--phase", "fused_sweep", "--quick"],
-        capture_output=True, text=True, timeout=600, cwd=REPO, env=env)
-    assert r.returncode == 0, r.stderr[-2000:]
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["ok"] is True, out
-    res = out["result"]
-    assert res["fused_step_images_per_sec"] > 0
-    assert set(res["fused_sweep_by_policy"]) == {"none+donate",
-                                                 "none+nodonate"}
-    assert res["fused_step_retraces_after_warmup"] == 0
-    assert res["fused_step_speedup_vs_unfused"] > 0
-    assert res["fused_pallas_active"] is False   # CPU: fallback, honestly
-
-
-def test_committed_offender_pair_shows_classes_moving():
-    """The committed before/after offender artifacts (fusion off/on,
-    ResNet-18 train) exist, are honestly marked, and the fused round's
-    gated scalars do not regress vs the unfused one."""
-    before_p = os.path.join(REPO, "benchmark", "results",
-                            "offenders_resnet18_r10_before.json")
-    after_p = os.path.join(REPO, "benchmark", "results",
-                           "offenders_resnet18_r10_after.json")
-    with open(before_p) as f:
-        before = json.load(f)
-    with open(after_p) as f:
-        after = json.load(f)
-    assert before["name"].endswith("_unfused")
-    for rep in (before, after):
-        assert rep["platform"]          # honesty: backend recorded
-        assert rep["n_units"] > 0
-    # the kernel tier must not WORSEN the structural scalars anywhere,
-    # and the memory-bound byte share must fall (the point of the tier)
-    assert after["memory_bound_byte_share"] \
-        <= before["memory_bound_byte_share"]
-    assert after["est_step_mfu_ceiling"] \
-        >= before["est_step_mfu_ceiling"] * 0.99
-
-
-def test_committed_fused_bench_artifact():
-    p = os.path.join(REPO, "benchmark", "results", "fused_r10.json")
-    with open(p) as f:
-        art = json.load(f)
-    assert art["fused_step_images_per_sec"] > 0
-    assert art["fused_step_unfused_images_per_sec"] > 0
-    assert art["fused_step_speedup_vs_unfused"] > 0
-    assert "fused_pallas_active" in art
-    assert art["platform"]              # CPU rounds honestly marked
-    if art["platform"] == "cpu":
-        assert art["fused_pallas_active"] is False
-
-
 def test_opperf_fused_category_speedup_column():
     """opperf --quick includes the fused category with the
     fused-vs-unfused speedup column."""
-    out = os.path.join(REPO, "benchmark", "results")
     import tempfile
     with tempfile.TemporaryDirectory() as d:
         path = os.path.join(d, "opperf.json")
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         r = subprocess.run(
             [sys.executable,
-             os.path.join(REPO, "benchmark", "opperf.py"),
+             os.path.join(REPO, "tools", "opperf.py"),
              "--quick", "--categories", "fused", "--json", path],
             capture_output=True, text=True, timeout=600, cwd=REPO,
             env=env)

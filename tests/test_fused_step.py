@@ -148,8 +148,7 @@ def test_fused_step_honors_param_multipliers():
 
 def test_remat_policies_numerically_identical():
     """remat trades FLOPs for residual HBM traffic — it must never change
-    the math. All three policies produce identical losses and weights;
-    bench.py A/Bs their THROUGHPUT on the attached chip."""
+    the math. All three policies produce identical losses and weights."""
     import incubator_mxnet_tpu as mx
     from incubator_mxnet_tpu import gluon
     from incubator_mxnet_tpu import optimizer as opt_mod

@@ -14,10 +14,7 @@ The tiny committed fixture `tests/data/tiny_imagerec.rec` holds 12 JPEGs
 of varied dims (2 with flag=2 multi-label headers), so parity runs
 without a toolchain or network.
 """
-import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -467,26 +464,3 @@ def test_native_advise_readahead_smoke():
     r.advise(np.arange(N_REC))           # coalesced WILLNEED: no crash
     r.advise(np.array([11, 0, 5, 5, -3, 99]))   # unsorted + out of range
     r.close()
-
-
-# ---------------------------------------------------------------------------
-# bench smoke (CI satellite)
-# ---------------------------------------------------------------------------
-def test_io_bench_quick_json_smoke():
-    here = os.path.dirname(HERE)
-    r = subprocess.run(
-        [sys.executable, os.path.join(here, "benchmark", "io_bench.py"),
-         "--quick"],
-        capture_output=True, text=True, timeout=420,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    assert r.returncode == 0, r.stderr[-2000:]
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["backend_ok"] is True
-    assert out["value"] > 0
-    for key in ("io_images_per_sec_uint8", "io_host_bytes_per_img",
-                "io_host_bytes_per_img_uint8", "io_stage_decode_share",
-                "io_bytes_reduction", "device_augment_retraces"):
-        assert key in out, key
-    # the uint8 handoff moves 4x fewer bytes per image
-    assert out["io_bytes_reduction"] >= 3.5
-    assert out["device_augment_retraces"] == 0

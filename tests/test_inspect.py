@@ -4,15 +4,13 @@
 Covers: the HLO text parser on handwritten modules (fusion flops summed
 from called computations, dot/conv contraction formulas, boundary-byte
 dedup), kernel-unit discovery through call/while wrappers, calibration
-resolution (explicit path > MXNET_INSPECT_CALIB > committed artifact with
-a platform guard > spec fallback), the cost-analysis degradation contract
+resolution (explicit path > MXNET_INSPECT_CALIB > the platform's table),
+the cost-analysis degradation contract
 (missing bytes keys / raising backends -> flops-only ranking, never a
 crash), inspection of every framework surface (jitted fn, FusedTrainStep,
 FusedInferStep, deploy.ExportedModel), fusion-class grouping + coverage,
-the wall-clock callback, the registry metrics, and the CLI/bench
-smokes (`tools/offenders.py --quick`, `benchmark/opperf.py --quick`,
-`bench.py --quick --phases offenders`) plus the committed ResNet-18
-artifact's acceptance numbers.
+the wall-clock callback, the registry metrics, and the CLI smokes
+(`tools/offenders.py --quick`, `tools/opperf.py --quick`).
 """
 import json
 import os
@@ -206,27 +204,60 @@ def test_load_calibration_env_override(tmp_path, monkeypatch):
     assert roofline.load_calibration()["peak_flops"] == 2e12
 
 
-def test_load_calibration_platform_guard(tmp_path, monkeypatch):
-    """A committed artifact calibrated on a different backend must not set
-    this run's ridge; malformed artifacts are skipped, not fatal."""
-    p = tmp_path / "roofline_calib.json"
-    p.write_text(json.dumps({"peak_flops": 9e13,
-                             "peak_bytes_per_sec": 1e12,
-                             "platform": "not_this_platform"}))
-    monkeypatch.setattr(roofline, "CALIB_PATH", str(p))
-    cal = roofline.load_calibration(platform="cpu")
-    assert cal["source"] == "spec-fallback"
-    assert cal["peak_flops"] == roofline.DEFAULT_CALIBRATIONS[
-        "cpu"]["peak_flops"]
-    p.write_text("{not json")
-    assert roofline.load_calibration(
-        platform="cpu")["source"] == "spec-fallback"
+@pytest.mark.parametrize("explicit,env,want", [
+    ("good", "good", "explicit"),        # the caller's path beats the variable
+    ("broken", "good", "env"),           # a file without peaks is passed over
+    ("missing", "broken", "table"),      # nothing usable named: the table
+    (None, None, "table"),               # nothing named: no file is read
+])
+def test_load_calibration_order(tmp_path, monkeypatch, explicit, env, want):
+    """explicit path > MXNET_INSPECT_CALIB > the platform's table; the
+    package looks for no calibration file of its own accord."""
+    def named(kind, peak):
+        if kind is None:
+            return None
+        p = tmp_path / f"{kind}_{peak:g}.json"
+        if kind == "good":
+            p.write_text(json.dumps({"peak_flops": peak,
+                                     "peak_bytes_per_sec": 1e11,
+                                     "platform": "not_this_platform"}))
+        elif kind == "broken":
+            p.write_text("{not json")
+        return str(p)                    # "missing": never written
+
+    path, envp = named(explicit, 3e12), named(env, 5e12)
+    if envp:
+        monkeypatch.setenv("MXNET_INSPECT_CALIB", envp)
+    else:
+        monkeypatch.delenv("MXNET_INSPECT_CALIB", raising=False)
+    opened = []
+    real_open = open
+
+    def spy(file, *a, **kw):
+        opened.append(str(file))
+        return real_open(file, *a, **kw)
+
+    with monkeypatch.context() as m:
+        m.setattr("builtins.open", spy)
+        cal = roofline.load_calibration(path=path, platform="cpu")
+    assert set(opened) <= {path, envp}, opened
+    if want == "explicit":
+        # a named file is trusted whatever platform it says it was made on
+        assert cal["peak_flops"] == 3e12 and cal["source"] == path
+    elif want == "env":
+        assert cal["peak_flops"] == 5e12 and cal["source"] == envp
+    else:
+        assert cal["source"] == "spec-fallback"
+        assert cal["peak_flops"] == roofline.DEFAULT_CALIBRATIONS[
+            "cpu"]["peak_flops"]
+    assert cal["ridge_flop_per_byte"] == \
+        cal["peak_flops"] / cal["peak_bytes_per_sec"]
 
 
 @pytest.mark.parametrize("kind,known", [
     ("TPU v5 lite", True), ("TPU v4", False), ("TPU v7x", False)])
 def test_tpu_spec_fallback_is_the_v5e_pair_or_an_error(
-        tmp_path, monkeypatch, kind, known):
+        monkeypatch, kind, known):
     """With no calibration a TPU's peaks come from the published spec —
     FLOP/s and HBM bandwidth of the SAME chip. Only the v5e's bandwidth
     is recorded, so a v4 (whose FLOP/s the table knows) is an error like
@@ -237,7 +268,6 @@ def test_tpu_spec_fallback_is_the_v5e_pair_or_an_error(
         platform, device_kind = "tpu", kind
 
     monkeypatch.setattr(jax, "devices", lambda *a: [_Dev()])
-    monkeypatch.setattr(roofline, "CALIB_PATH", str(tmp_path / "none.json"))
     monkeypatch.delenv("MXNET_INSPECT_CALIB", raising=False)
     if known:
         cal = roofline.load_calibration(platform="tpu")
@@ -558,7 +588,7 @@ def test_callable_cost_accepts_prejitted_fn():
 
 
 # ---------------------------------------------------------------------------
-# CLI + bench + committed artifacts (satellites)
+# CLI smokes
 # ---------------------------------------------------------------------------
 def _run(args, timeout=600):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
@@ -592,7 +622,7 @@ def test_offenders_cli_hlo_file_offline(tmp_path):
 def test_opperf_quick_json_smoke(tmp_path):
     """Satellite: opperf gains roofline columns + tier-1 coverage."""
     out = tmp_path / "opperf.json"
-    r = _run([os.path.join(REPO, "benchmark", "opperf.py"), "--quick",
+    r = _run([os.path.join(REPO, "tools", "opperf.py"), "--quick",
               "--json", str(out)])
     assert r.returncode == 0, r.stdout + r.stderr
     data = json.loads(out.read_text())
@@ -617,46 +647,20 @@ def test_opperf_quick_json_smoke(tmp_path):
             min(n["intensity"] for n in norm)
 
 
-def test_bench_offenders_quick_phase():
-    """Satellite: the offenders phase rides the hermetic bench runner and
-    emits exactly the keys benchdiff gates."""
-    r = _run([os.path.join(REPO, "bench.py"), "--quick",
-              "--phases", "offenders"])
-    assert r.returncode == 0, r.stdout + r.stderr
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert "phase_errors" not in out
-    assert 0.0 < out["offender_top1_share"] <= 1.0
-    assert 0.0 <= out["memory_bound_byte_share"] <= 1.0
-    assert 0.0 < out["est_step_mfu_ceiling"] <= 1.0
-    assert out["offenders_n_units"] > 0
-    assert out["offenders_top3"][0]["bound"] in ("compute", "memory")
-
-
-def test_committed_resnet18_artifact_acceptance():
-    """The acceptance numbers of the committed ResNet-18 offender
-    artifact: top-10 classes cover >= 80% of estimated step bytes, every
-    group is roofline-tagged consistently with the calibrated ridge."""
-    path = os.path.join(REPO, "benchmark", "results",
-                        "offenders_resnet18_r09.json")
-    rep = json.load(open(path))
-    assert rep["top10_byte_coverage"] >= 0.8
-    assert rep["ranking"] == "est_time"
+def test_offender_groups_bound_agrees_with_the_ridge():
+    """On a live report of a compiled train step: every class is tagged
+    on the side of the ridge its intensity lies on, the shares are
+    shares, and the ten heaviest classes cover the step's bytes."""
+    step, x, y = _tiny_train_step()
+    rep = mxinspect.inspect_step(step, x, y, name="tiny_train_ridge")
     ridge = rep["calibration"]["ridge_flop_per_byte"]
-    assert ridge > 0
+    assert ridge > 0 and rep["ranking"] == "est_time"
+    assert rep["offender_groups"]
     for g in rep["offender_groups"]:
         assert g["bound"] in ("compute", "memory")
         if g["intensity"] is not None:
             assert (g["intensity"] >= ridge) == (g["bound"] == "compute")
     for key in ("offender_top1_share", "memory_bound_byte_share",
-                "est_step_mfu_ceiling"):
-        assert 0.0 <= rep[key] <= 1.0
-
-
-def test_committed_roofline_calibration_artifact():
-    path = os.path.join(REPO, "benchmark", "results",
-                        "roofline_calib.json")
-    cal = json.load(open(path))
-    assert cal["format_version"] == 1
-    assert cal["peak_flops"] > 0 and cal["peak_bytes_per_sec"] > 0
-    assert cal["platform"]
-    assert cal["probes"]["membw"]["triad_gbps"] > 0
+                "est_step_mfu_ceiling", "top10_byte_coverage"):
+        assert 0.0 <= rep[key] <= 1.0, key
+    assert rep["offender_top1_share"] > 0

@@ -1,6 +1,6 @@
 """Model zoo + flagship transformer tests (≙ reference
 tests/python/unittest/test_gluon_model_zoo.py). Small inputs on the CPU mesh;
-the heavier full-res sweep lives in bench/driver runs."""
+the full-resolution step is the benchmark's (`resnet50_train.feed`)."""
 import numpy as np
 import pytest
 

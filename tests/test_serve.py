@@ -390,33 +390,6 @@ def test_exported_model_run_thread_safe(exported):
 
 
 # ---------------------------------------------------------------------------
-# CI smoke: the load generator produces valid JSON in --quick mode
-# ---------------------------------------------------------------------------
-def test_serve_bench_quick_smoke(tmp_path):
-    out = tmp_path / "serve_quick.json"
-    script = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmark", "serve_bench.py")
-    r = subprocess.run(
-        [sys.executable, script, "--quick", "--duration", "1.0",
-         "--out", str(out)],
-        capture_output=True, text=True, timeout=600)
-    assert r.returncode == 0, f"stdout={r.stdout}\nstderr={r.stderr}"
-    data = json.loads(out.read_text())
-    assert data["meta"]["quick"] is True
-    assert data["meta"]["concurrency"] == 32
-    for mode in ("serial", "batched"):
-        assert data[mode]["requests_per_sec"] > 0
-        assert data[mode]["p99_ms"] >= data[mode]["p50_ms"]
-    # steady state stayed on the warmed bucket programs
-    assert (data["batched"]["compile_cache_size_final"]
-            == data["batched"]["compile_cache_size_after_warmup"])
-    # the artifact reports through the telemetry registry and carries the
-    # backend preflight verdict benchdiff keys on
-    assert data["backend_ok"] is True
-    assert data["telemetry"]["serve.batches"] > 0
-
-
-# ---------------------------------------------------------------------------
 # regression (mxlint lock-shared-mutation): SERVE_STATS increments are a
 # read-modify-write — off-lock they lose counts under thread contention,
 # and serve_stats(reset=True) could eat increments landing between its

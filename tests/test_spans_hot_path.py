@@ -9,6 +9,7 @@ import glob
 import json
 import os
 import re
+import sys
 import time
 
 import numpy as np
@@ -23,6 +24,13 @@ from incubator_mxnet_tpu.io.device_feed import DeviceFeed
 from incubator_mxnet_tpu.serve.metrics import percentile
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from chipbench.paths.serve_engine import COUNTED  # noqa: E402
+from chipbench.paths.serve_hybrid import cache_counters  # noqa: E402
+from chipbench.readers import (cache_live_share, engine_stats,  # noqa: E402
+                               feed_stats, span_ms)
+
 KERNEL_FILES = ("incubator_mxnet_tpu/ops/pallas_kernels.py",
                 "incubator_mxnet_tpu/ops/pallas_attention.py")
 PROGRAM_METRICS = sorted(glob.glob(os.path.join(
@@ -443,6 +451,139 @@ def test_program_metric_pattern_finds_its_module_name(path, program_names):
     for prog, name in program_names.items():
         found = re.search(pattern, name + "(1234)") is not None
         assert found == (prog in FOUND_BY[metric]), (metric, prog, name)
+
+
+# ---------------------------------------------------------------------------
+# the program keeps what the benchmark's host-side readers read
+# ---------------------------------------------------------------------------
+HOST_READERS = ("span_ms", "engine_stats", "cache_live_share", "feed_stats")
+
+
+def _host_metrics():
+    out = []
+    for path in sorted(glob.glob(os.path.join(
+            ROOT, "chipbench", "layer_metrics", "*.json"))):
+        with open(path) as f:
+            if json.load(f)["reader"] in HOST_READERS:
+                out.append(path)
+    return out
+
+
+HOST_METRICS = _host_metrics()
+
+
+def split_bars(body):
+    """`body` cut at the `|`s that lie in no group of its own."""
+    parts, depth, cur, i = [], 0, "", 0
+    while i < len(body):
+        c = body[i]
+        if c == "\\":
+            cur, i = cur + body[i:i + 2], i + 2
+            continue
+        depth += (c == "(") - (c == ")")
+        if c == "|" and depth == 0:
+            parts, cur = parts + [cur], ""
+        else:
+            cur += c
+        i += 1
+    return parts + [cur]
+
+
+def alternatives(pattern):
+    """Every pattern got by keeping one side of each `|` of `pattern`."""
+    parts = split_bars(pattern)
+    if len(parts) > 1:
+        return [a for part in parts for a in alternatives(part)]
+    i = 0
+    while i < len(pattern):
+        if pattern[i] == "\\":
+            i += 2
+            continue
+        if pattern[i] == "(":
+            depth, j = 1, i + 1
+            while depth:
+                if pattern[j] == "\\":
+                    j += 1
+                depth += (pattern[j] == "(") - (pattern[j] == ")")
+                j += 1
+            body = pattern[i + 1:j - 1]
+            head = "?:" if body.startswith("?:") else ""
+            sides = split_bars(body[len(head):])
+            if len(sides) > 1:
+                return [a for side in sides for a in alternatives(
+                    pattern[:i] + "(?:" + side + ")" + pattern[j:])]
+        i += 1
+    return [pattern]
+
+
+def test_alternatives_keeps_one_side_of_every_bar():
+    assert alternatives(r"^a\.(b|c\.(d|e))$") == [
+        r"^a\.(?:b)$", r"^a\.(?:c\.(?:d))$", r"^a\.(?:c\.(?:e))$"]
+    assert alternatives(r"^x\|y$") == [r"^x\|y$"]
+    assert alternatives("p|q") == ["p", "q"]
+
+
+@pytest.fixture(scope="module")
+def fed():
+    """`profiler.feed_stats()` around a toy step fed by a `DeviceFeed`."""
+    step, data = toy_step()
+    before = profiler.feed_stats()
+    for x, y in DeviceFeed(data):
+        loss = step(x, y)
+    loss.wait_to_read()
+    return before, profiler.feed_stats()
+
+
+def test_every_host_side_metric_is_under_the_contract():
+    names = {os.path.basename(p)[:-len(".json")] for p in HOST_METRICS}
+    assert names == {"wave_host_ms.serve", "wave_pack_ms.serve",
+                     "wave_turnover_ms.serve", "step_host_ms.train",
+                     "sched_occupancy.serve", "cache_live_share.serve",
+                     "feed_stall.train"}
+
+
+@pytest.mark.parametrize("path", HOST_METRICS,
+                         ids=[os.path.basename(p) for p in HOST_METRICS])
+def test_program_records_what_the_host_side_metric_reads(
+        path, served, trained, fed):
+    """A span or counter renamed in the program would reach the chip as a
+    per-layer metric that reads `None`: here the benchmark's own readers
+    and patterns, taken as data, read a tiny run of the program."""
+    with open(path) as f:
+        metric = json.load(f)
+    reader, params = metric["reader"], metric.get("params", {})
+    s0, s1 = served["s0"], served["s1"]
+    if reader == "span_ms":
+        run = served if path.endswith(".serve.json") else trained
+        spans = [(e["name"], e["tid"], e["ts"], e["dur"])
+                 for e in run["buffer"] if e.get("ph") == "X"]
+        recorded = {name for name, *_ in spans}
+        for key in ("sum", "per"):
+            for alt in alternatives(params[key]):
+                assert any(re.search(alt, n) for n in recorded), \
+                    (key, alt, sorted(recorded))
+        got = span_ms.reduce(spans, dict(params, skip_head_s=0.0), 3600.0)
+        assert got is not None and got > 0
+    elif reader == "engine_stats":
+        assert set(COUNTED) <= set(s0)
+        counters = {k: s1[k] - s0[k] for k in COUNTED}
+        got = engine_stats.read(params, {
+            "counters": dict(counters, max_slots=s1["pool"]["max_slots"])})
+        assert 0 < got <= 100
+    elif reader == "cache_live_share":
+        assert all({"bytes", "live_bytes_sum"} <= set(kind)
+                   for kind in s1["cache"].values())
+        counters = dict(cache_counters(s0, s1), decode_iterations=(
+            s1["decode_iterations"] - s0["decode_iterations"]))
+        got = cache_live_share.read(params, {"counters": counters})
+        assert 0 < got <= 100
+    else:
+        before, after = fed
+        assert after["batches_consumed"] - before["batches_consumed"] == 3
+        stall_s = (after["stall_data_us"] - before["stall_data_us"]) * 1e-6
+        got = feed_stats.read(params, {
+            "counters": {"feed_stall_data_s": stall_s}, "window_s": 1.0})
+        assert got is not None and got >= 0
 
 
 def pallas_calls(node):

@@ -1,13 +1,11 @@
-"""mx.telemetry — unified metrics registry, step-timeline attribution, and
-the hermetic bench runner (ISSUE 6).
+"""mx.telemetry — unified metrics registry and step-timeline attribution.
 
 Covers: counter/gauge/histogram semantics under an 8-thread hammer,
 snapshot(reset) conservation, Prometheus exposition golden text, span
 nesting + Chrome-trace round-trip, MFU against a hand-counted matmul,
 legacy *_stats() shim parity (keys + reset semantics, registry-backed),
-StepTimeline data-stall attribution, the /metrics endpoint, per-phase
-bench subprocess isolation incl. the BENCH_r04 dtype crash class, and
-benchdiff regression/ok/missing-file exits.
+StepTimeline data-stall attribution, the /metrics endpoint, and that
+importing the package initialises no jax backend.
 """
 import json
 import os
@@ -461,168 +459,33 @@ def test_metrics_http_endpoint():
 
 
 # ---------------------------------------------------------------------------
-# hermetic bench runner
+# the package and the chip
 # ---------------------------------------------------------------------------
-def _run_bench(args, env_extra=None, timeout=600):
-    env = dict(os.environ, JAX_PLATFORMS="cpu", **(env_extra or {}))
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")] + args,
-        capture_output=True, text=True, timeout=timeout, cwd=REPO, env=env)
-    line = r.stdout.strip().splitlines()[-1]
-    return r.returncode, json.loads(line)
+@pytest.mark.parametrize("module", [
+    "incubator_mxnet_tpu.telemetry", "incubator_mxnet_tpu.inspect",
+    "incubator_mxnet_tpu.inspect.roofline",
+    "incubator_mxnet_tpu.inspect.memory"])
+def test_public_names_resolve(module):
+    """The modules a helper was last taken out of: what `__all__` still
+    lists is there, so `from module import *` cannot fail."""
+    import importlib
+    mod = importlib.import_module(module)
+    assert [n for n in mod.__all__ if not hasattr(mod, n)] == []
+    assert len(set(mod.__all__)) == len(mod.__all__)
 
 
-def test_bench_quick_dispatch_subprocess_smoke():
-    """Tier-1 smoke: the per-phase subprocess runner end to end on the
-    cheapest phase — preflight records backend_ok, the phase lands, and
-    its registry snapshot rides along."""
-    rc, out = _run_bench(["--quick", "--phases", "dispatch"])
-    assert rc == 0
-    assert out["backend_ok"] is True
-    assert out["per_dispatch_latency_us_sync"] > 0
-    assert out["per_dispatch_latency_us_chained"] > 0
-    assert "phase_errors" not in out
-    assert "dispatch.dispatch" in out["phase_telemetry"]["dispatch"] or \
-        out["phase_telemetry"]["dispatch"]   # snapshot shipped
-
-
-def test_bench_phase_crash_yields_partial_results():
-    """Acceptance: a forced crash (the BENCH_r04 dtype class, fault-
-    injected) in one phase still produces a JSON line with that phase
-    marked `error` and the other phases populated."""
-    rc, out = _run_bench(
-        ["--quick", "--phases", "dispatch,eager"],
-        env_extra={"MXNET_BENCH_FAULT_PHASE": "eager:dtype"})
-    assert rc == 0
-    assert out["backend_ok"] is True
-    assert out["per_dispatch_latency_us_sync"] > 0      # dispatch landed
-    assert "bfloat16" in out["phase_errors"]["eager"]   # dtype class
-    assert "TypeError" in out["phase_errors"]["eager"]
-
-
-def test_bench_phase_hard_exit_is_contained():
-    """A phase that dies without a traceback (os._exit) is still just one
-    phase_errors entry."""
-    rc, out = _run_bench(
-        ["--quick", "--phases", "dispatch,eager"],
-        env_extra={"MXNET_BENCH_FAULT_PHASE": "eager:exit"})
-    assert rc == 0
-    assert out["per_dispatch_latency_us_sync"] > 0
-    assert "eager" in out["phase_errors"]
-
-
-def test_bench_phase_timeout_kills_only_that_phase():
-    rc, out = _run_bench(
-        ["--quick", "--phases", "eager,dispatch"],
-        env_extra={"MXNET_BENCH_FAULT_PHASE": "eager:hang",
-                   "MXNET_BENCH_PHASE_TIMEOUT": "15"})
-    assert rc == 0
-    assert "TimeoutOrKilled" in out["phase_errors"]["eager"]
-    assert out["per_dispatch_latency_us_sync"] > 0
-
-
-def test_bench_single_phase_child_contract():
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"),
-         "--phase", "dispatch", "--quick"],
-        capture_output=True, text=True, timeout=300, cwd=REPO, env=env)
-    assert r.returncode == 0
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["phase"] == "dispatch" and out["ok"] is True
-    assert out["result"]["per_dispatch_latency_us_sync"] > 0
-    # unknown phase: rc 2, structured error
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"),
-         "--phase", "nope"],
-        capture_output=True, text=True, timeout=60, cwd=REPO, env=env)
-    assert r.returncode == 2
-    assert json.loads(r.stdout.strip().splitlines()[-1])["ok"] is False
-
-
-# ---------------------------------------------------------------------------
-# benchdiff
-# ---------------------------------------------------------------------------
-def _benchdiff(args, timeout=120):
-    return subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "benchdiff.py")]
-        + args, capture_output=True, text=True, timeout=timeout, cwd=REPO)
-
-
-def test_benchdiff_self_test_passes():
-    """Tier-1 smoke: the committed synthetic behavior check."""
-    r = _benchdiff(["--self-test"])
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "FAIL" not in r.stdout
-
-
-def test_benchdiff_exit_codes(tmp_path):
-    ok = {"backend_ok": True, "value": 1000.0,
-          "serve_requests_per_sec_c32": 50.0}
-    reg = dict(ok, value=800.0)                      # -20% regression
-    for name, payload in (("BENCH_r01.json", ok), ("BENCH_r02.json", reg)):
-        with open(tmp_path / name, "w") as f:
-            json.dump(payload, f)
-    r = _benchdiff(["--dir", str(tmp_path)])
-    assert r.returncode == 1
-    assert "REGRESSION value" in r.stdout
-    # same rounds, ok direction
-    with open(tmp_path / "BENCH_r03.json", "w") as f:
-        json.dump(dict(ok, value=990.0), f)
-    r = _benchdiff(["--old", str(tmp_path / "BENCH_r02.json"),
-                    "--new", str(tmp_path / "BENCH_r03.json")])
-    assert r.returncode == 0
-    # missing files
-    r = _benchdiff(["--dir", str(tmp_path / "empty")])
-    assert r.returncode == 2
-    r = _benchdiff(["--old", "/nonexistent.json",
-                    "--new", "/nonexistent.json"])
-    assert r.returncode == 2
-
-
-def test_benchdiff_dead_backend_is_skipped_not_failed(tmp_path):
-    ok = {"backend_ok": True, "value": 1000.0}
-    dead = {"backend_ok": False, "value": 0.0, "error": "backend dead"}
-    for name, payload in (("BENCH_r01.json", ok), ("BENCH_r02.json", dead)):
-        with open(tmp_path / name, "w") as f:
-            json.dump(payload, f)
-    r = _benchdiff(["--dir", str(tmp_path), "--json"])
-    assert r.returncode == 0
-    rep = json.loads(r.stdout)
-    assert rep["status"] == "skipped"
-    assert rep["reason"] == "backend_dead_new"
-
-
-def test_benchdiff_compares_committed_trend_rounds(tmp_path):
-    """Rounds in the driver's wrapper shape ({"n", "cmd", "rc", "tail",
-    "parsed"}): a run that died before any JSON (parsed null) and a
-    pre-`backend_ok` round that reported an error with value 0 must both
-    read as a dead backend and compare as skipped — the false-signal
-    classes this tool exists for."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import benchdiff
-    finally:
-        sys.path.pop(0)
-    wrap = {"cmd": "python bench.py", "tail": ""}
-    rounds = {
-        3: dict(wrap, n=3, rc=0, parsed={
-            "metric": "resnet50_train_images_per_sec_bs32",
-            "value": 2602.56, "unit": "images/sec"}),
-        4: dict(wrap, n=4, rc=1, parsed=None),
-        5: dict(wrap, n=5, rc=0, parsed={
-            "metric": "resnet50_train_images_per_sec_bs32", "value": 0.0,
-            "error": "accelerator backend unavailable"}),
-    }
-    for n, payload in rounds.items():
-        with open(tmp_path / f"BENCH_r{n:02d}.json", "w") as f:
-            json.dump(payload, f)
-    assert len(benchdiff.find_rounds(str(tmp_path))) == 3
-    r4 = benchdiff.load_round(str(tmp_path / "BENCH_r04.json"))
-    assert benchdiff.backend_dead(r4)
-    r5 = benchdiff.load_round(str(tmp_path / "BENCH_r05.json"))
-    assert benchdiff.backend_dead(r5)
-    r3 = benchdiff.load_round(str(tmp_path / "BENCH_r03.json"))
-    assert not benchdiff.backend_dead(r3)
-    rep = benchdiff.compare(r3, r5)
-    assert rep["status"] == "skipped"
+def test_importing_the_package_initialises_no_jax_backend():
+    """A chip belongs to one process: a parent that imports the package
+    (a launcher, the tune trial runner, `chipbench/tests/spread.py`'s
+    kind) must stay off jax, or it holds the chip and starves the
+    children that need it."""
+    code = ("import incubator_mxnet_tpu\n"
+            "from incubator_mxnet_tpu import deploy, serve, telemetry\n"
+            "from incubator_mxnet_tpu.tune.space import scrubbed_env\n"
+            "scrubbed_env()\n"
+            "from jax._src import xla_bridge\n"
+            "print(sorted(xla_bridge._backends))\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120, cwd=REPO)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.strip().splitlines()[-1] == "[]"

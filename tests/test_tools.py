@@ -37,6 +37,30 @@ def test_bandwidth_measures_collectives():
             assert row[k] > 0, (k, row)
 
 
+def test_bandwidth_calib_writes_where_told_and_is_what_roofline_reads(
+        tmp_path, monkeypatch):
+    """`--calib` writes the file it is told to (by default `calib.json` in
+    the working directory, never a path inside the checkout), in the
+    format `inspect.roofline.load_calibration` takes."""
+    from incubator_mxnet_tpu.inspect import roofline
+    bw = _load_tool("bandwidth")
+    monkeypatch.chdir(tmp_path)
+    assert not os.path.isabs(bw.DEFAULT_CALIB_PATH)
+    cal = bw.write_calibration(size_mb=1, reps=1)
+    with open(tmp_path / bw.DEFAULT_CALIB_PATH) as f:
+        written = json.load(f)
+    assert written == cal and written["format_version"] == 1
+    assert written["platform"] and "device_kind" in written
+    assert written["probes"]["membw"]["triad_gbps"] > 0
+    named = str(tmp_path / "sub.json")
+    bw.write_calibration(named, peak_tflops=2.0, size_mb=1, reps=1)
+    got = roofline.load_calibration(path=named)
+    assert got["peak_flops"] == 2.0e12 and got["source"] \
+        == "tools/bandwidth.py --calib"
+    assert got["ridge_flop_per_byte"] == \
+        got["peak_flops"] / got["peak_bytes_per_sec"]
+
+
 def test_flakiness_checker_normalize():
     fc = _load_tool("flakiness_checker")
     assert fc.normalize("tests/test_gluon.py::test_x") \

@@ -10,8 +10,8 @@ shm-worker decode lanes landing in the consuming iterator's Chrome trace;
 the flight-recorder ring/spool/dump contract (capacity knob, fault-logger
 chokepoint, watchdog + overload wiring, JSONL SIGKILL spool); the top-K
 slowest-requests timeline table and trace.*/flightrec.* exposure in
-metrics_text; open-loop knee detection + the serve_bench --open-loop
-smoke; the committed serve_openloop_r13.json acceptance; and the
+metrics_text; open-loop arrivals against a bounded queue accounting for
+every request; and the
 SIGKILL-parity crashtest --flightrec run (slow-marked).
 """
 import json
@@ -411,99 +411,89 @@ def test_serve_overload_shed_records_and_dumps(fresh_flightrec,
 
 
 # ---------------------------------------------------------------------------
-# open-loop harness
+# open-loop arrivals against a bounded queue
 # ---------------------------------------------------------------------------
-def _load_serve_bench():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "serve_bench_mod", os.path.join(REPO, "benchmark",
-                                        "serve_bench.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+@pytest.mark.parametrize("policy,deadline_ms", [
+    ("reject", None), ("shed", None), ("reject", 12.0)])
+def test_open_loop_arrivals_account_for_every_request(policy, deadline_ms):
+    """Arrivals sent on a seeded schedule, never waiting for replies, at
+    several times what the server can take: every request ends as exactly
+    one of completed / dropped-by-kind, none is lost or counted twice,
+    and the server's own counters agree with the caller's tally."""
+    from incubator_mxnet_tpu import serve
 
+    class SlowModel:
+        batch_sizes = [1, 2]
+        row_specs = [((4,), "float32")]
+        single_output = True
 
-def test_detect_knee_on_synthetic_sweep():
-    sb = _load_serve_bench()
+        def run_batch(self, bucket, arrs):
+            time.sleep(0.01)
+            return (np.zeros((bucket, 1), np.float32),)
 
-    def row(rate, achieved, p99, drop=0.0):
-        return {"offered_rps": rate, "achieved_rps": achieved,
-                "p99_ms": p99, "completed": int(achieved),
-                "drop_rate": drop}
+        def warmup(self):
+            pass
 
-    rows = [row(20, 20, 10), row(40, 40, 12), row(80, 79, 14),
-            row(160, 110, 400, drop=0.3), row(320, 112, 900, drop=0.6)]
-    knee = sb.detect_knee(rows)
-    assert knee["knee_rps"] == 80
-    assert knee["knee_p99_ms"] == 14
-    # p99 at 0.8 x 80 = 64 req/s: interpolated between the 40 and 80 rows
-    assert 12 < knee["p99_ms_at_0p8_knee"] < 14
-    # saturated from the very first rate: honest no-knee report
-    sat = sb.detect_knee([row(20, 5, 5000, drop=0.7)])
-    assert sat["knee_rps"] is None and sat["saturated_from_first_rate"]
-    assert sb.detect_knee([]) is None
+        def compile_cache_size(self):
+            return 1
 
+    n = 60
+    gaps = np.random.RandomState(13).exponential(1.0 / 800.0, size=n)
+    lock = threading.Lock()
+    completed, drops, futures = [], {}, []
 
-def test_serve_bench_open_loop_smoke(tmp_path):
-    out = str(tmp_path / "ol.json")
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmark", "serve_bench.py"),
-         "--quick", "--open-loop", "--rates", "25,50,100",
-         "--duration", "0.6", "--out", out],
-        capture_output=True, text=True, timeout=300, cwd=REPO,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert r.returncode == 0, r.stdout + r.stderr
-    with open(out) as f:
-        data = json.load(f)
-    assert data["backend_ok"] is True
-    assert data["meta"]["mode"] == "open_loop"
-    rows = data["open_loop"]["rows"]
-    assert [row["offered_rps"] for row in rows] == [25.0, 50.0, 100.0]
-    for row in rows:
-        # drop accounting present on every rate row
-        assert {"dropped", "drops_by_kind", "drop_rate",
-                "p50_ms", "p99_ms", "p999_ms"} <= set(row)
-        assert row["sent"] == row["completed"] + row["dropped"] \
-            + row["undrained"]
-    assert data["open_loop"]["knee"] is not None
+    def _drop(e):
+        with lock:
+            drops[type(e).__name__] = drops.get(type(e).__name__, 0) + 1
 
+    def _done(f):
+        try:
+            f.result()
+        except Exception as e:
+            _drop(e)
+        else:
+            with lock:
+                completed.append(f)
 
-def test_committed_openloop_artifact_acceptance():
-    path = os.path.join(REPO, "benchmark", "results",
-                        "serve_openloop_r13.json")
-    with open(path) as f:
-        data = json.load(f)
-    assert data["backend_ok"] is True
-    rows = data["open_loop"]["rows"]
-    offered = [r["offered_rps"] for r in rows]
-    # a monotone offered-load sweep with drop accounting on every row
-    assert len(offered) >= 5 and offered == sorted(offered)
-    assert all("drop_rate" in r and "drops_by_kind" in r for r in rows)
-    knee = data["open_loop"]["knee"]
-    assert knee["knee_rps"] is not None
-    assert data["serve_knee_rps"] == knee["knee_rps"]
-    assert data["serve_p99_ms_at_0p8_knee"] == knee["p99_ms_at_0p8_knee"]
-    # the sweep actually crossed the knee: at least one rate saturated
-    assert any(r["offered_rps"] > knee["knee_rps"] for r in rows), \
-        "sweep never exceeded the detected knee — knee not demonstrated"
-    # tracing overhead A/B rides the artifact when present
-    if "serve_trace_overhead_pct" in data:
-        assert data["serve_trace_overhead_pct"] <= 2.0
-
-
-def test_benchdiff_gates_openloop_keys():
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "benchdiff_mod", os.path.join(REPO, "tools", "benchdiff.py"))
-    bd = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bd)
-    assert bd.TREND_KEYS["serve_knee_rps"] == "higher"
-    assert bd.TREND_KEYS["serve_p99_ms_at_0p8_knee"] == "lower"
-    base = {"backend_ok": True, "serve_knee_rps": 100.0,
-            "serve_p99_ms_at_0p8_knee": 40.0}
-    rep = bd.compare(base, dict(base, serve_knee_rps=70.0))
-    assert rep["status"] == "regression"
-    assert rep["regressions"][0]["key"] == "serve_knee_rps"
+    with serve.Server(SlowModel(), max_queue=4, overload_policy=policy,
+                      default_deadline_ms=deadline_ms,
+                      batch_timeout_ms=0.1) as srv:
+        arrival = time.perf_counter()
+        for i in range(n):
+            arrival += gaps[i]
+            wait = arrival - time.perf_counter()
+            if wait > 0:
+                time.sleep(wait)
+            try:
+                fut = srv.submit(np.ones(4, np.float32))
+            except serve.QueueFullError as e:
+                _drop(e)
+                continue
+            fut.add_done_callback(_done)
+            futures.append(fut)
+        for f in futures:
+            try:
+                f.result(timeout=30)
+            except serve.ServeError:
+                pass
+        st = srv.stats()
+    dropped = sum(drops.values())
+    assert len(completed) + dropped == n, (len(completed), drops)
+    assert len(completed) > 0 and dropped > 0, (len(completed), drops)
+    assert set(drops) <= {"QueueFullError", "RequestTimeout"}, drops
+    # the server's own counters tell the same story, kind by kind
+    assert st["replies"] == len(completed)
+    assert st["rejected"] + st["shed"] == drops.get("QueueFullError", 0)
+    assert st["timeouts"] == drops.get("RequestTimeout", 0)
+    if policy == "shed":
+        # shed-oldest admits every arrival; the drops are queued requests
+        assert len(futures) == n and st["rejected"] == 0
+    else:
+        assert st["shed"] == 0
+    if deadline_ms is None:
+        assert set(drops) == {"QueueFullError"}
+    else:
+        assert drops.get("RequestTimeout", 0) > 0, drops
 
 
 # ---------------------------------------------------------------------------

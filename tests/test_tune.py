@@ -5,8 +5,7 @@ Contracts under test:
     choice, pow2 ladders are real powers of two, unknown knobs and
     out-of-space values are typed errors (a hand-edited profile must
     fail loudly, never half-apply)
-  * `scrubbed_env` (shared by the tune trial runner and bench.py phase
-    isolation) removes exactly the tunable env surface: knob vars go,
+  * `scrubbed_env` (the tune trial runner's) removes exactly the tunable env surface: knob vars go,
     infra vars (JAX_PLATFORMS, MXNET_FAULT_SPEC, the compile cache)
     stay — the trial-contamination regression
   * profiles round-trip through JSON (same hash, same knobs), activate
@@ -38,7 +37,6 @@ import threading
 
 import pytest
 
-import bench
 from incubator_mxnet_tpu import fault, tune
 from incubator_mxnet_tpu.base import MXNetError
 from incubator_mxnet_tpu.serve import fleet as fleet_mod
@@ -93,7 +91,7 @@ def test_tune_trial_is_a_registered_fault_point():
 
 
 # ---------------------------------------------------------------------------
-# scrubbed_env — the shared trial/bench isolation helper (satellite fix)
+# scrubbed_env — the trial isolation helper
 # ---------------------------------------------------------------------------
 def test_scrubbed_env_removes_knob_surface_only():
     base = {"MXNET_SERVE_DECODE_STEPS": "8", "MXNET_IO_WORKERS": "4",
@@ -116,15 +114,35 @@ def test_scrubbed_env_removes_knob_surface_only():
     assert "PATH" not in env2
 
 
-def test_bench_phase_children_get_scrubbed_env(monkeypatch):
-    """The bench-side of the satellite fix: an operator's ambient knob
-    export must not contaminate phase subprocess baselines."""
+def test_trial_children_get_scrubbed_env(monkeypatch):
+    """An operator's ambient knob export must not reach a trial's child:
+    the assignment under test arrives through argv alone."""
+    from incubator_mxnet_tpu.tune import search
+    seen = {}
+
+    class _Child:
+        pid, returncode = 0, 0
+
+        def __init__(self, argv, env=None, **kw):
+            seen.update(argv=argv, env=env)
+
+        def communicate(self, timeout=None):
+            return '{"ok": true, "score": 1.0}\n', ""
+
     monkeypatch.setenv("MXNET_SERVE_MAX_SLOTS", "32")
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    env = bench._phase_child_env()
-    assert env is not None
-    assert "MXNET_SERVE_MAX_SLOTS" not in env
-    assert env["JAX_PLATFORMS"] == "cpu"
+    monkeypatch.setenv("MXNET_TUNE_PROFILE", "/p")
+    monkeypatch.setenv("MXNET_FAULT_SPEC", "p:1:error")
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(search.subprocess, "Popen", _Child)
+    got = search._spawn_trial("serve_decode", {"serve.max_slots": 4},
+                              "quick", 5.0)
+    assert got == {"ok": True, "score": 1.0}
+    assert "MXNET_SERVE_MAX_SLOTS" not in seen["env"]
+    assert "MXNET_TUNE_PROFILE" not in seen["env"]
+    assert seen["env"]["MXNET_FAULT_SPEC"] == "p:1:error"
+    assert seen["env"]["JAX_PLATFORMS"] == "cpu"     # the runner's default
+    assert json.loads(seen["argv"][seen["argv"].index("--knobs") + 1]) \
+        == {"serve.max_slots": 4}
 
 
 # ---------------------------------------------------------------------------

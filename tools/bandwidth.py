@@ -14,14 +14,13 @@ for a sweep of tensor sizes. Prints a table and optional JSON.
 Roofline calibration (`--calib`): measures the DEVICE-LOCAL memory
 bandwidth (a jitted streaming triad — the roofline's byte ceiling, distinct
 from the interconnect numbers above) plus a dense-compute probe, and writes
-the machine-readable artifact `benchmark/results/roofline_calib.json` that
-`mx.inspect.roofline` consumes for compute- vs memory-bound classification.
-Re-run it whenever the attached hardware changes (workflow: docs/PERF.md
-"Roofline calibration"). On TPU the compute ceiling should instead come
-from bench.py's calib phase sweep (pass --peak-tflops to pin it); the
-triad bandwidth is measured either way.
+a machine-readable file (`calib.json` in the working directory, or the
+path given) that `mx.inspect.roofline` takes through `MXNET_INSPECT_CALIB`
+or `load_calibration(path=)` for compute- vs memory-bound classification
+(workflow: docs/PERF.md "Roofline calibration"). `--peak-tflops` pins the
+compute ceiling; the triad bandwidth is measured either way.
 
-    python tools/bandwidth.py --calib                    # default path
+    python tools/bandwidth.py --calib                    # ./calib.json
     python tools/bandwidth.py --calib --peak-tflops 22.4 # pin compute peak
 """
 import argparse
@@ -146,10 +145,9 @@ def measure_membw(size_mb=256, reps=5):
 
 def measure_compute_peak(reps=4):
     """Cheap dense-compute probe for the roofline flop ceiling: a chained
-    f32 matmul (bf16 on accelerators) sized to amortize dispatch. On TPU
-    prefer bench.py's full calib-phase sweep and pass --peak-tflops; this
-    probe exists so a CPU-only environment still gets a measured, if
-    modest, ceiling."""
+    f32 matmul (bf16 on accelerators) sized to amortize dispatch. On a
+    TPU pass --peak-tflops (the published peak); this probe exists so a
+    CPU-only environment still gets a measured, if modest, ceiling."""
     import jax
     import jax.numpy as jnp
 
@@ -175,14 +173,12 @@ def measure_compute_peak(reps=4):
             "flops_per_sec": flops / t, "n": n, "dtype": str(dt.__name__)}
 
 
-DEFAULT_CALIB_PATH = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "benchmark", "results", "roofline_calib.json")
+DEFAULT_CALIB_PATH = "calib.json"      # in the working directory
 
 
 def write_calibration(path=None, peak_tflops=None, size_mb=256, reps=5):
-    """Measure and write the roofline calibration artifact that
-    `mx.inspect.roofline.load_calibration()` consumes."""
+    """Measure and write the roofline calibration file that
+    `mx.inspect.roofline.load_calibration(path=)` consumes."""
     import jax
     path = path or DEFAULT_CALIB_PATH
     dev = jax.devices()[0]
@@ -222,12 +218,11 @@ def main():
     ap.add_argument("--calib", nargs="?", const=DEFAULT_CALIB_PATH,
                     default=None, metavar="PATH",
                     help="measure device membw + compute peak and write "
-                         "the roofline calibration artifact (default "
-                         "benchmark/results/roofline_calib.json)")
+                         "the roofline calibration file (default "
+                         "./calib.json)")
     ap.add_argument("--peak-tflops", type=float, default=None,
                     help="pin the calibration's compute ceiling (TFLOP/s) "
-                         "instead of the quick matmul probe — use the "
-                         "bench.py calib-phase attainable on TPU")
+                         "instead of the quick matmul probe")
     ap.add_argument("--calib-size-mb", type=float, default=256,
                     help="triad buffer size for --calib (default 256)")
     args = ap.parse_args()

@@ -56,7 +56,7 @@ def main(argv=None):
         formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--model", help="JSON file identifying the model "
                     "(fingerprint source)")
-    ap.add_argument("--phases", help="comma-separated bench phases "
+    ap.add_argument("--phases", help="comma-separated tune phases "
                     "(default: every phase the catalog declares)")
     ap.add_argument("--budget", type=int, default=None,
                     help="total trial budget (default MXNET_TUNE_BUDGET "

@@ -2,7 +2,7 @@
 
 The ranked, diffable work-list for the kernel tier (ROADMAP item 2): build
 a model, wrap it in the flagship `FusedTrainStep` (fwd+loss+bwd+update as
-ONE XLA program — the same program `bench.py` times), lower+compile it,
+ONE XLA program — the one `resnet50_train.feed` times), lower+compile it,
 and walk the optimized HLO through `mx.inspect`: per-fusion flops, bytes
 moved, arithmetic intensity, compute- vs memory-bound class against the
 calibrated ridge point, and estimated time share. "MFU is 0.15" becomes
@@ -14,9 +14,9 @@ calibrated ridge point, and estimated time share. "MFU is 0.15" becomes
     python tools/offenders.py --hlo-file dump.txt     # offline HLO dump
     python tools/offenders.py --model resnet18 --mode infer
 
-Calibration comes from `benchmark/results/roofline_calib.json`
-(`tools/bandwidth.py --calib`; docs/PERF.md has the recalibration
-workflow). Knobs: MXNET_INSPECT_TOP_K, MXNET_INSPECT_CALIB.
+Peaks come from `roofline.load_calibration()`: a file named by
+`MXNET_INSPECT_CALIB` (`tools/bandwidth.py --calib` writes one; docs/PERF.md
+has the workflow), else the platform's table. Knobs: MXNET_INSPECT_TOP_K, MXNET_INSPECT_CALIB.
 """
 import argparse
 import json

@@ -9,8 +9,8 @@ TPU-native design: each op times three ways —
 
 and carries its roofline coordinates (`mx.inspect.roofline.callable_cost`):
 estimated flops, bytes moved, arithmetic intensity (FLOP/B), and the
-compute- vs memory-bound class against the calibrated ridge point
-(`benchmark/results/roofline_calib.json`, see `tools/bandwidth.py --calib`)
+compute- vs memory-bound class against the ridge point of
+`roofline.load_calibration()` (see `tools/bandwidth.py --calib`)
 — so the latency table doubles as the offender work-list's per-op ground
 truth. Backends whose cost analysis lacks bytes-accessed keys degrade to
 the HLO shape model, and to flops-only rows when that fails too.
@@ -21,10 +21,10 @@ Categories mirror the reference's nd_operations modules: unary, binary
 activation, conv/pool, norm, optimizer-update.
 
 Usage:
-  python benchmark/opperf.py                       # all categories, table
-  python benchmark/opperf.py --categories unary gemm --json out.json
-  python benchmark/opperf.py --platform cpu        # force host platform
-  python benchmark/opperf.py --quick --json out.json   # CI smoke
+  python tools/opperf.py                       # all categories, table
+  python tools/opperf.py --categories unary gemm --json out.json
+  python tools/opperf.py --platform cpu        # force host platform
+  python tools/opperf.py --quick --json out.json   # CI smoke
 """
 import argparse
 import json
