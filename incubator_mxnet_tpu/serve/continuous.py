@@ -376,24 +376,53 @@ def _make_chunk_prefill(config, window=None, extent=None):
     return chunk_prefill
 
 
-def _copy_slot_rows(k_cache, v_cache, src_rows, dst_rows):
-    """Whole-row slab-to-slab KV copy — the prefix-cache data mover: a
-    cache row gathers into a claimed request slot at admission (the
-    memory-bound copy that replaces compute-bound prefill attention) and
-    a retiring request's slot gathers into a cache row at publish. Fixed
-    (C,) lane shapes; an idle lane copies the garbage row onto itself.
-    Donation makes it an in-place slab update on accelerators. int8
-    pools copy codes AND scales, so a copied position dequantizes
-    bit-identically to the original."""
-    k_slab, k_scale = _kv_split(k_cache)
-    v_slab, v_scale = _kv_split(v_cache)
-    k_slab = k_slab.at[dst_rows].set(k_slab[src_rows])
-    v_slab = v_slab.at[dst_rows].set(v_slab[src_rows])
-    if k_scale is None:
-        return k_slab, v_slab
-    k_scale = k_scale.at[dst_rows].set(k_scale[src_rows])
-    v_scale = v_scale.at[dst_rows].set(v_scale[src_rows])
-    return (k_slab, k_scale), (v_slab, v_scale)
+def _copy_block(max_len):
+    """Positions one step of `_copy_slot_rows` moves: one lane width (the
+    paged kernel's block) or, where 128 does not divide `max_len`, its
+    largest power-of-two divisor."""
+    return 128 if max_len % 128 == 0 else max_len & -max_len
+
+
+def _copy_slot_rows(k_cache, v_cache, src_rows, dst_rows, lengths):
+    """Slab-to-slab KV copy of the positions a prefix holds — the
+    prefix-cache data mover: a cache row lands in a claimed request slot
+    at admission (the memory-bound copy that replaces compute-bound
+    prefill attention) and a retiring request's slot lands in a cache row
+    at publish. Fixed (C,) lane shapes; lane i moves positions
+    [0, lengths[i]) of every layer from `src_rows[i]` to `dst_rows[i]` in
+    whole `_copy_block`s, so its last block may run past `lengths[i]` up
+    to the block's edge (positions no read is allowed to see until they
+    are rewritten) and a lane of length 0 moves nothing. One `while` on
+    the device over the live blocks of all lanes, each step a
+    `dynamic_slice` and an in-place `dynamic_update_slice` of the donated
+    slab: the work follows the bytes, the trip count is data. int8 pools
+    move codes AND scales over the same positions, so a copied position
+    dequantizes bit-identically to the original."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    # slabs, and an int8 pool's scales: all (rows, layers, positions, ...)
+    bufs, pools = jax.tree_util.tree_flatten((k_cache, v_cache))
+    max_len = bufs[0].shape[2]
+    bt = _copy_block(max_len)
+    live = (jnp.clip(lengths, 0, max_len) + bt - 1) // bt
+    ends = jnp.cumsum(live)
+
+    def move(step, bufs):
+        lane = jnp.sum(ends <= step)
+        pos = (step - ends[lane] + live[lane]) * bt
+        src, dst = src_rows[lane], dst_rows[lane]
+        out = []
+        for buf in bufs:
+            tail = (0,) * (buf.ndim - 3)
+            piece = lax.dynamic_slice(
+                buf, (src, 0, pos) + tail,
+                (1, buf.shape[1], bt) + buf.shape[3:])
+            out.append(lax.dynamic_update_slice(buf, piece,
+                                                (dst, 0, pos) + tail))
+        return out
+
+    return pools.unflatten(lax.fori_loop(0, ends[-1], move, bufs))
 
 
 def _make_decode(config, steps=1, eos_id=None):
@@ -724,9 +753,10 @@ class CachedDecoder:
         return fn
 
     def copy_program(self):
-        """The jitted slab-to-slab KV row-copy program (prefix-cache
-        admission hit / retire publish): a memory-bound gather, no
-        attention math, donated like every other slab consumer."""
+        """The jitted slab-to-slab KV copy program (prefix-cache
+        admission hit / retire publish): a memory-bound move of the
+        positions a prefix holds, no attention math, donated like every
+        other slab consumer."""
         import jax
         if self._copy is None:
             self._copy = _sanitize.maybe_wrap_donated(
@@ -1231,7 +1261,7 @@ class ContinuousEngine:
             "active_sum", "sampled_tokens", "sampled_waves",
             "draft_accepted",
             "draft_rejected", "prefix_hits", "prefix_misses",
-            "prefix_cached_tokens")}
+            "prefix_cached_tokens", "copied_positions")}
         # per cache kind: the bytes the lanes of each decode wave held
         # live, summed over waves (beside `decode_iterations`)
         self._cache_live = {k: 0 for k in self.pool.kinds()}
@@ -1331,11 +1361,10 @@ class ContinuousEngine:
                           jnp.zeros((C, 2), dtype=jnp.uint32),
                           jnp.zeros((C,), dtype=jnp.int32))
         if self._copy_prog is not None:
-            # garbage-onto-garbage row copy
+            # every lane at length 0: compiles, moves nothing
             kb, vb = self.pool.buffers()
-            k, v = self._copy_prog(
-                kb, vb, jnp.full((P,), g, dtype=jnp.int32),
-                jnp.full((P,), g, dtype=jnp.int32))
+            idle = jnp.zeros((P,), dtype=jnp.int32)
+            k, v = self._copy_prog(kb, vb, idle, idle, idle)
             self.pool.swap_buffers(k, v)
             n_progs += 1
         # wait for the compiles to actually finish so warmup_s is honest
@@ -1825,8 +1854,9 @@ class ContinuousEngine:
             # memory-bound copy replaces compute-bound prefill: the
             # pinned cache rows land in the claimed slots before this
             # wave's programs run (same thread, same device stream)
-            self._dispatch_copy([(r.entry.row, r.slot) for r in hits],
-                                "hit", on)
+            self._dispatch_copy(
+                [(r.entry.row, r.slot, r.cached_len) for r in hits],
+                "hit", on)
             self._count("prefix_hits", len(hits))
             self._count("prefix_cached_tokens",
                         int(sum(r.cached_len for r in hits)))
@@ -1983,23 +2013,21 @@ class ContinuousEngine:
         return done
 
     def _dispatch_copy(self, pairs, why, on):
-        """ONE fixed-shape donated gather program copies whole KV slot
-        rows slab-to-slab: cache row -> claimed slot at admission (`why`
-        "hit"), retiring slot -> cache row at "publish". Idle lanes copy
-        the garbage row onto itself."""
+        """ONE fixed-shape donated program moves the leading positions of
+        KV slot rows slab-to-slab, `(src row, dst row, positions)` a
+        pair: cache row -> claimed slot at admission (`why` "hit", the
+        matched length), retiring slot -> cache row at "publish" (the
+        entry's length). Idle lanes carry length 0 and move nothing."""
         import jax.numpy as jnp
+        positions = sum(n for _, _, n in pairs)
         with (_span("serve.copy.dispatch", cat="serve", pairs=len(pairs),
-                    why=why) if on else NO_SPAN):
-            g = self.pool.garbage_row
-            src = _np.full((self.prefill_lanes,), g, dtype=_np.int32)
-            dst = _np.full((self.prefill_lanes,), g, dtype=_np.int32)
-            for i, (s, d) in enumerate(pairs):
-                src[i] = s
-                dst[i] = d
+                    positions=positions, why=why) if on else NO_SPAN):
+            lanes = _np.zeros((3, self.prefill_lanes), dtype=_np.int32)
+            lanes[:, :len(pairs)] = _np.asarray(pairs, dtype=_np.int32).T
             kb, vb = self.pool.buffers()
-            k, v = self._copy_prog(kb, vb, jnp.asarray(src),
-                                   jnp.asarray(dst))
+            k, v = self._copy_prog(kb, vb, *(jnp.asarray(a) for a in lanes))
             self.pool.swap_buffers(k, v)
+        self._count("copied_positions", positions)
 
     def _run_decode(self, jnp, on, sp):
         """ONE decode wave: every active slot advances up to
@@ -2160,13 +2188,13 @@ class ContinuousEngine:
                     self._cache.release(req.entry)  # mxlint: disable=lock-shared-mutation -- PrefixCache serializes internally (leaf lock)
                     req.entry = None
                 elif self.prefix_cache_insert:
-                    row = self._cache.insert(req.prompt)  # mxlint: disable=lock-shared-mutation -- PrefixCache serializes internally (leaf lock)
-                    if row is not None:
+                    published = self._cache.insert(req.prompt)  # mxlint: disable=lock-shared-mutation -- PrefixCache serializes internally (leaf lock)
+                    if published is not None:
                         # publish BEFORE free: the copy is dispatched on
                         # this thread ahead of any wave that could
                         # rewrite the retiring slot's row
-                        self._dispatch_copy([(req.slot, row)], "publish",
-                                            on)
+                        self._dispatch_copy([(req.slot, *published)],
+                                            "publish", on)
             self.pool.free(req.slot)
             out = _np.asarray(req.generated, dtype=_np.int32)
             if self.eos_id is not None:

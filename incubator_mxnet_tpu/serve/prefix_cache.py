@@ -32,9 +32,10 @@ Design constraints (docs/SERVING.md "Prefix caching & chunked prefill"):
     and cache capacity are separate knobs (`max_slots` vs
     `prefix_cache_slots`) and `SlotsFullError` semantics are unchanged.
 
-Device KV movement is the ENGINE's job (one fixed-shape donated gather
-program, `CachedDecoder.copy_program()`); this module is pure host
-bookkeeping under one lock, exactly like `serve.kv_pool`.
+Device KV movement is the ENGINE's job (one fixed-shape donated copy
+program, `CachedDecoder.copy_program()`, which moves an entry's
+positions and no more); this module is pure host bookkeeping under one
+lock, exactly like `serve.kv_pool`.
 
 Counters: `PREFIX_STATS` ("prefix" stats group —
 `serve.prefix_cache.prefix_stats()`; catalog in docs/OBSERVABILITY.md).
@@ -214,11 +215,13 @@ class PrefixCache:
     def insert(self, prompt):
         """Publish `prompt`'s block-quantized prefix.
 
-        Returns the pool ROW the caller must copy the prefix KV into, or
-        `None` when nothing was published (prefix shorter than one block,
-        already cached — which refreshes its LRU tick — or no free row
-        and every resident entry is pinned: eviction REFUSES refcount>0
-        entries rather than reclaiming a row in use).
+        Returns `(row, n)` — the pool ROW the caller must copy the
+        prefix KV into and the entry's length `n` in tokens (whole
+        blocks: the positions that copy has to move) — or `None` when
+        nothing was published (prefix shorter than one block, already
+        cached — which refreshes its LRU tick — or no free row and every
+        resident entry is pinned: eviction REFUSES refcount>0 entries
+        rather than reclaiming a row in use).
 
         The entry is indexed immediately; the engine's single scheduler
         thread dispatches the KV copy before any later wave can hit the
@@ -244,7 +247,7 @@ class PrefixCache:
             self._tick += 1
             entry.tick = self._tick
             self._by_hash.setdefault(h, []).append(entry)
-            return row
+            return row, n
 
     def _claim_row_locked(self):
         if self._rows_free:

@@ -29,3 +29,37 @@ def sorts_and_conditionals(compiled):
                 unguarded.append(ins.name)
             stack.extend(ins.called)
     return count["sort"], count["conditional"], unguarded
+
+
+# opcodes whose result names another instruction's buffer
+_NO_BUFFER = {"parameter", "tuple", "get-tuple-element", "while", "bitcast"}
+
+
+def buffers_of_at_least(compiled, elements):
+    """Names of the instructions of a `jax.stages.Compiled` that make a
+    buffer of `elements` elements or more: a result (or one element of a
+    tuple result) that large, but for what only names another
+    instruction's buffer (parameters, tuples and their elements, the
+    `while` that carries them, bitcasts) and a `dynamic-update-slice`,
+    alone or as a fusion's root, which writes into its first operand. The
+    row copy of the prefix cache (`serve.continuous._copy_slot_rows`) has
+    to come out empty at one row's elements: its temporaries are pieces,
+    and a gather of rows or a copy of the slab fails a test and not a
+    benchmark."""
+    module = hlo.parse_module(compiled.as_text())
+
+    def updates_in_place(ins):
+        if ins.opcode == "fusion":
+            return any(updates_in_place(module.computations[c].root)
+                       for c in ins.called if c in module.computations)
+        return ins.opcode == "dynamic-update-slice"
+
+    found = []
+    for comp in module.computations.values():
+        for ins in comp.instructions:
+            leaves = ins.shape if isinstance(ins.shape, list) else [ins.shape]
+            if (ins.opcode not in _NO_BUFFER and not updates_in_place(ins)
+                    and any(hlo.num_elements(leaf) >= elements
+                            for leaf in leaves if leaf is not None)):
+                found.append(ins.name)
+    return found

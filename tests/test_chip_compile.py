@@ -209,6 +209,46 @@ def test_global_avg_pool_compiles_at_resnet50_shape(chip, sweep):
     _holds_kernel(text)
 
 
+@pytest.mark.parametrize("form,kv_dtype", [
+    ("blocks", "bfloat16"), ("blocks", "int8"), ("whole_rows", "bfloat16")])
+def test_prefix_copy_program_holds_pieces_not_rows(chip, form, kv_dtype):
+    """The prefix cache's copy program at the benchmark cell's shape (19
+    rows of 24 x 2048 x 16 x 128, 8 lanes): the TPU's compiler updates the
+    donated slabs in place inside the loop, so no instruction makes a
+    buffer of a row or more, the temporaries are under one 128-position
+    block and no Pallas call appears (the benchmark reads every
+    `tpu_custom_call` as attention). The whole-row gather it replaced is
+    the control: 5.2 GB of temporaries."""
+    import jax
+    from hlo_branches import buffers_of_at_least
+    from incubator_mxnet_tpu.serve import continuous
+    s = CELL
+    dims = (s["lanes"] + 1, s["layers"], s["max_len"], s["heads"],
+            s["head_dim"])
+    slab = chip(dims, kv_dtype)
+    if kv_dtype == "int8":
+        slab = (slab, chip(dims[:3], "float32"))
+    lanes = chip((8,), "int32")
+    if form == "blocks":
+        fn, args = continuous._copy_slot_rows, (slab, slab, lanes, lanes,
+                                                lanes)
+    else:
+        fn = lambda k, v, src, dst: (k.at[dst].set(k[src]),   # noqa: E731
+                                     v.at[dst].set(v[src]))
+        args = (slab, slab, lanes, lanes)
+    compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(*args).compile()
+    row = math.prod(dims[1:])
+    block_bytes = 2 * row // s["max_len"] * continuous._copy_block(
+        s["max_len"])
+    big = buffers_of_at_least(compiled, row)
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    if form == "blocks":
+        assert big == [] and temporaries < block_bytes
+        assert "tpu_custom_call" not in compiled.as_text()
+    else:
+        assert big and temporaries > 2 * row
+
+
 def test_hlo_parser_reads_a_tpu_compiled_module(chip):
     """`mx.inspect` on what the TPU's compiler prints: operands named
     without shapes, tiled layouts, a dot lowered to a convolution inside a

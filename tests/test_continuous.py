@@ -36,6 +36,8 @@ import time
 import numpy as np
 import pytest
 
+from hlo_branches import buffers_of_at_least
+
 from incubator_mxnet_tpu import profiler, serve
 from incubator_mxnet_tpu.serve.kv_pool import KVPOOL_STATS
 
@@ -341,6 +343,179 @@ def test_admission_budget_uses_post_cache_cost(decoder):
                 (("first", first), ("cold", cold), ("hot", hot))}
     assert admitted["hot"] < admitted["cold"], \
         f"suffix-cost waiter was not granted a slot first: {admitted}"
+
+
+def test_short_hit_into_a_slot_that_held_a_longer_request(decoder):
+    """The copy moves the matched prefix's blocks and no more, so the one
+    slot keeps a longer previous tenant's KV beyond them: the hit's own
+    suffix and tokens overwrite what they reach, the lengths mask the
+    rest, and the output is the hit-path reference's."""
+    model, ref = decoder
+    shared = list(range(1, 9))                # 8 tokens = 1 block of 8
+    long_prompt = list(range(20, 60))         # 40 of the row's 48 positions
+    with serve.ContinuousEngine(model, max_slots=1, prefill_window=16,
+                                prefix_block=8,
+                                prefix_cache_slots=2) as eng:
+        eng.generate(shared + [30], 2, timeout=120)    # publishes [0, 8)
+        eng.generate(long_prompt, 6, timeout=120)      # fills the slot
+        hot = eng.generate(shared + [31, 32], 8, timeout=120)
+        assert eng.prefix_hit_count() == 1
+        assert eng.assert_no_retraces() == 0
+        # two publishes (8 and 40 tokens) and one hit (8)
+        assert eng.stats()["copied_positions"] == 8 + 40 + 8
+    np.testing.assert_array_equal(
+        hot, ref.reference_generate(shared + [31, 32], 8, window=16,
+                                    cached_prefix_len=8),
+        err_msg="a previous tenant's positions showed through a short hit")
+
+
+def test_short_prefix_published_over_a_long_entry_then_hit(decoder):
+    """One cache row: a short prompt's publish evicts a long entry and
+    moves only its own block into the row, whose later positions still
+    hold the long entry's KV. A hit on the short prefix whose suffix runs
+    on into those positions is token-exact all the same."""
+    model, ref = decoder
+    long_prompt = list(range(20, 60))         # entry of 40 tokens
+    short = list(range(1, 9))                 # entry of 8 tokens
+    suffix = list(range(40, 60))              # the hit's prompt: 28 tokens
+    with serve.ContinuousEngine(model, max_slots=2, prefill_window=16,
+                                prefix_block=8,
+                                prefix_cache_slots=1) as eng:
+        eng.generate(long_prompt, 2, timeout=120)
+        assert eng.stats()["prefix_cache"]["resident_tokens"] == 40
+        eng.generate(short + [30], 2, timeout=120)     # evicts, publishes
+        assert eng.stats()["prefix_cache"]["resident_tokens"] == 8
+        hot = eng.generate(short + suffix, 6, timeout=120)
+        assert eng.prefix_hit_count() == 1
+        assert eng.assert_no_retraces() == 0
+    np.testing.assert_array_equal(
+        hot, ref.reference_generate(short + suffix, 6, window=16,
+                                    cached_prefix_len=8),
+        err_msg="the evicted entry's positions showed through the hit")
+
+
+# ---------------------------------------------------------------------------
+# the copy program alone: it moves the positions it is given, in whole
+# blocks, and nothing else
+# ---------------------------------------------------------------------------
+ROWS, LANES, COPY_LEN = 8, 4, 512             # 7 rows + garbage; 4 blocks
+COPY_BLOCK = 128
+POISON = 77.0
+
+
+def _whole_rows(k_cache, v_cache, src_rows, dst_rows):
+    """The oracle: the whole-row gather this program replaced (rows whole,
+    every lane live)."""
+    import jax
+    return jax.tree_util.tree_map(
+        lambda leaf: leaf.at[dst_rows].set(leaf[src_rows]),
+        (k_cache, v_cache))
+
+
+def _copy_slabs(kv_dtype, seed):
+    """K and V caches of (ROWS, 2, COPY_LEN, 2, 4) with distinct content
+    everywhere; int8 pools are (codes, scales) pairs."""
+    import jax.numpy as jnp
+    rng = np.random.RandomState(seed)
+    shape = (ROWS, 2, COPY_LEN, 2, 4)
+
+    def one():
+        if kv_dtype == "int8":
+            return (jnp.asarray(rng.randint(-127, 128, shape), jnp.int8),
+                    jnp.asarray(rng.rand(*shape[:3]), jnp.float32))
+        return jnp.asarray(rng.randn(*shape), kv_dtype)
+    return one(), one()
+
+
+def _leaves(cache):
+    import jax
+    return [np.asarray(leaf, np.float32)
+            for leaf in jax.tree_util.tree_leaves(cache)]
+
+
+COPY_CASES = {
+    # one live lane, row 1 -> row 4, at the lengths around a block's edge
+    **{f"n={n}": [(1, 4, n)] for n in (
+        0, 1, COPY_BLOCK - 1, COPY_BLOCK, COPY_BLOCK + 1, 3 * COPY_BLOCK,
+        COPY_LEN)},
+    "src_is_dst": [(2, 2, 200)],
+    "idle_lanes_name_live_rows": [(0, 3, 0), (1, 4, 0), (2, 5, 0)],
+    "lanes_of_different_lengths": [(0, 3, 130), (1, 4, 1), (2, 5, COPY_LEN)],
+}
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("case", list(COPY_CASES))
+def test_copy_program_moves_the_positions_it_is_given(case, kv_dtype):
+    """Against the whole-row oracle: positions [0, n) of every destination
+    equal the source's (codes and scales alike); with the destinations
+    poisoned first, every position from the end of a lane's last block on
+    still holds the poison, and every other row — idle lanes' rows and the
+    garbage row among them — is what it was."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.serve.continuous import (_copy_block,
+                                                      _copy_slot_rows)
+    assert _copy_block(COPY_LEN) == COPY_BLOCK
+    pairs = COPY_CASES[case]
+    lanes = np.zeros((3, LANES), np.int32)
+    lanes[:, :len(pairs)] = np.asarray(pairs, np.int32).T
+    src, dst, n = (jnp.asarray(a) for a in lanes)
+    k, v = _copy_slabs(kv_dtype, seed=len(case))
+    poisoned = jnp.asarray([d for s, d, _ in pairs if s != d], jnp.int32)
+    k, v = jax.tree_util.tree_map(
+        lambda leaf: leaf.at[poisoned].set(jnp.asarray(POISON, leaf.dtype)),
+        (k, v))
+    before = [_leaves(k), _leaves(v)]
+    want = [_leaves(c) for c in _whole_rows(k, v, src, dst)]
+    got = jax.jit(_copy_slot_rows)(k, v, src, dst, n)
+    for was, oracle, cache in zip(before, want, got):
+        for a, o, leaf in zip(was, oracle, _leaves(cache)):
+            expect = a.copy()
+            for s, d, length in pairs:
+                edge = -(-length // COPY_BLOCK) * COPY_BLOCK
+                np.testing.assert_array_equal(leaf[d, :, :length],
+                                              o[d, :, :length])
+                expect[d, :, :edge] = a[s, :, :edge]
+            # beyond each lane's last block, and in every other row,
+            # nothing moved
+            np.testing.assert_array_equal(leaf, expect)
+
+
+@pytest.mark.parametrize("form,kv_dtype", [
+    ("blocks", "float32"), ("blocks", "int8"), ("whole_rows", "float32")])
+def test_copy_program_makes_no_buffer_of_a_row(form, kv_dtype):
+    """From the compiled HLO: apart from the donated slabs themselves the
+    program holds pieces, never a row or a slab; the whole-row oracle is
+    the control that the check can fail. (float32 and int8: this
+    backend widens a bfloat16 slab to update it; the bfloat16 program is
+    compiled for the chip in tests/test_chip_compile.py.)"""
+    import jax
+    from incubator_mxnet_tpu.serve.continuous import _copy_slot_rows
+    k, v = jax.eval_shape(lambda: _copy_slabs(kv_dtype, seed=0))
+    lanes = jax.ShapeDtypeStruct((LANES,), "int32")
+    if form == "blocks":
+        compiled = jax.jit(_copy_slot_rows, donate_argnums=(0, 1)).lower(
+            k, v, lanes, lanes, lanes).compile()
+    else:
+        compiled = jax.jit(_whole_rows, donate_argnums=(0, 1)).lower(
+            k, v, lanes, lanes).compile()
+    row = 2 * COPY_LEN * 2 * 4
+    big = buffers_of_at_least(compiled, row)
+    assert (big == []) == (form == "blocks"), big
+
+
+def test_copy_program_traces_once_whatever_the_lengths():
+    import jax.numpy as jnp
+    model = serve.CachedDecoder(serve.DecoderConfig(**CFG), seed=3)
+    prog = model.copy_program()
+    k, v = _copy_slabs("float32", seed=0)
+    src = jnp.arange(LANES, dtype=jnp.int32)
+    sizes = []
+    for lengths in ([0, 0, 0, 0], [5, 0, 300, 0], [COPY_LEN] * LANES):
+        k, v = prog(k, v, src, src + 3, jnp.asarray(lengths, jnp.int32))
+        sizes.append(model.compile_cache_size())
+    assert sizes[0] >= 1 and sizes == sizes[:1] * 3
 
 
 # ---------------------------------------------------------------------------

@@ -45,9 +45,10 @@ def test_rolling_hash_is_prefix_consistent_and_order_sensitive():
 def test_match_returns_longest_verified_block_prefix():
     cache = PrefixCache(block=4, rows=[10, 11])
     p = _prompt(*range(1, 11))                    # 10 tokens
-    short_row = cache.insert(p[:4])               # 4-token entry
-    row = cache.insert(p)                         # 8 of 10 tokens
+    short_row, short_len = cache.insert(p[:4])    # 4-token entry
+    row, length = cache.insert(p)                 # 8 of 10 tokens
     assert {short_row, row} == {10, 11}
+    assert (short_len, length) == (4, 8)          # what the copy moves
     assert [e[0] for e in cache.entries()] == [4, 8]
     before = prefix_stats()
     entry, n = cache.match(p)
@@ -84,7 +85,7 @@ def test_match_acquire_false_is_a_free_peek():
 def test_hash_collision_is_verified_rejected_and_counted():
     cache = PrefixCache(block=4, rows=[7])
     cache._hash_override = lambda tokens: 42      # every block collides
-    assert cache.insert(_prompt(1, 2, 3, 4)) == 7
+    assert cache.insert(_prompt(1, 2, 3, 4)) == (7, 4)
     before = prefix_stats()
     # same hash bucket, different tokens: verify MUST reject the entry
     # and fall through to a miss (recompute), never reuse wrong KV

@@ -170,8 +170,13 @@ def test_prefill_span_children_and_copy_spans(served):
     assert cold and len(hit) == 1
     # a hit copies the cached row in, then prefills its suffix by chunks
     assert inside(host, hit[0]) == ["serve.copy.dispatch"] + PREFILL_CHILDREN
-    copies = {e[4]["why"] for e in host if e[1] == "serve.copy.dispatch"}
-    assert copies == {"hit", "publish"}
+    copies = [e[4] for e in host if e[1] == "serve.copy.dispatch"]
+    assert {c["why"] for c in copies} == {"hit", "publish"}
+    # one pair a copy, one 4-token block a pair: the span says how many
+    # positions it moved, and `stats()` sums them
+    assert all((c["pairs"], c["positions"]) == (1, 4) for c in copies)
+    assert sum(c["positions"] for c in copies) == (
+        served["s1"]["copied_positions"] - served["s0"]["copied_positions"])
     retires = [e for e in host if e[1] == "serve.retire"]
     # the lead request ran in the iteration that was not yet armed
     assert retires and sum(e[4]["n"] for e in retires) == 4
@@ -419,7 +424,7 @@ def program_names():
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, "int32")  # noqa: E731
     chunk = eng._chunk_progs[eng._chunk_extents[0]].lower(
         params, slab, slab, i32(S, W), i32(S), i32(S))
-    copy = eng._copy_prog.lower(slab, slab, i32(P), i32(P))
+    copy = eng._copy_prog.lower(slab, slab, i32(P), i32(P), i32(P))
     step, data = toy_step()
     return {"decode": module_name(low["decode"]),
             "prefill": module_name(low["prefill"]),
