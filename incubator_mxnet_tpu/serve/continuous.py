@@ -1153,6 +1153,12 @@ class ContinuousEngine:
                     raise CacheKindError(
                         f"{what} cannot serve a model whose cache holds "
                         f"{' and '.join(stateful)} leaves: {needs}")
+        if self.prefix_cache_slots and not hasattr(model, "copy_program"):
+            raise CacheKindError(
+                "prefix_cache_slots > 0 cannot serve a model without a "
+                "`copy_program`: a prefix hit copies the leading positions "
+                "of every cache leaf from row to row, and this model's "
+                "leaves have no such program")
         # the pool is carved with max_slots REQUEST rows plus the
         # dedicated prefix-cache rows; self.max_slots stays the request
         # capacity every admission/queue bound sees
@@ -1199,7 +1205,11 @@ class ContinuousEngine:
         self._chunk_extents = ()
         if (self.prefill_window < model.config.max_len
                 or self._cache is not None):
-            exts, e = [], self.prefill_window
+            # a chunk at an offset ends past one window unless a prefix
+            # hit put it at a small offset: without a prefix cache the
+            # first rung (extent == window) would be warmed and never run
+            exts = []
+            e = self.prefill_window * (1 if self._cache is not None else 2)
             while e < model.config.max_len:
                 exts.append(e)
                 e *= 2
@@ -1265,6 +1275,14 @@ class ContinuousEngine:
         # per cache kind: the bytes the lanes of each decode wave held
         # live, summed over waves (beside `decode_iterations`)
         self._cache_live = {k: 0 for k in self.pool.kinds()}
+        # a model may declare `counters` ({name: field names}): each of its
+        # programs then returns, last, {name: int vector}; the vectors wait
+        # in `_pending` for the wave's own readback and are summed there
+        self._counter_fields = dict(getattr(model, "counters", None) or {})
+        self._model_counters = {
+            name: _np.zeros((len(fields),), dtype=_np.int64)
+            for name, fields in self._counter_fields.items()}
+        self._pending = []
         self._auto_seed = 0                  # per-engine seed fountain
         # (ttft, tpot or None, e2e) ms of the newest retired requests, from
         # their RequestTiming fields: stats()'s one source of percentiles
@@ -1313,10 +1331,10 @@ class ContinuousEngine:
         S = self.pool.max_slots
         lens = jnp.ones((P,), dtype=jnp.int32)
         cache = self.pool.buffers()
-        *cache, logits = self._prefill_prog(
+        *cache, logits = self._outputs(self._prefill_prog(
             self.model.params, *cache,
             jnp.zeros((P, self.prefill_window), dtype=jnp.int32),
-            lens, jnp.full((P,), g, dtype=jnp.int32))
+            lens, jnp.full((P,), g, dtype=jnp.int32)))
         self.pool.swap_buffers(*cache)
         # warm the shared first-token sampler at this (P, vocab) shape
         # too — it is part of the steady-state prefill wave
@@ -1335,7 +1353,8 @@ class ContinuousEngine:
             args.append(jnp.zeros((S, self.max_len), dtype=jnp.int32))
         cache = self.pool.buffers()
         n = len(cache)
-        out = self._decode_prog(self.model.params, *cache, *args)
+        out = self._outputs(self._decode_prog(self.model.params, *cache,
+                                              *args))
         self.pool.swap_buffers(*out[:n])
         n_progs = 2
         if self._chunk_progs is not None:
@@ -1350,9 +1369,12 @@ class ContinuousEngine:
                     jnp.zeros((C,), dtype=jnp.int32)]
             if self._chunk_compact:
                 idle.append(jnp.full((C,), g, dtype=jnp.int32))
-            for prog in self._chunk_progs.values():
+            # (a model whose chunk ignores the extent hands back ONE
+            # program for every rung: it is warmed once)
+            for prog in dict.fromkeys(self._chunk_progs.values()):
                 cache = self.pool.buffers()
-                *cache, logits = prog(self.model.params, *cache, *idle)
+                *cache, logits = self._outputs(
+                    prog(self.model.params, *cache, *idle))
                 self.pool.swap_buffers(*cache)
                 n_progs += 1
             _sample_first(logits, jnp.zeros((C,), dtype=jnp.float32),
@@ -1369,7 +1391,31 @@ class ContinuousEngine:
             n_progs += 1
         # wait for the compiles to actually finish so warmup_s is honest
         jax.block_until_ready(self.pool.buffers())
+        self._pending.clear()  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
         self._count("programs_compiled", n_progs)
+
+    def _outputs(self, out):
+        """A step program's outputs without the model's counters: where
+        the model declares `counters` they come last, and wait for the
+        wave's readback (`_read_counters`)."""
+        if not self._counter_fields:
+            return out
+        self._pending.append(out[-1])  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
+        return out[:-1]
+
+    def _read_counters(self):
+        """Sum what the wave's programs counted. Called where the wave
+        reads its tokens back anyway, so the programs that counted have
+        finished; a model without `counters` pays one truth test."""
+        if not self._pending:
+            return
+        got = [{name: _np.asarray(vec) for name, vec in tree.items()}
+               for tree in self._pending]
+        self._pending.clear()  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
+        with self._mlock:
+            for tree in got:
+                for name, vec in tree.items():
+                    self._model_counters[name] += vec
 
 
     def __enter__(self):
@@ -1615,6 +1661,10 @@ class ContinuousEngine:
         out["cache"] = {
             kind: {"bytes": n, "live_bytes_sum": live[kind]}
             for kind, n in self.pool.bytes_by_kind().items()}
+        with self._mlock:
+            for name, fields in self._counter_fields.items():
+                out[name] = {f: int(v) for f, v in zip(
+                    fields, self._model_counters[name])}
         out["decode_steps"] = self.decode_steps
         out["draft_tokens"] = self.draft_tokens
         if c["draft_accepted"] + c["draft_rejected"] > 0:
@@ -1748,6 +1798,7 @@ class ContinuousEngine:
                 # on 'Array has been deleted'. Every in-flight request
                 # was just failed, so zeroed slabs are the correct state.
                 self.pool.reallocate()
+                self._pending.clear()  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
                 if self._canary is not None:
                     # fresh zeroed slabs replaced the poisoned row
                     self._canary.rearm()
@@ -1891,13 +1942,14 @@ class ContinuousEngine:
             with (_span("serve.prefill_batch.dispatch", cat="serve")
                   if on else NO_SPAN):
                 cache = self.pool.buffers()
-                *cache, logits = self._prefill_prog(
-                    self.model.params, *cache, jtoks, jlens, jrows)
+                *cache, logits = self._outputs(self._prefill_prog(
+                    self.model.params, *cache, jtoks, jlens, jrows))
                 first = _sample_first(logits, *sample)
                 self.pool.swap_buffers(*cache)
             with (_span("serve.prefill_batch.readback", cat="serve")
                   if on else NO_SPAN):
                 first_host = _np.asarray(first)
+                self._read_counters()
             for i, req in enumerate(cold):
                 head = min(int(req.prompt.size), W)
                 req.prefill_pos = head
@@ -1962,13 +2014,14 @@ class ContinuousEngine:
             with (_span("serve.prefill_batch.dispatch", cat="serve")
                   if on else NO_SPAN):
                 cache = self.pool.buffers()
-                *cache, logits = self._chunk_progs[ext](
-                    self.model.params, *cache, *chunk_args)
+                *cache, logits = self._outputs(self._chunk_progs[ext](
+                    self.model.params, *cache, *chunk_args))
                 first = _sample_first(logits, *sample)
                 self.pool.swap_buffers(*cache)
             with (_span("serve.prefill_batch.readback", cat="serve")
                   if on else NO_SPAN):
                 first_host = _np.asarray(first)
+                self._read_counters()
             for req in chunkers:
                 s = lane_of[req.slot]
                 n = int(nval[s])
@@ -2092,7 +2145,8 @@ class ContinuousEngine:
               else NO_SPAN):
             cache = self.pool.buffers()
             n = len(cache)
-            out = self._decode_prog(self.model.params, *cache, *args)
+            out = self._outputs(self._decode_prog(self.model.params, *cache,
+                                                  *args))
             self.pool.swap_buffers(*out[:n])
             out = out[n:]
         with (_span("serve.decode_batch.readback", cat="serve") if on
@@ -2108,6 +2162,7 @@ class ContinuousEngine:
                 self._canary.check(where="serve.decode")
             _sanitize.poll(where="serve.decode")
             emitted_host = _np.asarray(emitted)
+            self._read_counters()
         with (_span("serve.decode_batch.emit", cat="serve") if on
               else NO_SPAN):
             now = time.perf_counter()

@@ -249,6 +249,39 @@ def test_prefix_copy_program_holds_pieces_not_rows(chip, form, kv_dtype):
         assert big and temporaries > 2 * row
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill",
+                                     "chunk_prefill@16384"])
+def test_sparse_moe_programs_fit_the_chip_at_glm52_widths(chip, program):
+    """`chipbench/configs/glm52_serve.json`: the decode program (32 lanes,
+    4 micro-steps), the prefill at offset 0 and the chunk program at its
+    longest extent (1 lane of 1024 over 16384 cached positions), whole,
+    at every published width: arguments (3.88 B parameters in bfloat16, a
+    33-row cache of five latent and two index-key leaves) and temporaries
+    (the index scores of a chunk, the gathered latents of a decode step)
+    under one chip's 15.75 GiB, the cache updated in place."""
+    import json
+    import sys
+    import jax
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench.tests import compile_v5e_glm
+    with open(os.path.join(root, "chipbench/configs/glm52_serve.json")) as f:
+        cfg = json.load(f)
+    fn, args = compile_v5e_glm.serving_programs(cfg, chip)[program]
+    mem = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile() \
+        .memory_analysis()
+    cache = sum(math.prod(a.shape) * 2 for a in args[1].values())
+    assert mem.alias_size_in_bytes >= cache          # no second cache
+    # the outputs are the aliased cache and a few small arrays: arguments
+    # and temporaries are what the program holds
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 15.5 * 2 ** 30, held
+    # no second copy of the held experts (4.8 GB) among the temporaries
+    assert mem.temp_size_in_bytes < 2 * 2 ** 30, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes > 11e9         # the cell's own size
+
+
 def test_hlo_parser_reads_a_tpu_compiled_module(chip):
     """`mx.inspect` on what the TPU's compiler prints: operands named
     without shapes, tiled layouts, a dot lowered to a convolution inside a

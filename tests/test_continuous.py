@@ -55,6 +55,20 @@ def decoder():
     return model, ref
 
 
+@pytest.fixture(scope="module", params=["classic", "sparse_moe"])
+def any_decoder(request, decoder):
+    """The contract tests that ask nothing of a model beyond the engine's
+    protocol, over the classic decoder and over the latent-attention,
+    sparse-attention, sparse-expert one (`models.sparse_moe_decoder`; the
+    contexts here pass its `index_topk` of 8, so its decode chooses)."""
+    if request.param == "classic":
+        return decoder
+    from incubator_mxnet_tpu.models import sparse_moe_decoder as sm
+    cfg = sm.SparseMoEConfig(vocab=64, max_len=48)
+    model = sm.SparseMoEDecoder(cfg, seed=3)
+    return model, sm.SparseMoEDecoder(cfg, params=model.params)
+
+
 def _workload(n, seed=0, vocab=64, max_new_hi=20):
     rng = np.random.RandomState(seed)
     return [(rng.randint(1, vocab, size=rng.randint(2, 12)).tolist(),
@@ -64,8 +78,8 @@ def _workload(n, seed=0, vocab=64, max_new_hi=20):
 # ---------------------------------------------------------------------------
 # correctness + zero retraces
 # ---------------------------------------------------------------------------
-def test_engine_matches_reference_and_never_retraces(decoder):
-    model, ref = decoder
+def test_engine_matches_reference_and_never_retraces(any_decoder):
+    model, ref = any_decoder
     work = _workload(16)
     before = profiler.serve_stats()
     with serve.ContinuousEngine(model, max_slots=4, decode_steps=3) as eng:
@@ -100,10 +114,10 @@ def test_engine_matches_reference_and_never_retraces(decoder):
     assert json.dumps(st)
 
 
-def test_multi_step_decode_equals_single_step(decoder):
+def test_multi_step_decode_equals_single_step(any_decoder):
     """decode_steps is pure amortization: K=1 and K=6 produce identical
     tokens (the scan replays the exact single-step math)."""
-    model, ref = decoder
+    model, ref = any_decoder
     work = _workload(6, seed=5)
     outs = {}
     for steps in (1, 6):
@@ -115,8 +129,8 @@ def test_multi_step_decode_equals_single_step(decoder):
         np.testing.assert_array_equal(a, b)
 
 
-def test_eos_stops_generation_and_frees_early(decoder):
-    model, ref = decoder
+def test_eos_stops_generation_and_frees_early(any_decoder):
+    model, ref = any_decoder
     prompt, max_new = [7, 3, 19], 16
     base = ref.reference_generate(prompt, max_new)
     # pick a token the model actually emits mid-sequence as the eos
@@ -133,12 +147,12 @@ def test_eos_stops_generation_and_frees_early(decoder):
     assert out[-1] == eos
 
 
-def test_eos_mid_wave_keeps_exact_token_accounting(decoder):
+def test_eos_mid_wave_keeps_exact_token_accounting(any_decoder):
     """Regression: eos zeroes a lane's remaining budget in-scan, so
     deriving per-lane emission from the steps_left delta OVERCOUNTED
     (inflating cache_len, appending garbage 0-tokens, and keeping the
     slot past eos). The scan now counts emitted tokens exactly."""
-    model, ref = decoder
+    model, ref = any_decoder
     prompt, max_new = [7, 3, 19], 16
     base = ref.reference_generate(prompt, max_new)
     eos = int(base[len(base) // 2])
@@ -206,12 +220,12 @@ def test_step_failure_after_donation_engine_keeps_serving(decoder):
     assert st["errors"] == 1 and st["replies"] == 1
 
 
-def test_long_prompt_streams_in_window_sized_chunks(decoder):
+def test_long_prompt_streams_in_window_sized_chunks(any_decoder):
     """PR-14 rejected prompts longer than `prefill_window`; chunked
     prefill streams them window-sized slices per wave instead (through
     the warmed extent ladder), token-exact and zero-retrace, while
     short prompts keep using the cheap windowed head program."""
-    model, ref = decoder
+    model, ref = any_decoder
     long_prompt = list(range(1, 40))          # 39 tokens = 3 chunks @ 16
     with serve.ContinuousEngine(model, max_slots=2,
                                 prefill_window=16) as eng:
@@ -586,14 +600,14 @@ def test_kv_pool_concurrent_claim_free_hammer():
     assert pool.free_count() == 4 and pool.in_use() == []
 
 
-def test_slot_reuse_cannot_read_prior_request_cache(decoder):
+def test_slot_reuse_cannot_read_prior_request_cache(any_decoder):
     """Poison-fill + value check: fill the WHOLE slab with a sentinel,
     then run a request through a reused slot — output must match the
     fresh-pool reference bit-for-bit, proving no read escapes the
     current request's [0, cur_len] window (prefill_window < max_len, so
     the page is NOT fully overwritten at claim: only the mask protects
     the tail)."""
-    model, ref = decoder
+    model, ref = any_decoder
     eng = serve.ContinuousEngine(model, max_slots=1, prefill_window=16,
                                  decode_steps=2).start()
     try:
@@ -610,8 +624,8 @@ def test_slot_reuse_cannot_read_prior_request_cache(decoder):
         err_msg="reused slot leaked a prior tenant's cache into decode")
 
 
-def test_requests_queue_when_slots_full_then_complete(decoder):
-    model, ref = decoder
+def test_requests_queue_when_slots_full_then_complete(any_decoder):
+    model, ref = any_decoder
     work = _workload(10, seed=9)
     with serve.ContinuousEngine(model, max_slots=2,
                                 decode_steps=2) as eng:
@@ -898,12 +912,12 @@ def test_compile_cache_default_is_the_fixed_checkout_path(tmp_path):
 # ---------------------------------------------------------------------------
 # closed-loop callers (the benchmark's traffic shape)
 # ---------------------------------------------------------------------------
-def test_closed_loop_callers_never_retrace_and_drain(decoder):
+def test_closed_loop_callers_never_retrace_and_drain(any_decoder):
     """More callers than slots, each sending its next request when the
     last one's reply arrives (arrivals depend on completions, as in every
     serve cell): token-exact, zero retraces after warm-up, the compiled
     programs are there to be cached, and the pool ends empty."""
-    model, ref = decoder
+    model, ref = any_decoder
     callers, rounds = 6, 3
     work = _workload(callers * rounds, seed=21)
     outs, errors = {}, []
