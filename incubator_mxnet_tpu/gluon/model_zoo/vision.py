@@ -79,9 +79,9 @@ class BasicBlockV1(HybridBlock):
         if self.downsample is not None:
             residual = self.downsample(residual)
         if _fused.fusion_enabled():
-            # kernel tier: each BN(+relu) is one fused pass, and the block
-            # tail (BN + residual add + relu — the top memory-bound
-            # offender class) is ONE op
+            # fused ops: each BN(+relu) is one op, and the block tail (BN
+            # + residual add + relu — the top memory-bound offender
+            # class) is ONE op; XLA fuses each into its convolutions
             conv1, bn1, _act, conv2, bn2 = list(self.body)
             h = bn1.fused_forward(conv1(x), act_type="relu")
             return bn2.fused_forward(conv2(h), act_type="relu",

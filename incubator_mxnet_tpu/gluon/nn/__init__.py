@@ -205,10 +205,12 @@ class BatchNorm(HybridBlock):
 
     def fused_forward(self, x, act_type=None, residual=None):
         """BN + optional activation + optional pre-activation residual
-        add as ONE fused-tier op (npx.fused_batch_norm): the apply stage
-        runs as a single Pallas pass on TPU instead of the memory-bound
-        fusion chain. Numerics match forward() (+ activation, + add)
-        within float association; running stats update identically."""
+        add as ONE fused-tier op (npx.fused_batch_norm): one dispatch
+        where the eager path takes three; its apply stage is the jnp
+        composition, which XLA fuses into the neighbouring convolutions
+        inside a compiled step. Numerics match forward() (+ activation,
+        + add) within float association; running stats update
+        identically."""
         return npx.fused_batch_norm(
             x, self.gamma.data(), self.beta.data(),
             self.running_mean.data(), self.running_var.data(),
@@ -231,9 +233,10 @@ class BatchNorm(HybridBlock):
 
 
 class BatchNormReLU(BatchNorm):
-    """Fused BN+ReLU (≙ basic_layers.py:478). On the kernel tier
-    (`_fusion_on()`) the whole normalize+scale/shift+relu chain is one
-    fused pass; otherwise BN + relu as before (XLA fuses pointwise)."""
+    """Fused BN+ReLU (≙ basic_layers.py:478). Under `_fusion_on()` the
+    whole normalize+scale/shift+relu chain is one op
+    (npx.fused_batch_norm); otherwise BN + relu as before (XLA fuses
+    pointwise)."""
 
     def forward(self, x):
         if _fusion_on():
@@ -715,7 +718,7 @@ class _Pool(HybridBlock):
 
     def _fused_pool_size(self, x):
         """(ph, pw) when this pool can take the fused non-overlapping
-        NHWC kernel (VMEM-tiled Pallas backward), else None: avg type,
+        NHWC op (reshape+mean, broadcast backward), else None: avg type,
         NHWC 2-D, zero padding, kernel == stride dividing the spatial
         dims — which covers AvgPool2D(k, k) and GlobalAvgPool2D."""
         if self._type != "avg" or self._layout != "NHWC" or x.ndim != 4:

@@ -220,15 +220,14 @@ fused_image_augment = _make_fused("image_augment", "fused_image_augment")
 
 
 def fused_avg_pool2d(data, pool_size, layout="NHWC"):
-    """Fused non-overlapping NHWC average pool (kernel == stride, no
-    padding; GlobalAvgPool shapes included) with the VMEM-tiled Pallas
-    backward — see ops.fused.avg_pool2d."""
+    """Non-overlapping NHWC average pool (kernel == stride, no padding;
+    GlobalAvgPool shapes included) as the f32 reshape+mean composition,
+    whose backward is a broadcast — see ops.fused.avg_pool2d."""
     info = get_op("npx.fused_avg_pool2d")
     note_layout(info, layout)
     ps = (pool_size, pool_size) if isinstance(pool_size, int) \
         else tuple(pool_size)
-    kw = {"pool_size": ps, "layout": layout,
-          "interpret": _fused_ops._interpret()}
+    kw = {"pool_size": ps, "layout": layout}
     return invoke(functools.partial(_fused_ops.avg_pool2d, **kw),
                   (_as_nd(data),), name="fused_avg_pool2d", op=info,
                   key=record_key(_avg_pool_key, kw))
@@ -243,16 +242,16 @@ def fused_batch_norm(x, gamma, beta, running_mean, running_var, eps=1e-5,
                      momentum=0.9, axis=1, use_global_stats=False,
                      training=None, sync_axis_name=None, act_type=None,
                      residual=None):
-    """Batch norm with the apply stage routed through the fused kernel
-    tier, plus optional fused activation and pre-activation residual add
-    (ops.fused.batch_norm). Same running-stat write-back protocol as
+    """Batch norm, optional activation and optional pre-activation
+    residual add as ONE dispatch-level op (ops.fused.batch_norm); the
+    apply stage is the jnp composition on every platform, left to XLA to
+    fuse with its neighbours. Same running-stat write-back protocol as
     npx.batch_norm."""
     if training is None:
         training = _autograd.is_training()
     kw = dict(momentum=momentum, eps=eps, training=training, axis=axis,
               use_global_stats=use_global_stats,
-              sync_axis_name=sync_axis_name, act_type=act_type,
-              interpret=_fused_ops._interpret())
+              sync_axis_name=sync_axis_name, act_type=act_type)
     info = get_op("npx.fused_batch_norm")
     arrs = (_as_nd(x), _as_nd(gamma), _as_nd(beta), _as_nd(running_mean),
             _as_nd(running_var))

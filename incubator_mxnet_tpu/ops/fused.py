@@ -17,33 +17,47 @@ ranking; the classes' intensities below are the cost model's):
                           after dense/conv                  shift_act
   bn_inference            folded BN-inference scale/shift   apply_scale_
                           (+ optional act/residual)         shift_act
-  batch_norm              training BN: batch stats + ONE    apply_scale_
-                          fused apply pass                  shift_act
-  avg_pool2d              reduce-window (0.18 FLOP/B, 35    avg_pool2d_fwd /
-                          instances) — non-overlapping avg  avg_pool2d_bwd
-                          pool incl. GlobalAvgPool, with a  (VMEM-tiled
-                          broadcast backward                backward)
+  batch_norm              training BN: batch stats + the    none: the jnp
+                          apply (+ act, + residual) as ONE  composition,
+                          dispatch-level op                 everywhere
+  avg_pool2d              reduce-window (0.18 FLOP/B, 35    none: f32
+                          instances) — non-overlapping avg  reshape+mean,
+                          pool incl. GlobalAvgPool, with a  everywhere
+                          broadcast backward
 
-Each op is a Pallas TPU kernel (ops/pallas_kernels.py) with a
+The first three are a Pallas TPU kernel (ops/pallas_kernels.py) with a
 mathematically identical `jnp` composition fallback off-TPU — the
 `*_ref` functions here ARE the fallback, so CPU gradient parity is exact
 by construction and the kernels are interpret-mode tested against them.
 On the kernel path the backward is a hand-derived custom_vjp (one
 recompute of the pre-activation, then the analytic chain).
 
+The two ops a train step is made of, `batch_norm` and `avg_pool2d`, take
+no kernel on any platform: they lower to that composition (`_ref_apply`,
+`avg_pool2d_ref`) and JAX differentiates it. A Pallas call takes its
+(M, C) operand row-major, while the TPU compiler keeps convolution
+activations batch-minor; every call in a train step was therefore wrapped
+in two physical copies and a reshape of a whole activation, and the
+element-wise work could no longer ride in the convolutions' own fusions
+(PERF.md §6, PR 32: ResNet-50's step moved 116 GB where the composition
+moves 47). The op granularity, the statistics protocol and the AMP class
+are what they were; only the lowering of the apply stage changed.
+
 Gating: the gluon rewrites (nn.Dense/_Conv/BatchNorm/_Pool, model-zoo
 residual blocks) engage only when `fusion_enabled()` — an explicit
 `fusion_scope(True)` / `set_fusion_default(True)` AND the
 `MXNET_USE_FUSION` env knob (default on). `FusedTrainStep` /
 `FusedInferStep` enter the scope automatically, so the flagship fused
-step gets the kernel tier by default while eager paths stay unchanged
+step gets the fused ops by default while eager paths stay unchanged
 unless opted in. `MXNET_FUSION_INTERPRET=1` runs the Pallas kernels in
 interpret mode (CI exercises the kernel path on CPU); on a TPU, where
 the kernels compile, asking for interpret mode is an error.
 
 Counters: `profiler.fused_stats()` / telemetry `fused.*` —
-`pallas_calls` (kernel-path dispatches) vs `fallback_calls` (jnp
-composition). Inside a jitted step these count per TRACE (path choices
+`pallas_calls` (kernel-path dispatches) vs `fallback_calls` (a kernel
+was wanted and the jnp composition served). `batch_norm` and
+`avg_pool2d` count in neither: their composition is the route, not a
+fallback. Inside a jitted step these count per TRACE (path choices
 baked into the program), eagerly they count per call.
 """
 from __future__ import annotations
@@ -595,17 +609,19 @@ def bn_inference(x, gamma, beta, mean, var, eps=1e-5, axis=-1,
 
 def batch_norm(x, gamma, beta, running_mean, running_var, momentum=0.9,
                eps=1e-5, training=True, axis=1, use_global_stats=False,
-               sync_axis_name=None, act_type=None, residual=None,
-               interpret=None):
-    """Batch norm with the apply stage routed through the fused kernel.
+               sync_axis_name=None, act_type=None, residual=None):
+    """Batch norm + optional activation + optional pre-activation residual
+    add as ONE dispatch-level op, lowered to the jnp composition.
 
     Identical stats protocol to ops.nn.batch_norm (same f32 moments, same
-    pmean sync, same running-stat update; returns (out, new_rm, new_rv))
-    but the normalize/scale/shift(/act/residual) applies as ONE fused
-    pass instead of the chain XLA splits into memory-bound fusions.
-    Gradients flow through the batch moments exactly as in the unfused
-    composition — scale/shift are traced functions of x, and the apply's
-    custom_vjp chains through them."""
+    pmean sync, same running-stat update; returns (out, new_rm, new_rv)).
+    The normalize/scale/shift(/act/residual) is `_ref_apply` (f32 inside,
+    cast out) on every platform, differentiated by JAX: no Pallas call,
+    so that XLA fuses the apply with the convolution that produces `x`
+    and the one that consumes the result, in the layout the convolutions
+    want (see the module docstring). Gradients flow through the batch
+    moments exactly as in the unfused composition — scale/shift are
+    traced functions of x."""
     import jax
     jnp = _jnp()
     lax = jax.lax
@@ -624,7 +640,7 @@ def batch_norm(x, gamma, beta, running_mean, running_var, momentum=0.9,
         mean, var = running_mean, running_var
         new_rm, new_rv = running_mean, running_var
     scale, shift = _fold_bn(gamma, beta, mean, var, eps)
-    out = _apply(x, scale, shift, residual, act_type, axis, interpret)
+    out = _ref_apply(x, scale, shift, residual, act_type, axis)
     return out, new_rm, new_rv
 
 
@@ -703,29 +719,24 @@ def _kernel_avg_pool(h, w, ph, pw, dtype, interpret):
     return f
 
 
-def avg_pool2d(x, pool_size, layout="NHWC", interpret=None):
-    """Non-overlapping (kernel == stride, no padding) NHWC average pool
-    with a VMEM-tiled Pallas backward — covers AvgPool2D(k, k) and the
-    GlobalAvgPool2D shape (pool_size = spatial dims, keepdims output).
-    Falls back to the f32 reshape+mean composition off-TPU (whose XLA
-    gradient is already a broadcast, not a reduce-window scatter)."""
+def avg_pool2d(x, pool_size, layout="NHWC"):
+    """Non-overlapping (kernel == stride, no padding) NHWC average pool —
+    covers AvgPool2D(k, k) and the GlobalAvgPool2D shape (pool_size =
+    spatial dims, keepdims output). The f32 reshape+mean composition
+    (`avg_pool2d_ref`) on every platform: its XLA gradient is already a
+    broadcast, not a reduce-window scatter, and it pins no layout between
+    the last convolution and the classifier (the Pallas pair
+    `_kernel_avg_pool` did; no op routes to it)."""
     ph, pw = (pool_size, pool_size) if isinstance(pool_size, int) \
         else tuple(pool_size)
     if layout != "NHWC" or x.ndim != 4:
         raise ValueError("fused avg_pool2d is NHWC 2-D only "
                          f"(got layout={layout!r}, ndim={x.ndim})")
-    n, h, w, c = x.shape
+    h, w = x.shape[1], x.shape[2]
     if h % ph or w % pw:
         raise ValueError(f"pool {ph}x{pw} must divide spatial dims "
                          f"{h}x{w} (non-overlapping pooling)")
-    interpret = _resolve_interpret(interpret)
-    if not (_on_tpu() or interpret) \
-            or _pk._pool_blocks(n, h, w, c, ph, pw) is None:
-        _fell_back("avg_pool2d",
-                   f"x {tuple(x.shape)} pool {ph}x{pw}: no tile, or no TPU")
-        return avg_pool2d_ref(x, (ph, pw))
-    _STATS["pallas_calls"] += 1
-    return _kernel_avg_pool(h, w, ph, pw, str(x.dtype), interpret)(x)
+    return avg_pool2d_ref(x, (ph, pw))
 
 
 # Dispatch-record AMP classes (PR2 metadata; picked up by register_op in

@@ -4,8 +4,9 @@ The TPU's compiler is installed here and compiles for a chip that is
 described, not attached (`jax.experimental.topologies`). These cases hand it
 the Pallas kernels of the two main paths at the widths `chip_smoke.py` runs —
 what interpret mode cannot see: block shapes the lowering refuses, and more
-VMEM than a kernel may allocate. Each is a second or two and costs no chip
-time. Nothing runs, so nothing here says anything about results or speed.
+VMEM than a kernel may allocate — and the programs that must hold none.
+Each is a second or two and costs no chip time. Nothing runs, so nothing
+here says anything about results or speed.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU's library, and every xdist worker imports
@@ -207,6 +208,58 @@ def test_global_avg_pool_compiles_at_resnet50_shape(chip, sweep):
         text = _compile(lambda dy: pk.avg_pool2d_bwd(dy, h, w, h, w),
                         chip((n, 1, 1, c), "bfloat16"))
     _holds_kernel(text)
+
+
+@pytest.mark.parametrize("hw,in_channels,channels,downsample", [
+    (56, 64, 256, True), (7, 2048, 2048, False)],
+    ids=["resnet50-stage1-downsample", "resnet50-stage4"])
+def test_bottleneck_block_leaves_batch_norm_to_the_compiler(
+        chip, monkeypatch, hw, in_channels, channels, downsample):
+    """Forward and backward of one `BottleneckV1` under `fusion_scope` and
+    bf16 AMP, batch 32, with the program told it is on a TPU: no Pallas
+    call, and no top-level copy of a whole activation beyond the block's
+    own argument and its cotangent crossing from the default layout. A
+    Pallas call takes its (M, C) operand row-major where the compiler
+    keeps convolution activations batch-minor: every batch norm routed
+    through one cost two copies and a reshape (PERF.md §6, PR 32: 4 and 3
+    custom calls, 5 and 4 such copies in these two blocks)."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    import incubator_mxnet_tpu as mx
+    from incubator_mxnet_tpu import amp, autograd
+    from incubator_mxnet_tpu.gluon.model_zoo.vision import BottleneckV1
+    from incubator_mxnet_tpu.ndarray import _wrap
+    from incubator_mxnet_tpu.ops import fused
+    batch = 32
+    monkeypatch.setattr(fused, "_on_tpu", lambda: True)
+    amp.init("bfloat16")
+    try:
+        block = BottleneckV1(channels, 1, downsample=downsample,
+                             in_channels=in_channels, layout="NHWC")
+        block.initialize()
+        block(mx.np.zeros((1, hw, hw, in_channels), dtype="float32"))
+        params = [p.data() for _, p in sorted(block.collect_params().items())]
+
+        def loss(bufs, x):
+            for p, buf in zip(params, bufs):     # the block dies with the test
+                p._set_arr(buf)
+            with fused.fusion_scope(True), autograd.train_mode():
+                y = block(_wrap(x))._arr
+            return jnp.sum(jnp.square(y.astype(jnp.float32)))
+
+        text = _compile(
+            jax.grad(loss, argnums=(0, 1)),
+            [chip(p.shape, p.dtype) for p in params],
+            chip((batch, hw, hw, in_channels), "bfloat16"))
+    finally:
+        amp.uninit()
+    assert "tpu_custom_call" not in text
+    entry = text[text.index("ENTRY"):]
+    copied = re.findall(
+        rf"^\s*(?:ROOT )?%copy\S* = \w+\[{batch},\d+,\d+,\d+\]\S* copy\(",
+        entry, re.M)
+    assert len(copied) <= 2, copied
 
 
 @pytest.mark.parametrize("form,kv_dtype", [

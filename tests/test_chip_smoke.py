@@ -43,7 +43,8 @@ def test_tiny_smoke_runs_every_phase_on_cpu(tmp_path):
     assert train["ok"] and train["platform"] == "cpu"
     assert train["fused_dispatches"] == 4 and len(train["fused_losses"]) == 5
     assert np.isfinite(train["fused_losses"] + train["eager_losses"]).all()
-    assert train["pallas_calls"] > 0 and train["fell_back"] == []
+    assert train["step_custom_calls"] == 0 and train["pallas_calls"] == 0
+    assert train["fell_back"] == [] and train["fallback_calls"] == 0
     assert train["eager_bulked_ops"] > 0
     assert serve["ok"] and serve["platform"] == "cpu"
     assert serve["token_exact"] is True and serve["divergences"] == {}

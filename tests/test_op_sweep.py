@@ -450,7 +450,7 @@ EXEMPT = {
     "npx.roi_align": "covered in test_detection_ops.py",
     # PR 8 fused kernel tier: ops with tuple/stateful signatures the
     # numeric sweep cannot express — parity-swept in test_fused_ops.py
-    "npx.fused_avg_pool2d": "pool_size-tuple op; fwd+VMEM-tiled-backward "
+    "npx.fused_avg_pool2d": "pool_size-tuple op; fwd+bwd "
                             "parity in test_fused_ops.py",
     "npx.fused_batch_norm": "stats-writing multi-output; train+infer "
                             "parity in test_fused_ops.py",
