@@ -79,6 +79,17 @@ EXPERT_BLOCK = 128
 SCORE_BYTES = 2 ** 28
 
 
+def whole_tiles(w):
+    """Width of a `lat{l}` leaf's row: the latent's width in whole lane
+    tiles of 128. The device tiles a narrower last axis up to that anyway,
+    and for a last axis that is NOT whole tiles it stores the array with
+    the positions innermost, which every program then undoes and redoes
+    with a copy of the whole leaf (found when the decode program was first
+    compiled for the chip: five copies of 660 MB each way). Rows narrower
+    than one tile (the tests' sizes) are left alone."""
+    return w if w < 128 else -(-w // 128) * 128
+
+
 class SparseMoEConfig:
     """Static shape record (ints, floats and tuples of strings; nothing
     here ever becomes a tracer). `indexer_types[l]` is `full` or `shared`,
@@ -91,6 +102,8 @@ class SparseMoEConfig:
               "indexer_types", "mlp_types", "mlp_hidden", "expert_hidden",
               "routed_experts", "experts_per_token", "routed_scaling_factor",
               "held_first", "held_count", "max_len", "dtype", "norm_eps")
+    #: `route`'s group limit; 1 group is no limit (this model's)
+    n_group = topk_group = 1
 
     def __init__(self, vocab=128, embed=64, heads=4, q_lora_rank=32,
                  kv_lora_rank=16, qk_nope_head_dim=12, qk_rope_head_dim=4,
@@ -140,17 +153,7 @@ class SparseMoEConfig:
     lat_width = property(lambda self: self.kv_lora_rank
                          + self.qk_rope_head_dim)
 
-    @property
-    def lat_stored(self):
-        """Width of a `lat{l}` leaf's row: `lat_width` in whole lane tiles
-        of 128. The device tiles a narrower last axis up to that anyway,
-        and for a last axis that is NOT whole tiles it stores the array
-        with the positions innermost, which every program then undoes and
-        redoes with a copy of the whole leaf (found when the decode program
-        was first compiled for the chip: five copies of 660 MB each way).
-        Rows narrower than one tile (the tests' sizes) are left alone."""
-        w = self.lat_width
-        return w if w < 128 else -(-w // 128) * 128
+    lat_stored = property(lambda self: whole_tiles(self.lat_width))
     n_full = property(lambda self: self.indexer_types.count("full"))
     n_dense = property(lambda self: self.mlp_types.count("dense"))
     n_sparse = property(lambda self: self.mlp_types.count("sparse"))
@@ -224,13 +227,18 @@ FLOAT32_LEAVES = ("r_b",)
 
 
 def draw_leaf(key, shape, kind, scales=INIT_SCALES):
-    """One leaf's initial value in float32."""
+    """One leaf's initial value in float32: ones, zeros, a normal at the
+    standard deviation `scales[kind]`, or a uniform draw where that is a
+    (low, high) pair."""
     import jax
     import jax.numpy as jnp
     if kind == "ones":
         return jnp.ones(shape)
     if kind == "zeros":
         return jnp.zeros(shape)
+    if isinstance(scales[kind], (tuple, list)):     # (low, high): uniform
+        low, high = scales[kind]
+        return jax.random.uniform(key, shape, minval=low, maxval=high)
     return jax.random.normal(key, shape) * scales[kind]
 
 
@@ -285,11 +293,16 @@ def mla_project(w, c, h, pos):
     """h (.., d) at positions pos (..) -> (cq (.., q_lora_rank), q_nope
     (.., H, nope), q_rope (.., H, rope) rotated, ckr (.., lat_stored): the
     position's cache entry [c normalised | kr rotated | zeros to whole
-    tiles])."""
+    tiles]). With `c.q_lora_rank` None the queries are h `w["wq"]` and cq
+    is None."""
     import jax.numpy as jnp
     dn, dr, kvr = c.qk_nope_head_dim, c.qk_rope_head_dim, c.kv_lora_rank
-    cq = rms_norm(h @ w["wq_a"], w["q_norm"], c.norm_eps)
-    q = (cq @ w["wq_b"]).reshape(h.shape[:-1] + (c.heads, dn + dr))
+    if c.q_lora_rank is None:
+        cq, q = None, h @ w["wq"]
+    else:
+        cq = rms_norm(h @ w["wq_a"], w["q_norm"], c.norm_eps)
+        q = cq @ w["wq_b"]
+    q = q.reshape(h.shape[:-1] + (c.heads, dn + dr))
     ckr = h @ w["wkv_a"]
     parts = [rms_norm(ckr[..., :kvr], w["kv_norm"], c.norm_eps),
              rope(ckr[..., kvr:], pos, c.rope_theta)]
@@ -401,17 +414,35 @@ def mla_read_rebuilt(q_nope, q_rope, ckr, mask, wkv_b, c):
     return o.transpose(1, 2, 0, 3, 4).reshape(B, W, H * dv)
 
 
-def mla_read_absorbed(q_nope, q_rope, ckr, valid, wkv_b, c):
+def mla_read_absorbed(q_nope, q_rope, ckr, valid, wkv_b, c, lengths=None):
     """One query a lane over ITS chosen positions, in the latent space:
     q~_h = q_nope_h Wuk_h^T meets c itself, and Wuv_h follows the read.
     q_nope (S, H, nope), q_rope (S, H, rope), ckr (S, K, lat_stored) the
-    gathered cache entries, valid (S, K) -> (S, H * v_head_dim)."""
+    gathered cache entries, valid (S, K) -> (S, H * v_head_dim).
+
+    With `lengths` (S,) nothing was chosen: `ckr` is the cache leaf itself
+    (rows, max_len, lat_stored), `valid` None, and lane s reads positions
+    [0, lengths[s]] of row s, all of them: H query heads over the one
+    cached vector a position (`ops.fused.paged_attention`'s leaf read: its
+    time follows the live positions)."""
     import jax
     import jax.numpy as jnp
     S, H, dn = q_nope.shape
     dv, kvr = c.v_head_dim, c.kv_lora_rank
     w = wkv_b.reshape(kvr, H, dn + dv)
     q_lat = jnp.einsum("shd,chd->shc", q_nope, w[..., :dn])
+    if lengths is not None:
+        from ..ops import fused as _fused
+        # the stored entry [c | kr | 0] is key and value at once
+        q = jnp.concatenate(
+            [q_lat, q_rope, jnp.zeros((S, H, c.lat_stored - c.lat_width),
+                                      q_lat.dtype)], -1).astype(ckr.dtype)
+        o_lat = _fused.paged_attention(
+            q[:, None], ckr, ckr, lengths, None,
+            scale=1.0 / math.sqrt(dn + c.qk_rope_head_dim),
+            out_dtype=jnp.float32)[:, 0, :, :kvr].astype(ckr.dtype)
+        return jnp.einsum("shc,chd->shd", o_lat, w[..., dn:]).reshape(
+            S, H * dv)
     s = (jnp.einsum("shc,skc->shk", q_lat, ckr[..., :kvr],
                     preferred_element_type=jnp.float32)
          + jnp.einsum("shd,skd->shk", q_rope, ckr[..., kvr:c.lat_width],
@@ -422,16 +453,31 @@ def mla_read_absorbed(q_nope, q_rope, ckr, valid, wkv_b, c):
     return jnp.einsum("shc,chd->shd", o_lat, w[..., dn:]).reshape(S, H * dv)
 
 
-def route(h, r_w, r_b, c):
+def route(h, r_w, r_b, c, with_kept=False):
     """h (T, d) -> (expert ids (T, k) int32, gates (T, k) float32): the
-    top-k of sigma + b, gated by sigma normalised over the k chosen."""
+    top-k of sigma + b, gated by sigma normalised over the k chosen.
+
+    With `c.n_group` > 1 the choice is group-limited (DeepSeek-V3's
+    `noaux_tc`): the experts lie in `n_group` contiguous groups, a group
+    scores the sum of its 2 largest sigma + b, and the top-k is taken
+    inside the `c.topk_group` best groups only. `with_kept` appends the
+    kept groups (T, n_group) bool."""
     import jax
     import jax.numpy as jnp
     sig = jax.nn.sigmoid(jnp.dot(h, r_w, preferred_element_type=jnp.float32))
-    _, idx = jax.lax.top_k(sig + r_b.astype(jnp.float32),
-                           c.experts_per_token)
+    biased = sig + r_b.astype(jnp.float32)
+    kept = jnp.ones(sig.shape[:-1] + (1,), bool)
+    if c.n_group > 1:
+        grouped = biased.reshape(sig.shape[:-1] + (c.n_group, -1))
+        score = jnp.sum(jax.lax.top_k(grouped, 2)[0], -1)
+        _, best = jax.lax.top_k(score, c.topk_group)
+        kept = jnp.any(best[..., None] == jnp.arange(c.n_group), -2)
+        biased = jnp.where(kept[..., None], grouped, -jnp.inf).reshape(
+            sig.shape)
+    _, idx = jax.lax.top_k(biased, c.experts_per_token)
     g = jnp.take_along_axis(sig, idx, -1)
-    return idx, c.routed_scaling_factor * g / jnp.sum(g, -1, keepdims=True)
+    gates = c.routed_scaling_factor * g / jnp.sum(g, -1, keepdims=True)
+    return (idx, gates, kept) if with_kept else (idx, gates)
 
 
 def routed_experts(h, idx, gates, token_ok, w_gate_up, w_down, held_first,
@@ -530,16 +576,19 @@ def _weights(params, c, l):
 def _ffn(x, w, c, l, token_ok):
     """x (T, d) -> (x + FFN_l(RMSNorm(x)), moe counters (4,) int32:
     token-expert pairs on held experts, held experts that received a
-    token, held experts offered, the largest load)."""
+    token, held experts offered, the largest load). A group-limited router
+    (`c.n_group` > 1) counts two more: the tokens whose kept groups hold
+    one of this process's experts (`c.held_groups`), and the tokens routed."""
     import jax
     import jax.numpy as jnp
     h = rms_norm(x, w["ln2_w"], c.norm_eps)
+    count_groups = c.n_group > 1
     if c.mlp_types[l] == "dense":
         with jax.named_scope(f"layer{l}/mlp"):
             return x + gated_mlp(h, w["d_gate_up"], w["d_down"]), \
-                jnp.zeros((4,), jnp.int32)
+                jnp.zeros((6 if count_groups else 4,), jnp.int32)
     with jax.named_scope(f"layer{l}/router"):
-        idx, gates = route(h, w["r_w"], w["r_b"], c)
+        idx, gates, kept = route(h, w["r_w"], w["r_b"], c, with_kept=True)
     with jax.named_scope(f"layer{l}/experts"):
         y, loads = routed_experts(h, idx, gates, token_ok, w["e_gate_up"],
                                   w["e_down"], c.held_first, c.held_count,
@@ -547,9 +596,13 @@ def _ffn(x, w, c, l, token_ok):
     with jax.named_scope(f"layer{l}/shared_expert"):
         y = y + gated_mlp(h, w["s_gate_up"], w["s_down"])
     any_token = jnp.any(token_ok).astype(jnp.int32)
-    return x + y, jnp.stack([
-        jnp.sum(loads), jnp.sum(loads > 0, dtype=jnp.int32),
-        c.held_count * any_token, jnp.max(loads)])
+    counted = [jnp.sum(loads), jnp.sum(loads > 0, dtype=jnp.int32),
+               c.held_count * any_token, jnp.max(loads)]
+    if count_groups:
+        here = jnp.any(kept[:, jnp.asarray(c.held_groups)], -1) & token_ok
+        counted += [jnp.sum(here, dtype=jnp.int32),
+                    jnp.sum(token_ok, dtype=jnp.int32)]
+    return x + y, jnp.stack(counted)
 
 
 def _head(params, x, c):
@@ -707,16 +760,25 @@ def _make_micro(config):
     return micro
 
 
-def _make_decode(config, steps, eos_id):
+#: what every program of this decoder counts (`SparseMoEDecoder.counters`)
+COUNTERS = {
+    "moe": ("pairs_held", "experts_hit", "experts_offered", "max_load_sum"),
+    "sparse": ("queries", "live_positions", "chosen_positions"),
+}
+
+
+def _make_decode(config, steps, eos_id, micro=None, counter_fields=COUNTERS):
     """The decode step: every pool slot advances up to `steps` tokens in
     one program (`lax.scan` over the micro-step),
     `serve.continuous._make_decode`'s contract with the counters last:
     `decode(params, cache, tokens, lengths, steps_left, temps, top_ks,
-    top_ps, keys) -> (cache, out_tokens (steps, S), emitted, counters)`."""
+    top_ps, keys) -> (cache, out_tokens (steps, S), emitted, counters)`.
+    Another block's decoder hands in its own `micro` and the fields it
+    counts ({name: field names})."""
     import jax
     import jax.numpy as jnp
     from ..serve.sampling import sample_tokens
-    micro = _make_micro(config)
+    micro = micro or _make_micro(config)
 
     def decode(params, cache, tokens, lengths, steps_left, temps, top_ks,
                top_ps, keys):
@@ -736,8 +798,8 @@ def _make_decode(config, steps, eos_id):
             return (cache, last, lens, new_left, emitted, counters), nxt
 
         zero = jnp.zeros_like(steps_left)
-        counters = {"moe": jnp.zeros((4,), jnp.int32),
-                    "sparse": jnp.zeros((3,), jnp.int32)}
+        counters = {name: jnp.zeros((len(fields),), jnp.int32)
+                    for name, fields in counter_fields.items()}
         (cache, _, _, _, emitted, counters), toks = jax.lax.scan(
             step, (cache, tokens, lengths, steps_left, zero, counters), None,
             length=steps)
@@ -758,18 +820,21 @@ class SparseMoEDecoder:
     these fields, which the engine sums into `stats()[name]`)."""
 
     chunk_rows_as_data = True
-    counters = {
-        "moe": ("pairs_held", "experts_hit", "experts_offered",
-                "max_load_sum"),
-        "sparse": ("queries", "live_positions", "chosen_positions"),
-    }
+    counters = COUNTERS
+    # what a decoder of another block brings (`models.delta_moe_decoder`):
+    # its initializer, its chunk and micro-step builders, `counters` and
+    # `cache_spec`; the pool, the program table and `reference_generate`
+    # are the same
+    _init_params = staticmethod(init_sparse_moe_params)
+    _make_chunk = staticmethod(_make_chunk)
+    _make_micro = staticmethod(_make_micro)
 
     def __init__(self, config, params=None, seed=0):
         from ..deploy import maybe_enable_compile_cache
         maybe_enable_compile_cache()
         self.config = config
         self.params = params if params is not None \
-            else init_sparse_moe_params(config, seed)
+            else self._init_params(config, seed)
         try:
             from ..inspect import memory as _mem
             _mem.register(self.params, owner="decoder_params")
@@ -790,8 +855,8 @@ class SparseMoEDecoder:
         if dtype is not None and str(dtype) != self.config.dtype:
             raise ServeError(
                 f"this decoder's cache is stored in its own dtype "
-                f"({self.config.dtype}); kv_dtype={dtype!r} has no latent "
-                f"or index-key form")
+                f"({self.config.dtype}); kv_dtype={dtype!r} has no latent, "
+                f"index-key or state form")
         return KVCachePool(max_slots, dtype=self.config.dtype,
                            spec=self.cache_spec())
 
@@ -807,7 +872,8 @@ class SparseMoEDecoder:
     def prefill_program(self, window):
         w = int(window)
         return self._program(("prefill", w),
-                             lambda: _make_chunk(self.config, w, w, True),
+                             lambda: self._make_chunk(self.config, w, w,
+                                                      True),
                              f"prefill[w={w}]")
 
     def chunk_prefill_program(self, window, extent=None):
@@ -816,7 +882,8 @@ class SparseMoEDecoder:
         w = int(window)
         e = int(extent if extent is not None else self.config.max_len)
         return self._program(("chunk", w, e),
-                             lambda: _make_chunk(self.config, w, e, False),
+                             lambda: self._make_chunk(self.config, w, e,
+                                                      False),
                              f"chunk_prefill[w={w},e={e}]")
 
     def decode_program(self, steps, eos_id=None, draft=0):
@@ -824,10 +891,13 @@ class SparseMoEDecoder:
             raise ServeError(
                 "speculative decode verifies drafts against a dense read "
                 "of K and V rows; a read of chosen positions of a latent "
-                "cache has no verify program yet")
+                "cache has no verify program yet, and a recurrent state "
+                "cannot be rolled back to the last accepted token")
         key = ("decode", int(steps), eos_id)
         return self._program(
-            key, lambda: _make_decode(self.config, key[1], eos_id),
+            key, lambda: _make_decode(self.config, key[1], eos_id,
+                                      self._make_micro(self.config),
+                                      self.counters),
             f"decode[s={key[1]},eos={eos_id}]")
 
     def compile_cache_size(self):

@@ -950,8 +950,11 @@ class RequestTiming:
     slot claimed), `t_first` (first token out of prefill), `t_done` (set
     just before the future resolves; stays None for a request that
     failed). `prompt_tokens`, `cached_tokens` (served from the prefix
-    cache) and `tokens` (generated so far) are counts. `stats()`'s
-    ttft/tpot/e2e percentiles are computed from these same fields."""
+    cache) and `tokens` (generated so far) are counts; `slot` is the pool
+    row the request was given at `t_admit` (None before): what it left in
+    `engine.pool`'s leaves stays there until the row's next tenant.
+    `stats()`'s ttft/tpot/e2e percentiles are computed from these same
+    fields."""
 
     __slots__ = ("_req",)
 
@@ -965,11 +968,12 @@ class RequestTiming:
     prompt_tokens = property(lambda self: int(self._req.prompt.size))
     cached_tokens = property(lambda self: self._req.cached_len)
     tokens = property(lambda self: len(self._req.generated))
+    slot = property(lambda self: self._req.slot)
 
     def as_dict(self):
         return {k: getattr(self, k) for k in (
             "t_submit", "t_admit", "t_first", "t_done", "prompt_tokens",
-            "cached_tokens", "tokens")}
+            "cached_tokens", "tokens", "slot")}
 
     def __repr__(self):
         return f"RequestTiming({self.as_dict()})"

@@ -335,6 +335,37 @@ def test_sparse_moe_programs_fit_the_chip_at_glm52_widths(chip, program):
     assert mem.argument_size_in_bytes > 11e9         # the cell's own size
 
 
+def test_delta_moe_decode_fits_the_chip_at_ling3f_widths(chip, monkeypatch):
+    """`chipbench/configs/ling3f_serve.json`: the decode program (128 lanes,
+    4 micro-steps) whole, at every published width: arguments (2.87 B
+    parameters in bfloat16, a 129-row cache of six float32 state leaves,
+    six conv tails and one latent leaf: 10.1 GB) with the cache updated in
+    place, the latent read in the paged kernel's leaf mode, and NO second
+    copy of a state leaf among the temporaries (271 MB each: a decayed copy
+    a layer was 290 MB more than the program needs)."""
+    import json
+    import sys
+    import jax
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench.tests import compile_v5e_ling
+    from incubator_mxnet_tpu.ops import fused
+    # the platform this process sees is the CPU: take the chip's branch
+    monkeypatch.setattr(fused, "_on_tpu", lambda: True)
+    with open(os.path.join(root, "chipbench/configs/ling3f_serve.json")) as f:
+        cfg = json.load(f)
+    fn, args = compile_v5e_ling.serving_programs(cfg, chip)["decode"]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    cache = sum(math.prod(a.shape) * a.dtype.itemsize
+                for a in args[1].values())
+    assert mem.alias_size_in_bytes >= cache          # no second cache
+    assert 10.0e9 < mem.argument_size_in_bytes < 10.3e9   # the cell's size
+    assert mem.temp_size_in_bytes < 0.5 * 2 ** 30, mem.temp_size_in_bytes
+    assert compiled.as_text().count("tpu_custom_call") >= 1
+
+
 def test_hlo_parser_reads_a_tpu_compiled_module(chip):
     """`mx.inspect` on what the TPU's compiler prints: operands named
     without shapes, tiled layouts, a dot lowered to a convolution inside a

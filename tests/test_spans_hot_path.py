@@ -329,7 +329,7 @@ def test_request_timing_is_public_monotone_and_whole_at_resolution(served):
         eng.close()
     for tm in [f.timing for f in served["futs"]] + [fut.timing]:
         assert tm.t_submit <= tm.t_admit <= tm.t_first <= tm.t_done
-        assert tm.tokens >= 5
+        assert tm.tokens >= 5 and tm.slot is not None
     # whole before the future resolved: the callback saw the final record
     assert seen and seen[0] == fut.timing.as_dict()
     assert seen[0]["t_done"] is not None and seen[0]["tokens"] == 6
