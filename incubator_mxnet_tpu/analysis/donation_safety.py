@@ -497,8 +497,8 @@ def _own_walk(node):
 def _donating_functions(mod, table, scopes):
     """Simple names of functions whose body (transitively, via same-module
     simple-name calls) performs a donated-program call — so a try/except
-    around `self._run_decode()` is recognized as guarding the donated
-    decode call one level down."""
+    around `self._iterate()` is recognized as guarding the donated
+    decode call two levels down."""
     direct = set()
     calls = {}                      # fn simple name -> {callee last segs}
     for qual, fn in scopes:

@@ -11,9 +11,9 @@ literals fail outright), and dicts built from unordered sets (pytree
 structure varies per process, silently doubling the program cache).
 
 A function is STEADY-STATE when it sits on the engine's replay path: it
-contains a loop that (directly, or through a same-module helper such as
-`ContinuousEngine._run_decode` / the batcher `_execute`) invokes a
-compiled program resolved by the donation-safety program table.
+contains a loop that (directly, or through same-module helpers such as
+`ContinuousEngine._iterate` -> `_dispatch_wave` / the batcher `_execute`)
+invokes a compiled program resolved by the donation-safety program table.
 
 Rules:
 
@@ -68,23 +68,37 @@ def _loops(fn):
             yield n
 
 
-def _calls_any(node, names):
-    """True when `node`'s subtree calls a simple/attr name in `names`."""
+def _called(node, names):
+    """The simple/attr names of `names` that `node`'s subtree calls."""
+    out = set()
     for n in _own_walk(node):
         if isinstance(n, ast.Call):
             cname = call_name(n)
             if cname and cname.split(".")[-1] in names:
-                return True
-    return False
+                out.add(cname.split(".")[-1])
+    return out
 
 
 def _steady_regions(mod, table, scopes):
     """[(qual, fn, region_node)] — regions executed once per steady-state
     iteration. A loop body that calls a compiled program (or a same-module
     program-calling helper) is a region; so is the WHOLE body of a helper
-    that a loop invokes each iteration."""
+    that a loop invokes each iteration, directly or through other
+    helpers."""
     prog_callers = {fn.name for qual, fn in scopes
                     if _program_calls(fn, table, qual)}
+    by_name = {}
+    for qual, fn in scopes:
+        by_name.setdefault(fn.name, []).append(fn)
+    # a function that calls a program-calling helper is one itself
+    grew = True
+    while grew:
+        grew = False
+        for name, fns in by_name.items():
+            if name not in prog_callers and any(
+                    _called(fn, prog_callers) for fn in fns):
+                prog_callers.add(name)
+                grew = True
     regions = []
     helpers_in_loops = set()
     for qual, fn in scopes:
@@ -92,15 +106,17 @@ def _steady_regions(mod, table, scopes):
             direct = any(True for n in _own_walk(loop)
                          if isinstance(n, ast.Call)
                          and table.lookup_call(n, qual) is not None)
-            via_helper = _calls_any(loop, prog_callers)
+            via_helper = _called(loop, prog_callers)
             if direct or via_helper:
                 regions.append((qual, fn, loop))
-            if via_helper:
-                for n in _own_walk(loop):
-                    if isinstance(n, ast.Call):
-                        cname = call_name(n)
-                        if cname and cname.split(".")[-1] in prog_callers:
-                            helpers_in_loops.add(cname.split(".")[-1])
+            helpers_in_loops |= via_helper
+    # ... and so are the helpers that those helpers call
+    todo = list(helpers_in_loops)
+    while todo:
+        for fn in by_name.get(todo.pop(), ()):
+            new = _called(fn, prog_callers) - helpers_in_loops
+            helpers_in_loops |= new
+            todo.extend(new)
     for qual, fn in scopes:
         if fn.name in helpers_in_loops:
             regions.append((qual, fn, fn))

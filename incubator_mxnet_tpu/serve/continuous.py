@@ -19,12 +19,18 @@ plain device buffers, zero retraces):
     lanes, their writes land in the garbage row). Both donate the KV
     buffers, so updates are in-place `dynamic_update_slice` scatters on
     accelerators (the `.at[rows, layer, pos].set(...)` idiom).
-  * **Iteration-level scheduling**: every engine iteration first retires
-    finished requests (their slots free IMMEDIATELY, not at batch end),
-    then admits waiting requests under a prefill token budget
+  * **Iteration-level scheduling**: every engine iteration admits
+    waiting requests under a prefill token budget
     (`MXNET_SERVE_PREFILL_BUDGET` — bounds how much prefill work may
-    delay in-flight decode iterations), then runs one decode step for
-    every active slot. Admission is DEADLINE-AWARE, not FIFO: waiting
+    delay in-flight decode iterations), dispatches their prefill
+    programs and one decode wave for every slot with budget left, and
+    only THEN reads back what the PREVIOUS iteration dispatched (first
+    tokens, the wave's tokens), resolves and retires (a finished
+    request's slot frees at that read, not at batch end) — so the
+    device runs wave n+1 while the host does wave n's work. A wave's
+    `tokens`, `lengths` and `steps_left` never pass through the host:
+    they live on the device between waves (`_join_lanes`,
+    `_advance_lanes`). Admission is DEADLINE-AWARE, not FIFO: waiting
     requests are granted slots earliest-deadline-first (SLO-aware
     admission over the PR-3 deadline plumbing), and a request whose
     deadline expires while waiting fails fast with `RequestTimeout`.
@@ -648,6 +654,64 @@ def _make_spec_decode(config, steps=1, eos_id=None, draft=2):
     return spec
 
 
+def _join_lanes(tokens, lengths, steps_left, first, join, eos_id=None):
+    """Lane state for the requests whose prompt ended in a prefill
+    program, set on the device: lane s with `join[0, s] >= 0` takes
+    `first[join[0, s]]` (that program's `sample_first` output, never
+    read by the host before the wave that consumes it) as its last
+    token, `join[1, s]` as its cache length and `join[2, s]` as its
+    budget — 0 where the first token is `eos_id`, as `_finished` would
+    have found on the host. Every other lane keeps what it has."""
+    import jax.numpy as jnp
+    src, lens, budget = join
+    on = src >= 0
+    tok = first[jnp.maximum(src, 0)]
+    if eos_id is not None:
+        budget = jnp.where(tok == eos_id, 0, budget)
+    # a request that ends at its first token joins as the idle lane (0, 0, 0)
+    live = budget > 0
+    return (jnp.where(on, jnp.where(live, tok, 0), tokens),
+            jnp.where(on, jnp.where(live, lens, 0), lengths),
+            jnp.where(on, budget, steps_left))
+
+
+def _advance_lanes(tokens, lengths, steps_left, out_tokens, emitted,
+                   eos_id=None):
+    """Lane state after a decode wave, from the wave's own outputs and
+    without a host read: exactly the `(last, lens, left)` the decode scan
+    carried and dropped. A lane that emitted n tokens holds its n-th as
+    last token, n more positions and n less budget (0 after `eos_id`,
+    which is then its last token); a lane left without budget is handed
+    to the next wave as the idle lane the host used to pack (0, 0, 0),
+    so its paged read stays one block."""
+    import jax.numpy as jnp
+    on = emitted > 0
+    last = out_tokens[jnp.maximum(emitted - 1, 0),
+                      jnp.arange(emitted.shape[0])]
+    left = steps_left - emitted
+    if eos_id is not None:
+        left = jnp.where(on & (last == eos_id), 0, left)
+    live = left > 0
+    return (jnp.where(live, jnp.where(on, last, tokens), 0),
+            jnp.where(live, lengths + emitted, 0), left)
+
+
+def _lane_programs(eos_id):
+    """One engine's own jits of `_join_lanes` and `_advance_lanes` (jax
+    keys a jit's traces by the function it wraps: wrapped here, an
+    engine's `compile_cache_size` counts its own), named `jit_join_lanes`
+    and `jit_advance_lanes` in a device trace."""
+    import jax
+
+    def join_lanes(*state_first_join):
+        return _join_lanes(*state_first_join, eos_id=eos_id)
+
+    def advance_lanes(*state_and_outputs):
+        return _advance_lanes(*state_and_outputs, eos_id=eos_id)
+
+    return jax.jit(join_lanes), jax.jit(advance_lanes)
+
+
 class CachedDecoder:
     """The model side of the continuous engine: two jitted programs over
     a KV slot pool. Programs are shape-generic in the POOL (the garbage
@@ -983,7 +1047,8 @@ class _GenRequest:
     __slots__ = ("prompt", "max_new", "future", "deadline", "t_submit",
                  "ctx", "slot", "generated", "cache_len", "t_admit",
                  "t_first", "t_last", "t_done", "temperature", "top_k",
-                 "top_p", "key", "entry", "cached_len", "prefill_pos")
+                 "top_p", "key", "entry", "cached_len", "prefill_pos",
+                 "left")
 
     def __init__(self, prompt, max_new, deadline, ctx,
                  temperature=0.0, top_k=0, top_p=1.0, key=None):
@@ -1008,6 +1073,10 @@ class _GenRequest:
         self.entry = None        # pinned prefix-cache entry (hit path)
         self.cached_len = 0      # prompt tokens served from the cache
         self.prefill_pos = 0     # prompt tokens already in KV (chunked)
+        # the budget the lane holds after every wave DISPATCHED so far, as
+        # far as the host can know before reading them: exact without an
+        # eos, an upper bound with one (the device holds the truth)
+        self.left = 0
 
     def sort_key(self):
         """Earliest-deadline-first; deadline-less requests rank after
@@ -1016,6 +1085,25 @@ class _GenRequest:
                 self.deadline if self.deadline is not None
                 else self.t_submit,
                 self.t_submit)
+
+
+class _Unread:
+    """What one scheduler iteration handed the device and nobody has read
+    back: `firsts`, a (first-token vector, [(request, its lane there)])
+    pair for each prefill program in which a prompt ended; `wave`, the
+    decode wave's (lanes by slot, device outputs, lanes it sampled for)
+    or None; `counters`, the count vectors that the iteration's programs
+    returned (a model that declares `counters`). False when empty."""
+
+    __slots__ = ("firsts", "wave", "counters")
+
+    def __init__(self):
+        self.firsts = []
+        self.wave = None
+        self.counters = []
+
+    def __bool__(self):
+        return bool(self.firsts or self.wave is not None or self.counters)
 
 
 class ContinuousEngine:
@@ -1272,7 +1360,7 @@ class ContinuousEngine:
             "requests", "replies", "rejected", "timeouts", "errors",
             "admitted", "retired", "decode_iterations", "decode_tokens",
             "prefill_tokens", "prefill_batches", "programs_compiled",
-            "active_sum", "sampled_tokens", "sampled_waves",
+            "active_sum", "waves_ahead", "sampled_tokens", "sampled_waves",
             "draft_accepted",
             "draft_rejected", "prefix_hits", "prefix_misses",
             "prefix_cached_tokens", "copied_positions")}
@@ -1280,13 +1368,23 @@ class ContinuousEngine:
         # live, summed over waves (beside `decode_iterations`)
         self._cache_live = {k: 0 for k in self.pool.kinds()}
         # a model may declare `counters` ({name: field names}): each of its
-        # programs then returns, last, {name: int vector}; the vectors wait
-        # in `_pending` for the wave's own readback and are summed there
+        # programs then returns, last, {name: int vector}; an iteration's
+        # vectors gather in `_pending`, leave with its `_Unread` record
+        # and are summed where that record is read
         self._counter_fields = dict(getattr(model, "counters", None) or {})
         self._model_counters = {
             name: _np.zeros((len(fields),), dtype=_np.int64)
             for name, fields in self._counter_fields.items()}
         self._pending = []
+        # the decode wave's `tokens`, `lengths`, `steps_left` live on the
+        # device between waves (`_lanes`): a prompt's end writes its lane
+        # (`_join_prog`), a wave's outputs advance every lane
+        # (`_advance_prog`), the host reads neither before the next
+        # dispatch. `_unread` is what the last iteration dispatched and
+        # nobody has read yet. The scheduler thread alone touches the three
+        self._join_prog, self._advance_prog = _lane_programs(eos_id)
+        self._reset_lanes()
+        self._unread = None  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
         self._auto_seed = 0                  # per-engine seed fountain
         # (ttft, tpot or None, e2e) ms of the newest retired requests, from
         # their RequestTiming fields: stats()'s one source of percentiles
@@ -1303,7 +1401,7 @@ class ContinuousEngine:
         if warmup:
             self._warmup()
         with self._cv:
-            self._warm_cache_size = self.model.compile_cache_size()
+            self._warm_cache_size = self.compile_cache_size()
             self._started = True
         self.warmup_s = round(time.perf_counter() - t0, 3)
         with self._mlock:
@@ -1341,11 +1439,15 @@ class ContinuousEngine:
             lens, jnp.full((P,), g, dtype=jnp.int32)))
         self.pool.swap_buffers(*cache)
         # warm the shared first-token sampler at this (P, vocab) shape
-        # too — it is part of the steady-state prefill wave
-        _sample_first(logits, jnp.zeros((P,), dtype=jnp.float32),
-                      jnp.zeros((P,), dtype=jnp.int32),
-                      jnp.ones((P,), dtype=jnp.float32),
-                      jnp.zeros((P, 2), dtype=jnp.uint32), lens - 1)
+        # too — it is part of the steady-state prefill wave — and the
+        # lane join at its output's shape, with no lane joining
+        first = _sample_first(logits, jnp.zeros((P,), dtype=jnp.float32),
+                              jnp.zeros((P,), dtype=jnp.int32),
+                              jnp.ones((P,), dtype=jnp.float32),
+                              jnp.zeros((P, 2), dtype=jnp.uint32), lens - 1)
+        self._reset_lanes()
+        nobody = jnp.full((3, S), -1, dtype=jnp.int32)
+        self._lanes = self._join_prog(*self._lanes, first, nobody)  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
         args = [jnp.zeros((S,), dtype=jnp.int32),
                 jnp.zeros((S,), dtype=jnp.int32),
                 jnp.zeros((S,), dtype=jnp.int32),
@@ -1360,6 +1462,8 @@ class ContinuousEngine:
         out = self._outputs(self._decode_prog(self.model.params, *cache,
                                               *args))
         self.pool.swap_buffers(*out[:n])
+        if not self.draft_tokens:
+            self._lanes = self._advance_prog(*self._lanes, *out[n:])  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
         n_progs = 2
         if self._chunk_progs is not None:
             # all-idle chunk wave (every lane scatters into garbage)
@@ -1381,11 +1485,13 @@ class ContinuousEngine:
                     prog(self.model.params, *cache, *idle))
                 self.pool.swap_buffers(*cache)
                 n_progs += 1
-            _sample_first(logits, jnp.zeros((C,), dtype=jnp.float32),
-                          jnp.zeros((C,), dtype=jnp.int32),
-                          jnp.ones((C,), dtype=jnp.float32),
-                          jnp.zeros((C, 2), dtype=jnp.uint32),
-                          jnp.zeros((C,), dtype=jnp.int32))
+            first = _sample_first(
+                logits, jnp.zeros((C,), dtype=jnp.float32),
+                jnp.zeros((C,), dtype=jnp.int32),
+                jnp.ones((C,), dtype=jnp.float32),
+                jnp.zeros((C, 2), dtype=jnp.uint32),
+                jnp.zeros((C,), dtype=jnp.int32))
+            self._lanes = self._join_prog(*self._lanes, first, nobody)  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
         if self._copy_prog is not None:
             # every lane at length 0: compiles, moves nothing
             kb, vb = self.pool.buffers()
@@ -1394,33 +1500,42 @@ class ContinuousEngine:
             self.pool.swap_buffers(k, v)
             n_progs += 1
         # wait for the compiles to actually finish so warmup_s is honest
-        jax.block_until_ready(self.pool.buffers())
+        jax.block_until_ready((self.pool.buffers(), self._lanes))
         self._pending.clear()  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
+        self._reset_lanes()
         self._count("programs_compiled", n_progs)
+
+    def _reset_lanes(self):
+        """Every lane idle: what the device holds before the first join,
+        and again after a failed step."""
+        import jax.numpy as jnp
+        idle = jnp.zeros((self.pool.max_slots,), dtype=jnp.int32)
+        self._lanes = (idle, idle, idle)  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
 
     def _outputs(self, out):
         """A step program's outputs without the model's counters: where
-        the model declares `counters` they come last, and wait for the
-        wave's readback (`_read_counters`)."""
+        the model declares `counters` they come last, and wait in
+        `_pending` for the read of the iteration that dispatched them
+        (`_read_counters`)."""
         if not self._counter_fields:
             return out
+        for vec in out[-1].values():
+            vec.copy_to_host_async()
         self._pending.append(out[-1])  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
         return out[:-1]
 
-    def _read_counters(self):
-        """Sum what the wave's programs counted. Called where the wave
-        reads its tokens back anyway, so the programs that counted have
+    def _read_counters(self, trees):
+        """Sum what an iteration's programs counted. Called where its
+        tokens are read back anyway, so the programs that counted have
         finished; a model without `counters` pays one truth test."""
-        if not self._pending:
+        if not trees:
             return
         got = [{name: _np.asarray(vec) for name, vec in tree.items()}
-               for tree in self._pending]
-        self._pending.clear()  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
+               for tree in trees]
         with self._mlock:
             for tree in got:
                 for name, vec in tree.items():
                     self._model_counters[name] += vec
-
 
     def __enter__(self):
         return self.start()
@@ -1572,7 +1687,12 @@ class ContinuousEngine:
                 SERVE_STATS[stats_key] += n
 
     def compile_cache_size(self):
-        return self.model.compile_cache_size()
+        """The model's compiled programs and the engine's own two (the
+        lane join and advance), -1 where the jax version hides a count."""
+        sizes = [self.model.compile_cache_size()] + [
+            int(getattr(f, "_cache_size", lambda: -1)())
+            for f in (self._join_prog, self._advance_prog)]
+        return -1 if min(sizes) < 0 else sum(sizes)
 
     def prefix_hit_count(self):
         """Lifetime prefix-cache hits — the cheap accessor the replica
@@ -1585,7 +1705,7 @@ class ContinuousEngine:
         state (-1 when the jax version hides the counter)."""
         if self._warm_cache_size is None or self._warm_cache_size < 0:
             return -1
-        now = self.model.compile_cache_size()
+        now = self.compile_cache_size()
         return -1 if now < 0 else now - self._warm_cache_size
 
     def assert_no_retraces(self):
@@ -1759,20 +1879,7 @@ class ContinuousEngine:
                         self._cv.wait(timeout=0.005)
                 continue
             try:
-                # _prefilling is only ever mutated on this thread, so the
-                # unlocked read is single-writer safe
-                if admitted or self._prefilling:
-                    with (_span("serve.prefill_batch", cat="serve",
-                                requests=len(admitted)) if on
-                          else NO_SPAN) as sp:
-                        done = self._run_prefill(admitted, jnp, on, sp)
-                    self._retire(done, on)
-                if self._running:
-                    with (_span("serve.decode_batch", cat="serve",
-                                steps=self.decode_steps) if on
-                          else NO_SPAN) as sp:
-                        done = self._run_decode(jnp, on, sp)
-                    self._retire(done, on)
+                self._iterate(admitted, jnp, on)
             except BaseException as e:
                 # a step failure fails the IN-FLIGHT requests, frees
                 # their slots, and the engine keeps serving (the PR-3
@@ -1781,6 +1888,9 @@ class ContinuousEngine:
                 # + flightrec ring) BEFORE the slab reallocation below
                 # rewrites the memory picture.
                 mem_on_oom(e, where="serve.continuous")
+                # what was dispatched and not read belongs to the requests
+                # failed below: both outstanding iterations go unread
+                self._unread = None  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
                 err = e if isinstance(e, MXNetError) else ServeError(
                     f"engine step failed: {type(e).__name__}: {e}")
                 with self._cv:
@@ -1803,6 +1913,7 @@ class ContinuousEngine:
                 # was just failed, so zeroed slabs are the correct state.
                 self.pool.reallocate()
                 self._pending.clear()  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
+                self._reset_lanes()
                 if self._canary is not None:
                     # fresh zeroed slabs replaced the poisoned row
                     self._canary.rearm()
@@ -1885,21 +1996,77 @@ class ContinuousEngine:
             self._prefilling[req.slot] = req  # mxlint: disable=lock-shared-mutation -- _admit_locked runs with self._cv held by its only caller (_loop)
         return admitted, expired
 
-    def _run_prefill(self, admitted, jnp, on, sp):
-        """One prefill wave: slab-to-slab KV row copies for the admitted
-        prefix-cache hits, the fixed-shape windowed program for lanes
-        starting at page offset 0, then ONE chunk dispatch advancing
-        EVERY lane with pending suffix/chunk work (admitted hits and
-        long prompts mid-stream alike). A request emits its first token
-        the wave its prefill completes — `prefill_tokens` bills only
-        tokens a program actually processed (suffix-only on a hit).
-        Returns the requests that finished at their first token.
+    def _iterate(self, admitted, jnp, on):
+        """One iteration's device work, dispatch before read: the prefill
+        programs, the decode wave, and only then the blocking read of
+        what the PREVIOUS iteration dispatched (`_unread`: first tokens,
+        the wave's tokens, counters), its bookkeeping and the retirement
+        of what it finished — all of it while the device runs what this
+        iteration just handed over. An iteration that finds nothing to
+        dispatch reads at once, so a last token never waits for traffic.
+
+        A drafting wave (`draft_tokens > 0`) packs a token history that
+        the host builds from the tokens read so far: it reads its OWN
+        iteration back as soon as it is dispatched, and a request's first
+        wave is the one after its first token is read. Same order, the
+        read one record earlier.
+
+        The read sits in the iteration's `serve.decode_batch` span
+        (`.readback`, `.emit`) whenever there is one — it dispatches a
+        wave, or the record read holds one — and else, first tokens only,
+        in a `serve.prefill_batch` span of its own (`.readback`)."""
+        rec = _Unread()
+        self._pending = rec.counters  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
+        reading = rec if self.draft_tokens else self._unread
+        # a wave dispatched now goes out while this one is unread
+        unread_wave = reading is not None and reading.wave is not None
+        done = []
+        # _prefilling is only ever mutated on this thread, so the
+        # unlocked read is single-writer safe
+        if admitted or self._prefilling:
+            with (_span("serve.prefill_batch", cat="serve",
+                        requests=len(admitted)) if on else NO_SPAN) as sp:
+                self._dispatch_prefill(admitted, rec, jnp, on, sp)
+        lanes = self._wave_lanes()
+        if lanes or unread_wave:
+            with (_span("serve.decode_batch", cat="serve",
+                        steps=self.decode_steps) if on else NO_SPAN) as sp:
+                if lanes:
+                    self._dispatch_wave(lanes, rec, jnp, on)
+                    if unread_wave:
+                        self._count("waves_ahead")
+                active = tokens = sampled = 0
+                if reading:
+                    done, active, tokens, sampled = self._read(
+                        reading, True, on)
+                if on:
+                    sp.set(ahead=int(bool(lanes) and unread_wave),
+                           active=active, tokens=tokens, sampled=sampled)
+        elif reading:
+            with (_span("serve.prefill_batch", cat="serve", requests=0)
+                  if on else NO_SPAN):
+                done = self._read(reading, False, on)[0]
+        self._unread = rec if rec and not self.draft_tokens else None  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
+        self._retire(done, on)
+
+    def _dispatch_prefill(self, admitted, rec, jnp, on, sp):
+        """One prefill wave, dispatched and not read: slab-to-slab KV row
+        copies for the admitted prefix-cache hits, the fixed-shape
+        windowed program for lanes starting at page offset 0, then ONE
+        chunk dispatch advancing EVERY lane with pending suffix/chunk
+        work (admitted hits and long prompts mid-stream alike). A request
+        whose prompt ends here — the host knows that from its size —
+        JOINS the decode lanes on the device (`_join_lanes`: its first
+        token straight from `sample_first`'s output, its length and
+        budget from the host) and moves to `_running`; its first token
+        reaches the host with `rec` (`rec.firsts`). `prefill_tokens`
+        bills only tokens a program actually processed (suffix-only on a
+        hit).
 
         Runs inside the loop's `serve.prefill_batch` span `sp`; armed
         (`on`), each program's host side is split into `.pack` (NumPy
-        arrays and their transfers), `.dispatch` (the jit calls
-        returning) and `.readback` (the blocking read of the first
-        tokens)."""
+        arrays and their transfers) and `.dispatch` (the jit calls
+        returning, the join among them)."""
         _fault.inject("serve.execute")
         W = self.prefill_window
         g = self.pool.garbage_row
@@ -1918,7 +2085,6 @@ class ContinuousEngine:
         if self._cache is not None and cold:
             self._count("prefix_misses", len(cold))
         n_tokens = 0
-        finished = []                        # (req, first token)
         if cold:
             with (_span("serve.prefill_batch.pack", cat="serve") if on
                   else NO_SPAN):
@@ -1930,6 +2096,7 @@ class ContinuousEngine:
                 tks = _np.zeros((P,), dtype=_np.int32)
                 tps = _np.ones((P,), dtype=_np.float32)
                 keys = _np.zeros((P, 2), dtype=_np.uint32)
+                ended = []                   # (req, lane): prompt ends here
                 for i, req in enumerate(cold):
                     head = min(int(req.prompt.size), W)
                     toks[i, :head] = req.prompt[:head]
@@ -1939,6 +2106,10 @@ class ContinuousEngine:
                     tks[i] = req.top_k
                     tps[i] = req.top_p
                     keys[i] = req.key
+                    req.prefill_pos = head
+                    n_tokens += head
+                    if head == req.prompt.size:
+                        ended.append((req, i))
                 jtoks, jlens, jrows = (jnp.asarray(toks), jnp.asarray(lens),
                                        jnp.asarray(rows))
                 sample = (jnp.asarray(temps), jnp.asarray(tks),
@@ -1950,16 +2121,7 @@ class ContinuousEngine:
                     self.model.params, *cache, jtoks, jlens, jrows))
                 first = _sample_first(logits, *sample)
                 self.pool.swap_buffers(*cache)
-            with (_span("serve.prefill_batch.readback", cat="serve")
-                  if on else NO_SPAN):
-                first_host = _np.asarray(first)
-                self._read_counters()
-            for i, req in enumerate(cold):
-                head = min(int(req.prompt.size), W)
-                req.prefill_pos = head
-                n_tokens += head
-                if head == req.prompt.size:
-                    finished.append((req, int(first_host[i])))
+                self._join(first, ended, rec, jnp)
         # chunk wave: admitted hits prefill their suffix, long prompts
         # mid-stream advance one window — ONE fixed-shape dispatch at
         # pool width; lanes with no chunk work scatter into garbage
@@ -1991,6 +2153,7 @@ class ContinuousEngine:
                 tps = _np.ones((S,), dtype=_np.float32)
                 keys = _np.zeros((S, 2), dtype=_np.uint32)
                 fold = _np.zeros((S,), dtype=_np.int32)
+                ended = []
                 for req in chunkers:
                     s = lane_of[req.slot]
                     crows[s] = req.slot
@@ -2004,6 +2167,10 @@ class ContinuousEngine:
                     tps[s] = req.top_p
                     keys[s] = req.key
                     fold[s] = int(req.prompt.size) - 1
+                    req.prefill_pos += n
+                    n_tokens += n
+                    if req.prefill_pos == int(req.prompt.size):
+                        ended.append((req, s))
                 # smallest warmed extent covering the furthest lane: the
                 # wave's attention read scales with streamed progress
                 need = int((offs + nval).max())
@@ -2022,52 +2189,38 @@ class ContinuousEngine:
                     self.model.params, *cache, *chunk_args))
                 first = _sample_first(logits, *sample)
                 self.pool.swap_buffers(*cache)
-            with (_span("serve.prefill_batch.readback", cat="serve")
-                  if on else NO_SPAN):
-                first_host = _np.asarray(first)
-                self._read_counters()
-            for req in chunkers:
-                s = lane_of[req.slot]
-                n = int(nval[s])
-                req.prefill_pos += n
-                n_tokens += n
-                if req.prefill_pos == int(req.prompt.size):
-                    finished.append((req, int(first_host[s])))
-        now = time.perf_counter()
+                self._join(first, ended, rec, jnp)
         if admitted:
             self._count("admitted", len(admitted))
         if cold or chunkers:
             self._count("prefill_batches")
         if n_tokens:
             self._count("prefill_tokens", n_tokens)
-        n_sampled = sum(1 for r, _ in finished if r.temperature > 0)
-        if n_sampled:
-            self._count("sampled_tokens", n_sampled)
-        prof = _profiler_on()
-        done = []
-        for req, tok in finished:
-            req.cache_len = int(req.prompt.size)
-            req.generated.append(tok)
-            req.t_first = req.t_last = now
-            if req.ctx is not None and prof:
-                # admission -> first token, child of the request root:
-                # iteration 0 of the request's one trace
-                record_span("serve.prefill", (now - req.t_submit) * 1e6,
-                            ts_us=req.t_submit * 1e6, cat="serve",
-                            ctx=_trace.child_context(req.ctx,
-                                                     "serve.prefill"),
-                            prompt_tokens=req.prompt.size,
-                            cached_tokens=req.cached_len,
-                            slot=req.slot)
-            if self._finished(req):
-                done.append(req)
-        with self._cv:
-            for req, _ in finished:
-                self._prefilling.pop(req.slot, None)
-                self._running[req.slot] = req
         if on:
             sp.set(tokens=n_tokens)
-        return done
+
+    def _join(self, first, ended, rec, jnp):
+        """The requests whose prompt ended in the program that produced
+        `first` ((request, its lane there) pairs) become decode lanes:
+        on the device now (`_join_lanes`), on the host by moving to
+        `_running` with the budget the lane was given; `rec` keeps
+        `first` for the read that delivers the tokens."""
+        if not ended:
+            return
+        join = _np.full((3, self.pool.max_slots), -1, dtype=_np.int32)
+        for req, lane in ended:
+            plen = int(req.prompt.size)
+            # what the request still wants after its first token, capped
+            # by its page space (see `_wave_lanes`)
+            req.left = min(req.max_new - 1, self.max_len - 1 - plen)
+            join[:, req.slot] = lane, plen, req.left
+        self._lanes = self._join_prog(*self._lanes, first, jnp.asarray(join))  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
+        first.copy_to_host_async()
+        rec.firsts.append((first, ended))
+        with self._cv:
+            for req, _ in ended:
+                self._prefilling.pop(req.slot, None)
+                self._running[req.slot] = req
 
     def _dispatch_copy(self, pairs, why, on):
         """ONE fixed-shape donated program moves the leading positions of
@@ -2086,53 +2239,66 @@ class ContinuousEngine:
             self.pool.swap_buffers(k, v)
         self._count("copied_positions", positions)
 
-    def _run_decode(self, jnp, on, sp):
-        """ONE decode wave: every active slot advances up to
-        `decode_steps` tokens (times up to `draft_tokens + 1` when
-        speculating) through the compiled multi-step program. Lanes are
-        ALL pool rows (request slots, mid-prefill slots, and prefix-cache
-        rows alike) so lane index == slab row; non-decoding lanes are
-        inactive and scatter into the garbage row. Returns the requests
-        that finished.
+    def _wave_lanes(self):
+        """The lanes the next decode wave advances, by slot: the running
+        requests with budget left as far as the host can know
+        (`req.left`; with an `eos_id` the device may know better, and the
+        lane then idles through a wave the host still counts on).
 
-        Runs inside the loop's `serve.decode_batch` span `sp`; armed
-        (`on`), its host side is split into `.pack` (the seven NumPy
-        arrays and their transfers), `.dispatch` (the program call
-        returning), `.readback` (the blocking reads of the wave's tokens)
-        and `.emit` (per-lane bookkeeping and counters)."""
+        A lane's budget is what the request still wants, capped by its
+        page space. The cap mirrors `_finished`'s `cache_len + 1 >=
+        max_len` stop: the K=1 engine (and the reference) emit their last
+        token FROM state max_len - 2, so a multi-step wave may advance
+        cache_len at most to max_len - 1 — not max_len, which would emit
+        one extra token and break the K-invariance contract."""
+        with self._cv:
+            running = dict(self._running)
+        if self.draft_tokens:
+            # every dispatched wave has been read: the budget is exact, and
+            # a request whose first token is not read yet waits a wave
+            for req in running.values():
+                req.left = min(req.max_new - len(req.generated),
+                               self.max_len - 1 - req.cache_len) \
+                    if req.generated else 0
+        return {slot: req for slot, req in running.items() if req.left > 0}
+
+    def _dispatch_wave(self, lanes, rec, jnp, on):
+        """ONE decode wave, dispatched and not read: every lane of `lanes`
+        advances up to `decode_steps` tokens (times up to `draft_tokens +
+        1` when speculating) through the compiled multi-step program.
+        Program lanes are ALL pool rows (request slots, mid-prefill
+        slots, and prefix-cache rows alike) so lane index == slab row;
+        the others are inactive and scatter into the garbage row. The
+        wave's `tokens`, `lengths` and `steps_left` are the device's own
+        (`_lanes`), and `_advance_lanes` derives the next wave's from this
+        wave's outputs, so nothing here waits for the device; only the
+        sampling parameters, constants of a request, are packed. A
+        drafting wave consumes the token history besides, which the host
+        builds from the tokens read so far, and packs all of its inputs
+        there. The wave's outputs go to `rec.wave`.
+
+        Runs inside the loop's `serve.decode_batch` span; armed (`on`),
+        `.pack` is the NumPy arrays and their transfers, `.dispatch` the
+        program calls returning."""
         S = self.pool.max_slots
         draft = self.draft_tokens
         with (_span("serve.decode_batch.pack", cat="serve") if on
               else NO_SPAN):
-            toks = _np.zeros((S,), dtype=_np.int32)
-            lens = _np.zeros((S,), dtype=_np.int32)
-            left = _np.zeros((S,), dtype=_np.int32)
             temps = _np.zeros((S,), dtype=_np.float32)
             tks = _np.zeros((S,), dtype=_np.int32)
             tps = _np.ones((S,), dtype=_np.float32)
             keys = _np.zeros((S, 2), dtype=_np.uint32)
-            buf = (_np.zeros((S, self.max_len), dtype=_np.int32)
-                   if draft else None)
-            with self._cv:
-                running = dict(self._running)
-            for slot, req in running.items():
-                toks[slot] = req.generated[-1]
-                lens[slot] = req.cache_len
-                # this wave's per-lane budget: what the request still
-                # wants, capped by its page space. The cap mirrors
-                # _finished's `cache_len + 1 >= max_len` stop: the K=1
-                # engine (and the reference) emit their last token FROM
-                # state max_len - 2, so a multi-step wave may advance
-                # cache_len at most to max_len - 1 — not max_len, which
-                # would emit one extra token and break the K-invariance
-                # contract
-                left[slot] = min(req.max_new - len(req.generated),
-                                 self.max_len - 1 - req.cache_len)
+            if draft:
+                state = _np.zeros((3, S), dtype=_np.int32)
+                buf = _np.zeros((S, self.max_len), dtype=_np.int32)
+            for slot, req in lanes.items():
                 temps[slot] = req.temperature
                 tks[slot] = req.top_k
                 tps[slot] = req.top_p
                 keys[slot] = req.key
                 if draft:
+                    state[:, slot] = (req.generated[-1], req.cache_len,
+                                      req.left)
                     # the draft source: token history = prompt +
                     # generated, exactly cache_len + 1 valid entries (tail
                     # not yet in KV)
@@ -2140,9 +2306,10 @@ class ContinuousEngine:
                     buf[slot, :plen] = req.prompt
                     buf[slot, plen:plen + len(req.generated)] = \
                         req.generated
-            args = [jnp.asarray(toks), jnp.asarray(lens),
-                    jnp.asarray(left), jnp.asarray(temps),
-                    jnp.asarray(tks), jnp.asarray(tps), jnp.asarray(keys)]
+            if draft:
+                self._lanes = tuple(jnp.asarray(a) for a in state)  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
+            args = [*self._lanes, jnp.asarray(temps), jnp.asarray(tks),
+                    jnp.asarray(tps), jnp.asarray(keys)]
             if draft:
                 args.append(jnp.asarray(buf))
         with (_span("serve.decode_batch.dispatch", cat="serve") if on
@@ -2153,68 +2320,113 @@ class ContinuousEngine:
                                                   *args))
             self.pool.swap_buffers(*out[:n])
             out = out[n:]
-        with (_span("serve.decode_batch.readback", cat="serve") if on
-              else NO_SPAN):
-            if draft:
-                blocks, n_emits, emitted, acc, rej = out
-                blocks_host = _np.asarray(blocks)   # (steps, S, draft+1)
-                nem_host = _np.asarray(n_emits)     # (steps, S)
-            else:
-                out_toks, emitted = out
-                out_host = _np.asarray(out_toks)    # (decode_steps, S)
-            if self._canary is not None:
-                self._canary.check(where="serve.decode")
-            _sanitize.poll(where="serve.decode")
-            emitted_host = _np.asarray(emitted)
-            self._read_counters()
-        with (_span("serve.decode_batch.emit", cat="serve") if on
-              else NO_SPAN):
+            if not draft:
+                self._lanes = self._advance_prog(*self._lanes, *out)  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
+                for req in lanes.values():
+                    req.left -= min(req.left, self.decode_steps)
+            for a in out:
+                a.copy_to_host_async()
+        # lanes whose temperature the wave packed above 0: with any, the
+        # program's sampler took its sorting side for every lane
+        rec.wave = (lanes, out, int(_np.count_nonzero(temps)))
+
+    def _read(self, rec, in_wave, on):
+        """Read back what an iteration dispatched (`rec`) and do its
+        bookkeeping: first tokens, then the wave's. Returns (the requests
+        that finished, lanes the wave advanced, tokens it emitted, lanes
+        it sampled for). The blocking reads are the `.readback` span of
+        the loop's open span (`serve.decode_batch` where `in_wave`, else
+        `serve.prefill_batch`); the per-lane bookkeeping and counters are
+        `serve.decode_batch.emit`."""
+        name = "serve.decode_batch" if in_wave else "serve.prefill_batch"
+        draft = self.draft_tokens
+        with (_span(name + ".readback", cat="serve") if on else NO_SPAN):
+            firsts = [(_np.asarray(first), ended)
+                      for first, ended in rec.firsts]
+            lanes, out, sampled_lanes = rec.wave or ({}, None, 0)
+            if rec.wave is not None:
+                # (decode_steps, S) tokens and (S,) counts; drafting,
+                # (steps, S, draft+1) blocks, (steps, S) counts a block,
+                # (S,) totals, accepted and rejected drafts
+                out = [_np.asarray(a) for a in out]
+                if self._canary is not None:
+                    self._canary.check(where="serve.decode")
+                _sanitize.poll(where="serve.decode")
+            self._read_counters(rec.counters)
+        n_active = n_tokens = 0
+        with (_span("serve.decode_batch.emit", cat="serve")
+              if on and in_wave else NO_SPAN):
             now = time.perf_counter()
-            n_active = len(running)
-            n_tokens = 0
+            prof = _profiler_on()
+            touched = []
             n_sampled = 0
-            done = []
-            for slot, req in running.items():
-                n_new = int(emitted_host[slot])
-                if n_new > 0:
-                    if draft:
-                        for i in range(nem_host.shape[0]):
-                            m = int(nem_host[i, slot])
+            for first, ended in firsts:
+                for req, lane in ended:
+                    req.cache_len = int(req.prompt.size)
+                    req.generated.append(int(first[lane]))
+                    req.t_first = req.t_last = now
+                    n_sampled += int(req.temperature > 0)
+                    touched.append(req)
+                    if req.ctx is not None and prof:
+                        # admission -> first token, child of the request
+                        # root: iteration 0 of the request's one trace
+                        record_span(
+                            "serve.prefill", (now - req.t_submit) * 1e6,
+                            ts_us=req.t_submit * 1e6, cat="serve",
+                            ctx=_trace.child_context(req.ctx,
+                                                     "serve.prefill"),
+                            prompt_tokens=req.prompt.size,
+                            cached_tokens=req.cached_len, slot=req.slot)
+            if rec.wave is not None:
+                emitted = out[2] if draft else out[1]
+                lens = []
+                for slot, req in lanes.items():
+                    if req.t_done is not None:
+                        # retired at the read before this one, by an eos
+                        # the host had not seen when this wave went out:
+                        # the lane idled here, the slot may have a tenant
+                        continue
+                    n_new = int(emitted[slot])
+                    if n_new > 0:
+                        if draft:
+                            for i in range(out[1].shape[0]):
+                                m = int(out[1][i, slot])
+                                req.generated.extend(
+                                    int(t) for t in out[0][i, slot, :m])
+                        else:
                             req.generated.extend(
-                                int(t) for t in blocks_host[i, slot, :m])
-                    else:
-                        req.generated.extend(
-                            int(t) for t in out_host[:n_new, slot])
-                    req.cache_len += n_new
-                    req.t_last = now
-                    n_tokens += n_new
-                    if req.temperature > 0:
-                        n_sampled += n_new
-                if self._finished(req):
-                    done.append(req)
-            self._count("decode_iterations")
-            self._count("decode_tokens", n_tokens)
-            self._count("active_sum", n_active)
-            # lanes whose temperature the wave packed above 0: with any,
-            # the program's sampler took its sorting side for every lane
-            sampled_lanes = int(_np.count_nonzero(temps))
-            if sampled_lanes:
-                self._count("sampled_waves")
-            # the lanes' lengths after this wave, from the array the wave
-            # packed: one vectorised sum a cache kind, no loop over lanes
-            at = _np.fromiter(running, dtype=_np.intp, count=n_active)
-            live = self.pool.bytes_by_kind(lens[at] + emitted_host[at])
-            with self._mlock:
-                for kind, n in live.items():
-                    self._cache_live[kind] += n
+                                int(t) for t in out[0][:n_new, slot])
+                        req.cache_len += n_new
+                        req.t_last = now
+                        n_active += 1
+                        n_tokens += n_new
+                        if req.temperature > 0:
+                            n_sampled += n_new
+                        lens.append(req.cache_len)
+                    touched.append(req)
+                self._count("decode_iterations")
+                self._count("decode_tokens", n_tokens)
+                # the lanes the wave advanced, not those it carried spent
+                self._count("active_sum", n_active)
+                if sampled_lanes:
+                    self._count("sampled_waves")
+                # the advanced lanes' lengths after this wave: one
+                # vectorised sum a cache kind, no loop over lanes
+                live = self.pool.bytes_by_kind(
+                    _np.asarray(lens, dtype=_np.int64))
+                with self._mlock:
+                    for kind, nbytes in live.items():
+                        self._cache_live[kind] += nbytes
+                if draft:
+                    self._count("draft_accepted", int(out[3].sum()))
+                    self._count("draft_rejected", int(out[4].sum()))
             if n_sampled:
                 self._count("sampled_tokens", n_sampled)
-            if draft:
-                self._count("draft_accepted", int(_np.asarray(acc).sum()))
-                self._count("draft_rejected", int(_np.asarray(rej).sum()))
-        if on:
-            sp.set(active=n_active, tokens=n_tokens, sampled=sampled_lanes)
-        return done
+            # a request touched twice (its first token and its first wave
+            # in one record) is judged once
+            done = [req for req in dict.fromkeys(touched)
+                    if self._finished(req)]
+        return done, n_active, n_tokens, sampled_lanes
 
     def _finished(self, req):
         if len(req.generated) >= req.max_new:

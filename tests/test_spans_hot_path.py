@@ -37,9 +37,12 @@ PROGRAM_METRICS = sorted(glob.glob(os.path.join(
     ROOT, "chipbench", "layer_metrics", "*_prog_ms.*.json")))
 DECODE_CHILDREN = ["serve.decode_batch.pack", "serve.decode_batch.dispatch",
                    "serve.decode_batch.readback", "serve.decode_batch.emit"]
+# pack and dispatch are the wave the span hands the device, readback and
+# emit the wave before it: the first span of a busy stretch has only the
+# former, the span that finds nothing more to dispatch only the latter
+DECODE_SHAPES = (DECODE_CHILDREN, DECODE_CHILDREN[:2], DECODE_CHILDREN[2:])
 PREFILL_CHILDREN = ["serve.prefill_batch.pack",
-                    "serve.prefill_batch.dispatch",
-                    "serve.prefill_batch.readback"]
+                    "serve.prefill_batch.dispatch"]
 
 
 @pytest.fixture(autouse=True)
@@ -140,15 +143,28 @@ def served(tmp_path_factory):
 
 
 def test_one_decode_span_per_decode_iteration_on_host_plane(served):
+    """Every wave is packed once and emitted once, a span later; a span
+    that does both stands for one `decode_iterations`, and a busy stretch
+    has one span more than waves (its first only dispatches, its last
+    only reads)."""
     grew = (served["s1"]["decode_iterations"]
             - served["s0"]["decode_iterations"])
     assert grew >= 3
+    since = [e for e in served["buffer"] if e["ts"] >= served["t0_us"]]
+    for child in ("pack", "emit"):
+        assert sum(1 for e in since
+                   if e["name"] == "serve.decode_batch." + child) == grew
     buffered = [e for e in served["buffer"]
                 if e["name"] == "serve.decode_batch"]
-    assert sum(1 for e in buffered if e["ts"] >= served["t0_us"]) == grew
+    spans = [e for e in buffered if e["ts"] >= served["t0_us"]]
+    ahead = sum(e["args"]["ahead"] for e in spans)
+    assert ahead == (served["s1"]["waves_ahead"]
+                     - served["s0"]["waves_ahead"])
+    # a wave not dispatched ahead opens a stretch: one more span each
+    assert len(spans) == grew + (grew - ahead)
     on_host = [e for e in served["host"] if e[1] == "serve.decode_batch"]
     assert len(on_host) == len(buffered)
-    assert sum(e[4]["tokens"] for e in on_host[-grew:]) == (
+    assert sum(e[4]["tokens"] for e in on_host[-len(spans):]) == (
         served["s1"]["decode_tokens"] - served["s0"]["decode_tokens"])
 
 
@@ -156,9 +172,17 @@ def test_decode_span_has_its_four_children_in_order(served):
     waves = [e for e in served["host"] if e[1] == "serve.decode_batch"]
     assert waves
     for w in waves:
-        assert inside(served["host"], w) == DECODE_CHILDREN
-        assert w[4]["steps"] == 2 and w[4]["active"] >= 1
-        assert w[4]["parent"] == "serve.wave"
+        kids = inside(served["host"], w)
+        assert kids in DECODE_SHAPES
+        assert w[4]["steps"] == 2 and w[4]["parent"] == "serve.wave"
+        # dispatched while the wave before it was unread: all four
+        assert w[4]["ahead"] in (0, 1)
+        assert w[4]["ahead"] == 0 or kids == DECODE_CHILDREN
+        if "serve.decode_batch.emit" in kids:
+            assert w[4]["active"] >= 1 and w[4]["tokens"] >= 1
+        else:
+            assert w[4]["active"] == w[4]["tokens"] == 0
+    assert any(w[4]["ahead"] for w in waves)
 
 
 def test_prefill_span_children_and_copy_spans(served):
@@ -178,8 +202,9 @@ def test_prefill_span_children_and_copy_spans(served):
     assert sum(c["positions"] for c in copies) == (
         served["s1"]["copied_positions"] - served["s0"]["copied_positions"])
     retires = [e for e in host if e[1] == "serve.retire"]
-    # the lead request ran in the iteration that was not yet armed
-    assert retires and sum(e[4]["n"] for e in retires) == 4
+    # the lead request was admitted in the iteration that was not yet
+    # armed (the four admissions below) and read back in the next
+    assert retires and sum(e[4]["n"] for e in retires) == 5
     published = [r for r in retires
                  if "serve.copy.dispatch" in inside(host, r)]
     assert published
@@ -201,7 +226,7 @@ def test_buffer_holds_the_same_spans_with_wave_trace_ids(served):
         kids = [e["name"] for e in buf
                 if e["args"].get("trace_id") == tid
                 and e["args"].get("parent") == "serve.decode_batch"]
-        assert kids == DECODE_CHILDREN
+        assert kids in DECODE_SHAPES
     assert len({w["args"]["trace_id"] for w in waves}) == len(waves)
     # per-request spans stay request scale: buffer only, their own traces
     assert names.count("serve.request") == 5      # request scale: all
@@ -283,18 +308,20 @@ def test_armed_without_a_session_spans_reach_no_buffer(monkeypatch):
 
 
 def test_decode_span_says_how_many_lanes_sampled():
-    """`serve.decode_batch` carries `sampled=<lanes>`: 0 on a greedy wave,
-    the lanes with `temperature > 0` otherwise; the waves with any are
-    `stats()["sampled_waves"]`."""
+    """`serve.decode_batch` carries `sampled=<lanes>` of the wave it
+    emits: 0 on a greedy wave, the lanes with `temperature > 0`
+    otherwise; the waves with any are `stats()["sampled_waves"]`."""
+    def emitted():
+        return [e["args"]["sampled"] for e in profiler.events("serve")
+                if e["name"] == "serve.decode_batch" and e["args"]["tokens"]]
+
     eng = toy_engine().start()
     profiler.start()
     try:
         eng.generate([1, 2, 3], 6)
-        greedy = [e["args"]["sampled"] for e in profiler.events("serve")
-                  if e["name"] == "serve.decode_batch"]
+        greedy = emitted()
         eng.generate([4, 5, 6], 7, temperature=0.8, top_p=0.9, seed=2)
-        both = [e["args"]["sampled"] for e in profiler.events("serve")
-                if e["name"] == "serve.decode_batch"]
+        both = emitted()
         stats = eng.stats()
     finally:
         profiler.stop()
@@ -302,6 +329,32 @@ def test_decode_span_says_how_many_lanes_sampled():
     assert greedy and set(greedy) == {0}
     assert both[len(greedy):] == [1, 1, 1]       # 1 + 3 waves x 2 tokens
     assert stats["sampled_waves"] == 3
+
+
+def test_first_tokens_alone_are_read_in_the_prefill_span():
+    """A request that ends at its first token dispatches no wave: the
+    iteration after its prefill finds nothing to dispatch and reads the
+    token at once, in a `serve.prefill_batch` span with the one child."""
+    eng = toy_engine().start()
+    profiler.start()
+    try:
+        eng.generate([9, 9, 9], 2)       # takes the not-yet-armed iteration
+        mark = len(profiler.events("serve"))
+        out = eng.generate([1, 2, 3], 1)
+        got = profiler.events("serve")[mark:]
+    finally:
+        profiler.stop()
+        eng.close()
+    assert len(out) == 1
+    names = [e["name"] for e in got if e["name"] != "serve.idle"
+             and e["args"].get("parent") in ("serve.wave",
+                                             "serve.prefill_batch")]
+    assert names == ["serve.admit",
+                     "serve.prefill_batch.pack",
+                     "serve.prefill_batch.dispatch", "serve.prefill_batch",
+                     "serve.admit",
+                     "serve.prefill_batch.readback", "serve.prefill_batch",
+                     "serve.retire"]
 
 
 def test_event_buffer_is_bounded():
