@@ -274,8 +274,7 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True,
     If `variables` is given, returns their gradients instead of writing into
     marked .grad buffers (≙ autograd.grad, autograd.py:272).
     """
-    from .telemetry import span as _span
-    with _amp_suspended(), _span("autograd.backward"):
+    with _amp_suspended():
         return _backward_impl(heads, head_grads, retain_graph, train_mode,
                               create_graph, variables)
 

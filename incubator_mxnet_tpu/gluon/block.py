@@ -108,6 +108,7 @@ class Block:
             existing = self.__dict__.get("_children")
             if existing is not None:
                 existing[name] = value
+                value.__dict__["_scope_name"] = name
         elif isinstance(value, Parameter):
             params = self.__dict__.get("_reg_params")
             if params is not None:
@@ -120,6 +121,8 @@ class Block:
         if name is None:
             name = str(len(self._children))
         self._children[name] = block
+        # the name it runs under in a compiled program (`__call__`)
+        block.__dict__["_scope_name"] = name
         super().__setattr__(f"_child_{name}", block)
 
     def register_block(self, *a, **kw):
@@ -285,6 +288,24 @@ class Block:
     # call path
     # ------------------------------------------------------------------
     def __call__(self, *args, **kwargs):
+        if _in_trace(args):
+            return self._call_traced(args, kwargs)
+        return self._forward_with_hooks(*args, **kwargs)
+
+    def _call_traced(self, args, kwargs):
+        # while it is traced into a compiled program (hybridize, a fused
+        # step), a child runs under its registered name as a
+        # `jax.named_scope`: the program's instructions then carry
+        # `features/4/0/...` in their `op_name`
+        # (`profiler.program_scopes`). An eager call never comes here
+        name = self.__dict__.get("_scope_name")
+        if name is None:
+            return self._forward_with_hooks(*args, **kwargs)
+        import jax
+        with jax.named_scope(name):
+            return self._forward_with_hooks(*args, **kwargs)
+
+    def _forward_with_hooks(self, *args, **kwargs):
         if not self.__dict__.get("_params_ready", False):
             self._resolve_own_deferred(*args)
         for hook in self._forward_pre_hooks.values():
@@ -359,13 +380,19 @@ def _walk(block):
         yield from _walk(c)
 
 
+_TRACE_TYPES = []       # (NDArray, jax.core.Tracer), resolved at first use
+
+
 def _in_trace(args):
-    """True when any input is a jax tracer (we're under an enclosing jit)."""
-    import jax
-    from ..ndarray import NDArray
+    """True when any input is a jax tracer (we're under an enclosing jit).
+    Every block's call asks: two type checks an argument, no import."""
+    if not _TRACE_TYPES:
+        import jax
+        from ..ndarray import NDArray
+        _TRACE_TYPES[:] = [NDArray, jax.core.Tracer]
+    nd, tracer = _TRACE_TYPES
     for a in args:
-        raw = a._arr if isinstance(a, NDArray) else a
-        if isinstance(raw, jax.core.Tracer):
+        if isinstance(a._arr if isinstance(a, nd) else a, tracer):
             return True
     return False
 
@@ -408,16 +435,18 @@ class HybridBlock(Block):
                           static_shape=static_shape, **kwargs)
 
     def __call__(self, *args, **kwargs):
-        if not self._active or kwargs or _in_trace(args):
+        if _in_trace(args):
             # inside an enclosing trace the parent cache already captures this
             # block's ops (≙ child CachedOps fold into the parent graph)
-            return super().__call__(*args, **kwargs)
+            return self._call_traced(args, kwargs)
+        if not self._active or kwargs:
+            return self._forward_with_hooks(*args, **kwargs)
         if not self._shapes_ready:
             # deferred params anywhere in the tree: run ONE eager pass so each
             # leaf resolves its shapes just in time, then cache from next call
             if any(p._deferred_init is not None
                    for _, p in self.collect_params().items()):
-                return super().__call__(*args, **kwargs)
+                return self._forward_with_hooks(*args, **kwargs)
             self._shapes_ready = True
         return self._call_cached(*args)
 

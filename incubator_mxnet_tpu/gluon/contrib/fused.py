@@ -267,6 +267,7 @@ class FusedTrainStep:
                             if p.grad_req == "null"]
         self._states = None
         self._jit = None
+        self._registered = False        # `_run`'s own: `lowered` may come first
         self._calls = 0                 # the live `train.step` span's number
         self._meta = {"aux_idx": None}  # frozen params mutated in forward
 
@@ -431,6 +432,38 @@ class FusedTrainStep:
             "fused.train_step")
 
     # ------------------------------------------------------------------
+    def _call_args(self, inputs, key, t_of):
+        """What `_jit` takes for these inputs, the one place that lays it
+        out: the step's real call and `lowered` pass the same list.
+        `t_of(i)` is parameter i's update count at the call's first inner
+        step (asked only for rules that take `t`)."""
+        from ...ndarray import NDArray
+        from ...optimizer import _state_bufs
+
+        opt = self._opt
+        lrs = _np.asarray([opt._get_lr(i) for i in self._train_idx],
+                          _np.float32)
+        wds = _np.asarray([opt._get_wd(i) for i in self._train_idx],
+                          _np.float32)
+        ts = (_np.asarray([t_of(i) for i in self._train_idx], _np.float32)
+              if type(opt)._step_takes_t() else None)
+        train_bufs = [self._params[i].data()._arr for i in self._train_idx]
+        frozen_bufs = [self._params[i].data()._arr
+                       for i in self._frozen_idx]
+        sbufs = [_state_bufs(s) for s in self._states]
+        # stage inputs asynchronously: host arrays start their H2D transfer
+        # now (overlapping the caller's prologue), while batches that are
+        # already committed device arrays with the right placement — e.g.
+        # from io.DeviceFeed — skip the redundant transfer entirely (counted
+        # in profiler.feed_stats()["device_put_skipped"]). Raw python
+        # scalars pass through untouched to keep weak-typed promotion
+        # semantics.
+        in_raw = tuple(
+            _stage_raw(a._arr if isinstance(a, NDArray) else a)
+            for a in inputs)
+        return (train_bufs, sbufs, frozen_bufs, key, lrs, wds,
+                _np.float32(opt.rescale_grad), ts, *in_raw)
+
     def lowered(self, *inputs):
         """The fused step lowered for these input shapes WITHOUT running
         it: a `jax.stages.Lowered` whose `.compile()` yields the exact
@@ -439,35 +472,20 @@ class FusedTrainStep:
         compiled HLO for fusion-level offender attribution, and
         `flops_per_call` cost-counts it. The lowering lands in jax's jit
         cache, so a subsequent real `step(...)` with the same shapes does
-        not re-pay compilation."""
+        not re-pay compilation. It is the registered program's own
+        (`profiler.program_scopes()` finds `jit_step` from here on)."""
         import jax
-        from ...ndarray import NDArray
-        from ...optimizer import _state_bufs
+        from ... import profiler as _profiler
 
         self._ensure_states()
         if self._jit is None:
             self._jit = self._build()
-        opt = self._opt
-        lrs = _np.asarray([opt._get_lr(i) for i in self._train_idx],
-                          _np.float32)
-        wds = _np.asarray([opt._get_wd(i) for i in self._train_idx],
-                          _np.float32)
-        ts = (_np.asarray([1.0] * len(self._train_idx), _np.float32)
-              if type(opt)._step_takes_t() else None)
         # fixed key: only shapes matter for lowering, and consuming the
         # global RNG stream here would silently change training
         # reproducibility for callers that cost-count before training
-        key = jax.random.PRNGKey(0)
-        train_bufs = [self._params[i].data()._arr for i in self._train_idx]
-        frozen_bufs = [self._params[i].data()._arr
-                       for i in self._frozen_idx]
-        sbufs = [_state_bufs(s) for s in self._states]
-        in_raw = tuple(
-            _stage_raw(a._arr if isinstance(a, NDArray) else a)
-            for a in inputs)
-        return self._jit.lower(
-            train_bufs, sbufs, frozen_bufs, key, lrs, wds,
-            _np.float32(opt.rescale_grad), ts, *in_raw)
+        args = self._call_args(inputs, jax.random.PRNGKey(0),
+                               lambda i: 1.0)
+        return _profiler.register_program(self._jit, args).lower()
 
     def flops_per_call(self, *inputs):
         """XLA-counted FLOPs of ONE compiled step call (cost analysis of
@@ -490,8 +508,8 @@ class FusedTrainStep:
 
     def _run(self, inputs, on):
         from ... import random as _random
-        from ...ndarray import NDArray, _wrap
-        from ...optimizer import _state_bufs, _state_restore
+        from ...ndarray import _wrap
+        from ...optimizer import _state_restore
 
         self._ensure_states()
         if self._jit is None:
@@ -500,32 +518,19 @@ class FusedTrainStep:
         for _ in range(self._K):
             for i in self._train_idx:
                 opt._update_count(i)
-        lrs = _np.asarray([opt._get_lr(i) for i in self._train_idx],
-                          _np.float32)
-        wds = _np.asarray([opt._get_wd(i) for i in self._train_idx],
-                          _np.float32)
         # takes_t rules see t = count at that inner step: base + scan offset
-        ts = (_np.asarray([opt._index_update_count[i] - self._K + 1
-                           for i in self._train_idx], _np.float32)
-              if type(opt)._step_takes_t() else None)
-        key = _random.next_key()
-        train_bufs = [self._params[i].data()._arr for i in self._train_idx]
-        frozen_bufs = [self._params[i].data()._arr for i in self._frozen_idx]
-        sbufs = [_state_bufs(s) for s in self._states]
-        # stage inputs asynchronously: host arrays start their H2D transfer
-        # now (overlapping this prologue), while batches that are already
-        # committed device arrays with the right placement — e.g. from
-        # io.DeviceFeed — skip the redundant transfer entirely (counted in
-        # profiler.feed_stats()["device_put_skipped"]). Raw python scalars
-        # pass through untouched to keep weak-typed promotion semantics.
-        in_raw = tuple(
-            _stage_raw(a._arr if isinstance(a, NDArray) else a)
-            for a in inputs)
-
+        args = self._call_args(
+            inputs, _random.next_key(),
+            lambda i: opt._index_update_count[i] - self._K + 1)
+        if not self._registered:
+            # the step's first call notes the program with its arguments'
+            # shapes (no buffer) where `profiler.program_scopes()` finds
+            # it: `jit_step` in a device trace
+            from ... import profiler as _profiler
+            _profiler.register_program(self._jit, args)
+            self._registered = True
         with (_span("train.step.dispatch") if on else NO_SPAN):
-            new_w, new_s, loss, extras, aux_bufs = self._jit(
-                train_bufs, sbufs, frozen_bufs, key, lrs, wds,
-                _np.float32(opt.rescale_grad), ts, *in_raw)
+            new_w, new_s, loss, extras, aux_bufs = self._jit(*args)
 
         for k, i in enumerate(self._train_idx):
             self._params[i].data()._set_arr(new_w[k])

@@ -20,7 +20,7 @@ Catalog of the `inspect.*` registry metrics: docs/OBSERVABILITY.md.
 from __future__ import annotations
 
 from .hlo import (HloInstruction, HloComputation, HloModule, parse_module,
-                  parse_shape, shape_bytes)
+                  parse_shape, shape_bytes, scope_of, scope_table)
 from .roofline import (analyze_compiled, analyze_module, callable_cost,
                        classify, cost_analysis_summary, instr_flops,
                        kernel_units, load_calibration, unit_cost)
@@ -35,7 +35,7 @@ from .memory import (memory_plan, plan_from_compiled, assert_donation,
 
 __all__ = [
     "HloInstruction", "HloComputation", "HloModule", "parse_module",
-    "parse_shape", "shape_bytes",
+    "parse_shape", "shape_bytes", "scope_of", "scope_table",
     "analyze_compiled", "analyze_module", "callable_cost", "classify",
     "cost_analysis_summary", "instr_flops", "kernel_units",
     "load_calibration", "unit_cost",

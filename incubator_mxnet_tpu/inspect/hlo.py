@@ -24,7 +24,8 @@ from __future__ import annotations
 import re
 
 __all__ = ["HloInstruction", "HloComputation", "HloModule", "parse_module",
-           "parse_shape", "shape_bytes", "DTYPE_BYTES"]
+           "parse_shape", "shape_bytes", "DTYPE_BYTES", "scope_of",
+           "scope_table"]
 
 # element width in bytes per HLO primitive type (pred is byte-addressed)
 DTYPE_BYTES = {
@@ -290,3 +291,64 @@ def _split_top_level(text):
     if tail.strip():
         parts.append(tail)
     return parts
+
+
+# -- instruction -> the program's own scope ---------------------------------
+# `op_name` is jax's name stack at the equation: the program's
+# `jax.named_scope`s between what jax itself adds. These are jax's:
+# control flow and call wrappers (dropped), and transforms, which wrap the
+# next element (`transpose(jvp(forward))`: unwrapped, and remembered as
+# the instruction's pass)
+_JAX_ELEMENTS = frozenset((
+    "while", "body", "cond", "closed_call", "core_call", "checkpoint",
+    "remat", "custom_jvp_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
+    "custom_lin", "shard_map", "pallas_call"))
+_JAX_BRANCH_RE = re.compile(r"branch_\d+_fun$")
+_JAX_WRAP_RE = re.compile(r"([A-Za-z_]\w*)\((.*)\)$")
+_JAX_CALLS = frozenset(("jit", "pjit", "xla_call"))
+
+
+def scope_of(op_name):
+    """`(scope path, pass)` of one `op_name`: the path of the scopes the
+    PROGRAM chose (`jax.named_scope`), without `jit(...)`, `while/body`,
+    `transpose(jvp(...))` and jax's other wrappers and without the
+    primitive's name at the end; pass is `"bwd"` under a `transpose(...)`,
+    `"fwd"` under a `jvp(...)` alone, `""` under neither.
+    `jit(step)/transpose(jvp(forward))/features/4/0/conv` ->
+    `("forward/features/4/0", "bwd")`. No `op_name`, or no scope of the
+    program's in it -> `("", "")`."""
+    if not op_name:
+        return "", ""
+    kept, which = [], ""
+    # (instructions that XLA merged carry both names, `;` between them)
+    for elem in op_name.split(";")[0].split("/")[:-1]:
+        m = _JAX_WRAP_RE.match(elem)
+        while m:
+            if m.group(1) == "transpose":
+                which = "bwd"
+            elif m.group(1) == "jvp" and not which:
+                which = "fwd"
+            elem = "" if m.group(1) in _JAX_CALLS else m.group(2)
+            m = _JAX_WRAP_RE.match(elem)
+        if elem and elem not in _JAX_ELEMENTS \
+                and not _JAX_BRANCH_RE.match(elem):
+            kept.append(elem)
+    return "/".join(kept), (which if kept else "")
+
+
+def scope_table(compiled):
+    """`{instruction name: (scope path, pass)}` for every instruction of
+    every computation of a compiled program: fusions (a fusion carries its
+    root's `op_name`), custom calls, copies, a `while` and what its body
+    holds. `compiled` is a `jax.stages.Compiled`, its `as_text()`, or a
+    parsed `HloModule`. An instruction the COMPILER added (a re-layout
+    copy with no `op_name`) maps to `("", "")`, as does one under no scope
+    of the program's. Instruction names are unique in a module, and are
+    what a device trace's `XLA Ops` line prints before ` = `."""
+    if hasattr(compiled, "as_text"):
+        compiled = compiled.as_text()
+    module = parse_module(compiled) if isinstance(compiled, str) \
+        else compiled
+    return {ins.name: scope_of(ins.op_name)
+            for comp in module.computations.values()
+            for ins in comp.instructions}

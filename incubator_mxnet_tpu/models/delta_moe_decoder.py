@@ -531,23 +531,29 @@ def _make_micro(config):
     def micro(params, cache, tokens, lengths, active):
         cache = dict(cache)
         S = tokens.shape[0]
-        rows = jnp.where(active, jnp.arange(S), S)       # garbage row = S
-        wpos = jnp.clip(lengths, 0, c.max_len - 1)
-        with jax.named_scope("embed"):
-            x = params["emb"][tokens]                            # (S, d)
 
         def every(a):
             """A state leaf is read and rewritten whole, the garbage row
             too: one more row of zeros under a lane's inputs."""
             return jnp.pad(a, ((0, 1),) + ((0, 0),) * (a.ndim - 1))
 
-        keep = every(active)[:, None, None, None]
+        # (every equation runs under one of the program's scopes, the
+        # lanes' bookkeeping and a layer's first norm too:
+        # `profiler.program_scopes` names the device's time by them)
+        with jax.named_scope("embed"):
+            rows = jnp.where(active, jnp.arange(S), S)   # garbage row = S
+            wpos = jnp.clip(lengths, 0, c.max_len - 1)
+            x = params["emb"][tokens]                            # (S, d)
+            keep = every(active)[:, None, None, None]
         moe = jnp.zeros((6,), jnp.int32)
         for l in range(c.layers):
             i = c.slots[l][0]
-            w = _weights(params, c, l)
-            h = rms_norm(x, w["ln1_w"], c.norm_eps)
-            if c.mixer_types[l] == "kda":
+            kda = c.mixer_types[l] == "kda"
+            with jax.named_scope(f"layer{l}/" + ("kda_proj" if kda
+                                                 else "mla")):
+                w = _weights(params, c, l)
+                h = rms_norm(x, w["ln1_w"], c.norm_eps)
+            if kda:
                 with jax.named_scope(f"layer{l}/kda_proj"):
                     own = h @ w["k_qkv"]
                 with jax.named_scope(f"layer{l}/kda_conv"):
@@ -573,11 +579,13 @@ def _make_micro(config):
                     o = mla_read_absorbed(q_nope, q_rope, lat, None,
                                           w["wkv_b"], c, lengths=lengths)
                     x = x + _head_gate(o, h, w, c) @ w["m_wo"]
-            x, counted = _ffn(x, w, c, l, active)
-            moe = moe + counted
-        touched = jnp.sum(active, dtype=jnp.int32) * c.n_kda
-        return cache, _head(params, x, c), {"moe": moe, "state": jnp.stack(
-            [touched, jnp.zeros((), jnp.int32)])}
+            x, moe = _ffn(x, w, c, l, active, moe)
+        with jax.named_scope("head"):
+            touched = jnp.sum(active, dtype=jnp.int32) * c.n_kda
+        logits = _head(params, x, c)
+        with jax.named_scope("head"):
+            state = jnp.stack([touched, jnp.zeros((), jnp.int32)])
+        return cache, logits, {"moe": moe, "state": state}
 
     return micro
 

@@ -432,8 +432,8 @@ def _upper(params, cache, x, mem, rows, lengths, c):
     qw = c.heads * c.head_dim
     for l in range(c.layers // 2 + 1, c.layers):
         kind, i = c.kinds[l]
-        w = _weights(params, l, kind, i)
         with jax.named_scope(f"layer{l}/{kind}"):
+            w = _weights(params, l, kind, i)
             h = layer_norm(x, w["ln1_w"], w["ln1_b"], c.ln_eps)
             if kind == "gmu":
                 x = x + gated_memory(h, mem, w["g_w1"], w["g_w2"])
@@ -461,9 +461,9 @@ def _write_shared(params, cache, x, rows, at, c):
     shared cache at [rows, at]; its attention is `_upper`'s first layer."""
     import jax
     l = c.layers // 2 + 1
-    w = _weights(params, l, *c.kinds[l])
     qw, kvw = c.heads * c.head_dim, c.kv_width
     with jax.named_scope(f"layer{l}/full"):
+        w = _weights(params, l, *c.kinds[l])
         h = layer_norm(x, w["ln1_w"], w["ln1_b"], c.ln_eps)
         kv = h @ w["a_qkv"][:, qw:] + w["a_qkv_b"][qw:]
         cache["shared_k"] = cache["shared_k"].at[rows, at].set(kv[..., :kvw])
@@ -498,16 +498,16 @@ def _make_chunk(config, window, fresh):
         B = tokens.shape[0]
         G = cache["shared_k"].shape[0] - 1               # garbage row
         j = jnp.arange(W)
-        valid = j[None, :] < nvalid[:, None]                     # (B, W)
-        wrows = jnp.where(valid, rows[:, None], G)
-        pos = offsets[:, None] + j[None, :]
         with jax.named_scope("embed"):
+            valid = j[None, :] < nvalid[:, None]                 # (B, W)
+            wrows = jnp.where(valid, rows[:, None], G)
+            pos = offsets[:, None] + j[None, :]
             x = params["emb"][tokens]                            # (B, W, d)
         mem = None
         for l in range(half + 1):
             kind, i = kinds[l]
-            w = _weights(params, l, kind, i)
             with jax.named_scope(f"layer{l}/{kind}"):
+                w = _weights(params, l, kind, i)
                 h = layer_norm(x, w["ln1_w"], w["ln1_b"], c.ln_eps)
                 if kind == "mamba":
                     if fresh:
@@ -575,15 +575,18 @@ def _make_micro(config):
     def micro(params, cache, tokens, lengths, active):
         cache = dict(cache)
         S = tokens.shape[0]
-        rows = jnp.where(active, jnp.arange(S), S)       # garbage row = S
-        wpos = jnp.clip(lengths, 0, c.max_len - 1)
+        # (every equation runs under one of the program's scopes, the
+        # lanes' bookkeeping too: `profiler.program_scopes` names the
+        # device's time by them)
         with jax.named_scope("embed"):
+            rows = jnp.where(active, jnp.arange(S), S)   # garbage row = S
+            wpos = jnp.clip(lengths, 0, c.max_len - 1)
             x = params["emb"][tokens]                            # (S, d)
         mem = None
         for l in range(half + 1):
             kind, i = kinds[l]
-            w = _weights(params, l, kind, i)
             with jax.named_scope(f"layer{l}/{kind}"):
+                w = _weights(params, l, kind, i)
                 h = layer_norm(x, w["ln1_w"], w["ln1_b"], c.ln_eps)
                 if kind == "mamba":
                     # a state leaf is read and rewritten whole, in place:
@@ -634,16 +637,20 @@ def _make_decode(config, steps, eos_id):
                top_ps, keys):
         def step(carry, _):
             cache, last, lens, left, emitted = carry
-            act = left > 0
+            with jax.named_scope("sampler"):
+                act = left > 0
             cache, logits = micro(params, cache, last, lens, act)
-            nxt = jnp.where(act, sample_tokens(logits, temps, top_ks,
-                                               top_ps, keys, lens), 0)
-            new_left = jnp.where(act, left - 1, left)
-            if eos_id is not None:
-                new_left = jnp.where(act & (nxt == eos_id), 0, new_left)
-            lens = jnp.where(act, lens + 1, lens)
-            last = jnp.where(act, nxt, last)
-            emitted = emitted + act.astype(jnp.int32)
+            nxt = sample_tokens(logits, temps, top_ks, top_ps, keys, lens)
+            # the lanes' carry: what the sampler's token does to each
+            with jax.named_scope("sampler"):
+                nxt = jnp.where(act, nxt, 0)
+                new_left = jnp.where(act, left - 1, left)
+                if eos_id is not None:
+                    new_left = jnp.where(act & (nxt == eos_id), 0,
+                                         new_left)
+                lens = jnp.where(act, lens + 1, lens)
+                last = jnp.where(act, nxt, last)
+                emitted = emitted + act.astype(jnp.int32)
             return (cache, last, lens, new_left, emitted), nxt
 
         zero = jnp.zeros_like(steps_left)

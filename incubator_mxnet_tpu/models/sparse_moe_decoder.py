@@ -573,36 +573,51 @@ def _weights(params, c, l):
     return w
 
 
-def _ffn(x, w, c, l, token_ok):
+def _ffn(x, w, c, l, token_ok, total=None):
     """x (T, d) -> (x + FFN_l(RMSNorm(x)), moe counters (4,) int32:
     token-expert pairs on held experts, held experts that received a
     token, held experts offered, the largest load). A group-limited router
     (`c.n_group` > 1) counts two more: the tokens whose kept groups hold
-    one of this process's experts (`c.held_groups`), and the tokens routed."""
+    one of this process's experts (`c.held_groups`), and the tokens routed.
+    With `total` the counters come added to it. Every equation runs under
+    one of the layer's scopes (the norm under `/mlp`, the counts under
+    `/experts`), in the order it always had: the lowered program is the
+    same text, its locations apart."""
     import jax
     import jax.numpy as jnp
-    h = rms_norm(x, w["ln2_w"], c.norm_eps)
     count_groups = c.n_group > 1
-    if c.mlp_types[l] == "dense":
-        with jax.named_scope(f"layer{l}/mlp"):
-            return x + gated_mlp(h, w["d_gate_up"], w["d_down"]), \
-                jnp.zeros((6 if count_groups else 4,), jnp.int32)
-    with jax.named_scope(f"layer{l}/router"):
-        idx, gates, kept = route(h, w["r_w"], w["r_b"], c, with_kept=True)
-    with jax.named_scope(f"layer{l}/experts"):
-        y, loads = routed_experts(h, idx, gates, token_ok, w["e_gate_up"],
-                                  w["e_down"], c.held_first, c.held_count,
-                                  w["e_row0"])
-    with jax.named_scope(f"layer{l}/shared_expert"):
-        y = y + gated_mlp(h, w["s_gate_up"], w["s_down"])
-    any_token = jnp.any(token_ok).astype(jnp.int32)
-    counted = [jnp.sum(loads), jnp.sum(loads > 0, dtype=jnp.int32),
-               c.held_count * any_token, jnp.max(loads)]
-    if count_groups:
-        here = jnp.any(kept[:, jnp.asarray(c.held_groups)], -1) & token_ok
-        counted += [jnp.sum(here, dtype=jnp.int32),
-                    jnp.sum(token_ok, dtype=jnp.int32)]
-    return x + y, jnp.stack(counted)
+    dense = c.mlp_types[l] == "dense"
+    with jax.named_scope(f"layer{l}/mlp"):
+        h = rms_norm(x, w["ln2_w"], c.norm_eps)
+        if dense:
+            x = x + gated_mlp(h, w["d_gate_up"], w["d_down"])
+            counted = jnp.zeros((6 if count_groups else 4,), jnp.int32)
+    if not dense:
+        with jax.named_scope(f"layer{l}/router"):
+            idx, gates, kept = route(h, w["r_w"], w["r_b"], c,
+                                     with_kept=True)
+        with jax.named_scope(f"layer{l}/experts"):
+            y, loads = routed_experts(h, idx, gates, token_ok,
+                                      w["e_gate_up"], w["e_down"],
+                                      c.held_first, c.held_count,
+                                      w["e_row0"])
+        with jax.named_scope(f"layer{l}/shared_expert"):
+            y = y + gated_mlp(h, w["s_gate_up"], w["s_down"])
+        with jax.named_scope(f"layer{l}/experts"):
+            any_token = jnp.any(token_ok).astype(jnp.int32)
+            counted = [jnp.sum(loads), jnp.sum(loads > 0, dtype=jnp.int32),
+                       c.held_count * any_token, jnp.max(loads)]
+            if count_groups:
+                here = jnp.any(kept[:, jnp.asarray(c.held_groups)], -1) \
+                    & token_ok
+                counted += [jnp.sum(here, dtype=jnp.int32),
+                            jnp.sum(token_ok, dtype=jnp.int32)]
+        with jax.named_scope(f"layer{l}/shared_expert"):
+            x = x + y
+    with jax.named_scope(f"layer{l}/" + ("mlp" if dense else "experts")):
+        if not dense:
+            counted = jnp.stack(counted)
+        return x, counted if total is None else total + counted
 
 
 def _head(params, x, c):
@@ -713,20 +728,23 @@ def _make_micro(config):
         cache = dict(cache)
         S = tokens.shape[0]
         lane = jnp.arange(S)
-        rows = jnp.where(active, lane, S)                # garbage row = S
-        wpos = jnp.clip(lengths, 0, c.max_len - 1)
-        live = jnp.arange(c.max_len)[None, :] <= lengths[:, None]  # (S, T)
-        n_live = jnp.minimum(lengths + 1, c.max_len)
+        # (every equation runs under one of the program's scopes, the
+        # lanes' bookkeeping and a layer's first norm too:
+        # `profiler.program_scopes` names the device's time by them)
         with jax.named_scope("embed"):
+            rows = jnp.where(active, lane, S)            # garbage row = S
+            wpos = jnp.clip(lengths, 0, c.max_len - 1)
+            live = jnp.arange(c.max_len)[None, :] <= lengths[:, None]
+            n_live = jnp.minimum(lengths + 1, c.max_len)
             x = params["emb"][tokens]                            # (S, d)
         chosen = ok = None
         moe = jnp.zeros((4,), jnp.int32)
         sparse = jnp.zeros((3,), jnp.int32)
         for l in range(c.layers):
             fi, _ = c.slots[l]
-            w = _weights(params, c, l)
-            h = rms_norm(x, w["ln1_w"], c.norm_eps)
             with jax.named_scope(f"layer{l}/mla"):
+                w = _weights(params, c, l)
+                h = rms_norm(x, w["ln1_w"], c.norm_eps)
                 cq, q_nope, q_rope, ckr = mla_project(w, c, h, lengths)
                 lat = cache[f"lat{l}"].at[rows, wpos].set(ckr)
                 cache[f"lat{l}"] = lat
@@ -753,8 +771,7 @@ def _make_micro(config):
                     active, n_live, jnp.sum(ok, -1, dtype=jnp.int32))
             with jax.named_scope(f"layer{l}/mla"):
                 x = x + o @ w["wo"]
-            x, counted = _ffn(x, w, c, l, active)
-            moe = moe + counted
+            x, moe = _ffn(x, w, c, l, active, moe)
         return cache, _head(params, x, c), {"moe": moe, "sparse": sparse}
 
     return micro
@@ -784,17 +801,22 @@ def _make_decode(config, steps, eos_id, micro=None, counter_fields=COUNTERS):
                top_ps, keys):
         def step(carry, _):
             cache, last, lens, left, emitted, counters = carry
-            act = left > 0
+            with jax.named_scope("sampler"):
+                act = left > 0
             cache, logits, counted = micro(params, cache, last, lens, act)
-            nxt = jnp.where(act, sample_tokens(logits, temps, top_ks,
-                                               top_ps, keys, lens), 0)
-            new_left = jnp.where(act, left - 1, left)
-            if eos_id is not None:
-                new_left = jnp.where(act & (nxt == eos_id), 0, new_left)
-            lens = jnp.where(act, lens + 1, lens)
-            last = jnp.where(act, nxt, last)
-            emitted = emitted + act.astype(jnp.int32)
-            counters = jax.tree_util.tree_map(jnp.add, counters, counted)
+            nxt = sample_tokens(logits, temps, top_ks, top_ps, keys, lens)
+            # the lanes' carry: what the sampler's token does to each
+            with jax.named_scope("sampler"):
+                nxt = jnp.where(act, nxt, 0)
+                new_left = jnp.where(act, left - 1, left)
+                if eos_id is not None:
+                    new_left = jnp.where(act & (nxt == eos_id), 0,
+                                         new_left)
+                lens = jnp.where(act, lens + 1, lens)
+                last = jnp.where(act, nxt, last)
+                emitted = emitted + act.astype(jnp.int32)
+                counters = jax.tree_util.tree_map(jnp.add, counters,
+                                                  counted)
             return (cache, last, lens, new_left, emitted, counters), nxt
 
         zero = jnp.zeros_like(steps_left)

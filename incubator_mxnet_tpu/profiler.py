@@ -14,17 +14,19 @@ TPU-native: two layers.
 from __future__ import annotations
 
 import json
+import re
 import sys
 import threading
 import time
-from collections import defaultdict, deque
+from collections import OrderedDict, defaultdict, deque
 
 from .base import MXNetError, get_env
 
 __all__ = ["set_config", "start", "stop", "pause", "resume", "dump", "dumps",
            "state", "Task", "Frame", "Event", "Counter", "Domain", "Marker",
            "profiler_scope", "scope", "dispatch_stats", "serve_stats",
-           "feed_stats", "events", "collecting"]
+           "feed_stats", "events", "collecting", "register_program",
+           "program_scopes"]
 
 _lock = threading.Lock()
 # chrome trace events, newest EVENTS_CAP kept: a collector left running
@@ -95,10 +97,6 @@ def state():
     return "run" if _state["running"] else "stop"
 
 
-def is_running():
-    return _state["running"]
-
-
 # jax.profiler.TraceAnnotation, resolved once jax is loaded: this module
 # stays importable without jax, and a session needs jax to be open
 _annotation = [None]
@@ -134,18 +132,155 @@ def events(cat=None):
                 if cat is None or e["cat"] == cat]
 
 
-def record_event(name, category, dur_us, ts_us=None, args=None):
-    """Internal hook: ops.registry calls this when profiling is on."""
+# -- the compiled programs of this process, and their scopes ---------------
+# Whoever runs a jitted program over and over (a serving engine at warm-up,
+# a fused train step at its first call) registers it here: the jitted
+# function and the abstract values it is called with, no buffer. The newest
+# PROGRAMS_CAP are kept; a table is made when somebody asks for it.
+PROGRAMS_CAP = 64
+_programs = OrderedDict()     # (module, owner, id(fn), avals) -> _Program
+
+
+class _Program:
+    """One registered program: `lower()` gives its `jax.stages.Lowered`
+    at the registered shapes; `table` is its memoised scope table."""
+
+    __slots__ = ("module", "fn", "args", "owner", "table")
+
+    def __init__(self, module, fn, args, owner):
+        self.module, self.fn, self.args = module, fn, args
+        self.owner, self.table = owner, None
+
+    def lower(self):
+        return self.fn.lower(*self.args)
+
+
+def _abstract(args):
+    """`args` with every array replaced by its shape and dtype."""
+    import jax
+
+    def one(a):
+        if hasattr(a, "shape") and hasattr(a, "dtype"):
+            spread = getattr(a, "sharding", None)
+            if spread is not None and len(spread.device_set) < 2:
+                spread = None           # one device: wherever the call puts it
+            return jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=spread,
+                weak_type=getattr(a, "weak_type", False))
+        return a
+    return jax.tree_util.tree_map(one, tuple(args))
+
+
+def register_program(fn, args, owner=None):
+    """Note that this process runs the jitted `fn` with arguments shaped
+    like `args` (arrays, or `jax.ShapeDtypeStruct`s: only shapes and
+    dtypes are kept). The program's name is the one a device trace
+    prints, `jit_<fn.__name__>`; `owner` (any hashable) says which
+    programs were registered together, an engine's for one (see
+    `program_scopes`). Nothing is lowered or compiled; `fn` itself is
+    kept, and with it whatever it closes over (a serving program closes
+    over its config, a fused step over its net), until `PROGRAMS_CAP`
+    newer programs have pushed it out. Returns the registered program
+    (`.module`, `.lower()`)."""
+    import jax
+    module = f"jit_{fn.__name__}"
+    owner = id(fn) if owner is None else owner
+    args = _abstract(args)
+    leaves, tree = jax.tree_util.tree_flatten(args)
+    key = (module, owner, id(fn), tree, tuple(
+        (a.shape, str(a.dtype)) if hasattr(a, "shape") else a
+        for a in leaves))
+    with _lock:
+        prog = _programs.pop(key, None) or _Program(module, fn, args, owner)
+        _programs[key] = prog
+        while len(_programs) > PROGRAMS_CAP:
+            _programs.popitem(last=False)
+    return prog
+
+
+def _registered(pattern=None):
+    """The registered programs whose module name, written as a device
+    trace writes it (`jit_decode(`), the regular expression finds."""
+    rx = re.compile(pattern) if pattern else None
+    with _lock:
+        return [p for p in _programs.values()
+                if rx is None or rx.search(p.module + "(")]
+
+
+def _fresh_compile(lowered):
+    """The lowering compiled anew, never the executable that runs: jax's
+    persistent cache keys a program WITHOUT its `op_name` metadata, so the
+    running executable may be one that another commit compiled, with that
+    commit's scopes. Past the executable jax keeps in memory: a compiler
+    option that changes no code. Past the persistent cache, for this
+    thread and this compile alone: a key that holds the metadata, which
+    no entry has, and a least compile time out of reach, so that none is
+    written. Every process that asks pays the compile."""
+    from jax._src import config as jax_config
+    with jax_config.compilation_cache_include_metadata_in_key(True), \
+            jax_config.persistent_cache_min_compile_time_secs(float("inf")):
+        return lowered.compile(
+            compiler_options={"xla_dump_hlo_as_proto": False})
+
+
+def program_scopes(pattern=None):
+    """`{module name: {instruction name: (scope path, pass)}}` of the
+    registered programs whose module name matches `pattern` (all of them
+    without one): what `inspect.scope_table` reads from each program,
+    compiled here on demand from its registered shapes, once. A device
+    trace names an operation by its instruction (`%fusion.17 = ...`), the
+    table says under which `jax.named_scope` of the program it runs; the
+    two are joined by `chipbench/readers/xplane_scope_ms.py`.
+
+    Works after the engine or step that registered is closed and gone.
+    Where several owners registered programs of one module name (a
+    second engine in the process), the one that registered last answers
+    for it. One owner's programs of one name (a ladder of chunk rungs)
+    share a table, without the instruction names on whose scope they
+    disagree. The first call for a program costs its compile (seconds to
+    a minute at real sizes); call it outside a measured window."""
+    from .inspect.hlo import scope_table
+    progs = _registered(pattern)
+    newest = {prog.module: prog.owner for prog in progs}
+    out, clash = {}, {}
+    for prog in progs:
+        if prog.owner != newest[prog.module]:
+            continue
+        if prog.table is None:
+            prog.table = scope_table(_fresh_compile(prog.lower()))
+        table = out.setdefault(prog.module, {})
+        bad = clash.setdefault(prog.module, set())
+        for name, where in prog.table.items():
+            if table.setdefault(name, where) != where:
+                bad.add(name)
+    for module, bad in clash.items():
+        for name in bad:
+            del out[module][name]
+    return out
+
+
+def record_event(name, category, dur_us, ts_us=None, args=None,
+                 async_id=None):
+    """Internal hook: ops.registry calls this when profiling is on.
+    With `async_id` the interval is one that overlaps others of its
+    thread without nesting in them (a request's wait for a slot, noted
+    when the request retires): it is recorded as Chrome's async pair,
+    `b` at its start and `e` at its end under that id, and the `e` event
+    carries `dur` too. A complete event (`X`) never starts before the
+    collector was armed; an async pair may."""
     if not collecting():
         return
+    ts = ts_us if ts_us is not None else _now_us()
+    event = {"name": name, "cat": category, "ph": "X", "ts": ts,
+             "dur": dur_us, "pid": 0,
+             "tid": threading.get_ident() % 100000, "args": args or {}}
     with _lock:
-        _events.append({
-            "name": name, "cat": category, "ph": "X",
-            "ts": ts_us if ts_us is not None else _now_us(),
-            "dur": dur_us, "pid": 0,
-            "tid": threading.get_ident() % 100000,
-            "args": args or {},
-        })
+        if async_id is None:
+            _events.append(event)
+        else:
+            _events.append(dict(event, ph="b", dur=0, id=async_id))
+            _events.append(dict(event, ph="e", ts=ts + dur_us,
+                                id=async_id))
 
 
 def dump(finished=True, profile_process="worker", filename=None):
@@ -245,6 +380,8 @@ def dumps(reset=False, format="table"):
     with _lock:
         agg = defaultdict(lambda: [0, 0.0, float("inf"), 0.0])
         for e in _events:
+            if e["ph"] == "b":       # its `e` partner carries the duration
+                continue
             a = agg[e["name"]]
             a[0] += 1
             a[1] += e["dur"]
@@ -382,23 +519,11 @@ scope = profiler_scope
 
 # ---------------------------------------------------------------------------
 # storage profiler lanes (≙ src/profiler/storage_profiler.{h,cc}: per-alloc
-# timeline + pool stats dump). PJRT owns the allocator, so the equivalents
-# are (a) the live-allocation snapshot XLA exposes (pprof-format heap dump,
-# attributing bytes to the HLO that owns them) and (b) a sampled
-# device-memory timeline — the Chrome-trace "storage lane" the reference
-# renders from its per-alloc events.
+# timeline + pool stats dump). PJRT owns the allocator, so the equivalent
+# is a sampled device-memory timeline — the Chrome-trace "storage lane"
+# the reference renders from its per-alloc events. (XLA's pprof heap dump
+# is one call of jax's own: `jax.profiler.device_memory_profile()`.)
 # ---------------------------------------------------------------------------
-def dump_storage_profile(filename="memory.prof", backend=None):
-    """Write XLA's live-buffer heap profile (pprof format; inspect with
-    `pprof -http` or speedscope). ≙ storage_profiler's aggregate dump."""
-    import jax.profiler as _jp
-    data = _jp.device_memory_profile(backend) if backend \
-        else _jp.device_memory_profile()
-    with open(filename, "wb") as f:
-        f.write(data)
-    return filename
-
-
 def read_memory_sample(device=None):
     """ONE memory reading with an honest provenance stamp:
     `(bytes_in_use, source)`.

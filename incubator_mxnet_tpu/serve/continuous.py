@@ -45,11 +45,12 @@ plain device buffers, zero retraces):
     PERF.md §2 is where a cell pays for it).
 
 Tracing: one request = ONE trace across its N iterations. The root
-`serve.request` context is minted at `submit()` (PR-13 plumbing); the
-engine records `serve.prefill` (admission -> first token) and
-`serve.decode` (first -> last token, N iterations) as children, and
-closes the root at retirement — while the profiler collects, the whole
-request renders as a single tree in the Chrome trace.
+`serve.request` context is minted at `submit()` (PR-13 plumbing); at
+retirement the engine records `serve.queue` (submit -> admission),
+`serve.prefill` (admission -> first token) and `serve.decode` (first ->
+last token, N iterations) as its children and closes the root — while
+the profiler collects, the whole request renders as a single tree in the
+Chrome trace.
 
 The bundled `CachedDecoder` is a small pre-norm transformer decoder over
 the slot pool — the LLM-shaped model side for tests and the benchmark; any
@@ -104,6 +105,7 @@ import numpy as _np
 
 from ..base import MXNetError, get_env
 from .. import fault as _fault
+from .. import profiler as _profiler
 from .. import sanitize as _sanitize
 from ..telemetry import (record_span, span as _span, NO_SPAN,
                          trace as _trace, mem_on_oom, mem_install_oom_hook)
@@ -113,6 +115,7 @@ from .metrics import SERVE_STATS, _STATS_LOCK, percentile
 from .kv_pool import CacheKindError, KVCachePool, SlotsFullError
 from .prefix_cache import PrefixCache
 from .sampling import (sample_first as _sample_first,
+                       sample_first_program as _sample_first_program,
                        sample_tokens as _sample_tokens,
                        seed_key as _seed_key)
 
@@ -460,9 +463,12 @@ def _make_decode(config, steps=1, eos_id=None):
         # KV lands at position `lengths`); active (S,) bool
         S = tokens.shape[0]
         T = c.max_len
-        rows = jnp.where(active, jnp.arange(S), S)       # garbage row = S
-        wpos = jnp.clip(lengths, 0, T - 1)
+        # (every equation of the program runs under one of its scopes, the
+        # lanes' bookkeeping too: `profiler.program_scopes` names the
+        # device's time by them)
         with jax.named_scope("embed"):
+            rows = jnp.where(active, jnp.arange(S), S)   # garbage row = S
+            wpos = jnp.clip(lengths, 0, T - 1)
             x = params["emb"][tokens] + params["pos"][wpos]  # (S, E)
         # attention reads positions 0..lengths INCLUSIVE (the new token's
         # KV is written before the read); anything past that — pad-token
@@ -486,22 +492,27 @@ def _make_decode(config, steps=1, eos_id=None):
             logits = _rmsnorm(x, params["lnf"]) @ params["emb"].T
         nxt = _sample_tokens(logits, temps, top_ks, top_ps, keys,
                              lengths)
-        return k_cache, v_cache, jnp.where(active, nxt, 0)
+        with jax.named_scope("sampler"):
+            return k_cache, v_cache, jnp.where(active, nxt, 0)
 
     def decode(params, k_cache, v_cache, tokens, lengths, steps_left,
                temps, top_ks, top_ps, keys):
         def step(carry, _):
             k_cache, v_cache, last, lens, left, emitted = carry
-            act = left > 0
+            with jax.named_scope("sampler"):
+                act = left > 0
             k_cache, v_cache, nxt = micro(params, k_cache, v_cache,
                                           last, lens, act,
                                           temps, top_ks, top_ps, keys)
-            new_left = jnp.where(act, left - 1, left)
-            if eos_id is not None:
-                new_left = jnp.where(act & (nxt == eos_id), 0, new_left)
-            lens = jnp.where(act, lens + 1, lens)
-            last = jnp.where(act, nxt, last)
-            emitted = emitted + act.astype(jnp.int32)
+            # the lanes' carry: what the sampler's token does to each
+            with jax.named_scope("sampler"):
+                new_left = jnp.where(act, left - 1, left)
+                if eos_id is not None:
+                    new_left = jnp.where(act & (nxt == eos_id), 0,
+                                         new_left)
+                lens = jnp.where(act, lens + 1, lens)
+                last = jnp.where(act, nxt, last)
+                emitted = emitted + act.astype(jnp.int32)
             return (k_cache, v_cache, last, lens, new_left, emitted), nxt
 
         zero = jnp.zeros_like(steps_left)
@@ -1048,7 +1059,7 @@ class _GenRequest:
                  "ctx", "slot", "generated", "cache_len", "t_admit",
                  "t_first", "t_last", "t_done", "temperature", "top_k",
                  "top_p", "key", "entry", "cached_len", "prefill_pos",
-                 "left")
+                 "left", "rid", "wave")
 
     def __init__(self, prompt, max_new, deadline, ctx,
                  temperature=0.0, top_k=0, top_p=1.0, key=None):
@@ -1062,6 +1073,8 @@ class _GenRequest:
         self.slot = None
         self.generated = []
         self.cache_len = 0
+        self.rid = None                      # the engine's n-th request
+        self.wave = None                     # decode waves before admission
         self.t_admit = None                  # KV slot claimed
         self.t_first = None                  # first token (TTFT anchor)
         self.t_last = None
@@ -1383,9 +1396,14 @@ class ContinuousEngine:
         # dispatch. `_unread` is what the last iteration dispatched and
         # nobody has read yet. The scheduler thread alone touches the three
         self._join_prog, self._advance_prog = _lane_programs(eos_id)
+        # every program warm-up ran, by name (`_warm`), and the token
+        # under which the process-wide registry keeps them together
+        self._programs = {}
+        self._programs_owner = object()
         self._reset_lanes()
         self._unread = None  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
         self._auto_seed = 0                  # per-engine seed fountain
+        self._submitted = 0                  # requests queued so far (_cv)
         # (ttft, tpot or None, e2e) ms of the newest retired requests, from
         # their RequestTiming fields: stats()'s one source of percentiles
         self._latencies = deque(maxlen=4096)
@@ -1423,87 +1441,119 @@ class ContinuousEngine:
 
     def _warmup(self):
         """One garbage-lane pass through EVERY step program (prefill +
-        decode, plus the chunk-prefill and row-copy programs when
-        configured): compiles (or loads from MXNET_COMPILE_CACHE_DIR)
-        each without touching any real slot."""
+        decode, their sampler and lane programs, plus the chunk-prefill
+        and row-copy programs when configured): compiles (or loads from
+        MXNET_COMPILE_CACHE_DIR) each without touching any real slot."""
         import jax
+        cache, lanes = self._walk_programs(self._warm, self.pool.buffers())
+        self.pool.swap_buffers(*cache)
+        # wait for the compiles to actually finish so warmup_s is honest
+        jax.block_until_ready((cache, lanes))
+        self._reset_lanes()
+        self._count("programs_compiled", sum(
+            name.startswith(("prefill", "decode", "chunk_prefill", "copy"))
+            for name in self._programs))
+
+    def _walk_programs(self, call, cache):
+        """Every step program this engine will run, once, with arguments
+        under which no real slot is touched (every lane idle or writing
+        the garbage row), in the order of a serving iteration:
+        `call(name, program, *args)` -> the program's outputs. `cache` is
+        the pool's buffers; each donating program's outputs take their
+        place. Returns (cache, lane state) as the last calls left them.
+        With `_warm` as `call` this is warm-up; with `_describe` nothing
+        runs and `cache` may be the pool's avals."""
         import jax.numpy as jnp
         g = self.pool.garbage_row
         P = self.prefill_lanes
         S = self.pool.max_slots
+        params = self.model.params
+        sample_first = _sample_first_program()
+        n = len(cache)
+        # (a model that declares `counters` returns them last: nothing
+        # counted here is kept)
+        held = -1 if self._counter_fields else None
         lens = jnp.ones((P,), dtype=jnp.int32)
-        cache = self.pool.buffers()
-        *cache, logits = self._outputs(self._prefill_prog(
-            self.model.params, *cache,
+        *cache, logits = call(
+            "prefill", self._prefill_prog, params, *cache,
             jnp.zeros((P, self.prefill_window), dtype=jnp.int32),
-            lens, jnp.full((P,), g, dtype=jnp.int32)))
-        self.pool.swap_buffers(*cache)
-        # warm the shared first-token sampler at this (P, vocab) shape
-        # too — it is part of the steady-state prefill wave — and the
-        # lane join at its output's shape, with no lane joining
-        first = _sample_first(logits, jnp.zeros((P,), dtype=jnp.float32),
-                              jnp.zeros((P,), dtype=jnp.int32),
-                              jnp.ones((P,), dtype=jnp.float32),
-                              jnp.zeros((P, 2), dtype=jnp.uint32), lens - 1)
-        self._reset_lanes()
+            lens, jnp.full((P,), g, dtype=jnp.int32))[:held]
+        # the shared first-token sampler at this (P, vocab) shape — it is
+        # part of the steady-state prefill wave — and the lane join at
+        # its output's shape, with no lane joining
+        first = call("sample_first", sample_first, logits,
+                     jnp.zeros((P,), dtype=jnp.float32),
+                     jnp.zeros((P,), dtype=jnp.int32),
+                     jnp.ones((P,), dtype=jnp.float32),
+                     jnp.zeros((P, 2), dtype=jnp.uint32), lens - 1)
+        idle = jnp.zeros((S,), dtype=jnp.int32)
         nobody = jnp.full((3, S), -1, dtype=jnp.int32)
-        self._lanes = self._join_prog(*self._lanes, first, nobody)  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
-        args = [jnp.zeros((S,), dtype=jnp.int32),
-                jnp.zeros((S,), dtype=jnp.int32),
-                jnp.zeros((S,), dtype=jnp.int32),
+        lanes = call("join_lanes", self._join_prog, idle, idle, idle,
+                     first, nobody)
+        args = [idle, idle, idle,
                 jnp.zeros((S,), dtype=jnp.float32),
                 jnp.zeros((S,), dtype=jnp.int32),
                 jnp.ones((S,), dtype=jnp.float32),
                 jnp.zeros((S, 2), dtype=jnp.uint32)]
         if self.draft_tokens:
             args.append(jnp.zeros((S, self.max_len), dtype=jnp.int32))
-        cache = self.pool.buffers()
-        n = len(cache)
-        out = self._outputs(self._decode_prog(self.model.params, *cache,
-                                              *args))
-        self.pool.swap_buffers(*out[:n])
+        out = call("decode", self._decode_prog, params, *cache,
+                   *args)[:held]
+        cache = out[:n]
         if not self.draft_tokens:
-            self._lanes = self._advance_prog(*self._lanes, *out[n:])  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
-        n_progs = 2
+            lanes = call("advance_lanes", self._advance_prog, *lanes,
+                         *out[n:])
         if self._chunk_progs is not None:
             # all-idle chunk wave (every lane scatters into garbage)
             # through EVERY extent rung, so wave-time extent selection
-            # never compiles; warm the first-token sampler at the
-            # (S, vocab) shape the chunk path samples from too
-            logits = None
+            # never compiles; the first-token sampler and the join at
+            # the (C, vocab) shape the chunk path samples from too
             C = P if self._chunk_compact else S
-            idle = [jnp.zeros((C, self.prefill_window), dtype=jnp.int32),
-                    jnp.zeros((C,), dtype=jnp.int32),
-                    jnp.zeros((C,), dtype=jnp.int32)]
+            idle_c = [jnp.zeros((C, self.prefill_window), dtype=jnp.int32),
+                      jnp.zeros((C,), dtype=jnp.int32),
+                      jnp.zeros((C,), dtype=jnp.int32)]
             if self._chunk_compact:
-                idle.append(jnp.full((C,), g, dtype=jnp.int32))
+                idle_c.append(jnp.full((C,), g, dtype=jnp.int32))
             # (a model whose chunk ignores the extent hands back ONE
-            # program for every rung: it is warmed once)
-            for prog in dict.fromkeys(self._chunk_progs.values()):
-                cache = self.pool.buffers()
-                *cache, logits = self._outputs(
-                    prog(self.model.params, *cache, *idle))
-                self.pool.swap_buffers(*cache)
-                n_progs += 1
-            first = _sample_first(
-                logits, jnp.zeros((C,), dtype=jnp.float32),
-                jnp.zeros((C,), dtype=jnp.int32),
-                jnp.ones((C,), dtype=jnp.float32),
-                jnp.zeros((C, 2), dtype=jnp.uint32),
-                jnp.zeros((C,), dtype=jnp.int32))
-            self._lanes = self._join_prog(*self._lanes, first, nobody)  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
+            # program for every rung: it is called once, under its
+            # first rung's name)
+            rung_of = {}
+            for x, prog in self._chunk_progs.items():
+                rung_of.setdefault(prog, x)
+            for prog, x in rung_of.items():
+                *cache, logits = call(f"chunk_prefill[{x}]", prog, params,
+                                      *cache, *idle_c)[:held]
+            first = call("sample_first[chunk]", sample_first, logits,
+                         jnp.zeros((C,), dtype=jnp.float32),
+                         jnp.zeros((C,), dtype=jnp.int32),
+                         jnp.ones((C,), dtype=jnp.float32),
+                         jnp.zeros((C, 2), dtype=jnp.uint32),
+                         jnp.zeros((C,), dtype=jnp.int32))
+            lanes = call("join_lanes[chunk]", self._join_prog, *lanes,
+                         first, nobody)
         if self._copy_prog is not None:
             # every lane at length 0: compiles, moves nothing
-            kb, vb = self.pool.buffers()
-            idle = jnp.zeros((P,), dtype=jnp.int32)
-            k, v = self._copy_prog(kb, vb, idle, idle, idle)
-            self.pool.swap_buffers(k, v)
-            n_progs += 1
-        # wait for the compiles to actually finish so warmup_s is honest
-        jax.block_until_ready((self.pool.buffers(), self._lanes))
-        self._pending.clear()  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts; afterwards only that thread touches it
-        self._reset_lanes()
-        self._count("programs_compiled", n_progs)
+            idle_p = jnp.zeros((P,), dtype=jnp.int32)
+            cache = call("copy", self._copy_prog, *cache, idle_p, idle_p,
+                         idle_p)
+        return tuple(cache), lanes
+
+    def _warm(self, name, prog, *args):
+        """One call of a step program, noted first under `name` with its
+        arguments' shapes in the process-wide program registry
+        (`profiler.register_program`: shapes and dtypes, no buffer): the
+        one list that `lowered_programs()`, `memory_plans()` and
+        `profiler.program_scopes()` read."""
+        self._programs[name] = _profiler.register_program(  # mxlint: disable=lock-shared-mutation -- warm-up runs before the scheduler thread starts
+            prog, args, owner=self._programs_owner)
+        return prog(*args)
+
+    def _describe(self, name, prog, *args):
+        """`_warm` without the call: the outputs' shapes alone."""
+        import jax
+        self._programs[name] = _profiler.register_program(  # mxlint: disable=lock-shared-mutation -- an engine that was never started has no scheduler thread
+            prog, args, owner=self._programs_owner)
+        return jax.eval_shape(prog, *args)
 
     def _reset_lanes(self):
         """Every lane idle: what the device holds before the first join,
@@ -1654,6 +1704,7 @@ class ContinuousEngine:
                 rejected = True
             else:
                 rejected = False
+                req.rid = self._submitted = self._submitted + 1
                 self._waiting.append(req)
                 self._cv.notify()
         if rejected:
@@ -1717,46 +1768,35 @@ class ContinuousEngine:
         return r
 
     def lowered_programs(self):
-        """The prefill and decode jits lowered at the exact warmup shapes
-        via abstract avals (`{"prefill", "decode"}` of
-        `jax.stages.Lowered`) — no buffers touched, no extra compile in
+        """Every step program this engine runs, lowered at the exact
+        shapes warm-up called it with (`{name: jax.stages.Lowered}`):
+        `prefill`, `decode`, `sample_first`, `join_lanes`,
+        `advance_lanes`, and where configured `chunk_prefill[<extent>]` a
+        rung, `copy`, and the sampler and join at the chunk path's shape.
+        Abstract values only — no buffers touched, no extra compile in
         steady state: the lowering hits the same jit cache entry the
-        engine replays. The inspection surface `memory_plans()` reads,
-        and where `chip_smoke.py` looks for the paged-attention kernel
-        in the compiled decode program."""
-        import jax
-        import jax.tree_util as jtu
-
-        def aval(shape, dtype="int32"):
-            return jax.ShapeDtypeStruct(shape, dtype)
-
-        params_avals = jtu.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-            self.model.params)
-        cache_avals = self.pool.avals()
-        P, S, W = (self.prefill_lanes, self.pool.max_slots,
-                   self.prefill_window)
-        prefill = self._prefill_prog.lower(
-            params_avals, *cache_avals, aval((P, W)), aval((P,)),
-            aval((P,)))
-        dec_avals = [params_avals, *cache_avals, aval((S,)),
-                     aval((S,)), aval((S,)), aval((S,), "float32"),
-                     aval((S,)), aval((S,), "float32"),
-                     aval((S, 2), "uint32")]
-        if self.draft_tokens:
-            dec_avals.append(aval((S, self.max_len)))
-        decode = self._decode_prog.lower(*dec_avals)
-        return {"prefill": prefill, "decode": decode}
+        engine replays. An engine that was never started describes its
+        programs by their shapes alone (`_describe`). The same registered
+        programs answer `profiler.program_scopes()`, also once this
+        engine is closed; `memory_plans()` reads them, and `chip_smoke.py`
+        looks here for the paged-attention kernel in the compiled decode
+        program."""
+        if not self._programs:      # never warmed: shapes only
+            self._walk_programs(self._describe, tuple(self.pool.avals()))
+        return {name: prog.lower() for name, prog in self._programs.items()}
 
     def memory_plans(self):
-        """Predicted device-memory plans of the TWO compiled step
-        programs (`mx.inspect.memory.memory_plan` over
-        `lowered_programs()`). The KV slab dominates both plans'
-        argument size and is donated, so `alias_size` covering ~2x the
-        slab is the zero-copy-update evidence."""
+        """Predicted device-memory plans of the compiled step programs
+        that hold a model (`mx.inspect.memory.memory_plan` over
+        `lowered_programs()`; the lane and sampler programs are a few
+        vectors). The KV slab dominates the plans' argument size and is
+        donated, so `alias_size` covering ~2x the slab is the
+        zero-copy-update evidence."""
         from ..inspect.memory import memory_plan
+        small = ("sample_first", "join_lanes", "advance_lanes")
         return {name: memory_plan(low, name=f"{self.name}.{name}")
-                for name, low in self.lowered_programs().items()}
+                for name, low in self.lowered_programs().items()
+                if not name.startswith(small)}
 
     def stats(self):
         """Plain-data snapshot: counters, slot occupancy, TTFT/TPOT
@@ -1976,6 +2016,7 @@ class ContinuousEngine:
                 except SlotsFullError:   # raced a test's direct claim
                     break
                 req.t_admit = now
+                req.wave = self._counters["decode_iterations"]
                 if self._cache is not None:
                     # pin the matched prefix for this request's lifetime
                     # (released at retire); eviction can never reclaim
@@ -2357,7 +2398,6 @@ class ContinuousEngine:
         with (_span("serve.decode_batch.emit", cat="serve")
               if on and in_wave else NO_SPAN):
             now = time.perf_counter()
-            prof = _profiler_on()
             touched = []
             n_sampled = 0
             for first, ended in firsts:
@@ -2367,16 +2407,6 @@ class ContinuousEngine:
                     req.t_first = req.t_last = now
                     n_sampled += int(req.temperature > 0)
                     touched.append(req)
-                    if req.ctx is not None and prof:
-                        # admission -> first token, child of the request
-                        # root: iteration 0 of the request's one trace
-                        record_span(
-                            "serve.prefill", (now - req.t_submit) * 1e6,
-                            ts_us=req.t_submit * 1e6, cat="serve",
-                            ctx=_trace.child_context(req.ctx,
-                                                     "serve.prefill"),
-                            prompt_tokens=req.prompt.size,
-                            cached_tokens=req.cached_len, slot=req.slot)
             if rec.wave is not None:
                 emitted = out[2] if draft else out[1]
                 lens = []
@@ -2486,6 +2516,27 @@ class ContinuousEngine:
                     total_ms))
             self._count("replies")
             self._count("retired")
+            if prof and req.t_first is not None:
+                # every request's wait for a slot and its prefill, from
+                # the timeline it keeps anyway (no clock read here):
+                # submit -> admission -> first token, caused by the wave
+                # whose iteration admitted it. Children of the request's
+                # root where it has one (submitted under a collector), of
+                # this retirement's span else; `retired_us` says when
+                # they were noted (a wait ends long before its request).
+                # They begin before the collector was armed and overlap
+                # other requests', hence an async pair, not `X`
+                ids = dict(request=req.rid, cause=f"wave-{req.wave}",
+                           slot=req.slot, retired_us=now * 1e6)
+                for name, t0, t1 in (
+                        ("serve.queue", req.t_submit, req.t_admit),
+                        ("serve.prefill", req.t_admit, req.t_first)):
+                    record_span(
+                        name, (t1 - t0) * 1e6, ts_us=t0 * 1e6, cat="serve",
+                        ctx=(None if req.ctx is None
+                             else _trace.child_context(req.ctx, name)),
+                        async_id=req.rid, prompt_tokens=req.prompt.size,
+                        cached_tokens=req.cached_len, **ids)
             if req.ctx is not None and prof:
                 if req.t_first is not None and req.t_last > req.t_first:
                     # first -> last token: the N decode iterations as one

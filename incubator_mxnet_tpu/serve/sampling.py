@@ -79,6 +79,16 @@ def sample_tokens(logits, temps, top_ks, top_ps, keys, positions):
 _SAMPLE_JIT = None
 
 
+def sample_first_program():
+    """The ONE process-wide jitted sampler behind `sample_first`
+    (`jit_sample_tokens` in a device trace)."""
+    global _SAMPLE_JIT
+    if _SAMPLE_JIT is None:
+        import jax
+        _SAMPLE_JIT = jax.jit(sample_tokens)
+    return _SAMPLE_JIT
+
+
 def sample_first(logits, temps, top_ks, top_ps, keys, positions):
     """First-token draw from prefill logits through ONE process-wide
     jitted sampler. The sampling math compiles once per (lanes, vocab)
@@ -86,8 +96,5 @@ def sample_first(logits, temps, top_ks, top_ps, keys, positions):
     re-traced into each model's prefill program (the decode program
     keeps its own in-scan copy, where it must live). Identical math
     either way, so engine == reference still holds bit-for-bit."""
-    global _SAMPLE_JIT
-    if _SAMPLE_JIT is None:
-        import jax
-        _SAMPLE_JIT = jax.jit(sample_tokens)
-    return _SAMPLE_JIT(logits, temps, top_ks, top_ps, keys, positions)
+    return sample_first_program()(logits, temps, top_ks, top_ps, keys,
+                                  positions)

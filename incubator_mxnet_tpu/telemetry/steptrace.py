@@ -92,7 +92,8 @@ def current_span():
     return ctx.name if ctx is not None else None
 
 
-def record_span(name, dur_us, ts_us=None, cat="span", ctx=None, **attrs):
+def record_span(name, dur_us, ts_us=None, cat="span", ctx=None,
+                async_id=None, **attrs):
     """Record an externally-timed span: the one implementation behind
     every Chrome-trace lane (`serve.batch`, `io.feed`, `feed.stage`, and
     `with span(...)` itself). Feeds the `span.duration_us{name=...}`
@@ -103,7 +104,9 @@ def record_span(name, dur_us, ts_us=None, cat="span", ctx=None, **attrs):
     a request tree; with no `ctx`, the ambient `trace.current_context()`
     — if any — becomes the parent and a fresh child id is minted. Either
     way the trace/span/parent ids land in the event args, so the
-    cross-thread tree reassembles from the exported trace JSON."""
+    cross-thread tree reassembles from the exported trace JSON.
+    `async_id`: see `profiler.record_event` (an interval that overlaps
+    others without nesting: an async pair in the Chrome trace)."""
     if not _enabled():
         return
     bounds = _bound_memo.get(name)
@@ -138,7 +141,8 @@ def record_span(name, dur_us, ts_us=None, cat="span", ctx=None, **attrs):
     # + import-lock traffic per call
     if _trace._profiler_running():
         _trace._profiler_mod[0].record_event(name, cat, dur_us,
-                                             ts_us=ts_us, args=attrs)
+                                             ts_us=ts_us, args=attrs,
+                                             async_id=async_id)
 
 
 # (TraceAnnotation, StepTraceAnnotation), resolved under the first open

@@ -794,13 +794,20 @@ def test_one_trace_across_iterations(decoder, tmp_path):
     for root in roots:
         tid = root["args"]["trace_id"]
         span_id = root["args"]["span_id"]
+        # (the wait for a slot and the prefill overlap other requests':
+        # an async pair each, `e` at the end with the duration)
+        queue = [e for e in events if e["name"] == "serve.queue"
+                 and e["ph"] == "e" and e["args"].get("trace_id") == tid]
         prefill = [e for e in events if e["name"] == "serve.prefill"
-                   and e["args"].get("trace_id") == tid]
+                   and e["ph"] == "e" and e["args"].get("trace_id") == tid]
         decode = [e for e in events if e["name"] == "serve.decode"
                   and e["args"].get("trace_id") == tid]
-        # admission->first-token and first->last-token (N iterations)
-        # both hang off the SAME request root: one trace, N iterations
-        assert len(prefill) == 1 and len(decode) == 1
+        # submit->admission, admission->first-token and first->last-token
+        # (N iterations) hang off the SAME request root: one trace
+        assert len(queue) == 1 and len(prefill) == 1 and len(decode) == 1
+        assert queue[0]["args"]["parent_span_id"] == span_id
+        assert abs(queue[0]["ts"] + prefill[0]["dur"]
+                   - prefill[0]["ts"]) < 1.0
         assert prefill[0]["args"]["parent_span_id"] == span_id
         assert decode[0]["args"]["parent_span_id"] == span_id
         assert decode[0]["args"]["tokens"] == root["args"]["tokens"]

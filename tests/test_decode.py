@@ -648,7 +648,12 @@ def test_memory_plans_cover_quantized_spec_programs(int8_engine):
     per-position scale pairs and the speculative token-history page —
     so the PR-15 plan surface keeps working on the new program family."""
     plans = int8_engine.memory_plans()
-    assert set(plans) == {"prefill", "decode"}
+    # every program that holds the model: the two, and what the engine's
+    # options add (chunk rungs, the prefix copy)
+    assert {"prefill", "decode"} <= set(plans) <= {
+        n for n in int8_engine.lowered_programs()}
+    assert not any(n.startswith(("sample_first", "join_lanes",
+                                 "advance_lanes")) for n in plans)
     for key, plan in plans.items():
         assert plan["name"].endswith(key)
         assert plan.get("complete") in (True, False)

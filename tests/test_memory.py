@@ -354,8 +354,10 @@ def test_device_memory_info_typed_sentinel(monkeypatch):
 # kvpool slab gauge (satellite 3)
 # ---------------------------------------------------------------------------
 def test_kvpool_slab_gauge_matches_census_owner_bytes():
+    import gc
     from incubator_mxnet_tpu.serve.kv_pool import KVCachePool
 
+    gc.collect()    # pools of engines that earlier tests of this worker left
     pool = KVCachePool(max_slots=4, layers=2, max_len=16, heads=2,
                        head_dim=8)
     gauge = telemetry.REGISTRY.snapshot()["kvpool.slab_bytes"]

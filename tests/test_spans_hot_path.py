@@ -29,7 +29,7 @@ sys.path.insert(0, ROOT)
 from chipbench.paths.serve_engine import COUNTED  # noqa: E402
 from chipbench.paths.serve_hybrid import cache_counters  # noqa: E402
 from chipbench.readers import (cache_live_share, engine_stats,  # noqa: E402
-                               feed_stats, span_ms)
+                               feed_stats, span_mean_ms, span_ms)
 
 KERNEL_FILES = ("incubator_mxnet_tpu/ops/pallas_kernels.py",
                 "incubator_mxnet_tpu/ops/pallas_attention.py")
@@ -235,6 +235,85 @@ def test_buffer_holds_the_same_spans_with_wave_trace_ids(served):
     assert served["quiet"], "spans were buffered after the session closed"
 
 
+def test_every_retired_request_leaves_its_queue_and_prefill_span(served):
+    """Request scale, noted at retirement from `fut.timing`'s fields: the
+    wait for a slot and the prefill, an async pair each (they overlap
+    other requests' without nesting), with the request's number and the
+    wave whose iteration admitted it."""
+    pairs = {}
+    for e in served["buffer"]:
+        if e["ph"] in "be":
+            pairs.setdefault((e["name"], e["id"]), {})[e["ph"]] = e
+    assert sorted(n for n, _ in pairs) == ["serve.prefill"] * 5 \
+        + ["serve.queue"] * 5
+    for (name, rid), pair in pairs.items():
+        b, e = pair["b"], pair["e"]
+        assert abs(e["ts"] - b["ts"] - e["dur"]) < 0.01
+        assert e["dur"] >= 0 and b["dur"] == 0
+        assert e["args"]["request"] == rid == b["args"]["request"]
+        assert re.fullmatch(r"wave-\d+", e["args"]["cause"])
+        # noted when the request retired, after the span's own end
+        assert e["args"]["retired_us"] >= e["ts"]
+        assert e["args"]["parent"] == "serve.request"
+        if name == "serve.queue":       # ends where the prefill begins
+            after = pairs["serve.prefill", rid]
+            assert abs(after["b"]["ts"] - e["ts"]) < 1.0
+            assert after["e"]["args"]["cause"] == e["args"]["cause"]
+    # the durations are the timeline's
+    waits = sorted(p["e"]["dur"] for (n, _), p in pairs.items()
+                   if n == "serve.queue")
+    mine = sorted((f.timing.t_admit - f.timing.t_submit) * 1e6
+                  for f in served["futs"])
+    assert all(any(abs(w - m) < 1.0 for w in waits) for m in mine)
+    # and the reader's mean over the spans that end in the interval
+    complete = [(e["name"], e["tid"], e["ts"], e["dur"])
+                for e in served["buffer"] if e["ph"] == "X"]
+    ends = [(e["name"], e["args"]["retired_us"], e["dur"])
+            for e in served["buffer"] if e["ph"] == "e"]
+    retired = sorted(f.timing.t_done * 1e6 for f in served["futs"])
+    assert all(any(abs(r - t) < 1.0 for _, t, _ in ends) for r in retired)
+    got = span_mean_ms.reduce(complete, ends,
+                              {"name": r"^serve\.queue$"}, 3600.0)
+    assert got == pytest.approx(1e-3 * sum(waits) / 5)
+
+
+def test_a_request_queued_before_the_collector_moves_no_complete_span():
+    """Such a request's spans begin before the collector was armed; the
+    buffer's complete spans, whose first start places every span metric's
+    interval (`span_ms`), still begin after it."""
+    eng = toy_engine().start()
+    try:
+        with eng._cv:           # nothing is admitted before the collector
+            fut = eng.submit([1, 2, 3], 6)
+            armed_us = profiler._now_us()
+            profiler.start()
+        try:
+            fut.result(timeout=120)
+            eng.generate([4, 5], 2)
+        finally:
+            profiler.stop()
+    finally:
+        eng.close()
+    events = profiler.events()
+    begun = [e for e in events if e["ph"] == "b" and e["id"] == 1]
+    assert sorted(e["name"] for e in begun) == ["serve.prefill",
+                                                "serve.queue"]
+    queue = [e for e in begun if e["name"] == "serve.queue"][0]
+    assert queue["ts"] < armed_us
+    # submitted unarmed, so without a root: under the retirement that
+    # noted it
+    assert queue["args"]["parent"] == "serve.retire"
+    complete = [e for e in events if e["ph"] == "X"]
+    assert complete and min(e["ts"] for e in complete) >= armed_us
+    assert span_mean_ms.reduce(
+        span_ms.spans(), span_mean_ms.ended(),
+        {"name": r"^serve\.queue$"}, 3600.0) > 0
+    # the aggregate table counts a pair once
+    assert profiler.dumps(format="json").count('"serve.queue"') == 1
+    assert json.loads(profiler.dumps(format="json"))["events"][
+        "serve.queue"]["calls"] == 2
+
+
 def test_events_accessor_filters_and_copies():
     profiler.start()
     try:
@@ -267,12 +346,19 @@ def test_unarmed_engine_buffers_nothing_and_builds_no_annotation(
     monkeypatch.setattr(profiler, "_annotation", [Refuse])
     assert not telemetry.trace.armed()
     opens = telemetry.snapshot()["flightrec.events"]
+    request_scale = ['span.count{name="%s"}' % n for n in (
+        "serve.queue", "serve.prefill", "serve.decode", "serve.request")]
+    counted = [telemetry.snapshot().get(k, 0) for k in request_scale]
     eng = toy_engine().start()
     try:
+        # submit -> retirement records no span, request scale or wave
         out = eng.generate([1, 2, 3], 6)
         assert eng.stats()["decode_iterations"] >= 2
+        assert eng.stats()["retired"] == 1
     finally:
         eng.close()
+    assert [telemetry.snapshot().get(k, 0)
+            for k in request_scale] == counted
     step, data = toy_step()
     for x, y in DeviceFeed(data):
         step(x, y)
