@@ -227,27 +227,20 @@ def _store_pos(cache, rows, l, wpos, val):
             scales.at[rows, l, wpos].set(s))
 
 
-def _paged_attn(k_cache, v_cache, q, lengths, l, extent=None):
+def _paged_attn(k_cache, v_cache, q, lengths, l):
     """Decode-side attention read over the slot slab via
     `ops.fused.paged_attention`: Pallas block-sparse kernel on TPU (or
     interpret-mode CI), identical masked-einsum jnp fallback elsewhere.
     q is (S, C, H, D); chunk offset j reads positions [0, lengths+j].
 
-    `extent` statically slices the slab's position axis to [0, extent)
-    before the read: when the caller can bound `lengths + j < extent`
-    for every lane, the masked positions beyond it contribute exact
-    zeros, so the output is bit-identical to the full-width read at a
-    fraction of the cost (the chunk-prefill extent ladder)."""
+    The slab goes to the kernel WHOLE, as the donated buffer it is: the
+    kernel's grid is one step per live (lane, block) pair, so what a
+    lane has not reached costs no step and no byte, and a slice of the
+    slab to bound the read would be a copy of it (once a layer for K and
+    once for V: the write of layer `l` comes before its read)."""
     from ..ops import fused as _fused
     k_slab, k_scale = _kv_split(k_cache)
     v_slab, v_scale = _kv_split(v_cache)
-    if extent is not None and extent < k_slab.shape[2]:
-        k_slab = k_slab[:, :, :extent]
-        v_slab = v_slab[:, :, :extent]
-        if k_scale is not None:
-            k_scale = k_scale[:, :, :extent]
-        if v_scale is not None:
-            v_scale = v_scale[:, :, :extent]
     return _fused.paged_attention(q, k_slab, v_slab, lengths, l,
                                   k_scale=k_scale, v_scale=v_scale)
 
@@ -335,22 +328,23 @@ def _make_chunk_prefill(config, window=None, extent=None):
     meaningful for a lane whose chunk ends at its prompt tail, which is
     exactly when the engine samples the first token from them.
 
-    `extent` bounds the attention read to slab positions [0, extent):
-    valid for a wave whose furthest lane satisfies offset + nvalid <=
-    extent. Positions past the bound are mask-excluded zeros either
-    way, so a smaller extent is bit-identical and cheaper — the engine
-    warms a ladder of extents and dispatches the smallest one that
-    covers the wave."""
+    `extent`, the bound a wave's furthest lane satisfies (offset +
+    nvalid <= extent), is accepted, range-checked and NOT part of the
+    program: the paged kernel visits a lane's live blocks and no other,
+    so the bound is already the data's (`_paged_attn`), and every extent
+    is the same traced function. The engine's extent ladder is for the
+    models whose chunk reads the cached positions densely in plain XLA
+    (`SparseMoEDecoder`, `DeltaMoEDecoder`), where the extent does bound
+    the work."""
     import jax
     import jax.numpy as jnp
     c = config
     W = int(window if window is not None else c.max_len)
     if not 1 <= W <= c.max_len:
         raise ServeError(f"chunk window {W} outside [1, {c.max_len}]")
-    E = int(extent if extent is not None else c.max_len)
-    if not W <= E <= c.max_len:
+    if extent is not None and not W <= int(extent) <= c.max_len:
         raise ServeError(
-            f"chunk extent {E} outside [window={W}, {c.max_len}]")
+            f"chunk extent {extent} outside [window={W}, {c.max_len}]")
 
     def chunk_prefill(params, k_cache, v_cache, tokens, offsets, nvalid):
         # tokens (S, W) int32 chunk slice; offsets (S,) page position of
@@ -371,7 +365,7 @@ def _make_chunk_prefill(config, window=None, extent=None):
                 v = (h @ params["wv"][l]).reshape(S, W, c.heads, c.head_dim)
                 k_cache = _store_pos(k_cache, rows, l, wposs, k)
                 v_cache = _store_pos(v_cache, rows, l, wposs, v)
-                att = _paged_attn(k_cache, v_cache, q, offsets, l, extent=E)
+                att = _paged_attn(k_cache, v_cache, q, offsets, l)
                 x = x + att.reshape(S, W, c.embed) @ params["wo"][l]
             with jax.named_scope(f"layer{l}/mlp"):
                 h2 = _rmsnorm(x, params["ln2"][l])
@@ -807,23 +801,21 @@ class CachedDecoder:
         return fn
 
     def chunk_prefill_program(self, window, extent=None):
-        """The jitted CHUNK prefill program for a (window, extent) pair:
-        scatter one window-sized prompt slice at an arbitrary page
-        offset and emit logits at each lane's chunk tail (chunked
-        prefill of long prompts + prefix-cache suffix prefill,
-        serve/continuous.py). `extent` bounds the attention read — the
-        engine warms a ladder of extents per window and dispatches the
-        smallest one covering each wave."""
+        """The jitted CHUNK prefill program for a window: scatter one
+        window-sized prompt slice at an arbitrary page offset and emit
+        logits at each lane's chunk tail (chunked prefill of long
+        prompts + prefix-cache suffix prefill, serve/continuous.py).
+        `extent` is the protocol's read bound for a model whose chunk
+        reads its cache densely; the read here follows each lane's live
+        blocks (`_paged_attn`), so every extent is the one program."""
         import jax
-        key = (int(window),
-               int(extent if extent is not None else self.config.max_len))
+        key = int(window)
         fn = self._chunks.get(key)
         if fn is None:
             fn = _sanitize.maybe_wrap_donated(
-                jax.jit(_make_chunk_prefill(self.config, window=key[0],
-                                            extent=key[1]),
+                jax.jit(_make_chunk_prefill(self.config, window=key),
                         donate_argnums=(1, 2)),
-                (1, 2), f"chunk_prefill[w={key[0]},e={key[1]}]")
+                (1, 2), f"chunk_prefill[w={key}]")
             self._chunks[key] = fn
         return fn
 
@@ -1301,11 +1293,16 @@ class ContinuousEngine:
         self._prefill_prog = model.prefill_program(self.prefill_window)
         # the chunk programs exist whenever a prompt can outgrow the
         # window (chunked streaming) or a cache hit leaves a suffix to
-        # prefill at a nonzero page offset. They form an EXTENT LADDER
-        # (window, 2*window, ... max_len): attention cost follows how
-        # far a wave's furthest lane has actually streamed, not
-        # max_len — every rung is warmed, so picking one per wave is
-        # still zero-retrace
+        # prefill at a nonzero page offset. The engine asks the model
+        # for an EXTENT LADDER (window, 2*window, ... max_len) and picks
+        # per wave the smallest rung that covers the furthest lane.
+        # What a rung is, is the model's to say: where a chunk reads the
+        # cached positions densely in plain XLA (SparseMoEDecoder,
+        # DeltaMoEDecoder) each rung is a program whose read costs what
+        # its extent is long; where the read is the paged kernel over a
+        # lane's live blocks (CachedDecoder, HybridDecoder) the extent
+        # bounds nothing and every rung is the ONE program. Each distinct
+        # program is warmed once, so picking a rung is zero-retrace
         self._chunk_progs = None
         self._chunk_extents = ()
         if (self.prefill_window < model.config.max_len
@@ -1505,18 +1502,18 @@ class ContinuousEngine:
                          *out[n:])
         if self._chunk_progs is not None:
             # all-idle chunk wave (every lane scatters into garbage)
-            # through EVERY extent rung, so wave-time extent selection
-            # never compiles; the first-token sampler and the join at
-            # the (C, vocab) shape the chunk path samples from too
+            # through every rung's program, so wave-time extent
+            # selection never compiles; the first-token sampler and the
+            # join at the (C, vocab) shape the chunk path samples from too
             C = P if self._chunk_compact else S
             idle_c = [jnp.zeros((C, self.prefill_window), dtype=jnp.int32),
                       jnp.zeros((C,), dtype=jnp.int32),
                       jnp.zeros((C,), dtype=jnp.int32)]
             if self._chunk_compact:
                 idle_c.append(jnp.full((C,), g, dtype=jnp.int32))
-            # (a model whose chunk ignores the extent hands back ONE
-            # program for every rung: it is called once, under its
-            # first rung's name)
+            # (a model whose chunk read the extent does not bound hands
+            # back ONE program for every rung: it is called once, under
+            # its first rung's name)
             rung_of = {}
             for x, prog in self._chunk_progs.items():
                 rung_of.setdefault(prog, x)
@@ -1772,7 +1769,9 @@ class ContinuousEngine:
         shapes warm-up called it with (`{name: jax.stages.Lowered}`):
         `prefill`, `decode`, `sample_first`, `join_lanes`,
         `advance_lanes`, and where configured `chunk_prefill[<extent>]` a
-        rung, `copy`, and the sampler and join at the chunk path's shape.
+        distinct chunk program (one a rung, or one in all under its first
+        rung's name where the model's chunk is one program whatever the
+        extent), `copy`, and the sampler and join at the chunk path's shape.
         Abstract values only — no buffers touched, no extra compile in
         steady state: the lowering hits the same jit cache entry the
         engine replays. An engine that was never started describes its

@@ -63,3 +63,19 @@ def buffers_of_at_least(compiled, elements):
                             for leaf in leaves if leaf is not None)):
                 found.append(ins.name)
     return found
+
+
+def slab_slices(lowered, slab_shape):
+    """Result shapes of the `slice`s in a `jax.stages.Lowered` that keep
+    every row and every layer of a cache slab of `slab_shape` (rows,
+    layers, positions, ...) and cut it elsewhere: a bound on the positions
+    a read may see, written as a slice of the whole slab, is a copy of
+    the slab wherever the read wants its operand whole (the classic
+    decoder's chunk program made 48 of them, 1.9 GB each at the
+    benchmark cell's shapes). A read of ONE layer's rows (`slab[:S, l]`,
+    the jnp fallback's) is not one of them."""
+    import re
+    lead = "x".join(str(d) for d in slab_shape[:2]) + "x"
+    return [shape for shape in re.findall(
+        r"stablehlo\.slice [^\n]*-> tensor<((?:\d+x)+)\w+>",
+        lowered.as_text()) if shape.startswith(lead)]

@@ -302,6 +302,53 @@ def test_prefix_copy_program_holds_pieces_not_rows(chip, form, kv_dtype):
         assert big and temporaries > 2 * row
 
 
+def test_classic_chunk_program_copies_no_slab_below_the_full_extent(
+        chip, monkeypatch):
+    """`chipbench/configs/cgpt13b_serve.json`: the chunk program as the
+    engine asks for it BELOW the full extent (window 128, extent 1024, 18
+    pool lanes over 19 rows of 24 x 2048 x 16 x 128). The paged kernel's
+    grid already follows each lane's live blocks, so the extent must not
+    reach the program: a slice of the slab to bound the read was a copy
+    of it, once a layer for K and once for V (48 of 1.9 GB, 3.88 GB of
+    temporaries, where the full extent stated 25 MB)."""
+    import json
+    import re
+    import jax
+    from incubator_mxnet_tpu import serve
+    from incubator_mxnet_tpu.ops import fused
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "chipbench/configs/cgpt13b_serve.json")) as f:
+        cfg = json.load(f)
+    m, e = cfg["model"], cfg["engine"]
+    # the platform this process sees is the CPU: take the chip's branch
+    monkeypatch.setattr(fused, "_on_tpu", lambda: True)
+    dc = serve.DecoderConfig(vocab=m["vocab"], embed=m["embed"],
+                             layers=m["layers"], heads=m["heads"],
+                             head_dim=m["head_dim"],
+                             mlp_hidden=m["mlp_hidden"], max_len=m["max_len"],
+                             dtype=m["dtype"])
+    params = jax.tree_util.tree_map(
+        lambda a: chip(a.shape, a.dtype),
+        jax.eval_shape(lambda: serve.init_decoder_params(dc)))
+    model = serve.CachedDecoder(dc, params=params)
+    rows = e["max_slots"] + e["prefix_cache_slots"]
+    slab = chip((rows + 1, m["layers"], m["max_len"], m["heads"],
+                 m["head_dim"]), e["kv_dtype"])
+    W = e["prefill_window"]
+    prog = model.chunk_prefill_program(W, extent=1024)
+    assert prog is model.chunk_prefill_program(W, extent=m["max_len"])
+    compiled = prog.lower(params, slab, slab, chip((rows, W), "int32"),
+                          chip((rows,), "int32"),
+                          chip((rows,), "int32")).compile()
+    text = compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+    assert text.count("tpu_custom_call") == m["layers"]
+    slab_sized = re.findall(
+        rf"^\s*(?:ROOT )?(\S+) = bf16\[{rows + 1},{m['layers']},\S* "
+        r"(slice|copy)\(", text, re.M)
+    assert not slab_sized, slab_sized[:4]
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill",
                                      "chunk_prefill@16384"])
 def test_sparse_moe_programs_fit_the_chip_at_glm52_widths(chip, program):

@@ -20,7 +20,9 @@ Contracts under test (ISSUE 14 acceptance):
 ISSUE 19 additions (shared-prefix KV cache + chunked prefill; cache
 bookkeeping unit tests live in tests/test_prefix_cache.py):
   * prompts longer than `prefill_window` stream through window-sized
-    chunks (extent ladder), token-exact and zero-retrace
+    chunks (the engine's extent ladder: one program a rung for a model
+    whose chunk reads its cache densely, ONE program for `CachedDecoder`,
+    whose read follows the live blocks), token-exact and zero-retrace
   * a prefix-cache hit copies cached KV and prefills ONLY the suffix:
     billing, EDF post-cache-cost ranking, and poison-fill isolation of
     the pinned cache rows all hold; hit / int8-hit outputs match the
@@ -36,7 +38,7 @@ import time
 import numpy as np
 import pytest
 
-from hlo_branches import buffers_of_at_least
+from hlo_branches import buffers_of_at_least, slab_slices
 
 from incubator_mxnet_tpu import profiler, serve
 from incubator_mxnet_tpu.serve.kv_pool import KVPOOL_STATS
@@ -237,6 +239,58 @@ def test_long_prompt_streams_in_window_sized_chunks(any_decoder):
         err_msg="chunked prefill diverged from the reference")
     np.testing.assert_array_equal(
         short, ref.reference_generate([1, 2, 3], 4, window=16))
+
+
+KV_POOLS = pytest.mark.parametrize("kv", [{}, {"kv_dtype": "int8"}],
+                                   ids=["float32", "int8"])
+
+
+@KV_POOLS
+def test_classic_chunk_is_one_program_whatever_the_extent(decoder, kv):
+    """The engine asks `CachedDecoder` for a rung an extent (16, 32, 48
+    here) and is handed ONE program: warmed once, listed once, and
+    token-exact on a prompt whose chunks end at 32 and 45 (the two upper
+    rungs) and on a prefix hit's suffix at offset 8 (the first rung,
+    which exists only with a prefix cache), with nothing retraced."""
+    model, ref = decoder
+    prompt = list(range(1, 46))               # 45 tokens = 3 chunks @ 16
+    short = list(range(50, 59))               # 9 tokens: publishes 8
+    suffix = short[:8] + [40, 41]             # 1 cached block of 8 + 2
+    with serve.ContinuousEngine(model, max_slots=2, prefill_window=16,
+                                prefix_block=8, prefix_cache_slots=2,
+                                **kv) as eng:
+        assert eng._chunk_extents == (16, 32, 48)
+        assert len(set(eng._chunk_progs.values())) == 1
+        cold = eng.generate(prompt, 3, timeout=120)
+        eng.generate(short, 3, timeout=120)
+        hot = eng.generate(suffix, 3, timeout=120)
+        assert eng.prefix_hit_count() == 1
+        assert eng.assert_no_retraces() == 0
+        assert [n for n in eng.lowered_programs()
+                if n.startswith("chunk_prefill")] == ["chunk_prefill[16]"]
+    np.testing.assert_array_equal(
+        cold, ref.reference_generate(prompt, 3, window=16, **kv))
+    np.testing.assert_array_equal(
+        hot, ref.reference_generate(suffix, 3, window=16,
+                                    cached_prefix_len=8, **kv))
+
+
+@KV_POOLS
+def test_classic_chunk_program_slices_no_slab(decoder, kv):
+    """The extent never reaches the traced function: the lowered chunk
+    program holds no slice of a whole slab (nor of an int8 pool's
+    scales), which the rung below the full extent was made of."""
+    import jax
+    model, _ = decoder
+    eng = serve.ContinuousEngine(model, max_slots=2, prefill_window=16,
+                                 prefix_block=8, prefix_cache_slots=2, **kv)
+    shape = eng.pool.shape
+    assert slab_slices(eng.lowered_programs()["chunk_prefill[16]"],
+                       shape) == []
+    # the helper does see one: the rung the program used to be
+    bounded = jax.jit(lambda slab: slab[:, :, :16].sum()).lower(
+        jax.ShapeDtypeStruct(shape, "float32"))
+    assert len(slab_slices(bounded, shape)) == 1
 
 
 # ---------------------------------------------------------------------------

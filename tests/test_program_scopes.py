@@ -140,9 +140,9 @@ ENGINE_PROGRAMS = {
     "prefill": "jit_prefill", "decode": "jit_decode",
     "sample_first": "jit_sample_tokens", "join_lanes": "jit_join_lanes",
     "advance_lanes": "jit_advance_lanes",
+    # one program whatever the extent: the classic chunk's read follows
+    # the live blocks, so the engine's three rungs are the one jit
     "chunk_prefill[16]": "jit_chunk_prefill",
-    "chunk_prefill[32]": "jit_chunk_prefill",
-    "chunk_prefill[48]": "jit_chunk_prefill",
     "sample_first[chunk]": "jit_sample_tokens",
     "join_lanes[chunk]": "jit_join_lanes", "copy": "jit__copy_slot_rows"}
 
@@ -210,7 +210,11 @@ def test_program_scopes_answers_after_the_engine_is_closed_and_gone():
 
 
 def test_rungs_of_one_name_share_a_table_without_their_disagreements():
-    eng = toy_engine().start()
+    """A model whose chunk reads its cache densely is a program a rung
+    (the sparse-expert decoder: extents 32 and 48 over a window of 16)."""
+    eng = serve.ContinuousEngine(
+        tiny_model("sparse_moe"), max_slots=2, prefill_window=16,
+        prefix_cache_slots=0, draft_tokens=0, decode_steps=2).start()
     try:
         eng.generate([1, 2, 3], 4)
         rungs = [p for n, p in eng._programs.items()
@@ -219,7 +223,7 @@ def test_rungs_of_one_name_share_a_table_without_their_disagreements():
         eng.close()
     merged = profiler.program_scopes(r"^jit_chunk_prefill\(")[
         "jit_chunk_prefill"]
-    assert len(rungs) == 3 and all(p.table for p in rungs)
+    assert len(rungs) == 2 and all(p.table for p in rungs)
     for name, where in merged.items():
         assert all(p.table.get(name, where) == where for p in rungs)
     dropped = set().union(*(p.table for p in rungs)) - set(merged)
