@@ -413,6 +413,63 @@ def test_delta_moe_decode_fits_the_chip_at_ling3f_widths(chip, monkeypatch):
     assert compiled.as_text().count("tpu_custom_call") >= 1
 
 
+@pytest.mark.parametrize("program", ["micro", "prefill", "chunk_prefill"])
+def test_looped_programs_hold_the_stack_once_and_copy_no_leaf(
+        chip, monkeypatch, program):
+    """`chipbench/configs/ouro26b_serve.json`: the decode micro-step (8
+    lanes), the dense prefill and the chunk program (1 lane of 256) at the
+    cell's widths, window, lanes and `max_len`, over a 9-row cache of K and
+    V leaves of (4 passes, 512, 2048), with the depth cut to 2 layers (all
+    48 take a minute a program: `chipbench/tests/compile_v5e_ouro.py`). The
+    pass is a loop in the program, so each holds ONE paged kernel a layer
+    (the dense prefill none) whatever `ut_steps` is; the pass index reaches
+    the leaf as a row number, so no `slice` or `copy` gives a result as
+    large as a leaf (PR 36's lesson), no weight leaf is laid out anew
+    (rotary on the flat axis: with the heads split first W_q and W_k were
+    copied whole), nothing fell back, and the cache is updated in place.
+    The micro-step stands for the decode program without the scan and the
+    shared sampler, whose sort over 49,152 logits is 23 s of compile and
+    no part of this model."""
+    import json
+    import re
+    import sys
+    import jax
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench import weights_ouro
+    from chipbench.tests import compile_v5e_ouro
+    from incubator_mxnet_tpu.models import looped_decoder as ld
+    from incubator_mxnet_tpu.ops import fused
+    # the platform this process sees is the CPU: take the chip's branch
+    monkeypatch.setattr(fused, "_on_tpu", lambda: True)
+    with open(os.path.join(root, "chipbench/configs/ouro26b_serve.json")) as f:
+        cfg = json.load(f)
+    cfg["model"] = dict(cfg["model"], layers=2)
+    programs = compile_v5e_ouro.serving_programs(cfg, chip)
+    if program == "micro":
+        _, (params, cache, tokens, lengths, *_) = programs["decode"]
+        fn = ld._make_micro(weights_ouro.looped_config(cfg["model"]))
+        args = [params, cache, tokens, lengths, chip(tokens.shape, "bool")]
+    else:
+        fn, args = programs[program]
+    before = fused.fused_stats()
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    after = fused.fused_stats()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    reads = 0 if program == "prefill" else 2
+    assert after["paged_attention_calls"] - before["paged_attention_calls"] \
+        == reads
+    assert text.count("tpu_custom_call") == reads
+    assert after["fallback_calls"] == before["fallback_calls"]
+    assert compile_v5e_ouro.leaf_sized(text, args[1]) == []
+    assert not re.findall(r" copy\(%params", text)
+    leaves = sum(math.prod(a.shape) * 2 for a in args[1].values())
+    assert leaves == 4 * 9 * 4 * 512 * 2048 * 2
+    assert mem.alias_size_in_bytes >= leaves          # no second cache
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20, mem.temp_size_in_bytes
+
+
 def test_hlo_parser_reads_a_tpu_compiled_module(chip):
     """`mx.inspect` on what the TPU's compiler prints: operands named
     without shapes, tiled layouts, a dot lowered to a convolution inside a

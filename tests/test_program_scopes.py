@@ -8,6 +8,7 @@ under a scope of the program's. Counts and names, never times."""
 import collections
 import gc
 import os
+import re
 import sys
 
 import numpy as np
@@ -25,6 +26,7 @@ from incubator_mxnet_tpu.inspect import hlo
 from incubator_mxnet_tpu.inspect import scope_of, scope_table
 from incubator_mxnet_tpu.models import delta_moe_decoder as dm
 from incubator_mxnet_tpu.models import hybrid_decoder as hd
+from incubator_mxnet_tpu.models import looped_decoder as ld
 from incubator_mxnet_tpu.models import sparse_moe_decoder as sm
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -438,6 +440,11 @@ def tiny_model(kind):
             max_len=48)
         return sm.SparseMoEDecoder(
             c, sm.init_sparse_moe_params(c, 1, sm.INIT_SCALES))
+    if kind == "looped":
+        c = ld.LoopedConfig(vocab=96, embed=64, layers=3, heads=4,
+                            head_dim=16, mlp_hidden=128, ut_steps=3,
+                            max_len=48)
+        return ld.LoopedDecoder(c, ld.init_looped_params(c, 1))
     c = dm.DeltaMoEConfig(
         vocab=96, embed=64, heads=4, kda_lower_bound=-20.0,
         mixer_types=("kda", "kda", "mla", "kda"),
@@ -449,7 +456,7 @@ def tiny_model(kind):
 
 
 @pytest.mark.parametrize("kind", ["cached", "hybrid", "sparse_moe",
-                                  "delta_moe", "train_step"])
+                                  "delta_moe", "looped", "train_step"])
 def test_nine_tenths_of_a_program_run_under_a_scope_of_its_own(kind):
     """A later PR cannot add nameless device work unnoticed: of the
     compiled decode program's (the fused step's) own instructions, those
@@ -465,7 +472,14 @@ def test_nine_tenths_of_a_program_run_under_a_scope_of_its_own(kind):
     scoped, total, bare = scoped_share(compiled)
     assert total >= 30, total
     assert scoped >= 0.9 * total, (scoped, total, bare.most_common(12))
-    if kind != "train_step":
+    if kind == "looped":
+        # the pass is a loop inside the scan's body: what is left is the
+        # machinery of the two loops, the program's own code never
+        assert all(re.search(r"/while(/body|/cond)?(/[\w\-]+)?$", name)
+                   for name in bare), bare
+        paths = {p for p, _ in scope_table(compiled).values()}
+        assert "head/loop_norm" in paths and "layer2/attn" in paths
+    elif kind != "train_step":
         # nothing of the program's own code: what is left is the scan's
         assert all("/while" in name and "closed_call/" not in name
                    for name in bare), bare
