@@ -667,12 +667,7 @@ class HybridDecoder:
     jitted programs over a pool built from `cache_spec()`. The engine's
     model protocol: `config` (with `max_len`), `params`, `new_pool`,
     `prefill_program`, `chunk_prefill_program`, `decode_program`,
-    `compile_cache_size`, `reference_generate`; `chunk_rows_as_data` tells
-    the engine that the chunk program's lanes are prefill lanes whose pool
-    rows ride as a fourth array (at pool width a chunk of this model would
-    pay the whole model for every idle slot)."""
-
-    chunk_rows_as_data = True
+    `compile_cache_size`, `reference_generate`."""
 
     def __init__(self, config, params=None, seed=0):
         from ..deploy import maybe_enable_compile_cache

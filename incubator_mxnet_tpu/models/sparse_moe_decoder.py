@@ -836,12 +836,10 @@ class SparseMoEDecoder:
     built from `cache_spec()`. The engine's model protocol: `config` (with
     `max_len`), `params`, `cache_spec`, `new_pool`, `prefill_program`,
     `chunk_prefill_program`, `decode_program`, `compile_cache_size`,
-    `reference_generate`; `chunk_rows_as_data` (the chunk program's lanes
-    are prefill lanes whose pool rows ride as a fourth array) and
-    `counters` (every program returns, last, {name: int32 vector} with
-    these fields, which the engine sums into `stats()[name]`)."""
+    `reference_generate`; `counters` (every program returns, last, {name:
+    int32 vector} with these fields, which the engine sums into
+    `stats()[name]`)."""
 
-    chunk_rows_as_data = True
     counters = COUNTERS
     # what a decoder of another block brings (`models.delta_moe_decoder`):
     # its initializer, its chunk and micro-step builders, `counters` and

@@ -285,7 +285,8 @@ def paged_attention_ref(q, k_slab, v_slab, lengths, layer,
 
     `q`: (S, C, H, D) — C chunk queries per lane at positions
     `lengths[s] + j`. `k_slab`/`v_slab`: (rows, layers, T, H, D) with
-    rows > S (lane s reads row s). `k_scale`/`v_scale`: optional
+    rows > S (lane s reads row s), or any rows where `rows` is given
+    (lane s reads row `rows[s]`). `k_scale`/`v_scale`: optional
     per-position f32 dequant scales (rows, layers, T) for int8 slabs.
 
     `layer=None` reads a cache LEAF instead: `k_slab`/`v_slab` are
@@ -304,14 +305,13 @@ def paged_attention_ref(q, k_slab, v_slab, lengths, layer,
         return _leaf_attention_ref(q, k_slab, v_slab, lengths, rows,
                                    window, scale).astype(out_dtype or q.dtype)
     t = k_slab.shape[2]
-    kk = k_slab[:s_lanes, layer]
-    vv = v_slab[:s_lanes, layer]
+    at = slice(None, s_lanes) if rows is None else rows
+    kk = k_slab[at, layer]
+    vv = v_slab[at, layer]
     if k_scale is not None:
-        kk = kk.astype(jnp.float32) * k_scale[:s_lanes, layer][..., None,
-                                                               None]
+        kk = kk.astype(jnp.float32) * k_scale[at, layer][..., None, None]
     if v_scale is not None:
-        vv = vv.astype(jnp.float32) * v_scale[:s_lanes, layer][..., None,
-                                                               None]
+        vv = vv.astype(jnp.float32) * v_scale[at, layer][..., None, None]
     scores = jnp.einsum("schd,sthd->shct", q, kk) * scale
     pos = jnp.arange(t)
     mask = pos[None, None, :] <= (lengths[:, None, None]
@@ -373,8 +373,9 @@ def paged_attention(q, k_slab, v_slab, lengths, layer,
     composition otherwise; the choice is static per trace. Honors the
     MXNET_USE_FUSION kill switch (falls back, never fails).
 
-    `layer=None` reads a named cache leaf (rows, T, Hkv * D) with
-    grouped heads, `rows` as data and an optional ring `window`
+    `rows` (S,) names the slab row each lane reads, in either mode
+    (default: lane s reads row s). `layer=None` reads a named cache leaf
+    (rows, T, Hkv * D) with grouped heads and an optional ring `window`
     (`paged_attention_ref` has the contract). The leaf that grows with
     the request runs in the kernel, on the live-block grid; the ring
     read (at most `window` positions a lane) is the composition."""
@@ -383,13 +384,14 @@ def paged_attention(q, k_slab, v_slab, lengths, layer,
     if layer is None:
         return _paged_leaf(q, k_slab, v_slab, lengths, rows, window, scale,
                            out_dtype or q.dtype, interpret)
-    if rows is not None or window is not None or out_dtype is not None:
-        raise ValueError("rows=, window= and out_dtype= read a cache leaf "
+    if window is not None or out_dtype is not None:
+        raise ValueError("window= and out_dtype= read a cache leaf "
                          "(layer=None)")
     if (_on_tpu() or interpret) and _env_use_fusion():
         out = _pk.paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
                                       k_scale=k_scale, v_scale=v_scale,
-                                      interpret=interpret, scale=scale)
+                                      interpret=interpret, scale=scale,
+                                      rows=rows)
         if out is not None:
             _STATS["pallas_calls"] += 1
             body = _pk.paged_body(q, k_slab, k_scale)
@@ -399,7 +401,8 @@ def paged_attention(q, k_slab, v_slab, lengths, layer,
                f"q {tuple(q.shape)} over slab {tuple(k_slab.shape)} "
                f"{k_slab.dtype} does not tile, or no TPU / fusion off")
     return paged_attention_ref(q, k_slab, v_slab, lengths, layer,
-                               k_scale=k_scale, v_scale=v_scale, scale=scale)
+                               k_scale=k_scale, v_scale=v_scale, rows=rows,
+                               scale=scale)
 
 
 def _paged_leaf(q, k_leaf, v_leaf, lengths, rows, window, scale, out_dtype,

@@ -484,10 +484,13 @@ def _live_steps(lengths, c, bt, n_blocks):
 
 
 def _paged_slab_call(q, k_slab, v_slab, lengths, layer, k_scale, v_scale,
-                     scale):
+                     scale, rows=None):
     """The pieces of `paged_attention_fwd`'s one `pallas_call` over the
     K/V slab pair (kernel, grid spec, out shape, arguments), or None when
-    the shape does not tile."""
+    the shape does not tile. Lane s reads slab row s, or `rows[s]` where
+    `rows` is given: a fourth scalar-prefetch list that the K/V and scale
+    maps index by lane, as `_paged_leaf_call`'s do; without it the call is
+    the lane-indexed one, operand for operand."""
     import jax
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -495,7 +498,8 @@ def _paged_slab_call(q, k_slab, v_slab, lengths, layer, k_scale, v_scale,
 
     s_lanes, c, h, d = q.shape
     t = k_slab.shape[2]
-    if k_slab.shape[0] <= s_lanes or k_slab.shape[1] <= layer:
+    if (rows is None and k_slab.shape[0] <= s_lanes) \
+            or k_slab.shape[1] <= layer:
         return None
     quantized = k_scale is not None
     n_layers = k_slab.shape[1]
@@ -509,21 +513,29 @@ def _paged_slab_call(q, k_slab, v_slab, lengths, layer, k_scale, v_scale,
     scale = (1.0 / float(d) ** 0.5) if scale is None else float(scale)
     ends, lane_of, blk_of = _live_steps(lengths, c, bt, n_blocks)
 
-    def qidx(i, lens_ref, lane_ref, blk_ref):
-        return (lane_ref[i], 0, 0, 0)
+    # scalar prefetch: lengths, [rows,] the lane and the block of each step
+    prefetch = [lengths, lane_of, blk_of] if rows is None \
+        else [lengths, rows, lane_of, blk_of]
 
-    def kidx(i, lens_ref, lane_ref, blk_ref):
-        return (lane_ref[i], layer, blk_ref[i], 0, 0)
+    def row_of(i, refs):
+        lane = refs[-2][i]
+        return lane if rows is None else refs[1][lane]
 
-    def sidx(i, lens_ref, lane_ref, blk_ref):
-        return (lane_ref[i], 0, blk_ref[i])
+    def qidx(i, *refs):
+        return (refs[-2][i], 0, 0, 0)
+
+    def kidx(i, *refs):
+        return (row_of(i, refs), layer, refs[-1][i], 0, 0)
+
+    def sidx(i, *refs):
+        return (row_of(i, refs), 0, refs[-1][i])
 
     in_specs = [
         pl.BlockSpec((1, c, h, d), qidx),
         pl.BlockSpec((1, 1, bt, h, d), kidx),
         pl.BlockSpec((1, 1, bt, h, d), kidx),
     ]
-    args = [lengths, lane_of, blk_of, q, k_slab, v_slab]
+    args = prefetch + [q, k_slab, v_slab]
     if quantized:
         in_specs.append(pl.BlockSpec((1, n_layers, bt), sidx))
         in_specs.append(pl.BlockSpec((1, n_layers, bt), sidx))
@@ -531,7 +543,7 @@ def _paged_slab_call(q, k_slab, v_slab, lengths, layer, k_scale, v_scale,
     acc_shape = (c * h, d) if body == "flat" else (h, c, d)
     ml_shape = (c * h, 1) if body == "flat" else (h, c)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetch),
         grid=(ends[-1],),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, c, h, d), qidx),
@@ -544,8 +556,15 @@ def _paged_slab_call(q, k_slab, v_slab, lengths, layer, k_scale, v_scale,
     kernel = functools.partial(
         _paged_attn_kernel, bt=bt, n_blocks=n_blocks,
         chunk=c, scale=scale, layer=layer, quantized=quantized, body=body)
+    if rows is not None:
+        # the rows list is the index maps' alone: the body never reads it
+        kernel = functools.partial(_skip_rows_ref, kernel)
     return (kernel, grid_spec,
             jax.ShapeDtypeStruct((s_lanes, c, h, d), q.dtype), tuple(args))
+
+
+def _skip_rows_ref(kernel, lens_ref, rows_ref, *refs):
+    return kernel(lens_ref, *refs)
 
 
 def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
@@ -555,9 +574,10 @@ def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
     lane at positions `lengths[s] + j` (C == 1 plain decode, C == k+1
     speculative verify, C == the window in chunk prefill).
     `k_slab`/`v_slab`: the whole KV pool slab (rows, layers, T, H, D);
-    lane s reads row s of layer `layer`, positions clamped to
-    `[0, lengths[s] + j]`. `k_scale`/`v_scale`: per-position f32 dequant
-    scales (rows, layers, T) for int8 slabs.
+    lane s reads row s of layer `layer` (row `rows[s]` where `rows` is
+    given: lanes that are not pool rows, as chunk prefill's), positions
+    clamped to `[0, lengths[s] + j]`. `k_scale`/`v_scale`: per-position
+    f32 dequant scales (rows, layers, T) for int8 slabs.
 
     The grid has one step per LIVE (lane, token-block) pair and no other:
     its length is a run-time value (the sum of every lane's
@@ -578,7 +598,7 @@ def paged_attention_fwd(q, k_slab, v_slab, lengths, layer,
                                 out_dtype, interpret)
     else:
         call = _paged_slab_call(q, k_slab, v_slab, lengths, layer, k_scale,
-                                v_scale, scale)
+                                v_scale, scale, rows)
     if call is None:
         return None
     kernel, grid_spec, out_shape, args = call

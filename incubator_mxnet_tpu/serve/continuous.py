@@ -227,11 +227,12 @@ def _store_pos(cache, rows, l, wpos, val):
             scales.at[rows, l, wpos].set(s))
 
 
-def _paged_attn(k_cache, v_cache, q, lengths, l):
+def _paged_attn(k_cache, v_cache, q, lengths, l, rows=None):
     """Decode-side attention read over the slot slab via
     `ops.fused.paged_attention`: Pallas block-sparse kernel on TPU (or
     interpret-mode CI), identical masked-einsum jnp fallback elsewhere.
-    q is (S, C, H, D); chunk offset j reads positions [0, lengths+j].
+    q is (S, C, H, D); chunk offset j reads positions [0, lengths+j] of
+    row s, or of row `rows[s]` where the lanes are not pool rows.
 
     The slab goes to the kernel WHOLE, as the donated buffer it is: the
     kernel's grid is one step per live (lane, block) pair, so what a
@@ -242,7 +243,8 @@ def _paged_attn(k_cache, v_cache, q, lengths, l):
     k_slab, k_scale = _kv_split(k_cache)
     v_slab, v_scale = _kv_split(v_cache)
     return _fused.paged_attention(q, k_slab, v_slab, lengths, l,
-                                  k_scale=k_scale, v_scale=v_scale)
+                                  k_scale=k_scale, v_scale=v_scale,
+                                  rows=rows)
 
 
 def _make_prefill(config, window=None):
@@ -317,16 +319,18 @@ def _make_chunk_prefill(config, window=None, extent=None):
     longer than `prefill_window` stream in across waves, and how a
     prefix-cache hit prefills only its suffix.
 
-    Unlike `_make_prefill`, lanes here are POOL ROWS (lane s writes row
-    s, exactly like decode): ONE fixed-shape dispatch advances EVERY
-    slot with pending chunk work — a long cold prompt mid-stream and a
-    cache hit's suffix alike — and a lane with `nvalid == 0` scatters
-    into the garbage row. Queries at chunk offset j attend over slab
-    positions [0, offsets + j]; positions below `offsets` must already
-    hold the prefix KV (earlier chunks, or a prefix-cache row copy).
-    Logits come from each lane's LAST valid chunk position — only
-    meaningful for a lane whose chunk ends at its prompt tail, which is
-    exactly when the engine samples the first token from them.
+    Lanes are PREFILL lanes, as in `_make_prefill`: lane s writes and
+    reads pool row `slot_rows[s]`, the engine's P lanes carry its oldest
+    chunkers (a mid-stream cold prompt and a cache hit's suffix alike),
+    and a lane with `nvalid == 0` scatters into the garbage row (the
+    slab's last). Without `slot_rows` lane s is pool row s and the
+    garbage row is S, the form `reference_generate`'s one-slot pool
+    uses. Queries at chunk offset j attend over slab positions
+    [0, offsets + j]; positions below `offsets` must already hold the
+    prefix KV (earlier chunks, or a prefix-cache row copy). Logits come
+    from each lane's LAST valid chunk position — only meaningful for a
+    lane whose chunk ends at its prompt tail, which is exactly when the
+    engine samples the first token from them.
 
     `extent`, the bound a wave's furthest lane satisfies (offset +
     nvalid <= extent), is accepted, range-checked and NOT part of the
@@ -346,15 +350,21 @@ def _make_chunk_prefill(config, window=None, extent=None):
         raise ServeError(
             f"chunk extent {extent} outside [window={W}, {c.max_len}]")
 
-    def chunk_prefill(params, k_cache, v_cache, tokens, offsets, nvalid):
+    def chunk_prefill(params, k_cache, v_cache, tokens, offsets, nvalid,
+                      slot_rows=None):
         # tokens (S, W) int32 chunk slice; offsets (S,) page position of
-        # tokens[:, 0]; nvalid (S,) valid token count (0 = idle lane)
+        # tokens[:, 0]; nvalid (S,) valid token count (0 = idle lane);
+        # slot_rows (S,) the pool row of each lane
         S = tokens.shape[0]
         T = c.max_len
         j = jnp.arange(W)
         wposs = jnp.clip(offsets[:, None] + j[None, :], 0, T - 1)
         valid = j[None, :] < nvalid[:, None]                    # (S, W)
-        rows = jnp.where(valid, jnp.arange(S)[:, None], S)   # garbage=S
+        if slot_rows is None:
+            rows = jnp.where(valid, jnp.arange(S)[:, None], S)   # garbage=S
+        else:
+            garbage = _kv_split(k_cache)[0].shape[0] - 1
+            rows = jnp.where(valid, slot_rows[:, None], garbage)
         with jax.named_scope("embed"):
             x = params["emb"][tokens] + params["pos"][wposs]
         for l in range(c.layers):
@@ -365,7 +375,8 @@ def _make_chunk_prefill(config, window=None, extent=None):
                 v = (h @ params["wv"][l]).reshape(S, W, c.heads, c.head_dim)
                 k_cache = _store_pos(k_cache, rows, l, wposs, k)
                 v_cache = _store_pos(v_cache, rows, l, wposs, v)
-                att = _paged_attn(k_cache, v_cache, q, offsets, l)
+                att = _paged_attn(k_cache, v_cache, q, offsets, l,
+                                  rows=slot_rows)
                 x = x + att.reshape(S, W, c.embed) @ params["wo"][l]
             with jax.named_scope(f"layer{l}/mlp"):
                 h2 = _rmsnorm(x, params["ln2"][l])
@@ -768,9 +779,6 @@ class CachedDecoder:
             keys = jnp.zeros((n, 2), dtype=jnp.uint32)
         return temps, top_ks, top_ps, keys
 
-    # lane s of the chunk program is pool row s (see `_make_chunk_prefill`)
-    chunk_rows_as_data = False
-
     def new_pool(self, max_slots=None, dtype=None):
         c = self.config
         return KVCachePool(max_slots, layers=c.layers, max_len=c.max_len,
@@ -804,10 +812,12 @@ class CachedDecoder:
         """The jitted CHUNK prefill program for a window: scatter one
         window-sized prompt slice at an arbitrary page offset and emit
         logits at each lane's chunk tail (chunked prefill of long
-        prompts + prefix-cache suffix prefill, serve/continuous.py).
-        `extent` is the protocol's read bound for a model whose chunk
-        reads its cache densely; the read here follows each lane's live
-        blocks (`_paged_attn`), so every extent is the one program."""
+        prompts + prefix-cache suffix prefill, serve/continuous.py); the
+        engine hands each lane's pool row as a fourth array
+        (`_make_chunk_prefill`). `extent` is the protocol's read bound
+        for a model whose chunk reads its cache densely; the read here
+        follows each lane's live blocks (`_paged_attn`), so every extent
+        is the one program."""
         import jax
         key = int(window)
         fn = self._chunks.get(key)
@@ -1131,13 +1141,13 @@ class ContinuousEngine:
                        (bounds how long admission may stall in-flight
                        decode; >= 1 request always admitted when a slot
                        is free)
-      prefill_lanes    FIXED lane count of the prefill program (default
-                       min(max_slots, 8)): its cost is paid in full per
-                       admission wave regardless of how many lanes carry
-                       real requests, so it is sized for the admission
-                       RATE, not the pool — a max_slots-wide prefill
-                       would bill a 1-request wave the whole pool's
-                       prefill FLOPs
+      prefill_lanes    FIXED lane count of the prefill and chunk
+                       programs (default min(max_slots, 8)): their cost
+                       is paid in full per wave regardless of how many
+                       lanes carry real requests, so it is sized for the
+                       admission RATE, not the pool — a max_slots-wide
+                       prefill would bill a 1-request wave the whole
+                       pool's prefill FLOPs
       max_queue        waiting-request bound (admission control; reuses
                        MXNET_SERVE_MAX_QUEUE; reject-newest)
       default_deadline_ms  queue deadline (MXNET_SERVE_DEADLINE_MS);
@@ -1323,9 +1333,6 @@ class ContinuousEngine:
                 for x in exts}
         self._copy_prog = (model.copy_program()
                            if self._cache is not None else None)
-        # the chunk program's lanes: pool rows (lane s writes row s), or
-        # prefill lanes whose rows ride as data (the model says which)
-        self._chunk_compact = bool(model.chunk_rows_as_data)
         self.prefill_budget = int(
             prefill_budget if prefill_budget is not None
             else get_env("MXNET_SERVE_PREFILL_BUDGET", 256, typ=int))
@@ -1373,7 +1380,8 @@ class ContinuousEngine:
             "active_sum", "waves_ahead", "sampled_tokens", "sampled_waves",
             "draft_accepted",
             "draft_rejected", "prefix_hits", "prefix_misses",
-            "prefix_cached_tokens", "copied_positions")}
+            "prefix_cached_tokens", "copied_positions", "chunk_lanes",
+            "chunk_deferred")}
         # per cache kind: the bytes the lanes of each decode wave held
         # live, summed over waves (beside `decode_iterations`)
         self._cache_live = {k: 0 for k in self.pool.kinds()}
@@ -1503,14 +1511,12 @@ class ContinuousEngine:
         if self._chunk_progs is not None:
             # all-idle chunk wave (every lane scatters into garbage)
             # through every rung's program, so wave-time extent
-            # selection never compiles; the first-token sampler and the
-            # join at the (C, vocab) shape the chunk path samples from too
-            C = P if self._chunk_compact else S
-            idle_c = [jnp.zeros((C, self.prefill_window), dtype=jnp.int32),
-                      jnp.zeros((C,), dtype=jnp.int32),
-                      jnp.zeros((C,), dtype=jnp.int32)]
-            if self._chunk_compact:
-                idle_c.append(jnp.full((C,), g, dtype=jnp.int32))
+            # selection never compiles; its lanes are the prefill lanes,
+            # so the first-token sampler and the join above are its own
+            idle_c = (jnp.zeros((P, self.prefill_window), dtype=jnp.int32),
+                      jnp.zeros((P,), dtype=jnp.int32),
+                      jnp.zeros((P,), dtype=jnp.int32),
+                      jnp.full((P,), g, dtype=jnp.int32))
             # (a model whose chunk read the extent does not bound hands
             # back ONE program for every rung: it is called once, under
             # its first rung's name)
@@ -1518,16 +1524,8 @@ class ContinuousEngine:
             for x, prog in self._chunk_progs.items():
                 rung_of.setdefault(prog, x)
             for prog, x in rung_of.items():
-                *cache, logits = call(f"chunk_prefill[{x}]", prog, params,
-                                      *cache, *idle_c)[:held]
-            first = call("sample_first[chunk]", sample_first, logits,
-                         jnp.zeros((C,), dtype=jnp.float32),
-                         jnp.zeros((C,), dtype=jnp.int32),
-                         jnp.ones((C,), dtype=jnp.float32),
-                         jnp.zeros((C, 2), dtype=jnp.uint32),
-                         jnp.zeros((C,), dtype=jnp.int32))
-            lanes = call("join_lanes[chunk]", self._join_prog, *lanes,
-                         first, nobody)
+                cache = call(f"chunk_prefill[{x}]", prog, params, *cache,
+                             *idle_c)[:n]
         if self._copy_prog is not None:
             # every lane at length 0: compiles, moves nothing
             idle_p = jnp.zeros((P,), dtype=jnp.int32)
@@ -2093,22 +2091,24 @@ class ContinuousEngine:
         """One prefill wave, dispatched and not read: slab-to-slab KV row
         copies for the admitted prefix-cache hits, the fixed-shape
         windowed program for lanes starting at page offset 0, then ONE
-        chunk dispatch advancing EVERY lane with pending suffix/chunk
-        work (admitted hits and long prompts mid-stream alike). A request
-        whose prompt ends here — the host knows that from its size —
-        JOINS the decode lanes on the device (`_join_lanes`: its first
-        token straight from `sample_first`'s output, its length and
-        budget from the host) and moves to `_running`; its first token
-        reaches the host with `rec` (`rec.firsts`). `prefill_tokens`
-        bills only tokens a program actually processed (suffix-only on a
-        hit).
+        chunk dispatch over the `prefill_lanes` lanes advancing the
+        oldest requests with pending suffix/chunk work (admitted hits and
+        long prompts mid-stream alike; `chunk_lanes` counts the lanes
+        that carried a chunk, `chunk_deferred` the requests beyond them,
+        which wait a wave). A request whose prompt ends here — the host
+        knows that from its size — JOINS the decode lanes on the device
+        (`_join_lanes`: its first token straight from `sample_first`'s
+        output, its length and budget from the host) and moves to
+        `_running`; its first token reaches the host with `rec`
+        (`rec.firsts`). `prefill_tokens` bills only tokens a program
+        actually processed (suffix-only on a hit).
 
         Runs inside the loop's `serve.prefill_batch` span `sp`; armed
         (`on`), each program's host side is split into `.pack` (NumPy
         arrays and their transfers) and `.dispatch` (the jit calls
         returning, the join among them)."""
         _fault.inject("serve.execute")
-        W = self.prefill_window
+        W, P = self.prefill_window, self.prefill_lanes
         g = self.pool.garbage_row
         hits = [r for r in admitted if r.cached_len > 0]
         cold = [r for r in admitted if r.cached_len == 0]
@@ -2128,7 +2128,6 @@ class ContinuousEngine:
         if cold:
             with (_span("serve.prefill_batch.pack", cat="serve") if on
                   else NO_SPAN):
-                P = self.prefill_lanes
                 toks = _np.zeros((P, W), dtype=_np.int32)
                 lens = _np.ones((P,), dtype=_np.int32)
                 rows = _np.full((P,), g, dtype=_np.int32)
@@ -2163,39 +2162,34 @@ class ContinuousEngine:
                 self.pool.swap_buffers(*cache)
                 self._join(first, ended, rec, jnp)
         # chunk wave: admitted hits prefill their suffix, long prompts
-        # mid-stream advance one window — ONE fixed-shape dispatch at
-        # pool width; lanes with no chunk work scatter into garbage
+        # mid-stream advance one window — ONE fixed-shape dispatch over
+        # the P prefill lanes, the oldest admissions first; a chunker
+        # beyond them waits a wave, a lane with no chunk work scatters
+        # into garbage
         with self._cv:
             pre = [self._prefilling[s] for s in sorted(self._prefilling)]
         coldset = set(id(r) for r in cold)
-        chunkers = [r for r in pre
-                    if id(r) not in coldset
-                    and r.prefill_pos < int(r.prompt.size)]
-        if self._chunk_compact:
-            # lanes are prefill lanes: the oldest admissions take them,
-            # the chunkers beyond them wait a wave
-            chunkers = sorted(chunkers, key=lambda r: r.t_admit)[
-                :self.prefill_lanes]
-            lane_of = {r.slot: i for i, r in enumerate(chunkers)}
-        else:
-            lane_of = {r.slot: r.slot for r in chunkers}
+        chunkers = sorted((r for r in pre
+                           if id(r) not in coldset
+                           and r.prefill_pos < int(r.prompt.size)),
+                          key=lambda r: r.t_admit)
+        if len(chunkers) > P:
+            self._count("chunk_deferred", len(chunkers) - P)
+            chunkers = chunkers[:P]
         if chunkers:
             with (_span("serve.prefill_batch.pack", cat="serve") if on
                   else NO_SPAN):
-                S = (self.prefill_lanes if self._chunk_compact
-                     else self.pool.max_slots)
-                ctoks = _np.zeros((S, W), dtype=_np.int32)
-                offs = _np.zeros((S,), dtype=_np.int32)
-                nval = _np.zeros((S,), dtype=_np.int32)
-                crows = _np.full((S,), g, dtype=_np.int32)
-                temps = _np.zeros((S,), dtype=_np.float32)
-                tks = _np.zeros((S,), dtype=_np.int32)
-                tps = _np.ones((S,), dtype=_np.float32)
-                keys = _np.zeros((S, 2), dtype=_np.uint32)
-                fold = _np.zeros((S,), dtype=_np.int32)
+                ctoks = _np.zeros((P, W), dtype=_np.int32)
+                offs = _np.zeros((P,), dtype=_np.int32)
+                nval = _np.zeros((P,), dtype=_np.int32)
+                crows = _np.full((P,), g, dtype=_np.int32)
+                temps = _np.zeros((P,), dtype=_np.float32)
+                tks = _np.zeros((P,), dtype=_np.int32)
+                tps = _np.ones((P,), dtype=_np.float32)
+                keys = _np.zeros((P, 2), dtype=_np.uint32)
+                fold = _np.zeros((P,), dtype=_np.int32)
                 ended = []
-                for req in chunkers:
-                    s = lane_of[req.slot]
+                for s, req in enumerate(chunkers):
                     crows[s] = req.slot
                     n = min(W, int(req.prompt.size) - req.prefill_pos)
                     ctoks[s, :n] = req.prompt[req.prefill_pos:
@@ -2215,10 +2209,8 @@ class ContinuousEngine:
                 # wave's attention read scales with streamed progress
                 need = int((offs + nval).max())
                 ext = next(x for x in self._chunk_extents if x >= need)
-                chunk_args = [jnp.asarray(ctoks), jnp.asarray(offs),
-                              jnp.asarray(nval)]
-                if self._chunk_compact:
-                    chunk_args.append(jnp.asarray(crows))
+                chunk_args = (jnp.asarray(ctoks), jnp.asarray(offs),
+                              jnp.asarray(nval), jnp.asarray(crows))
                 sample = (jnp.asarray(temps), jnp.asarray(tks),
                           jnp.asarray(tps), jnp.asarray(keys),
                           jnp.asarray(fold))
@@ -2230,6 +2222,7 @@ class ContinuousEngine:
                 first = _sample_first(logits, *sample)
                 self.pool.swap_buffers(*cache)
                 self._join(first, ended, rec, jnp)
+            self._count("chunk_lanes", len(chunkers))
         if admitted:
             self._count("admitted", len(admitted))
         if cold or chunkers:

@@ -52,8 +52,9 @@ KNOBS = {
         "kind": "pow2", "default": None, "choices": [None, 1, 2, 4, 8, 16],
         "env": "MXNET_SERVE_PREFILL_LANES", "phase": "serve_decode",
         "wire": "serve/continuous.py",
-        "help": "fixed lane count of the prefill program (None = derived "
-                "min(max_slots, 8)); sized to the admission rate"},
+        "help": "fixed lane count of the prefill and chunk programs "
+                "(None = derived min(max_slots, 8)); sized to the "
+                "admission rate"},
     "serve.max_slots": {
         "kind": "pow2", "default": 8, "choices": [4, 8, 16, 32],
         "env": "MXNET_SERVE_MAX_SLOTS", "phase": "serve_decode",

@@ -302,26 +302,28 @@ def test_prefix_copy_program_holds_pieces_not_rows(chip, form, kv_dtype):
         assert big and temporaries > 2 * row
 
 
-def test_classic_chunk_program_copies_no_slab_below_the_full_extent(
-        chip, monkeypatch):
-    """`chipbench/configs/cgpt13b_serve.json`: the chunk program as the
-    engine asks for it BELOW the full extent (window 128, extent 1024, 18
-    pool lanes over 19 rows of 24 x 2048 x 16 x 128). The paged kernel's
-    grid already follows each lane's live blocks, so the extent must not
-    reach the program: a slice of the slab to bound the read was a copy
-    of it, once a layer for K and once for V (48 of 1.9 GB, 3.88 GB of
-    temporaries, where the full extent stated 25 MB)."""
+@pytest.fixture(scope="module")
+def classic_chunk_temporaries():
+    """Temporaries of each form of the cgpt13b chunk program compiled so
+    far in this module, by form: the compact case holds itself under the
+    pool-width one."""
+    return {}
+
+
+def _compile_classic_chunk(chip, form):
+    """`chipbench/configs/cgpt13b_serve.json`'s chunk program (window 128)
+    compiled for a described v5e as the engine asks for it below the full
+    extent (1024): over the 18 pool rows as lanes (`pool-width`, the form
+    `reference_generate` and `chipbench/tests/compile_v5e.py` call), or
+    over the 8 prefill lanes with their rows as data (`compact`, the one
+    the engine runs). Returns (compiled, slab rows, layers)."""
     import json
-    import re
     import jax
     from incubator_mxnet_tpu import serve
-    from incubator_mxnet_tpu.ops import fused
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     with open(os.path.join(root, "chipbench/configs/cgpt13b_serve.json")) as f:
         cfg = json.load(f)
     m, e = cfg["model"], cfg["engine"]
-    # the platform this process sees is the CPU: take the chip's branch
-    monkeypatch.setattr(fused, "_on_tpu", lambda: True)
     dc = serve.DecoderConfig(vocab=m["vocab"], embed=m["embed"],
                              layers=m["layers"], heads=m["heads"],
                              head_dim=m["head_dim"],
@@ -337,16 +339,46 @@ def test_classic_chunk_program_copies_no_slab_below_the_full_extent(
     W = e["prefill_window"]
     prog = model.chunk_prefill_program(W, extent=1024)
     assert prog is model.chunk_prefill_program(W, extent=m["max_len"])
-    compiled = prog.lower(params, slab, slab, chip((rows, W), "int32"),
-                          chip((rows,), "int32"),
-                          chip((rows,), "int32")).compile()
+    lanes = rows if form == "pool-width" else e["prefill_lanes"]
+    ints = chip((lanes,), "int32")
+    args = [params, slab, slab, chip((lanes, W), "int32"), ints, ints]
+    if form == "compact":
+        args.append(ints)                    # slot_rows (P,)
+    return prog.lower(*args).compile(), rows + 1, m["layers"]
+
+
+@pytest.mark.parametrize("form", ["pool-width", "compact"])
+def test_classic_chunk_program_copies_no_slab_below_the_full_extent(
+        chip, monkeypatch, classic_chunk_temporaries, form):
+    """`chipbench/configs/cgpt13b_serve.json`: the chunk program as the
+    engine asks for it BELOW the full extent (window 128, extent 1024,
+    over 19 rows of 24 x 2048 x 16 x 128). The paged kernel's grid
+    already follows each lane's live blocks, so the extent must not
+    reach the program: a slice of the slab to bound the read was a copy
+    of it, once a layer for K and once for V (48 of 1.9 GB, 3.88 GB of
+    temporaries, where the full extent stated 25 MB). The compact form
+    (8 prefill lanes, `slot_rows` (8,)) holds the same 24 kernels and
+    states fewer temporaries than the pool-width one (18 lanes)."""
+    import re
+    from incubator_mxnet_tpu.ops import fused
+    # the platform this process sees is the CPU: take the chip's branch
+    monkeypatch.setattr(fused, "_on_tpu", lambda: True)
+    compiled, rows, layers = _compile_classic_chunk(chip, form)
     text = compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
-    assert text.count("tpu_custom_call") == m["layers"]
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    classic_chunk_temporaries[form] = temporaries
+    assert temporaries < 64 * 2 ** 20
+    assert text.count("tpu_custom_call") == layers
     slab_sized = re.findall(
-        rf"^\s*(?:ROOT )?(\S+) = bf16\[{rows + 1},{m['layers']},\S* "
+        rf"^\s*(?:ROOT )?(\S+) = bf16\[{rows},{layers},\S* "
         r"(slice|copy)\(", text, re.M)
     assert not slab_sized, slab_sized[:4]
+    if form == "compact":
+        if "pool-width" not in classic_chunk_temporaries:
+            wide, _, _ = _compile_classic_chunk(chip, "pool-width")
+            classic_chunk_temporaries["pool-width"] = \
+                wide.memory_analysis().temp_size_in_bytes
+        assert temporaries < classic_chunk_temporaries["pool-width"]
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill",

@@ -293,6 +293,50 @@ def test_classic_chunk_program_slices_no_slab(decoder, kv):
     assert len(slab_slices(bounded, shape)) == 1
 
 
+@pytest.mark.parametrize("route", ["composition", "interpret_kernel"])
+def test_classic_chunkers_beyond_the_prefill_lanes_wait_a_wave(route):
+    """The classic chunk program runs over the `prefill_lanes` prefill
+    lanes with their pool rows as data: three 40-token prompts on 4 slots
+    and 2 lanes (window 16) are three chunkers at once, the two oldest
+    take the lanes and the third waits a wave (`chunk_deferred`). Every
+    leaf is poisoned before the traffic, the garbage row included: an idle
+    lane writes and reads that row alone, and nothing of it or of a
+    previous tenant reaches a lane that carries a chunk. Token-exact to
+    the one-slot reference on the jnp read and on the kernel's (interpret
+    mode), nothing retraced, and `chunk_lanes` counts the 6 chunks (2 a
+    prompt past its windowed head)."""
+    from incubator_mxnet_tpu.ops import fused
+    prompts = [list(range(1 + i, 41 + i)) for i in range(3)]
+    prev = fused.set_interpret(route == "interpret_kernel")
+    try:
+        # built inside the scope: the read's route is chosen at trace time
+        cfg = serve.DecoderConfig(**CFG)
+        model = serve.CachedDecoder(cfg, seed=3)
+        ref = serve.CachedDecoder(cfg, params=model.params)
+        expect = [ref.reference_generate(p, 4, window=16) for p in prompts]
+        with serve.ContinuousEngine(model, max_slots=4, prefill_lanes=2,
+                                    prefill_window=16,
+                                    decode_steps=2) as eng:
+            assert eng.lowered_programs()["chunk_prefill[32]"].in_avals[
+                0][-1].shape == (2,)              # slot_rows: (P,)
+            held = [eng.pool.claim() for _ in range(4)]   # all wait at once
+            eng.pool.poison(1e9)
+            futs = [eng.submit(p, 4) for p in prompts]
+            time.sleep(0.05)
+            for s in held:
+                eng.pool.free(s)
+            outs = [f.result(timeout=300) for f in futs]
+            st = eng.stats()
+            assert eng.assert_no_retraces() == 0
+    finally:
+        fused.set_interpret(prev)
+    for p, o, e in zip(prompts, outs, expect):
+        np.testing.assert_array_equal(
+            o, e, err_msg=f"compact chunk lanes diverged for {p[:3]}...")
+    assert st["chunk_lanes"] == 6
+    assert st["chunk_deferred"] > 0
+
+
 # ---------------------------------------------------------------------------
 # shared-prefix KV cache (engine integration; unit tests in
 # tests/test_prefix_cache.py)

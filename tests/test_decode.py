@@ -505,6 +505,103 @@ def test_paged_attention_kernel_matches_ref_interpret(C, kv, heads):
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=atol)
 
 
+@pytest.mark.parametrize("heads", [(4, 8), (16, 32)],
+                         ids=["h4x8", "h16x32"])
+@pytest.mark.parametrize("kv", ["bfloat16", "int8"])
+@pytest.mark.parametrize("C", [1, 16], ids=["C1", "C16-window"])
+def test_paged_attention_slab_rows_as_data_interpret(C, kv, heads):
+    """Slab mode with `rows`: lane s reads pool row `rows[s]`, as chunk
+    prefill's prefill lanes do over a pool wider than they are. Permuted
+    rows over a slab of 8, one idle lane (length 0) on the garbage row,
+    the slab's last; C = 1 and C = a chunk window of 16; bf16 and int8
+    slabs with scales; both bodies where the dtype allows. The kernel
+    reads to the bit what the lane-indexed call reads over the slab
+    gathered by `rows`, the reference does likewise, and the kernel
+    meets the float64 oracle (bf16) or the reference (int8)."""
+    H, D = heads
+    T, L, bt, R, layer = 384, 2, 128, 8, 1
+    rows = np.asarray([4, 0, 6, 2, R - 1], np.int32)
+    lengths = np.asarray([bt + 1, bt - 1, T - C, 0, 0], np.int32)
+    S = len(rows)
+    rng = np.random.RandomState(4)
+    slab = (R, L, T, H, D)
+    scales = {}
+    if kv == "int8":
+        k_slab, v_slab = (jnp.asarray(rng.randint(
+            -127, 128, slab, dtype=np.int64).astype(np.int8))
+            for _ in range(2))
+        scales = {n: jnp.asarray(
+            (rng.rand(R, L, T) * 0.1 + 0.01).astype(np.float32))
+            for n in ("k_scale", "v_scale")}
+        dtype = jnp.float32
+    else:
+        dtype = jnp.bfloat16
+        k_slab, v_slab = (jnp.asarray(rng.randn(*slab), dtype)
+                          for _ in range(2))
+    q = jnp.asarray(rng.randn(S, C, H, D), dtype)
+    n, at = jnp.asarray(lengths), jnp.asarray(rows)
+    assert PK.paged_body(q, k_slab, scales.get("k_scale")) == (
+        "flat" if heads == (16, 32) and kv != "int8" else "head_major")
+    out = PK.paged_attention_fwd(q, k_slab, v_slab, n, layer, rows=at,
+                                 interpret=True, **scales)
+    assert out is not None and out.dtype == q.dtype
+    # the lane-indexed call over the rows gathered (and a garbage row)
+    gather = np.append(rows, R - 1)
+    lane_scales = {k: s[gather] for k, s in scales.items()}
+    lane = PK.paged_attention_fwd(q, k_slab[gather], v_slab[gather], n,
+                                  layer, interpret=True, **lane_scales)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(lane))
+    ref = F.paged_attention_ref(q, k_slab, v_slab, n, layer, rows=at,
+                                **scales)
+    np.testing.assert_array_equal(
+        np.asarray(ref), np.asarray(F.paged_attention_ref(
+            q, k_slab[gather], v_slab[gather], n, layer, **lane_scales)))
+    if kv == "bfloat16":
+        want = _paged_oracle(q, k_slab[rows, layer], v_slab[rows, layer],
+                             lengths)
+        # the bf16 output's own rounding: half an ulp of values to 4
+        np.testing.assert_allclose(np.asarray(out, np.float64), want,
+                                   rtol=2.0 ** -7, atol=2.0 ** -7)
+    else:
+        np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+
+
+def test_paged_slab_call_takes_rows_only_where_given():
+    """Without `rows` the slab call is the lane-indexed one it was before
+    rows existed: three scalar-prefetch operands (lengths, the lane and
+    the block of each step) and maps that name slab row = lane. With
+    `rows` a fourth list rides between lengths and the step lists, the
+    K/V and scale maps read `rows[lane]`, and q and out stay lane-indexed;
+    `fused.paged_attention` hands it through in slab mode."""
+    q = jnp.zeros((3, 1, 4, 8), jnp.float32)
+    slab = jnp.zeros((4, 2, 384, 4, 8), jnp.int8)
+    scale = jnp.ones((4, 2, 384), jnp.float32)
+    n = jnp.asarray([0, 5, 200], jnp.int32)
+    lane, blk = np.asarray([2, 0, 1]), np.asarray([0, 2, 1])
+    _, spec, _, args = PK._paged_slab_call(q, slab, slab, n, 1, scale,
+                                           scale, None)
+    assert spec.num_scalar_prefetch == 3 and len(args) == 3 + 3 + 2
+    q_map, k_map, v_map, ks_map, _ = (s.index_map for s in spec.in_specs)
+    assert q_map(1, n, lane, blk) == (0, 0, 0, 0)
+    assert k_map(1, n, lane, blk) == v_map(1, n, lane, blk) == (0, 1, 2, 0, 0)
+    assert ks_map(1, n, lane, blk) == (0, 0, 2)
+    rows = np.asarray([3, 1, 2])
+    _, spec, _, args = PK._paged_slab_call(q, slab, slab, n, 1, scale,
+                                           scale, None, rows=rows)
+    assert spec.num_scalar_prefetch == 4 and args[1] is rows
+    q_map, k_map, v_map, ks_map, _ = (s.index_map for s in spec.in_specs)
+    assert q_map(1, n, rows, lane, blk) == (0, 0, 0, 0)
+    assert k_map(1, n, rows, lane, blk) == (3, 1, 2, 0, 0)
+    assert ks_map(1, n, rows, lane, blk) == (3, 0, 2)
+    # a lane count the slab could not hold as rows of its own
+    wide = jnp.zeros((6, 1, 4, 8), jnp.float32)
+    assert PK._paged_slab_call(wide, slab, slab, jnp.zeros((6,), jnp.int32),
+                               1, None, None, None) is None
+    assert PK._paged_slab_call(wide, slab, slab, jnp.zeros((6,), jnp.int32),
+                               1, None, None, None,
+                               rows=np.zeros(6, np.int32)) is not None
+
+
 @pytest.mark.parametrize("q_dtype,kv_dtype,H,body", [
     ("bfloat16", "float32", 8, "head_major"),
     ("bfloat16", "float32", 16, "flat"),

@@ -145,8 +145,7 @@ ENGINE_PROGRAMS = {
     # one program whatever the extent: the classic chunk's read follows
     # the live blocks, so the engine's three rungs are the one jit
     "chunk_prefill[16]": "jit_chunk_prefill",
-    "sample_first[chunk]": "jit_sample_tokens",
-    "join_lanes[chunk]": "jit_join_lanes", "copy": "jit__copy_slot_rows"}
+    "copy": "jit__copy_slot_rows"}
 
 
 def test_engine_registers_every_program_it_runs_and_no_buffer():
