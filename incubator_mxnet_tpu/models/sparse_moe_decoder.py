@@ -480,16 +480,27 @@ def route(h, r_w, r_b, c, with_kept=False):
     return (idx, gates, kept) if with_kept else (idx, gates)
 
 
-def routed_experts(h, idx, gates, token_ok, w_gate_up, w_down, held_first,
-                   held, row0=0):
+def swiglu_up(x, w):
+    """A SiLU-gated expert's activation, silu(g) * u with [g | u] = x w:
+    x (R, d), w (d, 2F) -> (R, F)."""
+    gu = x @ w
+    F = gu.shape[-1] // 2
+    return silu(gu[:, :F]) * gu[:, F:]
+
+
+def routed_experts(h, idx, gates, token_ok, w_up, w_down, held_first,
+                   held, row0=0, up=swiglu_up):
     """The held experts' share of the routed sum. h (T, d); idx, gates
     (T, k) from `route`; token_ok (T,) bool (a pad token routes nowhere);
-    w_gate_up (n, d, 2F), w_down (n, F, d): a stack of experts' weights
-    whose rows [row0, row0 + held) are experts [held_first, held_first +
-    held) (the whole model's stack, every layer's experts on one axis: a
-    slice of one layer's would be written anew at every call, because the
-    `while_loop` below carries what it reads). -> (y (T, d) in h's type,
-    loads (held,) int32: the tokens each held expert received).
+    w_up (n, ...), w_down (n, F, d): a stack of experts' weights whose
+    rows [row0, row0 + held) are experts [held_first, held_first + held)
+    (the whole model's stack, every layer's experts on one axis: a slice of
+    one layer's would be written anew at every call, because the
+    `while_loop` below carries what it reads). An expert is
+    W_down up(x, w_up[e]): `up` is the model's, and reads its row of
+    `w_up` in the layout the model stores (`swiglu_up`: (d, 2F) gated).
+    -> (y (T, d) in h's type, loads (held,) int32: the tokens each held
+    expert received).
 
     The T*k token-expert pairs are sorted by expert with the pairs of
     experts held elsewhere last; a held expert's pairs are then a run of
@@ -528,9 +539,8 @@ def routed_experts(h, idx, gates, token_ok, w_gate_up, w_down, held_first,
         ok = rows < ends[e]
         rows = jnp.minimum(rows, M - 1)
         x = h[s_token[rows]]
-        gu = x @ jax.lax.dynamic_index_in_dim(w_gate_up, row0 + e, 0, False)
-        F = gu.shape[-1] // 2
-        y = jnp.dot(silu(gu[:, :F]) * gu[:, F:],
+        y = jnp.dot(up(x, jax.lax.dynamic_index_in_dim(w_up, row0 + e, 0,
+                                                        False)),
                     jax.lax.dynamic_index_in_dim(w_down, row0 + e, 0, False),
                     preferred_element_type=jnp.float32)
         y = y * jnp.where(ok, s_gate[rows], 0.0)[:, None]

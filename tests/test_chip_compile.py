@@ -502,6 +502,65 @@ def test_looped_programs_hold_the_stack_once_and_copy_no_leaf(
     assert mem.temp_size_in_bytes < 64 * 2 ** 20, mem.temp_size_in_bytes
 
 
+@pytest.mark.parametrize("program", ["micro", "prefill", "chunk_prefill"])
+def test_mamba_moe_programs_rewrite_the_state_in_place(chip, monkeypatch,
+                                                       program):
+    """`chipbench/configs/nemotron3nano_serve.json`: the decode micro-step
+    (64 lanes), the dense prefill and the chunk program (4 lanes of 256) at
+    the cell's widths, window, lanes and `max_len`, with the depth cut to
+    one layer of each kind, `M*E` (all 52 take half a minute a program:
+    `chipbench/tests/compile_v5e_nemotron.py`). The (65, 64, 64, 128)
+    float32 state leaf is rewritten in place (a fusion, never a copy, and
+    the cache aliased), no weight leaf is laid out anew (the up stack of
+    the experts is stored (F, d): its 1856-wide last axis is not whole lane
+    tiles, and stored (d, F) it was copied whole, 1.84 GB, at every call),
+    the attention layer reads through the paged kernel (the dense prefill
+    reads nothing), nothing fell back, and the temporaries stay small."""
+    import collections
+    import json
+    import re
+    import sys
+    import jax
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from chipbench import weights_nemotron
+    from chipbench.tests import compile_v5e_nemotron
+    from chipbench.tests.compile_v5e_glm import top_level_ops
+    from incubator_mxnet_tpu.models import mamba_moe_decoder as mmd
+    from incubator_mxnet_tpu.ops import fused
+    # the platform this process sees is the CPU: take the chip's branch
+    monkeypatch.setattr(fused, "_on_tpu", lambda: True)
+    with open(os.path.join(root,
+                           "chipbench/configs/nemotron3nano_serve.json")) as f:
+        cfg = json.load(f)
+    cfg["model"] = dict(cfg["model"], pattern="M*E")
+    programs = compile_v5e_nemotron.serving_programs(cfg, chip)
+    if program == "micro":
+        _, (params, cache, tokens, lengths, *_) = programs["decode"]
+        fn = mmd._make_micro(weights_nemotron.mamba_moe_config(cfg["model"]))
+        args = [params, cache, tokens, lengths, chip(tokens.shape, "bool")]
+    else:
+        fn, args = programs[program]
+    before = fused.fused_stats()
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    after = fused.fused_stats()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    reads = 0 if program == "prefill" else 1
+    assert text.count("tpu_custom_call") == reads
+    assert after["fallback_calls"] == before["fallback_calls"]
+    assert not re.findall(r" copy\(%params", text)
+    state = ",".join(str(n) for n in args[1]["ssm0"].shape)
+    kinds = collections.Counter(kind for _, kind, _, dims, _, _
+                                in top_level_ops(text) if dims == state)
+    assert set(kinds) <= {"fusion"}, kinds
+    leaves = sum(math.prod(a.shape) * a.dtype.itemsize
+                 for a in args[1].values())
+    assert args[1]["ssm0"].shape == (65, 64, 64, 128)
+    assert mem.alias_size_in_bytes >= leaves          # no second cache
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20, mem.temp_size_in_bytes
+
+
 def test_hlo_parser_reads_a_tpu_compiled_module(chip):
     """`mx.inspect` on what the TPU's compiler prints: operands named
     without shapes, tiled layouts, a dot lowered to a convolution inside a

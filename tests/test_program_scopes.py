@@ -27,6 +27,7 @@ from incubator_mxnet_tpu.inspect import scope_of, scope_table
 from incubator_mxnet_tpu.models import delta_moe_decoder as dm
 from incubator_mxnet_tpu.models import hybrid_decoder as hd
 from incubator_mxnet_tpu.models import looped_decoder as ld
+from incubator_mxnet_tpu.models import mamba_moe_decoder as mmd
 from incubator_mxnet_tpu.models import sparse_moe_decoder as sm
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -444,6 +445,12 @@ def tiny_model(kind):
                             head_dim=16, mlp_hidden=128, ut_steps=3,
                             max_len=48)
         return ld.LoopedDecoder(c, ld.init_looped_params(c, 1))
+    if kind == "mamba_moe":
+        c = mmd.MambaMoEConfig(vocab=96, embed=32, pattern="ME*MEM*E",
+                               mamba_heads=8, mamba_head_dim=4, n_groups=2,
+                               ssm_state=8, chunk_size=4, heads=4, kv_heads=2,
+                               head_dim=8, held_count=4, max_len=48)
+        return mmd.MambaMoEDecoder(c, mmd.init_mamba_moe_params(c, 1))
     c = dm.DeltaMoEConfig(
         vocab=96, embed=64, heads=4, kda_lower_bound=-20.0,
         mixer_types=("kda", "kda", "mla", "kda"),
@@ -455,7 +462,8 @@ def tiny_model(kind):
 
 
 @pytest.mark.parametrize("kind", ["cached", "hybrid", "sparse_moe",
-                                  "delta_moe", "looped", "train_step"])
+                                  "delta_moe", "looped", "mamba_moe",
+                                  "train_step"])
 def test_nine_tenths_of_a_program_run_under_a_scope_of_its_own(kind):
     """A later PR cannot add nameless device work unnoticed: of the
     compiled decode program's (the fused step's) own instructions, those
@@ -482,3 +490,11 @@ def test_nine_tenths_of_a_program_run_under_a_scope_of_its_own(kind):
         # nothing of the program's own code: what is left is the scan's
         assert all("/while" in name and "closed_call/" not in name
                    for name in bare), bare
+    if kind == "mamba_moe":
+        # the four parts of a Mamba-2 layer, each where the partition
+        # metrics' mixer pattern takes it
+        paths = {p for p, _ in scope_table(compiled).values()}
+        assert {f"layer3/mamba/{part}" for part in
+                ("proj", "conv", "state", "out")} <= paths, sorted(paths)
+        assert {"layer2/attn", "layer1/router", "layer1/experts",
+                "layer7/shared_expert", "head"} <= paths
