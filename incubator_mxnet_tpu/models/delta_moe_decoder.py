@@ -450,7 +450,7 @@ def _make_chunk(config, window, extent, fresh):
         live = jnp.arange(E)[None, None, :] <= pos[..., None]   # (B, W, E)
         with jax.named_scope("embed"):
             x = params["emb"][tokens]                            # (B, W, d)
-        moe = jnp.zeros((6,), jnp.int32)
+        moe = jnp.zeros((7,), jnp.int32)
         for l in range(c.layers):
             i = c.slots[l][0]
             w = _weights(params, c, l)
@@ -545,7 +545,7 @@ def _make_micro(config):
             wpos = jnp.clip(lengths, 0, c.max_len - 1)
             x = params["emb"][tokens]                            # (S, d)
             keep = every(active)[:, None, None, None]
-        moe = jnp.zeros((6,), jnp.int32)
+        moe = jnp.zeros((7,), jnp.int32)
         for l in range(c.layers):
             i = c.slots[l][0]
             kda = c.mixer_types[l] == "kda"
@@ -603,7 +603,8 @@ class DeltaMoEDecoder(_sm.SparseMoEDecoder):
 
     counters = {
         "moe": ("pairs_held", "experts_hit", "experts_offered",
-                "max_load_sum", "groups_kept_here", "tokens_routed"),
+                "max_load_sum", "groups_kept_here", "tokens_routed",
+                "blocks"),
         "state": ("lane_layer_steps", "chunk_positions"),
     }
     _init_params = staticmethod(init_delta_moe_params)

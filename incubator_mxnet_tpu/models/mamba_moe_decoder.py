@@ -60,8 +60,8 @@ from ..serve.batcher import ServeError
 from ..serve.kv_pool import CacheLeaf
 from . import sparse_moe_decoder as _sm
 from .hybrid_decoder import silu
-from .sparse_moe_decoder import (_head, draw_leaf, rms_norm, route,
-                                 routed_experts)
+from .sparse_moe_decoder import (ExpertUp, _head, draw_leaf, rms_norm,
+                                 route, routed_experts)
 
 __all__ = ["MambaMoEConfig", "MambaMoEDecoder", "init_mamba_moe_params",
            "param_shapes", "draw_mamba_leaf", "mamba2_project",
@@ -360,15 +360,15 @@ def _relu2_mlp(h, w_up, w_down):
     return jnp.square(jnp.maximum(h @ w_up, 0)) @ w_down
 
 
-def relu2_up(x, w_t):
-    """A routed expert's activation relu(x W_up)^2: x (R, d), w_t (F, d),
-    W_up stored transposed (a last axis of 1856 is not whole lane tiles:
-    stored (d, F) the stack is laid with d innermost and copied whole at
-    every call)."""
-    import jax
+def _relu2(u):
     import jax.numpy as jnp
-    return jnp.square(jnp.maximum(jax.lax.dot_general(
-        x, w_t, (((1,), (1,)), ((), ()))), 0))
+    return jnp.square(jnp.maximum(u, 0))
+
+
+#: a routed expert's activation relu(x W_up)^2: x (R, d), w_t (F, d), W_up
+#: stored transposed (a last axis of 1856 is not whole lane tiles: stored
+#: (d, F) the stack is laid with d innermost and copied whole at every call)
+relu2_up = ExpertUp(_relu2, transposed=True)
 
 
 # ---------------------------------------------------------------------------
@@ -395,25 +395,27 @@ def _weights(params, c, l):
 
 def _moe(x, w, c, l, token_ok, total):
     """x (T, d) -> (x + the layer's routed share and its shared expert,
-    total + moe counters (4,) int32: token-expert pairs on held experts,
+    total + moe counters (5,) int32: token-expert pairs on held experts,
     held experts that received a token, held experts offered, the largest
-    load)."""
+    load, the blocks of rows the grouped matmul walked)."""
     import jax
     import jax.numpy as jnp
     with jax.named_scope(f"layer{l}/router"):
         h = rms_norm(x, w["norm"], c.norm_eps)
         idx, gates = route(h, w["r_w"], w["r_b"], c)
     with jax.named_scope(f"layer{l}/experts"):
-        y, loads = routed_experts(h, idx, gates, token_ok, w["e_up"],
-                                  w["e_down"], c.held_first, c.held_count,
-                                  w["e_row0"], up=relu2_up)
+        y, loads, blocks = routed_experts(h, idx, gates, token_ok,
+                                          w["e_up"], w["e_down"],
+                                          c.held_first, c.held_count,
+                                          w["e_row0"], up=relu2_up)
     with jax.named_scope(f"layer{l}/shared_expert"):
         y = y + _relu2_mlp(h, w["s_up"], w["s_down"])
     with jax.named_scope(f"layer{l}/experts"):
         any_token = jnp.any(token_ok).astype(jnp.int32)
         counted = jnp.stack([jnp.sum(loads),
                              jnp.sum(loads > 0, dtype=jnp.int32),
-                             c.held_count * any_token, jnp.max(loads)])
+                             c.held_count * any_token, jnp.max(loads),
+                             blocks])
     with jax.named_scope(f"layer{l}/shared_expert"):
         return x + y, total + counted
 
@@ -469,7 +471,7 @@ def _make_chunk(config, window, extent, fresh):
                 first = (offsets[:, None]
                          + jnp.arange(0, W, Q)[None, :]).reshape(-1)
                 lane_rows = jnp.repeat(rows, W // Q)
-        moe = jnp.zeros((4,), jnp.int32)
+        moe = jnp.zeros((5,), jnp.int32)
         for l, kind in enumerate(c.kinds):
             i = c.slots[l]
             with jax.named_scope(f"layer{l}/{_FIRST_SCOPE[kind]}"):
@@ -573,7 +575,7 @@ def _make_micro(config):
             rows = jnp.where(active, jnp.arange(S), S)   # garbage row = S
             wpos = jnp.clip(lengths, 0, c.max_len - 1)
             x = params["emb"][tokens]                            # (S, d)
-        moe = jnp.zeros((4,), jnp.int32)
+        moe = jnp.zeros((5,), jnp.int32)
         for l, kind in enumerate(c.kinds):
             i = c.slots[l]
             with jax.named_scope(f"layer{l}/{_FIRST_SCOPE[kind]}"):
@@ -631,7 +633,7 @@ class MambaMoEDecoder(_sm.SparseMoEDecoder):
 
     counters = {
         "moe": ("pairs_held", "experts_hit", "experts_offered",
-                "max_load_sum"),
+                "max_load_sum", "blocks"),
         "state": ("lane_layer_steps", "chunk_positions"),
     }
     _init_params = staticmethod(init_mamba_moe_params)

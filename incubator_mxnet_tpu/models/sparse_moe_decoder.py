@@ -36,9 +36,9 @@ through the `[0, cur_len]` mask): the pool needed no new kind.
            E_shared(h): the share of the published sum that one process
            of an expert-parallel deployment computes, with no capacity and
            no dropped token (`routed_experts`: token-expert pairs sorted by
-           expert, one `while_loop` over the blocks of rows that exist this
-           call, each a matmul with ITS expert's weights; an expert nobody
-           chose costs nothing).
+           expert, each run of rows a matmul with ITS expert's weights in
+           one grouped-matmul kernel over the row blocks that exist this
+           call; an expert nobody chose costs nothing).
 
 `SparseMoEDecoder` builds the engine's three programs (`prefill`,
 `chunk_prefill`, `decode`: fixed shapes, donated cache, lanes as data) and
@@ -68,12 +68,13 @@ from .hybrid_decoder import gated_mlp, layer_norm, silu
 
 __all__ = ["SparseMoEConfig", "SparseMoEDecoder", "init_sparse_moe_params",
            "param_shapes", "draw_leaf", "rope", "select_mask",
-           "routed_experts", "mla_read_rebuilt", "mla_read_absorbed"]
+           "routed_experts", "expert_block", "ExpertUp", "swiglu_up",
+           "mla_read_rebuilt", "mla_read_absorbed"]
 
 #: heads a step of a chunk's attention (the float32 scores of one step are
 #: HEAD_GROUP x window x extent) and index heads a step of its scoring
 HEAD_GROUP = 4
-#: rows a block of the grouped expert matmul at most
+#: rows a block of the grouped expert matmul at most (`expert_block`)
 EXPERT_BLOCK = 128
 #: float32 bytes the per-head index scores of one step may take
 SCORE_BYTES = 2 ** 28
@@ -480,12 +481,46 @@ def route(h, r_w, r_b, c, with_kept=False):
     return (idx, gates, kept) if with_kept else (idx, gates)
 
 
-def swiglu_up(x, w):
-    """A SiLU-gated expert's activation, silu(g) * u with [g | u] = x w:
-    x (R, d), w (d, 2F) -> (R, F)."""
-    gu = x @ w
-    F = gu.shape[-1] // 2
-    return silu(gu[:, :F]) * gu[:, F:]
+class ExpertUp:
+    """A routed expert's activation from its row of the up stack, in the
+    layout the model stores: `up(x, w)` with x (R, d) -> (R, F). `gated`:
+    w is (d, 2F) and the activation is act(g) * u with [g | u] = x w;
+    else act(x w), over w (F, d) where `transposed`, (d, F) where not.
+    `act` is elementwise and computes in float32. The XLA loop calls the
+    object; the grouped-matmul kernel reads the same three facts and runs
+    `act` on its tiles (`ops.fused.grouped_matmul`). One object a body,
+    made once, so that a program's expert layers share one lowering."""
+
+    def __init__(self, act, gated=False, transposed=False):
+        self.act, self.gated, self.transposed = act, gated, transposed
+
+    def __call__(self, x, w):
+        import jax
+        u = jax.lax.dot_general(x, w, (((1,), (1 if self.transposed
+                                              else 0,)), ((), ())))
+        if not self.gated:
+            return self.act(u)
+        F = u.shape[-1] // 2
+        return self.act(u[:, :F]) * u[:, F:]
+
+
+#: a SiLU-gated expert's activation, silu(g) * u with [g | u] = x w:
+#: x (R, d), w (d, 2F) -> (R, F)
+swiglu_up = ExpertUp(silu, gated=True)
+
+
+def expert_block(T):
+    """Rows a block of the grouped expert matmul, for a call that routes T
+    tokens: T / 16 in whole sublane tiles of 16, from 16 to
+    `EXPERT_BLOCK`. A block holds the pairs of ONE expert, and the matrix
+    unit takes a block's rows through every tile of that expert's weights
+    once more for each row: a block sized to the load, not to the call,
+    keeps the weights' stream the bound. The routings served put T k / E
+    = T / 64 to 3 T / 64 pairs on an expert (k of E experts a token), so a
+    decode step's 32 to 128 tokens (1 to 6 an expert) take blocks of 16
+    and a chunk of 1024 (16 to 48) blocks of 64, into which an expert's
+    load fits whole in nearly every call."""
+    return min(EXPERT_BLOCK, max(16, T // 256 * 16))
 
 
 def routed_experts(h, idx, gates, token_ok, w_up, w_down, held_first,
@@ -495,68 +530,43 @@ def routed_experts(h, idx, gates, token_ok, w_up, w_down, held_first,
     w_up (n, ...), w_down (n, F, d): a stack of experts' weights whose
     rows [row0, row0 + held) are experts [held_first, held_first + held)
     (the whole model's stack, every layer's experts on one axis: a slice of
-    one layer's would be written anew at every call, because the
-    `while_loop` below carries what it reads). An expert is
-    W_down up(x, w_up[e]): `up` is the model's, and reads its row of
-    `w_up` in the layout the model stores (`swiglu_up`: (d, 2F) gated).
+    one layer's would be written anew at every call, and every layer of a
+    program passes the same arrays). An expert is W_down up(x, w_up[e]):
+    `up` is the model's `ExpertUp`, and reads its row of `w_up` in the
+    layout the model stores (`swiglu_up`: (d, 2F) gated).
     -> (y (T, d) in h's type, loads (held,) int32: the tokens each held
-    expert received).
+    expert received, blocks (int32): the blocks of rows walked).
 
     The T*k token-expert pairs are sorted by expert with the pairs of
     experts held elsewhere last; a held expert's pairs are then a run of
-    rows, cut into blocks of at most `EXPERT_BLOCK`. One `while_loop` runs
-    over the blocks that exist in THIS call (their number is data, so a
-    changed load retraces nothing): a block gathers its tokens, multiplies
-    them with its expert's weights and writes its rows of the sorted
-    result, in order, so that a later block rewrites whatever an earlier
-    one's tail left in its rows. Every pair of a held expert is computed
-    whatever the load: there is no capacity."""
-    import jax
+    rows. `ops.fused.grouped_matmul` multiplies each run with its
+    expert's weights in blocks of rows, the number of a call's blocks
+    being data (a changed load retraces nothing): on the TPU one Pallas
+    kernel over blocks of `expert_block(T)` rows, elsewhere an XLA loop a
+    block of at most `EXPERT_BLOCK` rows, and of the call's tokens in
+    whole sublane tiles below that.
+    Every pair of a held expert is computed whatever the load: there is
+    no capacity."""
     import jax.numpy as jnp
+    from ..ops import fused as _fused
     T, k = idx.shape
-    d = h.shape[-1]
     M = T * k
-    bm = min(EXPERT_BLOCK, -(-T // 16) * 16)
     local = idx - held_first
     mine = (local >= 0) & (local < held) & token_ok[:, None]
     group = jnp.where(mine, local, held).reshape(M)
     order = jnp.argsort(group)
-    s_group = group[order]
     s_token = (order // k).astype(jnp.int32)
     s_gate = gates.reshape(M)[order]
     loads = jnp.sum(group[:, None] == jnp.arange(held)[None, :], 0,
                     dtype=jnp.int32)                              # (held,)
     ends = jnp.cumsum(loads)
-    starts = ends - loads
-    blocks = (loads + bm - 1) // bm
-    block_ends = jnp.cumsum(blocks)
-
-    def body(carry):
-        i, out = carry
-        e = jnp.sum(block_ends <= i).astype(jnp.int32)
-        r0 = starts[e] + (i - (block_ends[e] - blocks[e])) * bm
-        rows = r0 + jnp.arange(bm)
-        ok = rows < ends[e]
-        rows = jnp.minimum(rows, M - 1)
-        x = h[s_token[rows]]
-        y = jnp.dot(up(x, jax.lax.dynamic_index_in_dim(w_up, row0 + e, 0,
-                                                        False)),
-                    jax.lax.dynamic_index_in_dim(w_down, row0 + e, 0, False),
-                    preferred_element_type=jnp.float32)
-        y = y * jnp.where(ok, s_gate[rows], 0.0)[:, None]
-        return i + 1, jax.lax.dynamic_update_slice_in_dim(
-            out, y.astype(out.dtype), r0, 0)
-
-    n_blocks = block_ends[-1]
-    _, out = jax.lax.while_loop(
-        lambda carry: carry[0] < n_blocks, body,
-        (jnp.int32(0), jnp.zeros((M + bm, d), h.dtype)))
-    # each pair's row of the sorted result, back at its token
     place = jnp.zeros((M,), jnp.int32).at[order].set(
         jnp.arange(M, dtype=jnp.int32))
-    pairs = jnp.where((s_group < held)[place][:, None], out[place], 0)
-    y = jnp.sum(pairs.reshape(T, k, d).astype(jnp.float32), 1)
-    return y.astype(h.dtype), loads
+    y, blocks = _fused.grouped_matmul(
+        h, s_token, s_gate, ends - loads, ends, place, w_up, w_down, row0,
+        up=up, bm=expert_block(T),
+        loop_bm=min(EXPERT_BLOCK, -(-T // 16) * 16))
+    return y, loads, blocks
 
 
 # ---------------------------------------------------------------------------
@@ -584,10 +594,11 @@ def _weights(params, c, l):
 
 
 def _ffn(x, w, c, l, token_ok, total=None):
-    """x (T, d) -> (x + FFN_l(RMSNorm(x)), moe counters (4,) int32:
+    """x (T, d) -> (x + FFN_l(RMSNorm(x)), moe counters (5,) int32:
     token-expert pairs on held experts, held experts that received a
-    token, held experts offered, the largest load). A group-limited router
-    (`c.n_group` > 1) counts two more: the tokens whose kept groups hold
+    token, held experts offered, the largest load, the blocks of rows the
+    grouped matmul walked). A group-limited router (`c.n_group` > 1)
+    counts two more before the blocks: the tokens whose kept groups hold
     one of this process's experts (`c.held_groups`), and the tokens routed.
     With `total` the counters come added to it. Every equation runs under
     one of the layer's scopes (the norm under `/mlp`, the counts under
@@ -601,16 +612,16 @@ def _ffn(x, w, c, l, token_ok, total=None):
         h = rms_norm(x, w["ln2_w"], c.norm_eps)
         if dense:
             x = x + gated_mlp(h, w["d_gate_up"], w["d_down"])
-            counted = jnp.zeros((6 if count_groups else 4,), jnp.int32)
+            counted = jnp.zeros((7 if count_groups else 5,), jnp.int32)
     if not dense:
         with jax.named_scope(f"layer{l}/router"):
             idx, gates, kept = route(h, w["r_w"], w["r_b"], c,
                                      with_kept=True)
         with jax.named_scope(f"layer{l}/experts"):
-            y, loads = routed_experts(h, idx, gates, token_ok,
-                                      w["e_gate_up"], w["e_down"],
-                                      c.held_first, c.held_count,
-                                      w["e_row0"])
+            y, loads, blocks = routed_experts(h, idx, gates, token_ok,
+                                              w["e_gate_up"], w["e_down"],
+                                              c.held_first, c.held_count,
+                                              w["e_row0"])
         with jax.named_scope(f"layer{l}/shared_expert"):
             y = y + gated_mlp(h, w["s_gate_up"], w["s_down"])
         with jax.named_scope(f"layer{l}/experts"):
@@ -622,6 +633,7 @@ def _ffn(x, w, c, l, token_ok, total=None):
                     & token_ok
                 counted += [jnp.sum(here, dtype=jnp.int32),
                             jnp.sum(token_ok, dtype=jnp.int32)]
+            counted.append(blocks)
         with jax.named_scope(f"layer{l}/shared_expert"):
             x = x + y
     with jax.named_scope(f"layer{l}/" + ("mlp" if dense else "experts")):
@@ -678,7 +690,7 @@ def _make_chunk(config, window, extent, fresh):
         with jax.named_scope("embed"):
             x = params["emb"][tokens]                            # (B, W, d)
         mask = live
-        moe = jnp.zeros((4,), jnp.int32)
+        moe = jnp.zeros((5,), jnp.int32)
         sparse = jnp.zeros((3,), jnp.int32)
         for l in range(c.layers):
             fi, _ = c.slots[l]
@@ -748,7 +760,7 @@ def _make_micro(config):
             n_live = jnp.minimum(lengths + 1, c.max_len)
             x = params["emb"][tokens]                            # (S, d)
         chosen = ok = None
-        moe = jnp.zeros((4,), jnp.int32)
+        moe = jnp.zeros((5,), jnp.int32)
         sparse = jnp.zeros((3,), jnp.int32)
         for l in range(c.layers):
             fi, _ = c.slots[l]
@@ -789,7 +801,8 @@ def _make_micro(config):
 
 #: what every program of this decoder counts (`SparseMoEDecoder.counters`)
 COUNTERS = {
-    "moe": ("pairs_held", "experts_hit", "experts_offered", "max_load_sum"),
+    "moe": ("pairs_held", "experts_hit", "experts_offered", "max_load_sum",
+            "blocks"),
     "sparse": ("queries", "live_positions", "chosen_positions"),
 }
 

@@ -75,9 +75,9 @@ from . import pallas_kernels as _pk
 
 __all__ = ["bias_act", "norm_act_residual", "bn_inference", "batch_norm",
            "avg_pool2d", "image_augment", "paged_attention",
-           "bias_act_ref",
+           "grouped_matmul", "bias_act_ref",
            "norm_act_residual_ref", "bn_inference_ref", "avg_pool2d_ref",
-           "paged_attention_ref",
+           "paged_attention_ref", "grouped_matmul_ref",
            "fusion_scope",
            "fusion_enabled", "set_fusion_default", "set_use_fusion",
            "set_interpret", "fused_stats", "FUSED_STATS", "FUSABLE_ACTS"]
@@ -99,6 +99,9 @@ FUSED_STATS = _stats_group("fused", {
     # layer's (`shared`), a ring under a window (`window`)
     "paged_shared_traces": 0,
     "paged_window_traces": 0,
+    # traces of the held experts' grouped-matmul kernel: one a program and
+    # shape, whatever the program's depth (`_grouped_entry`)
+    "grouped_matmul_traces": 0,
 })
 _STATS = FUSED_STATS
 
@@ -431,6 +434,126 @@ def _paged_leaf(q, k_leaf, v_leaf, lengths, rows, window, scale, out_dtype,
                f"{k_leaf.dtype} does not tile, or no TPU / fusion off")
     return paged_attention_ref(q, k_leaf, v_leaf, lengths, None, rows=rows,
                                scale=scale, out_dtype=out_dtype)
+
+
+def grouped_matmul_ref(h, s_token, s_gate, starts, ends, place, w_up, w_down,
+                       row0, *, up, bm):
+    """The held experts' grouped matmul as one XLA `while_loop`, the
+    fallback and parity oracle of `grouped_matmul` (its contract). One
+    trip a block of at most `bm` rows of one expert, over the blocks that
+    exist in THIS call (their number is data, so a changed load retraces
+    nothing): a block gathers its tokens, multiplies them with its
+    expert's weights and writes its rows of the sorted result, in order,
+    so that a later block rewrites whatever an earlier one's tail left in
+    its rows. Each pair's row then goes back to its token (`place`), whose
+    pairs are summed in float32."""
+    import jax
+    jnp = _jnp()
+    M, d = s_token.shape[0], h.shape[-1]
+    loads = ends - starts
+    blocks = (loads + bm - 1) // bm
+    block_ends = jnp.cumsum(blocks)
+
+    def body(carry):
+        i, out = carry
+        e = jnp.sum(block_ends <= i).astype(jnp.int32)
+        r0 = starts[e] + (i - (block_ends[e] - blocks[e])) * bm
+        rows = r0 + jnp.arange(bm)
+        ok = rows < ends[e]
+        rows = jnp.minimum(rows, M - 1)
+        x = h[s_token[rows]]
+        y = jnp.dot(up(x, jax.lax.dynamic_index_in_dim(w_up, row0 + e, 0,
+                                                        False)),
+                    jax.lax.dynamic_index_in_dim(w_down, row0 + e, 0, False),
+                    preferred_element_type=jnp.float32)
+        y = y * jnp.where(ok, s_gate[rows], 0.0)[:, None]
+        return i + 1, jax.lax.dynamic_update_slice_in_dim(
+            out, y.astype(out.dtype), r0, 0)
+
+    n_blocks = block_ends[-1]
+    _, out = jax.lax.while_loop(
+        lambda carry: carry[0] < n_blocks, body,
+        (jnp.int32(0), jnp.zeros((M + bm, d), h.dtype)))
+    pairs = jnp.where((place < ends[-1])[:, None], out[place], 0)
+    y = jnp.sum(pairs.reshape(h.shape[0], -1, d).astype(jnp.float32), 1)
+    return y.astype(h.dtype), n_blocks
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_entry():
+    """The kernel route of `grouped_matmul` as ONE jitted function: every
+    expert layer of a program calls it with the same shapes (the whole
+    model's stacks, `row0` as data), so a program traces and lowers the
+    kernel once whatever its depth, and its module holds one copy."""
+    import jax
+
+    def entry(h, s_token, s_gate, starts, ends, w_up, w_down, row0, *, up,
+              bm, tf, interpret):
+        # counts traces, not calls: that is what the counter is for
+        _STATS["grouped_matmul_traces"] += 1  # mxlint: disable=trace-closure-mutation
+        jnp = _jnp()
+        M, held = s_token.shape[0], starts.shape[0]
+        # at most a block a row, and at most one part-filled block an expert
+        bound = min(M, (M + held * (bm - 1)) // bm)
+        expert, n, shift = _pk.grouped_blocks(starts, ends, bm, bound)
+        # each padded row's sorted row (gathers: a scatter is slow on TPU)
+        p = jnp.arange(bound * bm, dtype=jnp.int32)
+        e = expert[p // bm]
+        r = p - shift[e]
+        real = (p // bm < n[0]) & (r < ends[e])
+        r = jnp.clip(r, 0, M - 1)
+        y = _pk.grouped_matmul_fwd(
+            h.astype(jnp.float32), jnp.where(real, s_token[r], 0),
+            jnp.where(real, s_gate[r].astype(jnp.float32), 0.0)[:, None],
+            w_up, w_down, row0 + expert, n, tf=tf, gated=up.gated,
+            transposed=up.transposed, act=up.act, interpret=interpret)
+        return y.astype(h.dtype), n[0]
+
+    return jax.jit(entry, static_argnames=("up", "bm", "tf", "interpret"))
+
+
+def grouped_matmul(h, s_token, s_gate, starts, ends, place, w_up, w_down,
+                   row0, *, up, bm, loop_bm, interpret=None):
+    """The held experts' grouped matmul over token-expert pairs sorted by
+    expert. h (T, d) the tokens; s_token, s_gate (M,) each sorted pair's
+    token and gate; expert e's pairs are the sorted rows [starts[e],
+    ends[e]) and its weights row `row0 + e` of `w_up`, `w_down` (n, F, d);
+    place (M,): the sorted row of each pair in its token's order (token
+    t's pairs are pairs t k .. t k + k - 1; the loop's gather back);
+    `up` the expert's activation (`models.sparse_moe_decoder.ExpertUp`:
+    `up(x, w_up[e])`, and the facts the kernel reads: `gated`,
+    `transposed`, `act`); `bm` the rows of a kernel block, `loop_bm` of a
+    loop trip (a trip's fixed cost asks for the fewest trips, the
+    kernel's blocks for rows that the load fills).
+
+    -> (y (T, d) in h's type: each token's sum, in float32, over its
+    pairs of the held experts, of the gate times W_down up(h[token]), each
+    term rounded to h's type; blocks walked (int32)). Routes to the Pallas
+    kernel (`pallas_kernels.grouped_matmul_fwd`, through `_grouped_entry`)
+    on TPU or in interpret mode, and to the XLA loop `grouped_matmul_ref`
+    otherwise; the choice is static per trace. A hidden width with no
+    tile of whole lane tiles (`pallas_kernels.expert_tile`: nemotron's
+    1856) is the loop's by design on every platform, as it was before the
+    kernel, and is not counted as a fallback; a call whose tokens do not
+    fit the kernel's VMEM (`grouped_vmem_bytes`), or MXNET_USE_FUSION off,
+    falls back to the loop and is counted (never fails)."""
+    interpret = _resolve_interpret(interpret)
+    tf = _pk.expert_tile(w_down.shape[1], h.shape[-1], up.gated,
+                         w_down.dtype.itemsize)
+    if (_on_tpu() or interpret) and tf:
+        footprint = _pk.grouped_vmem_bytes(
+            h.shape[0], h.shape[-1], tf, bm, up.gated, w_down.dtype.itemsize)
+        if footprint + footprint // 4 > _pk._EXPERT_VMEM:
+            _fell_back("grouped_matmul", f"{footprint} B of VMEM")
+        elif not _env_use_fusion():
+            _fell_back("grouped_matmul", "fusion off (MXNET_USE_FUSION)")
+        else:
+            _STATS["pallas_calls"] += 1
+            return _grouped_entry()(h, s_token, s_gate, starts, ends, w_up,
+                                    w_down, row0, up=up, bm=bm, tf=tf,
+                                    interpret=interpret)
+    return grouped_matmul_ref(h, s_token, s_gate, starts, ends, place, w_up,
+                              w_down, row0, up=up, bm=loop_bm)
 
 
 def avg_pool2d_ref(x, pool_size, layout="NHWC"):

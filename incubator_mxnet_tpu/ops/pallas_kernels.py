@@ -29,6 +29,13 @@ instead:
     (bf16 codes, float32 sums; `_paged_body`). Optional per-position f32
     scales dequantize int8 slabs on the fly (serve.kv_pool
     `dtype="int8"`).
+  * `grouped_matmul_fwd` — the held experts of a sparse-expert layer over
+    the token-expert pairs sorted by expert: one grid step a (block of
+    one expert's rows, F tile), the block's expert a scalar-prefetched
+    index into the whole stack of experts, so each expert's weights
+    stream once a block and an expert nobody chose costs nothing; the
+    tokens' rows are gathered from, and the gated results summed into, a
+    VMEM copy of the call's tokens.
 
 Everything here takes and returns raw jax arrays and is shape-strict: the
 caller (ops/fused.py) owns fallback policy, custom_vjp wiring and layout
@@ -44,7 +51,8 @@ from __future__ import annotations
 import functools
 
 __all__ = ["apply_scale_shift_act", "avg_pool2d_fwd", "avg_pool2d_bwd",
-           "paged_attention_fwd", "supported_act", "ACTS"]
+           "paged_attention_fwd", "grouped_matmul_fwd", "grouped_blocks",
+           "expert_tile", "grouped_vmem_bytes", "supported_act", "ACTS"]
 
 # activation set the kernels (and their hand-derived VJPs) support; None
 # means identity. Kept in sync with ops/fused.py's dispatch tables. Exact
@@ -753,6 +761,228 @@ def _paged_leaf_call(q, k_leaf, v_leaf, lengths, rows, scale, out_dtype,
     return (kernel, grid_spec,
             jax.ShapeDtypeStruct((s_lanes, q_rows, d), out_dtype),
             (lengths, rows, lane_of, blk_of, q_wide, k_leaf, v_leaf))
+
+
+# ---------------------------------------------------------------------------
+# the held experts' grouped matmul over token-expert pairs sorted by expert
+# ---------------------------------------------------------------------------
+#: weight bytes one grid step of the grouped matmul may bring (its gate, up
+#: and down tiles); double-buffered, what the step's DMA hides behind
+_EXPERT_STEP_BYTES = 10 * 1024 * 1024
+#: VMEM one grouped-matmul call may ask for, its footprint and a quarter
+#: more, of the 128 MiB a v5e core has: the rest is the compiler's
+_EXPERT_VMEM = 100 * 1024 * 1024
+
+
+def expert_tile(f, d, gated, itemsize):
+    """Columns of the expert's hidden width F that one step of
+    `grouped_matmul_fwd` reads: the largest divisor of `f` in whole lane
+    tiles whose gate, up and down tiles stay inside `_EXPERT_STEP_BYTES`,
+    or 0 when no divisor is whole lane tiles. The tile is the lane axis of
+    the activation and the contraction of the down matmul, whatever the up
+    stack's layout. A width with no such divisor (1856 = 14.5 lane tiles)
+    is not the kernel's: on a v5e its 928-wide tiles compiled, but the
+    first execution of a chunk program that carried them did not finish
+    (`PERF.md` section 6). Interpret mode follows the same rule, so a test
+    takes the route the chip takes."""
+    per_col = (3 if gated else 2) * d * itemsize
+    for tf in range(f - f % _LANES, 0, -_LANES):
+        if f % tf == 0 and (tf * per_col <= _EXPERT_STEP_BYTES
+                            or tf == _LANES):
+            return tf
+    return 0
+
+
+def grouped_vmem_bytes(t, d, tf, bm, gated, itemsize):
+    """VMEM a call of `grouped_matmul_fwd` takes: the call's tokens h and
+    the sum y, (t, d) float32 each, whole; the block's rows, their sum and
+    product (bm, d); the activation and its inputs (bm, tf); the weight
+    and gate tiles double-buffered. h and y grow with t: a call whose
+    footprint passes `_EXPERT_VMEM` is not the kernel's."""
+    weights = (3 if gated else 2) * _tile_bytes((tf, d), itemsize)
+    return (2 * (weights + _tile_bytes((bm, 1), 4))
+            + 2 * _tile_bytes((t, d), 4)
+            + 3 * _tile_bytes((bm, d), 4)
+            + 4 * _tile_bytes((bm, tf), 4))
+
+
+def _grouped_kernel(expert_ref, n_ref, tok_ref, h_hbm, g_ref, *refs, nf,
+                    gated, transposed, act, dtype):
+    """One block of the grouped matmul at one F tile: the block's rows,
+    all of one expert's pairs, meet that expert's weights.
+
+    Step (b, f) serves block b, whose expert (its row of the stacks) is
+    the scalar-prefetched `expert_ref[b]` and whose rows are the tokens
+    `tok_ref[b * bm:(b + 1) * bm]`. The first step brings the tokens' rows
+    h into VMEM and zeroes the sum y; a block's first F tile gathers its
+    rows from there, its last adds each row's gated result, rounded to the
+    activations' type, into its token's row of y; the last step writes y
+    out. A step past the last real block computes nothing, and its index
+    maps repeat the last block's, so it fetches nothing either."""
+    import jax
+    import jax.numpy as jnp
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    if gated:
+        gate_w, up_w, down_w, y_hbm, h_v, y_v, x_v, acc_v, sem = refs
+    else:
+        up_w, down_w, y_hbm, h_v, y_v, x_v, acc_v, sem = refs
+        gate_w = None
+    b, f = pl.program_id(0), pl.program_id(1)
+    bm = x_v.shape[0]
+
+    @pl.when(jnp.logical_and(b == 0, f == 0))
+    def _start():
+        copy = pltpu.make_async_copy(h_hbm, h_v, sem)
+        copy.start()
+        y_v[...] = jnp.zeros_like(y_v)
+        copy.wait()
+
+    @pl.when(b < n_ref[0])
+    def _block():
+        @pl.when(f == 0)
+        def _gather():
+            def row(j, carry):
+                x_v[pl.ds(j, 1), :] = h_v[pl.ds(tok_ref[b * bm + j], 1), :]
+                return carry
+            jax.lax.fori_loop(0, bm, row, 0)
+            acc_v[...] = jnp.zeros_like(acc_v)
+
+        x = x_v[...].astype(dtype)
+
+        def up_of(w):
+            # the matmul's output rounded to the activations' type, as the
+            # XLA form of the body computes it
+            dims = (((1,), (1,)), ((), ())) if transposed \
+                else (((1,), (0,)), ((), ()))
+            return jax.lax.dot_general(
+                x, w, dims, preferred_element_type=jnp.float32).astype(
+                    dtype).astype(jnp.float32)
+
+        a = act(up_of(gate_w[0])) * up_of(up_w[0]) if gated \
+            else act(up_of(up_w[0]))
+        acc_v[...] += jnp.dot(a.astype(dtype), down_w[0],
+                              preferred_element_type=jnp.float32)
+
+        @pl.when(f == nf - 1)
+        def _scatter():
+            acc_v[...] = (acc_v[...] * g_ref[...]).astype(dtype).astype(
+                jnp.float32)
+
+            def row(j, carry):
+                t = tok_ref[b * bm + j]
+                y_v[pl.ds(t, 1), :] += acc_v[pl.ds(j, 1), :]
+                return carry
+            jax.lax.fori_loop(0, bm, row, 0)
+
+    @pl.when(jnp.logical_and(b == pl.num_programs(0) - 1, f == nf - 1))
+    def _finish():
+        copy = pltpu.make_async_copy(y_v, y_hbm, sem)
+        copy.start()
+        copy.wait()
+
+
+def grouped_matmul_fwd(h, tokens, gates, w_up, w_down, expert, n_blocks, *,
+                       tf, gated, transposed, act, interpret=False):
+    """The held experts' grouped matmul over blocks of rows of one expert.
+
+    h (T, d) float32: the tokens (their values in the activations' type
+    `w_down.dtype`); tokens (NB * bm,) int32 and gates (NB * bm, 1)
+    float32: the token and the gate of each row of NB blocks of bm rows,
+    each block one expert's pairs (`grouped_blocks` lays them out), gate 0
+    on a padding row; w_up, w_down: the stacks of experts' weights,
+    `w_down` (n, F, d), `w_up` (n, d, 2F) `gated` ([gate | up] columns) or
+    (n, F, d) `transposed` (or (n, d, F)); `act` the float32 activation
+    (of the gate where gated); expert (NB,) int32 each block's row of the
+    stacks, n_blocks (1,) int32 the real blocks, the others repeating the
+    last; `tf` the F tile (`expert_tile`: the caller checks there is one,
+    and that `grouped_vmem_bytes` fits).
+
+    -> y (T, d) float32: each token's sum over its rows of the gate times
+    W_down act(...), each term rounded to the activations' type. The grid
+    is (NB, F / tf); h and y stay in VMEM whole, the rows are gathered and
+    summed there."""
+    import jax
+    import jax.numpy as jnp
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nb = expert.shape[0]
+    t, d = h.shape
+    bm = tokens.shape[0] // nb
+    f = w_down.shape[1]
+    dtype = w_down.dtype
+    nf = f // tf
+
+    def tile(b, fi, n_ref):
+        # a step past the last block repeats the last block's last tile
+        return jnp.where(b < n_ref[0], fi, nf - 1)
+
+    def block(b, fi, expert_ref, n_ref, tok_ref):
+        return (jnp.minimum(b, jnp.maximum(n_ref[0] - 1, 0)), 0)
+
+    def up_tile(part):
+        # the gate's tiles, then the up's: F / tf blocks further along
+        def index(b, fi, expert_ref, n_ref, tok_ref):
+            j = part * nf + tile(b, fi, n_ref)
+            return (expert_ref[b], j, 0) if transposed \
+                else (expert_ref[b], 0, j)
+        return index
+
+    def down_tile(b, fi, expert_ref, n_ref, tok_ref):
+        return (expert_ref[b], tile(b, fi, n_ref), 0)
+
+    parts = (0, 1) if gated else (0,)
+    w_specs = [pl.BlockSpec((1, tf, d) if transposed else (1, d, tf),
+                            up_tile(part)) for part in parts] \
+        + [pl.BlockSpec((1, tf, d), down_tile)]
+    w_args = [w_up] * len(parts)
+    footprint = grouped_vmem_bytes(t, d, tf, bm, gated, dtype.itemsize)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(nb, nf),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec((bm, 1), block)] + w_specs,
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),
+                        pltpu.VMEM((t, d), jnp.float32),
+                        pltpu.VMEM((bm, d), jnp.float32),
+                        pltpu.VMEM((bm, d), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
+    )
+    kernel = functools.partial(_grouped_kernel, nf=nf, gated=gated,
+                               transposed=transposed, act=act, dtype=dtype)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(footprint + footprint // 4, _VMEM_SCOPED)),
+        interpret=interpret,
+        name="grouped_matmul_fwd",
+    )(expert, n_blocks, tokens, h, gates, *w_args, w_down)
+
+
+def grouped_blocks(starts, ends, bm, bound):
+    """The layout of `grouped_matmul_fwd`: expert e's pairs, the sorted
+    rows [starts[e], ends[e]), fill ceil(load / bm) blocks of bm rows from
+    a block of their own. -> (expert (bound,) int32: each block's expert,
+    the blocks past the real ones repeating the last real one's, so that a
+    step there fetches nothing; n (1,) int32: the real blocks; shift
+    (held,) int32: sorted row r of expert e is padded row r + shift[e]).
+    Plain XLA ops outside the kernel."""
+    import jax.numpy as jnp
+    held = starts.shape[0]
+    blocks = (ends - starts + bm - 1) // bm
+    block_ends = jnp.cumsum(blocks)
+    n = block_ends[-1]
+    b = jnp.minimum(jnp.arange(bound, dtype=jnp.int32), jnp.maximum(n - 1, 0))
+    expert = jnp.minimum(jnp.sum(block_ends[None, :] <= b[:, None], 1,
+                                 dtype=jnp.int32), held - 1)
+    return (expert, n.reshape(1).astype(jnp.int32),
+            ((block_ends - blocks) * bm - starts).astype(jnp.int32))
 
 
 def avg_pool2d_bwd(dy, h, w, ph, pw, interpret=False):

@@ -445,6 +445,85 @@ def test_delta_moe_decode_fits_the_chip_at_ling3f_widths(chip, monkeypatch):
     assert compiled.as_text().count("tpu_custom_call") >= 1
 
 
+# the expert cells' widths: d, F, experts routed over, k, held, the body
+EXPERT_WIDTHS = {"ling3f": (2560, 768, 512, 8, 64, "swiglu", 128),
+                 "glm52": (6144, 2048, 256, 8, 16, "swiglu", 32),
+                 "nemotron": (2688, 1856, 128, 6, 8, "relu2", 64)}
+
+
+@pytest.mark.parametrize("tokens", ["decode", "chunk"])
+@pytest.mark.parametrize("cell", sorted(EXPERT_WIDTHS))
+def test_grouped_matmul_lowers_once_at_the_expert_widths(chip, monkeypatch,
+                                                         cell, tokens):
+    """Three expert layers at a cell's published widths, a decode step's
+    tokens or a chunk of 1024 (the most tokens any expert program routes
+    in one call), through `routed_experts` on the chip's
+    branch: the kernel's blocks and VMEM fit (the compiler refuses what
+    does not), the lowered module holds the kernel ONCE (the jitted entry
+    that every layer calls: what a process traces and lowers, and what the
+    persistent cache's key hashes), and the compiled module a kernel a
+    layer, each under its own layer's `/experts` scope, where the scope
+    metrics find it. A hidden width with no tile of whole lane tiles
+    (nemotron's 1856) keeps the XLA loop: no kernel at all."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.inspect.hlo import scope_of
+    from incubator_mxnet_tpu.models import mamba_moe_decoder as mm
+    from incubator_mxnet_tpu.models import sparse_moe_decoder as sm
+    from incubator_mxnet_tpu.ops import fused
+    monkeypatch.setattr(fused, "_on_tpu", lambda: True)
+    d, F, E, k, held, body, T = EXPERT_WIDTHS[cell]
+    T = T if tokens == "decode" else 1024
+    up = mm.relu2_up if body == "relu2" else sm.swiglu_up
+    layers = 3
+
+    def program(h, idx, gates, ok, w_up, w_down):
+        for l in range(layers):
+            with jax.named_scope(f"layer{l}/experts"):
+                y, _, _ = sm.routed_experts(h, idx, gates, ok, w_up, w_down,
+                                            0, held, row0=l * held, up=up)
+            h = h + y
+        return h
+
+    up_shape = (layers * held, F, d) if up.transposed \
+        else (layers * held, d, 2 * F)
+    lowered = jax.jit(program).lower(
+        chip((T, d), jnp.bfloat16), chip((T, k), jnp.int32),
+        chip((T, k), jnp.float32), chip((T,), jnp.bool_),
+        chip(up_shape, jnp.bfloat16),
+        chip((layers * held, F, d), jnp.bfloat16))
+    kernel = F % 128 == 0
+    assert lowered.as_text().count("tpu_custom_call") == int(kernel)
+    text = lowered.compile().as_text()
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="([^"]*)"', text)
+    assert sorted(scope_of(n)[0] for n in names) == [
+        f"layer{l}/experts/grouped_matmul_fwd"
+        for l in range(layers)] * kernel
+
+
+def test_grouped_matmul_beyond_its_vmem_takes_the_loop(chip, monkeypatch):
+    """The kernel keeps a call's tokens and their sums whole in VMEM: at
+    glm52's widths 1024 tokens (the most any expert program routes, the
+    `chunk` case above) fit, 2048 do not, and such a call lowers to the XLA
+    loop with a fallback counted instead of failing to compile."""
+    import jax
+    import jax.numpy as jnp
+    from incubator_mxnet_tpu.models import sparse_moe_decoder as sm
+    from incubator_mxnet_tpu.ops import fused
+    monkeypatch.setattr(fused, "_on_tpu", lambda: True)
+    d, F, E, k, held, _, _ = EXPERT_WIDTHS["glm52"]
+    T = 2048
+    before = fused.fused_stats()["fallback_calls"]
+    lowered = jax.jit(lambda *a: sm.routed_experts(*a, 0, held, row0=0)).lower(
+        chip((T, d), jnp.bfloat16), chip((T, k), jnp.int32),
+        chip((T, k), jnp.float32), chip((T,), jnp.bool_),
+        chip((held, d, 2 * F), jnp.bfloat16), chip((held, F, d), jnp.bfloat16))
+    assert "tpu_custom_call" not in lowered.as_text()
+    assert fused.fused_stats()["fallback_calls"] == before + 1
+
+
 @pytest.mark.parametrize("program", ["micro", "prefill", "chunk_prefill"])
 def test_looped_programs_hold_the_stack_once_and_copy_no_leaf(
         chip, monkeypatch, program):

@@ -416,6 +416,7 @@ def test_engine_lanes_join_and_leave_without_a_retrace(tiny):
     assert 0.25 < moe["groups_kept_here"] / moe["tokens_routed"] < 0.75
     assert 0 < moe["pairs_held"] <= 2 * moe["groups_kept_here"]
     assert 0 < moe["experts_hit"] <= moe["experts_offered"]
+    assert moe["experts_hit"] <= moe["blocks"] <= moe["pairs_held"]
     # the cache's price: 3 states of 4 x 16 x 16 float32 and 3 tails of
     # 3 x 192 beside one latent leaf of 20 a position; 3 slots + garbage
     assert st["cache"]["state"]["bytes"] == 4 * 3 * (4096 + 3 * 192 * 4)

@@ -321,7 +321,8 @@ def test_fused_stats_counters_move():
                                            "paged_flat_traces",
                                            "paged_head_major_traces",
                                            "paged_shared_traces",
-                                           "paged_window_traces"}
+                                           "paged_window_traces",
+                                           "grouped_matmul_traces"}
 
 
 def test_set_interpret_toggle_not_served_stale_programs():

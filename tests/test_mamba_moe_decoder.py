@@ -377,8 +377,8 @@ def test_relu2_body_against_a_plain_loop():
     down = jnp.asarray(rng.standard_normal((n, F, d)), jnp.float32)
     ok = jnp.asarray(np.arange(T) != 4)
     # experts [2, 5) held, rows [1, 4) of the stack
-    y, loads = sm.routed_experts(h, idx - 1, gates, ok, up, down, 1, 3,
-                                 row0=1, up=mm.relu2_up)
+    y, loads, _ = sm.routed_experts(h, idx - 1, gates, ok, up, down, 1, 3,
+                                    row0=1, up=mm.relu2_up)
     want = np.zeros((T, d))
     count = np.zeros(3, int)
     for t in range(T):
@@ -403,7 +403,7 @@ def test_all_the_shares_and_the_shared_expert_once_are_the_uncut_layer():
     x = params["emb"][jnp.asarray(prompt_of(16, 8))]
     w = mm._weights(params, whole, 1)
     ok = jnp.ones((16,), bool)
-    uncut, counted = mm._moe(x, w, whole, 1, ok, jnp.zeros((4,), jnp.int32))
+    uncut, counted = mm._moe(x, w, whole, 1, ok, jnp.zeros((5,), jnp.int32))
     assert int(counted[0]) == 16 * whole.experts_per_token
     h = sm.rms_norm(x, w["norm"], whole.norm_eps)
     shared = mm._relu2_mlp(h, w["s_up"], w["s_down"])
@@ -412,7 +412,7 @@ def test_all_the_shares_and_the_shared_expert_once_are_the_uncut_layer():
         cut = make_config(routed_experts=32, held_first=first, held_count=2)
         # this share's experts are rows [first, first + 2) of the stack
         y, counted = mm._moe(x, dict(w, e_row0=first), cut, 1, ok,
-                             jnp.zeros((4,), jnp.int32))
+                             jnp.zeros((5,), jnp.int32))
         parts = parts + (y - x - shared)
         pairs += int(counted[0])
     assert pairs == 16 * whole.experts_per_token
@@ -489,6 +489,7 @@ def test_engine_lanes_join_and_leave_without_a_retrace(tiny):
     moe = st["moe"]
     assert 0 < moe["pairs_held"] and 0 < moe["experts_hit"] \
         <= moe["experts_offered"]
+    assert moe["experts_hit"] <= moe["blocks"] <= moe["pairs_held"]
     # the cache's price: 3 states of 8 x 4 x 8 float32 and 3 tails of
     # 3 x 64 beside 2 K and 2 V leaves of 16 a position; 3 slots + garbage
     assert st["cache"]["state"]["bytes"] == 4 * 3 * (1024 + 3 * 64 * 4)

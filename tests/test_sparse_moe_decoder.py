@@ -292,7 +292,7 @@ def test_grouped_expert_matmul_against_the_dense_sum_without_a_retrace():
         for name, idx in cases.items():
             gates = rng.random((T, k)).astype(np.float32)
             ok = rng.random(T) < 0.9
-            y, loads = fn(jnp.asarray(h), jnp.asarray(idx, jnp.int32),
+            y, loads, _ = fn(jnp.asarray(h), jnp.asarray(idx, jnp.int32),
                           jnp.asarray(gates), jnp.asarray(ok),
                           jnp.asarray(w_gu), jnp.asarray(w_dn), first, held)
             np.testing.assert_allclose(
@@ -419,6 +419,8 @@ def test_engine_lanes_join_and_leave_without_a_retrace(tiny):
     assert 0 < moe["experts_hit"] <= moe["experts_offered"]
     assert moe["experts_offered"] % (3 * 4) == 0
     assert moe["max_load_sum"] >= moe["experts_hit"] / 4
+    # a held expert's pairs fill at least one block, a block holds one
+    assert moe["experts_hit"] <= moe["blocks"] <= moe["pairs_held"]
     # the cache's price: 4 latent leaves of 20 and 2 index leaves of 8
     # float32 values a position, 3 slots and the garbage row
     assert st["cache"]["full"]["bytes"] == 4 * 48 * (4 * 20 + 2 * 8) * 4
